@@ -9,9 +9,8 @@ loss.
 The package namespace is lazy (PEP 562): importing ``jimm_tpu`` (or a pure
 host subpackage like ``jimm_tpu.aot``/``jimm_tpu.tune``/``jimm_tpu.obs``)
 does NOT import jax. The model/config names below resolve on first access,
-which is when the version floor is checked and the flax compat backfills
-(`jimm_tpu.utils.compat`) load — so ``jimm-tpu tune ls``/``aot ls``/``obs``
-stay usable on a box with no accelerator stack.
+which is when the version floor is checked — so ``jimm-tpu tune ls``/
+``aot ls``/``obs`` stay usable on a box with no accelerator stack.
 """
 
 from __future__ import annotations
@@ -62,8 +61,8 @@ def _check_versions() -> None:
             parts.append(int(digits))
         return tuple(parts)
 
-    floors = (("jax", jax.__version__, (0, 4, 35)),
-              ("flax", flax_version, (0, 10)))
+    floors = (("jax", jax.__version__, (0, 9)),
+              ("flax", flax_version, (0, 12)))
     for name, have, floor in floors:
         if parse(have) and parse(have) < floor:
             raise ImportError(
@@ -76,16 +75,10 @@ _ready = False
 
 
 def _prepare() -> None:
-    """Version floor + compat backfills, once, before any model/config
-    attribute resolves. `jimm_tpu.utils.compat` is imported for its side
-    effects: it backfills nnx module/class attributes (to_flat_state,
-    Variable.set_value, ...) that flax 0.10 lacks. Modules that use those
-    backfills also import it directly, so reaching them through a plain
-    submodule import (bypassing this hook) stays safe."""
+    """Version floor, once, before any model/config attribute resolves."""
     global _ready
     if not _ready:
         _check_versions()
-        importlib.import_module("jimm_tpu.utils.compat")
         _ready = True
 
 

@@ -19,6 +19,8 @@ store above (trace+lower), jax cache below (XLA optimize+codegen).
 
 from __future__ import annotations
 
+import os
+from pathlib import Path
 from typing import Any, Callable
 
 __all__ = ["enable_persistent_cache", "load_serve_forward",
@@ -137,21 +139,28 @@ def load_serve_forward(payload: bytes, model,
     return forward
 
 
-def enable_persistent_cache(cache_dir: str) -> bool:
-    """Point jax's persistent compilation cache at ``cache_dir`` so repeat
-    XLA compiles (train steps across restarts, deserialized serve modules)
-    are disk hits. Thresholds drop to zero: on the cold-start path even a
-    sub-second compile is worth persisting. Returns False (without raising)
-    on jax lines that lack the knobs — the caller keeps working uncached."""
+#: where the cache lives when the environment does not say: a fixed path
+#: inside the checkout (the path is part of the cache key, so a directory
+#: that moves never hits)
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
+
+
+def enable_persistent_cache(cache_dir: str | os.PathLike | None = None) -> str:
+    """Turn on jax's persistent compilation cache so repeat XLA compiles
+    (train steps across restarts, deserialized serve modules) are disk hits,
+    and return the directory in use.
+
+    ``JAX_COMPILATION_CACHE_DIR`` wins: when it is set, jax has already read
+    it and no directory is set here. Otherwise the cache goes to
+    ``cache_dir``, or to :data:`DEFAULT_CACHE_DIR`. Thresholds drop to zero:
+    on the cold-start path even a sub-second compile is worth persisting.
+    This is the one place that sets ``jax_compilation_cache_dir``."""
     import jax
-    try:
-        jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-    except (AttributeError, ValueError):
-        return False
-    for knob, value in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                        ("jax_persistent_cache_min_entry_size_bytes", 0)):
-        try:
-            jax.config.update(knob, value)
-        except (AttributeError, ValueError):
-            pass  # threshold knobs are best-effort; the dir is what matters
-    return True
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if from_env:
+        return from_env
+    chosen = str(cache_dir or DEFAULT_CACHE_DIR)
+    jax.config.update("jax_compilation_cache_dir", chosen)
+    return chosen

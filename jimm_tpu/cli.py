@@ -29,6 +29,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import sys
+import types
 from typing import Any
 
 
@@ -331,6 +332,15 @@ def _batch_fingerprint(batch) -> int:
 
 
 def cmd_train(args: argparse.Namespace) -> int:
+    train(args)
+    return 0
+
+
+def train(args: argparse.Namespace) -> Any:
+    """The ``train`` subcommand. Returns the finished run's live objects
+    (``model``, ``optimizer``, ``step_fn``, ``mesh``, ``rules``, last
+    ``batch``) for a caller that inspects where the state landed
+    (``chip_smoke.py``)."""
     _configure_backend(args)
     _configure_journal(args)
     if args.compilation_cache_dir:
@@ -507,10 +517,14 @@ def cmd_train(args: argparse.Namespace) -> int:
     moment_dtype = ({"f32": "float32", "bf16": "bfloat16"}[args.moment_dtype]
                     if args.moment_dtype
                     else ("bfloat16" if args.bf16_momentum else None))
-    optimizer = make_optimizer(model, OptimizerConfig(
-        learning_rate=args.lr, weight_decay=args.weight_decay,
-        warmup_steps=args.warmup_steps, total_steps=args.steps,
-        moment_dtype=moment_dtype))
+    # under the mesh: the optimizer's scalar counters are then born with the
+    # mesh in their type, as the step returns them — created outside it they
+    # change type after step 0 and the whole step compiles a second time
+    with use_sharding(mesh, rules):
+        optimizer = make_optimizer(model, OptimizerConfig(
+            learning_rate=args.lr, weight_decay=args.weight_decay,
+            warmup_steps=args.warmup_steps, total_steps=args.steps,
+            moment_dtype=moment_dtype))
 
     import jax
 
@@ -730,7 +744,7 @@ def cmd_train(args: argparse.Namespace) -> int:
     # whole run when it is shorter than that
     profile_start = min(start_step + 2, max(args.steps - 1, start_step))
     profile_stop = min(start_step + 4, args.steps - 1)
-    dt = None
+    dt = batch = None
     try:
         with use_sharding(mesh, rules):
             for step in range(start_step, args.steps):
@@ -807,17 +821,23 @@ def cmd_train(args: argparse.Namespace) -> int:
     import json as _json
 
     from jimm_tpu.train.metrics import mfu as _mfu, train_step_flops
-    achieved_mfu = (None if dt is None
-                    else _mfu(train_step_flops(cfg, args.batch_size), dt))
+    # an MFU exists only against a chip's published peak: no TPU, no MFU
+    achieved_mfu = None
+    if dt is not None and jax.default_backend() == "tpu":
+        achieved_mfu = _mfu(
+            train_step_flops(cfg, args.batch_size), dt,
+            n_devices=mesh.devices.size if mesh is not None else 1)
     # precision + moment_dtype ride the goodput line so measurement
-    # consumers (lowp_train_smoke, window_report) can attribute MFU/img/s
-    # deltas to the policy that produced them
+    # consumers (lowp_train_smoke) can attribute MFU/img/s deltas to the
+    # policy that produced them
     print("goodput: " + _json.dumps({
         **acct.report(mfu=achieved_mfu),
         "precision": precision,
         "moment_dtype": moment_dtype or "param",
     }))
-    return 0
+    return types.SimpleNamespace(model=model, optimizer=optimizer,
+                                 step_fn=step_fn, mesh=mesh, rules=rules,
+                                 batch=batch)
 
 
 def _argv_flag_value(argv: list[str], flag: str, default):
@@ -1952,7 +1972,8 @@ def build_parser() -> argparse.ArgumentParser:
     sp.add_argument("--compilation-cache-dir", default=None,
                     help="persist XLA compiles to this dir (jax "
                          "compilation cache) so restarted runs skip the "
-                         "train-step compile")
+                         "train-step compile; JAX_COMPILATION_CACHE_DIR, "
+                         "when set, wins over this flag")
     sp.add_argument("--mesh", default=None,
                     help='e.g. "data=4,model=2" or "data=2,model=1,seq=4" '
                          '(a seq axis turns on sequence-parallel attention '

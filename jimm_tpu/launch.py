@@ -12,6 +12,12 @@ nothing spawns those processes for you:
 - **manual multi-node**: run the same command on every node with its
   ``--node-rank``; node 0's address is the coordinator.
 
+A TPU chip belongs to one process at a time, and one process drives every
+chip of its host. So ``--nproc`` above 1 is for virtual CPU clusters only:
+on a TPU host the second local process would fail or hang waiting for chips
+the first one holds, and ``--nproc > 1`` with ``--platform tpu`` is refused.
+The launcher itself never touches JAX, so its children are free to.
+
 Usage::
 
     # 2 local processes x 2 virtual CPU devices each (4-device cluster)
@@ -95,6 +101,10 @@ def main(argv: list[str] | None = None) -> int:
         p.error("--coordinator host:port is required with --nnodes > 1")
     if args.nproc < 1:
         p.error("--nproc must be >= 1")
+    if args.nproc > 1 and args.platform == "tpu":
+        p.error("--nproc > 1 with --platform tpu: a TPU host's chips belong "
+                "to one process; run one process per host (--nnodes) and "
+                "let it drive all local chips")
     if args.restarts < 0:
         p.error("--restarts must be >= 0")
     world = args.nnodes * args.nproc

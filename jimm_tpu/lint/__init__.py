@@ -1,6 +1,6 @@
 """``jimm_tpu.lint`` — TPU-correctness static analyzer.
 
-Layer 1 (always on) is pure-``ast`` rules JL001–JL016 and JL021 over
+Layer 1 (always on) is pure-``ast`` rules JL002–JL016 and JL021 over
 the source tree, plus the JL020 suppression-hygiene meta-rule. ``--concurrency``
 builds a project-wide symbol table and call graph (``lint.graph``) and
 runs the lock-discipline race detector (JL017–JL019), the tiered-

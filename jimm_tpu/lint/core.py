@@ -60,7 +60,7 @@ class Directive:
 def parse_directives(source: str) -> list[Directive]:
     """Every suppression directive in ``source``, in file order.
 
-    ``# jaxlint: disable=JL001`` (comma-separate for several rules) on a code
+    ``# jaxlint: disable=JL004`` (comma-separate for several rules) on a code
     line suppresses those rules on that line; on a standalone comment line it
     suppresses them on the next line. ``disable=all`` suppresses every rule.
     Comments are found with ``tokenize`` so strings containing the marker

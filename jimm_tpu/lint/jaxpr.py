@@ -45,7 +45,7 @@ LOWP_DTYPES = frozenset({"int8", "float8_e4m3fn", "float8_e5m2", "bfloat16"})
 
 #: cross-device collective primitives JLT106 tracks
 COLLECTIVE_PRIMITIVES = frozenset({
-    "psum", "psum2", "all_gather", "reduce_scatter", "ppermute",
+    "psum", "psum_invariant", "all_gather", "reduce_scatter", "ppermute",
     "all_to_all", "pmax", "pmin", "axis_index"})
 
 #: a const bigger than this (bytes) is "baked", not a tolerable epsilon
@@ -102,11 +102,6 @@ def _entry_data_parallel_psum():
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
 
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:  # newer jax: promoted out of experimental
-        from jax.sharding import shard_map  # type: ignore[attr-defined]
-
     devices = jax.devices()
     mesh = Mesh(np.array(devices).reshape(len(devices)), ("data",))
 
@@ -115,8 +110,8 @@ def _entry_data_parallel_psum():
             local = jnp.sum(xs * xs)
             return jax.lax.psum(local, "data")
 
-        return shard_map(shard_loss, mesh=mesh, in_specs=P("data"),
-                         out_specs=P())(x)
+        return jax.shard_map(shard_loss, mesh=mesh, in_specs=P("data"),
+                             out_specs=P())(x)
 
     return mean_loss, (jnp.zeros((len(devices) * 2, 4), jnp.float32),)
 
@@ -258,10 +253,10 @@ def run_jaxpr_checks(entry_points: dict | None = None,
     """Run JLT104–JLT106 over every entry point (default: the registered
     set, with goldens from :data:`GOLDENS_PATH`). Exceptions become JLT000
     findings — a broken trace is a finding, not a linter crash."""
-    from jimm_tpu.utils.env import set_host_device_count
+    import jax
 
-    try:  # must land before the XLA backend initializes; no-op after
-        set_host_device_count(8)
+    try:  # must land before the XLA backend initializes; refused after
+        jax.config.update("jax_num_cpu_devices", 8)
     except RuntimeError:
         pass
 
@@ -285,10 +280,8 @@ def update_goldens(path=None) -> dict:
     the written mapping."""
     import jax
 
-    from jimm_tpu.utils.env import set_host_device_count
-
     try:
-        set_host_device_count(8)
+        jax.config.update("jax_num_cpu_devices", 8)
     except RuntimeError:
         pass
     out: dict[str, dict] = {}

@@ -2,8 +2,6 @@
 
 Rule catalog (see ``docs/static_analysis.md`` for the narrative version):
 
-- **JL001** version-gated ``jax.config.update`` key used without a guard
-  (the exact bug that bricked the seed suite's collection on JAX 0.4.x).
 - **JL002** host-device sync inside jitted code: ``.item()``,
   ``float()``/``int()``/``bool()``/``np.asarray()`` on traced values, and
   Python ``if`` on a traced value (shape/dtype/``is None`` tests are static
@@ -141,14 +139,6 @@ import ast
 
 from jimm_tpu.lint.core import ERROR, WARNING, Finding
 
-#: jax.config keys that only exist on some JAX lines — using one unguarded
-#: makes the import/startup path crash on the other lines. Extend this table
-#: as new gated keys enter the codebase.
-VERSION_GATED_CONFIG_KEYS: dict[str, str] = {
-    "jax_num_cpu_devices": "JAX >= 0.5 (0.4.x: XLA_FLAGS "
-                           "--xla_force_host_platform_device_count)",
-}
-
 #: canonical physical mesh-axis vocabulary. Mirrors
 #: ``jimm_tpu.parallel.mesh.MESH_AXES`` — duplicated here so layer 1 never
 #: imports JAX; ``tests/test_lint.py`` asserts the two stay in sync.
@@ -235,56 +225,6 @@ def _jitted_functions(tree: ast.AST):
                 if jd is not None:
                     yield node, jd
                     break
-
-
-# ---------------------------------------------------------------------------
-# JL001 — version-gated config key without a guard
-# ---------------------------------------------------------------------------
-
-def _is_guarded(node: ast.AST) -> bool:
-    """True when an ancestor try/except catches AttributeError (or broader),
-    or an ancestor ``if`` gates on ``hasattr``/``__version__``."""
-    cur: ast.AST | None = node
-    while cur is not None:
-        parent = _parent(cur)
-        if isinstance(parent, ast.Try) and cur in parent.body:
-            for handler in parent.handlers:
-                if handler.type is None:
-                    return True
-                names = [_dotted(t) for t in (
-                    handler.type.elts if isinstance(handler.type, ast.Tuple)
-                    else [handler.type])]
-                if any(n in ("AttributeError", "Exception") for n in names):
-                    return True
-        if isinstance(parent, ast.If):
-            test_src = ast.dump(parent.test)
-            if "hasattr" in test_src or "__version__" in test_src:
-                return True
-        cur = parent
-    return False
-
-
-def check_version_gated_config(tree: ast.AST, path: str) -> list[Finding]:
-    findings = []
-    for node in ast.walk(tree):
-        if not isinstance(node, ast.Call):
-            continue
-        fname = _dotted(node.func)
-        if fname is None or not fname.endswith("config.update"):
-            continue
-        if not node.args or not isinstance(node.args[0], ast.Constant):
-            continue
-        key = node.args[0].value
-        if key not in VERSION_GATED_CONFIG_KEYS:
-            continue
-        if _is_guarded(node):
-            continue
-        findings.append(Finding(
-            "JL001", ERROR, path, node.lineno,
-            f"jax.config.update({key!r}, ...) is version-gated "
-            f"({VERSION_GATED_CONFIG_KEYS[key]}) but has no "
-            f"try/except AttributeError or hasattr guard"))
-    return findings
 
 
 # ---------------------------------------------------------------------------
@@ -1563,7 +1503,6 @@ def run_all(tree: ast.AST, path: str,
             vmem_budget: int | None = None) -> list[Finding]:
     _annotate_parents(tree)
     findings: list[Finding] = []
-    findings += check_version_gated_config(tree, path)
     findings += check_host_sync_in_jit(tree, path)
     findings += check_train_step_donation(tree, path)
     findings += check_partition_spec_axes(tree, path)

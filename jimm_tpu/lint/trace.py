@@ -79,15 +79,13 @@ def _vit_step_body(model, optimizer, images, labels):
     import optax
     from flax import nnx
 
-    from jimm_tpu.utils.compat import optimizer_update
-
     def loss_fn(model):
         logits = model(images)
         return optax.softmax_cross_entropy_with_integer_labels(
             logits, labels).mean()
 
     loss, grads = nnx.value_and_grad(loss_fn)(model)
-    optimizer_update(optimizer, model, grads)
+    optimizer.update(model, grads)
     return loss
 
 
@@ -95,13 +93,12 @@ def _siglip_step_body(model, optimizer, images, text):
     from flax import nnx
 
     from jimm_tpu.train import contrastive_loss_fn
-    from jimm_tpu.utils.compat import optimizer_update
 
     def loss_fn(model):
         return contrastive_loss_fn(model, images, text, kind="siglip")
 
     loss, grads = nnx.value_and_grad(loss_fn)(model)
-    optimizer_update(optimizer, model, grads)
+    optimizer.update(model, grads)
     return loss
 
 
@@ -276,11 +273,11 @@ def run_trace_checks() -> list[Finding]:
     """Run every trace check over every registered entry point. Exceptions
     inside a check become JLT000 error findings — a broken lowering path is
     itself a finding, not a linter crash."""
-    from jimm_tpu.utils.env import set_host_device_count
+    import jax
 
-    # must land before the XLA backend initializes; harmless no-op after
+    # must land before the XLA backend initializes; refused after
     try:
-        set_host_device_count(8)
+        jax.config.update("jax_num_cpu_devices", 8)
     except RuntimeError:
         pass
 

@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 from flax import nnx
 
-import jimm_tpu.utils.compat  # noqa: F401  (nnx backfills: to_flat_state, set_value)
 from jax.ad_checkpoint import checkpoint_name
 
 from jimm_tpu.configs import TransformerConfig
@@ -177,11 +176,8 @@ class Transformer(nnx.Module):
             return Block(cfg, rngs, dtype=dtype, param_dtype=param_dtype)
 
         # the clone keeps the blocks' captured RngState from aliasing the
-        # caller's rngs (flax 0.10 vmap broadcasts it by reference), so the
-        # stacking fixup below cannot corrupt sibling modules' streams
+        # caller's rngs
         self.blocks = create_block(nnx.clone(rngs))
-        from jimm_tpu.utils.compat import ensure_stacked_rng_state
-        ensure_stacked_rng_state(self.blocks, cfg.depth)
         if cfg.pipeline and cfg.pp_virtual > 1 and cfg.pp_stages:
             # circular placement is baked into STORAGE order once at
             # construction (stored row j = canonical layer order[j]), so the
@@ -264,10 +260,8 @@ class Transformer(nnx.Module):
 
         from jimm_tpu.configs import validate_pipeline
 
-        from jimm_tpu.utils.compat import get_abstract_mesh
-        mesh = get_abstract_mesh()
-        n_stage = (dict(mesh.shape).get("stage", 0)
-                   if mesh is not None else 0)
+        mesh = jax.sharding.get_abstract_mesh()
+        n_stage = dict(mesh.shape).get("stage", 0)
         # shared checks (stage axis present, depth divisibility, pp_stages
         # match) — identical function and messages as the parse-time path
         validate_pipeline(self.cfg, n_stages=n_stage)
@@ -296,10 +290,8 @@ class Transformer(nnx.Module):
             # so masks differ across training steps too.
             from jimm_tpu.parallel.pipeline import num_ticks
             t_total = num_ticks(self.cfg.pp_microbatches, n_stage, n_virtual)
-            # .value, not [...]: flax 0.10 __setitem__ writes through to the
-            # (immutable) jax array instead of replacing the variable's value
-            tick_offset = self.pp_tick.value
-            self.pp_tick.value = tick_offset + jnp.uint32(t_total)
+            tick_offset = self.pp_tick.get_value()
+            self.pp_tick.set_value(tick_offset + jnp.uint32(t_total))
 
         def stage_apply(state_chunk, xm, tick):
             # plain lax.scan + per-layer merge (nnx.scan can't consume
@@ -328,12 +320,7 @@ class Transformer(nnx.Module):
 
 
 def _is_rng_count(leaf) -> bool:
-    # flat-state leaves are Variables on flax >= 0.12 but VariableStates
-    # (carrying the Variable class in .type) on 0.10
-    if isinstance(leaf, nnx.RngCount):
-        return True
-    t = getattr(leaf, "type", None)
-    return isinstance(t, type) and issubclass(t, nnx.RngCount)
+    return isinstance(leaf, nnx.RngCount)
 
 
 def _set_rng_counts(state, value) -> nnx.State:

@@ -2,15 +2,12 @@
 ``jimm-tpu obs regress``.
 
 MEASUREMENTS.jsonl is an append-only trajectory: every bench/smoke run adds
-rows, including **fallback** rows recorded when the TPU backend was
-unreachable and the harness measured a CPU stand-in (BENCH_r01–r05 were
-exactly this, silently). The baseline store makes the trajectory
-gate-able:
+rows, including **fallback** rows that a smoke recorded on the CPU. The
+baseline store makes the trajectory gate-able:
 
 - :func:`is_fallback` is the single source of truth for "this row is not a
   real measurement" (the ``fallback: true`` stamp, plus the legacy
-  ``"(cpu smoke)"`` metric-name convention) — ``scripts/window_report.py``
-  imports it instead of re-deriving the heuristic.
+  ``"(cpu smoke)"`` metric-name convention).
 - :class:`BaselineStore` holds one adopted reference value per
   ``(workload, backend, preset, metric)`` key in a small JSON file
   (``BASELINES.json``), written only by an explicit ``adopt``.

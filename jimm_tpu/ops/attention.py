@@ -75,16 +75,15 @@ def _ambient_seq_axis() -> tuple[str, int] | None:
     gate that lets ``impl="auto"`` route to the sequence-parallel schemes
     exactly when the program runs under a seq-sharded mesh — single-chip
     programs never pay for the check beyond a mesh lookup."""
-    from jimm_tpu.parallel.sharding import current_rules
-    from jimm_tpu.utils.compat import get_abstract_mesh, manual_axis_names
+    from jimm_tpu.parallel.sharding import current_rules, manual_axis_names
     rules = current_rules()
     axis = (rules.seq if rules is not None and rules.seq else "seq")
     if not isinstance(axis, str):
         return None
-    mesh = get_abstract_mesh()
-    if mesh is None or getattr(mesh, "empty", True):
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return None
-    size = int(dict(getattr(mesh, "shape", {}) or {}).get(axis, 1))
+    size = int(dict(mesh.shape).get(axis, 1))
     if size <= 1 or axis in manual_axis_names(mesh):
         return None
     return axis, size

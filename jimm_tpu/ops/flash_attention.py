@@ -35,9 +35,10 @@ whose grid runs batch innermost to accumulate dbias across samples.
 Numerical contract: softmax variants match
 `jimm_tpu.ops.attention.reference_attention` (fp32 softmax einsum) to
 ~1e-5 in f32; the sigmoid variant matches
-`reference_sigmoid_attention`. Tested in interpret mode on CPU and
-compiled on TPU (`tests/test_flash_variants.py`,
-`scripts/flash_compiled_check.py`).
+`reference_sigmoid_attention`. Tested in interpret mode on CPU
+(`tests/test_flash_variants.py`), compiled for a described v5e topology
+(`tests/test_tpu_compile.py`) and run compiled on the chip
+(`chip_smoke.py`).
 
 Masking uses a large negative constant (not -inf) so padded/fully-masked
 rows degrade to garbage-but-finite values — no NaNs reach the gradient.
@@ -450,12 +451,10 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-from jimm_tpu.utils.compat import pallas_tpu_compiler_params
-
-_SEMANTICS = pallas_tpu_compiler_params(
+_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
 #: the dbias grid: batch innermost so the bias tile accumulates in scratch
-_SEMANTICS4 = pallas_tpu_compiler_params(
+_SEMANTICS4 = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
 
 

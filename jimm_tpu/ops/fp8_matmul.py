@@ -38,8 +38,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from jimm_tpu.utils.compat import pallas_tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_M = 256
 DEFAULT_BLOCK_N = 256
@@ -51,7 +50,7 @@ _FP8_SUBLANES = 32
 E4M3_MAX = 448.0
 E5M2_MAX = 57344.0
 
-_SEMANTICS = pallas_tpu_compiler_params(
+_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel"))
 
 #: VMEM budget for one grid cell's resident tiles (mirrors the int8 /
@@ -93,7 +92,9 @@ def _matmul_kernel(aq_ref, bq_ref, s_ref, b_ref, o_ref):
     # the combined scale arrives lane-broadcast (1, 128); every lane holds
     # the same scalar
     y = _dequant(acc, s_ref[0, 0])
-    o_ref[...] = (y + b_ref[...][None, :]).astype(o_ref.dtype)
+    # bias arrives as a (1, block_n) row: Mosaic refuses a rank-1 block
+    # whose extent is not XLA's 1-D tile (1024 for f32)
+    o_ref[...] = (y + b_ref[...]).astype(o_ref.dtype)
 
 
 def _resolve_blocks(a_shape, b_shape, dtypes, block_m, block_n):
@@ -134,8 +135,9 @@ def _fp8_gemm(a_q: jax.Array, b_q: jax.Array, scale: jax.Array,
     mp, np_, kp = _ceil_to(m, bm), _ceil_to(n, bn), _ceil_to(k, _LANES)
     s = jnp.broadcast_to(
         jnp.asarray(scale, jnp.float32).reshape(1, 1), (1, _LANES))
-    b = (jnp.zeros((np_,), jnp.float32) if bias is None
-         else jnp.pad(bias.astype(jnp.float32), ((0, np_ - bias.shape[0]),)))
+    b = (jnp.zeros((1, np_), jnp.float32) if bias is None
+         else jnp.pad(bias.astype(jnp.float32),
+                      ((0, np_ - bias.shape[0]),))[None, :])
     # zero padding contributes zero products to the fp8 dot
     out = pl.pallas_call(
         _matmul_kernel,
@@ -144,7 +146,7 @@ def _fp8_gemm(a_q: jax.Array, b_q: jax.Array, scale: jax.Array,
             pl.BlockSpec((bm, kp), lambda i, j: (i, 0)),
             pl.BlockSpec((kp, bn), lambda i, j: (0, j)),
             pl.BlockSpec((1, _LANES), lambda i, j: (0, 0)),
-            pl.BlockSpec((bn,), lambda i, j: (j,)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),

@@ -31,8 +31,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from jimm_tpu.utils.compat import pallas_tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_M = 256
 DEFAULT_BLOCK_N = 256
@@ -40,7 +39,7 @@ _LANES = 128
 #: int8 Mosaic tiles are (32, 128) — row blocks align to 32 sublanes
 _INT8_SUBLANES = 32
 
-_SEMANTICS = pallas_tpu_compiler_params(
+_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel"))
 
 #: VMEM budget for one grid cell's resident tiles (mirrors the flash /
@@ -58,8 +57,8 @@ def _ceil_to(x: int, m: int) -> int:
 
 def _per_cell_vmem_bytes(block_m: int, block_n: int, k: int) -> int:
     """Resident working set of one (block_m, block_n) grid cell: the int8
-    x/w tiles at the 128-padded K, the lane-broadcast row scales, the 1-D
-    column scale + bias, and the int32 accumulator / f32 epilogue / out
+    x/w tiles at the 128-padded K, the lane-broadcast row scales, the
+    column scale + bias rows, and the int32 accumulator / f32 epilogue / out
     tiles. Mirrored jax-free in ``tune.space.int8_matmul_vmem_bytes``
     (sync-tested)."""
     kp = _ceil_to(k, _LANES)
@@ -72,9 +71,10 @@ def _per_cell_vmem_bytes(block_m: int, block_n: int, k: int) -> int:
 
 def _dequant(acc: jax.Array, x_scale: jax.Array,
              w_scale: jax.Array) -> jax.Array:
-    """int32 accumulator -> f32 via the symmetric per-row / per-column
-    scales. The ONE sanctioned f32 upcast in this kernel (JL012)."""
-    return acc.astype(jnp.float32) * x_scale[:, None] * w_scale[None, :]
+    """int32 accumulator -> f32 via the symmetric per-row ``(bm,)`` /
+    per-column ``(1, bn)`` scales. The ONE sanctioned f32 upcast in this
+    kernel (JL012)."""
+    return acc.astype(jnp.float32) * x_scale[:, None] * w_scale
 
 
 def _apply_activation(y: jax.Array, activation: str | None) -> jax.Array:
@@ -96,8 +96,10 @@ def _matmul_kernel(xq_ref, xs_ref, wq_ref, ws_ref, b_ref, o_ref, *,
     # x_scale arrives lane-broadcast (block_m, 128) like the flash m/l
     # stats; max is an exact collapse over equal lanes
     x_scale = jnp.max(xs_ref[...], axis=1)
+    # w_scale and bias arrive as (1, block_n) rows: Mosaic refuses a rank-1
+    # block whose extent is not XLA's 1-D tile (1024 for f32)
     y = _dequant(acc, x_scale, ws_ref[...])
-    y = y + b_ref[...][None, :]
+    y = y + b_ref[...]
     o_ref[...] = _apply_activation(y, activation).astype(o_ref.dtype)
 
 
@@ -130,13 +132,13 @@ def _dequant_operands(x_scale: jax.Array, w_scale: jax.Array,
                       bias: jax.Array | None, mp: int, np_: int):
     """Pad/normalize the f32 dequant-side operands (scales + bias) to the
     grid extents: row scales lane-broadcast to ``(mp, 128)``, column scales
-    and bias to ``(np_,)``. Zero-padded scale rows dequantize padded output
-    rows to exact zeros, sliced off by the wrapper."""
+    and bias as ``(1, np_)`` rows. Zero-padded scale rows dequantize padded
+    output rows to exact zeros, sliced off by the wrapper."""
     xs = jnp.broadcast_to(
         _pad1(x_scale.astype(jnp.float32), mp)[:, None], (mp, _LANES))
-    ws = _pad1(w_scale.astype(jnp.float32), np_)
-    b = (jnp.zeros((np_,), jnp.float32) if bias is None
-         else _pad1(bias.astype(jnp.float32), np_))
+    ws = _pad1(w_scale.astype(jnp.float32), np_)[None, :]
+    b = (jnp.zeros((1, np_), jnp.float32) if bias is None
+         else _pad1(bias.astype(jnp.float32), np_)[None, :])
     return xs, ws, b
 
 
@@ -177,8 +179,8 @@ def int8_matmul(x_q: jax.Array, x_scale: jax.Array, w_q: jax.Array,
             pl.BlockSpec((bm, kp), lambda i, j: (i, 0)),
             pl.BlockSpec((bm, _LANES), lambda i, j: (i, 0)),
             pl.BlockSpec((kp, bn), lambda i, j: (0, j)),
-            pl.BlockSpec((bn,), lambda i, j: (j,)),
-            pl.BlockSpec((bn,), lambda i, j: (j,)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),

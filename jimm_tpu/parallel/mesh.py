@@ -163,10 +163,9 @@ def resolve_mesh_axis(mesh, axis_name: str) -> dict:
     None, on the ambient mesh installed by ``use_sharding``/``jax.set_mesh``)
     and return the mesh shape dict. Shared by the sequence-parallel
     attention schemes (`ring_attention`, `ulysses_attention`)."""
-    from jimm_tpu.utils.compat import get_abstract_mesh
     if mesh is None:
-        ambient = get_abstract_mesh()
-        if ambient is None or ambient.empty:
+        ambient = jax.sharding.get_abstract_mesh()
+        if ambient.empty:
             raise ValueError("no mesh given and no ambient mesh installed "
                              "(use use_sharding(mesh, ...))")
         if axis_name not in ambient.shape:

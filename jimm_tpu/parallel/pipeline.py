@@ -41,8 +41,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from jimm_tpu.utils.compat import axis_size, shard_map
-
 
 def circular_layer_order(n_layers: int, n_stages: int, n_virtual: int
                          ) -> np.ndarray:
@@ -98,7 +96,7 @@ def pipeline_forward(stage_apply: Callable, stage_params, x: jax.Array, *,
 
     def local(params_local, x_local):
         stage = jax.lax.axis_index(axis_name)
-        S = axis_size(axis_name)
+        S = jax.lax.axis_size(axis_name)
         b = x_local.shape[0]
         check_pp_schedule(M, V, n_stages=S, local_batch=b)
         micro = x_local.reshape(M, b // M, *x_local.shape[1:])
@@ -145,8 +143,8 @@ def pipeline_forward(stage_apply: Callable, stage_params, x: jax.Array, *,
         return result.reshape(b, *x_local.shape[1:])
 
     kwargs = {} if mesh is None else {"mesh": mesh}
-    fn = shard_map(local,
-                   in_specs=(P(axis_name), x_spec),
-                   out_specs=x_spec,
-                   check_vma=False, **kwargs)
+    fn = jax.shard_map(local,
+                       in_specs=(P(axis_name), x_spec),
+                       out_specs=x_spec,
+                       check_vma=False, **kwargs)
     return fn(stage_params, x)

@@ -20,7 +20,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jimm_tpu.utils.compat import axis_size, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 NEG_INF = -1e30
@@ -112,7 +111,7 @@ def _ring_local_flash(q, k, v, *, axis_name: str, causal: bool = False,
     ppermute barrier no longer waits on the last-chunk straggler."""
     from jimm_tpu.ops.flash_attention import flash_attention_lse
 
-    n_dev = axis_size(axis_name)
+    n_dev = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, sq, n, d = q.shape
     perm = [(i, (i + 1) % n_dev) for i in range(n_dev)]
@@ -219,7 +218,7 @@ def _ring_zigzag_causal_flash(q, k, v, merge, *, idx, n_dev, axis_name, perm):
 
 def _ring_local(q, k, v, *, axis_name: str, causal: bool,
                 zigzag: bool = False):
-    n_dev = axis_size(axis_name)
+    n_dev = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, sq, n, d = q.shape
     sk = k.shape[1]
@@ -308,7 +307,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     else:
         raise ValueError(f"unknown ring attention impl {impl!r}")
     kwargs = {} if mesh is None else {"mesh": mesh}  # None -> ambient mesh
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         in_specs=(P(None, axis_name), P(None, axis_name), P(None, axis_name)),
         out_specs=P(None, axis_name),

@@ -58,7 +58,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from jimm_tpu.utils.compat import axis_size, shard_map
 
 NEG_INF = -1e30
 
@@ -154,7 +153,7 @@ def _ring_fwd_local(q, k, v, maskrows, axis_name, kind, causal, sm_scale,
                     logit_bias, impl, blocks):
     """Per-device forward: returns ``(o, lse)`` (lse None for sigmoid).
     ``maskrows`` is the local additive f32 ``(B, Sk/p)`` chunk or None."""
-    n_dev = axis_size(axis_name)
+    n_dev = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, sq, n, d = q.shape
     perm = [(i, (i + 1) % n_dev) for i in range(n_dev)]
@@ -279,7 +278,7 @@ def _ring_bwd_local(q, k, v, maskrows, o, lse, do, axis_name, kind, causal,
     """Per-device backward. Recomputes each hop's probabilities against the
     GLOBAL (o, lse); (k, v, mask, dk_acc, dv_acc) rotate together and a
     final ppermute returns the accumulators to their owners."""
-    n_dev = axis_size(axis_name)
+    n_dev = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, sq, n, d = q.shape
     perm = [(i, (i + 1) % n_dev) for i in range(n_dev)]
@@ -494,10 +493,10 @@ def ring_attention_sp(q: jax.Array, k: jax.Array, v: jax.Array, *,
                           lb, impl, blocks)
 
     kwargs = {} if mesh is None else {"mesh": mesh}
-    fn = shard_map(local,
-                   in_specs=(P(None, axis_name),) * 4,
-                   out_specs=P(None, axis_name),
-                   check_vma=False, **kwargs)
+    fn = jax.shard_map(local,
+                       in_specs=(P(None, axis_name),) * 4,
+                       out_specs=P(None, axis_name),
+                       check_vma=False, **kwargs)
     return fn(q, k, v, maskrows)
 
 

@@ -35,17 +35,12 @@ from typing import Any, Callable, Sequence
 import jax
 import numpy as np
 from flax import nnx
+from flax.core import spmd as _core_spmd
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from jimm_tpu.utils.compat import (core_spmd as _core_spmd,
-                                   get_abstract_mesh, manual_axis_names,
-                                   set_mesh)
-
 # Parameters are annotated with logical names; we never want flax to eagerly
-# reshard at creation time (we control placement explicitly). flax < 0.11
-# has no eager sharding, which matches the disabled behavior.
-if hasattr(nnx, "use_eager_sharding"):
-    nnx.use_eager_sharding(False)
+# reshard at creation time (we control placement explicitly).
+nnx.use_eager_sharding(False)
 
 MeshAxis = str | tuple[str, ...] | None
 
@@ -159,7 +154,7 @@ def use_sharding(mesh: Mesh | None, rules: ShardingRules | str | None = None):
         _core_spmd.set_logical_axis_rules(rules.to_flax_rules())
     try:
         if mesh is not None:
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 yield
         else:
             yield
@@ -172,6 +167,14 @@ def current_rules() -> ShardingRules | None:
     if not flat:
         return None
     return ShardingRules(**dict(flat))
+
+
+def manual_axis_names(mesh: Any) -> frozenset[str]:
+    """Axes of ``mesh`` in Manual mode, i.e. already mapped by an enclosing
+    ``shard_map``."""
+    manual = jax.sharding.AxisType.Manual
+    return frozenset(n for n, t in zip(mesh.axis_names, mesh.axis_types)
+                     if t == manual)
 
 
 def logical(init: Callable, *names: str | None) -> Callable:
@@ -187,8 +190,8 @@ def logical_constraint(x: jax.Array, *names: str | None) -> jax.Array:
     partially-manual mesh (``shard_map(..., axis_names=...)`` subsets) are
     preserved rather than dropped wholesale."""
     rules = current_rules()
-    mesh = get_abstract_mesh()
-    if rules is None or mesh is None or mesh.empty or not mesh.shape_tuple:
+    mesh = jax.sharding.get_abstract_mesh()
+    if rules is None or mesh.empty or not mesh.shape_tuple:
         return x
     spec = rules.spec(*names)
     manual = manual_axis_names(mesh)
@@ -224,36 +227,6 @@ def prune_spec(spec: P, shape: tuple[int, ...], mesh: Mesh) -> P:
     return P(*out)
 
 
-_LOGICAL_AXES = tuple(f.name for f in dataclasses.fields(ShardingRules))
-
-
-def resolve_logical_spec(spec: P, rules: ShardingRules) -> P:
-    """Translate logical axis names in ``spec`` to physical mesh axes through
-    ``rules``. flax 0.10's ``nnx.get_partition_spec`` returns the raw logical
-    metadata names (newer flax resolves them itself, making this a no-op —
-    physical axis names are not in the logical vocabulary). Nested tuples
-    flatten; axes that resolve to nothing become ``None`` (replicated)."""
-    def resolve_one(a) -> tuple:
-        if a is None:
-            return ()
-        if isinstance(a, tuple):
-            out: tuple = ()
-            for el in a:
-                out += resolve_one(el)
-            return out
-        if a in _LOGICAL_AXES:
-            target = getattr(rules, a)
-            if target != a:  # e.g. rules.seq == "seq": already physical
-                return resolve_one(target)
-        return (a,)
-
-    out = []
-    for a in tuple(spec):
-        r = resolve_one(a)
-        out.append(None if not r else (r[0] if len(r) == 1 else r))
-    return P(*out)
-
-
 def partition_specs(state: Any) -> Any:
     """PartitionSpec pytree for an nnx state, resolving logical names through
     the ambient rules (falls back to raw names if no rules installed)."""
@@ -276,8 +249,7 @@ def shard_model(model: nnx.Module, mesh: Mesh,
             s = spec.get_value() if isinstance(spec, nnx.Variable) else spec
             if not isinstance(s, P):
                 s = P()
-            s = prune_spec(resolve_logical_spec(s, rules), np.shape(val),
-                           mesh)
+            s = prune_spec(s, np.shape(val), mesh)
             return jax.device_put(val, NamedSharding(mesh, s))
 
         new_state = jax.tree.map(put, state, specs,
@@ -304,8 +276,7 @@ def sharded_copy(model: nnx.Module, mesh: Mesh,
             s = spec.get_value() if isinstance(spec, nnx.Variable) else spec
             if not isinstance(s, P):
                 s = P()
-            s = prune_spec(resolve_logical_spec(s, rules), np.shape(val),
-                           mesh)
+            s = prune_spec(s, np.shape(val), mesh)
             return jax.device_put(val, NamedSharding(mesh, s))
 
         new_state = jax.tree.map(put, state, specs,
@@ -331,8 +302,7 @@ def create_sharded(ctor: Callable[[], nnx.Module], mesh: Mesh,
             s = spec.get_value() if isinstance(spec, nnx.Variable) else spec
             if not isinstance(s, P):
                 s = P()
-            s = prune_spec(resolve_logical_spec(s, rules), np.shape(val),
-                           mesh)
+            s = prune_spec(s, np.shape(val), mesh)
             return jax.lax.with_sharding_constraint(val, s)
 
         state = jax.tree.map(constrain, state, specs,

@@ -22,7 +22,6 @@ from __future__ import annotations
 from functools import partial
 
 import jax
-from jimm_tpu.utils.compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -114,7 +113,7 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     local = partial(_ulysses_local, axis_name=axis_name, kind=kind,
                     causal=is_causal, impl=impl, logit_bias=logit_bias)
     kwargs = {} if mesh is None else {"mesh": mesh}  # None -> ambient mesh
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         in_specs=(P(None, axis_name), P(None, axis_name), P(None, axis_name),
                   P()),  # mask replicated — see _ulysses_local

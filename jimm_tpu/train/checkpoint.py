@@ -18,7 +18,6 @@ import numpy as np
 import orbax.checkpoint as ocp
 from flax import nnx
 
-import jimm_tpu.utils.compat  # noqa: F401  (nnx backfills: to_flat_state, set_value)
 
 
 def _split_state(obj) -> Any:

@@ -17,7 +17,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jimm_tpu.utils.compat import axis_size, shard_map
 
 
 def clip_softmax_loss(img: jax.Array, txt: jax.Array, logit_scale: jax.Array
@@ -61,7 +60,7 @@ def _ring_sigmoid_local(img: jax.Array, txt: jax.Array, scale: jax.Array,
     ``axis_name`` may be a tuple of mesh axes (e.g. ``("replica", "data")``
     on a hybrid DCN x ICI mesh) — the ring then runs over the linearized
     product axis."""
-    n_dev = axis_size(axis_name)
+    n_dev = jax.lax.axis_size(axis_name)
     b = img.shape[0]
     img = img / jnp.linalg.norm(img, axis=-1, keepdims=True)
     txt = txt / jnp.linalg.norm(txt, axis=-1, keepdims=True)
@@ -109,7 +108,7 @@ def _ring_infonce_local(img: jax.Array, txt: jax.Array, scale: jax.Array,
     The positive logit is the diagonal of the step-0 (own-chunk) block. No
     device ever materializes more than its local b x b logit tile.
     """
-    n_dev = axis_size(axis_name)
+    n_dev = jax.lax.axis_size(axis_name)
     b = img.shape[0]
     img = img / jnp.linalg.norm(img, axis=-1, keepdims=True)
     txt = txt / jnp.linalg.norm(txt, axis=-1, keepdims=True)
@@ -166,7 +165,7 @@ def ring_clip_infonce_loss(img: jax.Array, txt: jax.Array,
     at pod batch sizes). Numerically identical to the dense loss and
     differentiable end-to-end; ``axis_name`` may be a tuple of mesh axes for
     hybrid DCN x ICI meshes."""
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_ring_infonce_local, axis_name=axis_name),
         mesh=mesh,
         in_specs=(P(axis_name), P(axis_name), P()),
@@ -182,7 +181,7 @@ def ring_sigmoid_loss(img: jax.Array, txt: jax.Array, logit_scale: jax.Array,
     a ``ppermute`` ring so no device ever holds the global text batch or the
     full logit matrix. Differentiable end-to-end (``ppermute``'s transpose is
     the reverse permute, handled by JAX AD)."""
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_ring_sigmoid_local, axis_name=axis_name),
         mesh=mesh,
         in_specs=(P(axis_name), P(axis_name), P(), P()),

@@ -22,7 +22,6 @@ from flax import nnx
 
 from jimm_tpu.train.losses import (clip_softmax_loss, ring_clip_infonce_loss,
                                    ring_sigmoid_loss, sigmoid_pairwise_loss)
-from jimm_tpu.utils.compat import optimizer_update
 
 
 @dataclass(frozen=True)
@@ -105,7 +104,7 @@ def make_classifier_train_step(*, donate: bool = False) -> Callable:
             (loss, logits), grads = nnx.value_and_grad(
                 loss_fn, has_aux=True)(model)
         with jax.named_scope("optimizer_update"):
-            optimizer_update(optimizer, model, grads)
+            optimizer.update(model, grads)
         accuracy = jnp.mean(jnp.argmax(logits, axis=-1) == labels)
         return {"loss": loss, "accuracy": accuracy}
 
@@ -180,7 +179,7 @@ def make_contrastive_train_step(kind: str = "siglip_ring", *, mesh=None,
         with jax.named_scope("fwd_bwd"):
             loss_val, grads = nnx.value_and_grad(loss_fn)(model)
         with jax.named_scope("optimizer_update"):
-            optimizer_update(optimizer, model, grads)
+            optimizer.update(model, grads)
         return {"loss": loss_val}
 
     return train_step

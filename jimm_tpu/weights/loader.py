@@ -23,7 +23,6 @@ import jax
 import numpy as np
 from flax import nnx
 
-import jimm_tpu.utils.compat  # noqa: F401  (nnx backfills: to_flat_state, set_value)
 
 
 class Transform:

@@ -1,9 +1,8 @@
 """Shared tolerant reader for MEASUREMENTS.jsonl.
 
 One place owns the parse rules (line must be a JSON object; anything else —
-partial writes from a killed attempt, log noise — is skipped) so the three
-consumers (adopt_sweep ranking, bench_sweep skip-resume, window_report)
-cannot drift.
+partial writes from a killed attempt, log noise — is skipped) so the
+consumers (adopt_sweep ranking, bench_sweep skip-resume) cannot drift.
 """
 
 from __future__ import annotations

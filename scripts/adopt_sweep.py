@@ -1,9 +1,8 @@
 """Pick the measured-best sweep variant and adopt it as the framework's
-default execution config (VERDICT r3 item 2 / r4 item 7).
+default execution config.
 
-Reads sweep records from MEASUREMENTS.jsonl (phase "sweep", as persisted
-by scripts/tpu_measure_r5.sh) or from a bench_sweep output file passed
-with --from. Only records with a real mfu field count; error records,
+Reads sweep records from MEASUREMENTS.jsonl (phase "sweep") or from a
+bench_sweep output file passed with --from. Only records with a real mfu field count; error records,
 CPU runs, --tiny validation runs, and records with no device provenance
 are ignored. Prints the winner, the full ranking, and the exact flag
 spelling for bench.py / docs.
@@ -16,7 +15,7 @@ execution config by default; explicit flags still win.
 
     python -m scripts.adopt_sweep              # rank only
     python -m scripts.adopt_sweep --apply      # rank + write adopted file
-    python -m scripts.adopt_sweep --from /tmp/sweep.log
+    python -m scripts.adopt_sweep --from chiprun_out/sweep.log
 """
 
 from __future__ import annotations
@@ -42,8 +41,8 @@ def load_records(path: pathlib.Path, phase_filter: bool,
             continue
         # fidelity: a --tiny validation or CPU run must never supersede a
         # real TPU measurement of the same variant in the ranking; a record
-        # with NO device provenance (pre-r4 sweep logs) is treated as
-        # low-fidelity too (ADVICE r4) — re-measure rather than trust it
+        # with NO device provenance is treated as low-fidelity too —
+        # re-measure rather than trust it
         device = str(rec.get("device", "")).lower()
         if rec.get("tiny") or "cpu" in device or not device:
             continue

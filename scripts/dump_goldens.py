@@ -118,9 +118,9 @@ def main(argv=None) -> int:
     failed = []
     for name in names:
         try:
-            # local alarm, NOT jimm_tpu.utils.alarm: this script runs on
-            # external machines with torch+transformers but no jax/flax,
-            # and importing the package would fail there
+            # self-contained alarm: this script runs on external machines
+            # with torch+transformers but no jax/flax, where importing
+            # jimm_tpu would fail
             disarm = _soft_alarm(args.per_spec_timeout)
             try:
                 dump_one(name, GOLDEN_SPECS[name], out_dir)

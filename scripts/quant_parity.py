@@ -14,7 +14,7 @@ serving fast path actually costs in accuracy:
 
 Prints one MEASUREMENTS.jsonl-format JSON line (``--record`` appends it),
 with ``"phase": "quant_parity"`` and a ``dtype`` field per variant so
-window_report and the serving rows stay join-able.
+these and the serving rows stay join-able.
 
 Usage:
     JAX_PLATFORMS=cpu python -m scripts.quant_parity --preset tiny

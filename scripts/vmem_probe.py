@@ -14,8 +14,8 @@ model is conservative there). Prints one JSON line per probe:
      "which": "fwd"|"bwd", "chosen": bool, "ok": bool, "est_bytes": ...,
      "err": "..."}
 
-Run on TPU (the watcher's vmem phase); off-TPU it exits 0 with a note —
-interpret mode has no VMEM to validate.
+Run on the TPU; off-TPU it exits non-zero — interpret mode has no VMEM to
+validate.
 """
 
 from __future__ import annotations
@@ -91,10 +91,9 @@ def main() -> int:
     jimm_tpu.utils.env.configure_platform()
     import jax
     if jax.default_backend() != "tpu":
-        print(json.dumps({"metric": "vmem_probe",
-                          "note": "not on TPU; interpret mode has no VMEM "
-                                  "to validate"}), flush=True)
-        return 0
+        print("vmem_probe needs a TPU: interpret mode has no VMEM to "
+              "validate", file=sys.stderr)
+        return 1
     budget = float(os.environ.get("VMEM_PROBE_BUDGET_S", "540"))
     deadline = time.monotonic() + budget
     # shipped shapes: ViT-B/16-256 towers (batch 128 x 12 heads, S=256 and

@@ -13,20 +13,10 @@ os.environ.setdefault("XLA_FLAGS",
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:
-    # JAX < 0.5 has no jax_num_cpu_devices config key; the XLA_FLAGS
-    # fallback set above already forces 8 virtual host devices.
-    pass
+jax.config.update("jax_num_cpu_devices", 8)
 
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
-
-# the package namespace is lazy (PEP 562) and only loads the flax compat
-# backfills when a model/config attribute resolves; tests use nnx directly
-# (Variable.set_value, to_flat_state, ...) so load them up front
-import jimm_tpu.utils.compat  # noqa: E402,F401
 
 
 @pytest.fixture(autouse=True)

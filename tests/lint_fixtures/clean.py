@@ -5,21 +5,14 @@ import jax
 from jax.sharding import PartitionSpec as P
 
 
-def guarded_config():
-    try:
-        jax.config.update("jax_num_cpu_devices", 8)  # guarded: no JL001
-    except AttributeError:
-        pass
-
-
 SPEC = P("data", "model")  # canonical axes: no JL004
 
 # suppression on the same line:
 BAD_BUT_WAIVED = P("batch")  # jaxlint: disable=JL004 logical name on purpose
 
 # standalone-comment suppression applies to the next line:
-# jaxlint: disable=JL001 exercised by tests on both JAX lines
-jax.config.update("jax_num_cpu_devices", 8)
+# jaxlint: disable=JL004 logical name on purpose
+ALSO_WAIVED = P("batch")
 
 
 @jax.jit

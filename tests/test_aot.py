@@ -9,6 +9,7 @@ or version-mismatched store degrades to fresh compiles — incrementing
 
 import asyncio
 import json
+import pathlib
 import subprocess
 import sys
 
@@ -345,17 +346,41 @@ class TestWarmStartE2E:
         assert all(r["traces"] == 1 for r in report.values())
         assert all(r["seconds"] >= 0 for r in report.values())
 
-    def test_enable_persistent_cache(self, tmp_path):
+    @pytest.mark.parametrize("env_dir, arg_dir, expected", [
+        (None, "xla", "xla"),        # explicit directory
+        (None, None, "default"),     # fixed in-checkout path
+        ("from-env", "xla", "env"),  # the environment wins: nothing is set
+    ])
+    def test_enable_persistent_cache(self, tmp_path, monkeypatch, env_dir,
+                                     arg_dir, expected):
         import jax
 
-        from jimm_tpu.aot.export import enable_persistent_cache
-        old = jax.config.jax_compilation_cache_dir
+        from jimm_tpu.aot import export
+        old = {k: getattr(jax.config, k) for k in (
+            "jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")}
+        if env_dir is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        else:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                               str(tmp_path / env_dir))
         try:
-            assert enable_persistent_cache(tmp_path / "xla") is True
-            assert jax.config.jax_compilation_cache_dir \
-                == str(tmp_path / "xla")
+            jax.config.update("jax_compilation_cache_dir", "untouched")
+            got = export.enable_persistent_cache(
+                None if arg_dir is None else tmp_path / arg_dir)
+            in_config = jax.config.jax_compilation_cache_dir
+            if expected == "env":
+                assert got == str(tmp_path / env_dir)
+                assert in_config == "untouched"
+            elif expected == "default":
+                repo = pathlib.Path(export.__file__).resolve().parents[2]
+                assert got == in_config == str(repo / ".jax_cache")
+            else:
+                assert got == in_config == str(tmp_path / arg_dir)
         finally:
-            jax.config.update("jax_compilation_cache_dir", old)
+            for k, v in old.items():
+                jax.config.update(k, v)
 
 
 if __name__ == "__main__":

@@ -25,14 +25,11 @@ import os
 import sys
 import numpy as np
 # override the parent suite's 8-device XLA_FLAGS: each worker owns 2 local
-# devices (JAX < 0.5 path; JAX >= 0.5 uses the config key below)
+# devices
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import jax
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 2)
-except AttributeError:
-    pass  # JAX < 0.5: XLA_FLAGS above covers it
+jax.config.update("jax_num_cpu_devices", 2)
 
 addr, pid = sys.argv[1], int(sys.argv[2])
 from jimm_tpu.parallel import initialize_distributed, make_mesh
@@ -48,7 +45,7 @@ initialize_distributed(coordinator_address=addr, num_processes=2,
                        process_id=pid)
 
 import jax.numpy as jnp
-from jimm_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.experimental import multihost_utils
 from jax.sharding import PartitionSpec as P
 
@@ -149,10 +146,7 @@ import sys
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import jax
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 2)
-except AttributeError:
-    pass  # JAX < 0.5: XLA_FLAGS above covers it
+jax.config.update("jax_num_cpu_devices", 2)
 
 addr, pid = sys.argv[1], int(sys.argv[2])
 from jimm_tpu.parallel import initialize_distributed

@@ -15,7 +15,7 @@ initialize_distributed()   # coordinator/world/rank all from launcher env
 assert jax.process_count() == 2, jax.process_count()
 assert jax.local_device_count() == 2
 import numpy as np
-from jimm_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 mesh = make_mesh({"data": -1})
 out = jax.jit(shard_map(lambda x: jax.lax.psum(x, "data"), mesh=mesh,
@@ -58,6 +58,8 @@ def test_launch_arg_validation():
         ["--nnodes", "2", "--nproc", "1", "--", "true"],    # no coordinator
         ["--nnodes", "2", "--node-rank", "2", "--coordinator", "h:1",
          "--nproc", "1", "--", "true"],                     # rank out of range
+        # one process per TPU host: a second local process cannot get chips
+        ["--nproc", "2", "--platform", "tpu", "--", "true"],
     ]
     for argv in cases:
         with pytest.raises(SystemExit):

@@ -28,12 +28,6 @@ def rules_and_lines(findings):
 
 
 class TestRuleFixtures:
-    def test_jl001_unguarded_version_gated_config(self):
-        findings = findings_for("bad_config_gate.py")
-        assert rules_and_lines(findings) == {("JL001", 6)}
-        assert findings[0].severity == ERROR
-        assert "jax_num_cpu_devices" in findings[0].message
-
     def test_jl002_host_sync_in_jit(self):
         findings = findings_for("bad_host_sync.py")
         assert rules_and_lines(findings) == {

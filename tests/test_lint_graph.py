@@ -279,7 +279,7 @@ class TestJaxprLayer:
         drift = run_jaxpr_checks(
             entry_points={"e": with_sum},
             goldens={"e": {"f32_promotions": 9,
-                           "collectives": {"psum2": 1}}})
+                           "collectives": {"psum_invariant": 1}}})
         assert [(f.rule, f.severity) for f in drift] == [("JLT106", ERROR)]
 
         # no golden at all -> WARNING nudging a goldens update
@@ -304,7 +304,7 @@ class TestJaxprLayer:
         from jimm_tpu.lint.jaxpr import ENTRY_POINTS, GOLDENS_PATH
         goldens = json.loads(GOLDENS_PATH.read_text())
         assert set(goldens) == set(ENTRY_POINTS)
-        assert goldens["data_parallel_psum"]["collectives"] == {"psum2": 1}
+        assert goldens["data_parallel_psum"]["collectives"] == {"psum_invariant": 1}
 
 
 class TestCliIntegration:

@@ -220,13 +220,37 @@ class TestMfuDegenerate:
         assert counter.value == before + 5
 
     def test_healthy_path_unchanged(self):
+        import types
+
+        from jimm_tpu.train.metrics import device_peak_tflops, mfu
+        v5e = types.SimpleNamespace(device_kind="TPU v5 lite", platform="tpu")
+        peak = device_peak_tflops(v5e) * 1e12
+        assert peak == 197e12
+        got = mfu(peak * 0.4, 1.0, n_devices=1, device=v5e)
+        assert got == pytest.approx(0.4)
+        assert math.isfinite(got)
+
+    def test_unknown_device_kind_has_no_peak(self):
+        """No default peak and no CPU peak: an MFU for a device that is not
+        in the table would be a made-up number."""
         import jax
 
         from jimm_tpu.train.metrics import device_peak_tflops, mfu
-        peak = device_peak_tflops(jax.devices()[0]) * 1e12
-        got = mfu(peak * 0.4, 1.0, n_devices=1)
-        assert got == pytest.approx(0.4)
-        assert math.isfinite(got)
+        cpu = jax.devices()[0]
+        with pytest.raises(ValueError, match="no peak FLOP/s recorded"):
+            device_peak_tflops(cpu)
+        with pytest.raises(ValueError, match=repr(cpu.device_kind)):
+            mfu(1e12, 1.0, n_devices=1)
+
+    def test_compiled_flops_does_not_swallow_errors(self):
+        from jimm_tpu.train.metrics import compiled_flops
+
+        class Broken:
+            def cost_analysis(self):
+                raise RuntimeError("cost analysis unavailable")
+
+        with pytest.raises(RuntimeError, match="unavailable"):
+            compiled_flops(Broken())
 
 
 class TestMetricsLoggerRegistry:

@@ -9,7 +9,6 @@ from flax import nnx
 
 from jimm_tpu.configs import TransformerConfig
 from jimm_tpu.nn.transformer import Transformer
-from jimm_tpu.utils import compat
 from jimm_tpu.parallel import PIPELINE, make_mesh, use_sharding
 from jimm_tpu.parallel.pipeline import pipeline_forward
 
@@ -33,7 +32,7 @@ def test_functional_core_matches_sequential(rng, pp_mesh):
         return jax.lax.scan(lambda h, wi: (jnp.tanh(h @ wi), None),
                             xm, w_local)[0]
 
-    with compat.set_mesh(pp_mesh):
+    with jax.set_mesh(pp_mesh):
         out = pipeline_forward(stage_apply, w, x, n_microbatches=4,
                                batch_axis="data")
         gp = jax.grad(lambda w: (pipeline_forward(
@@ -70,7 +69,7 @@ def test_functional_core_interleaved_matches_sequential(rng, pp_mesh,
                                 n_microbatches=n_micro, n_virtual=n_virtual,
                                 batch_axis="data")
 
-    with compat.set_mesh(pp_mesh):
+    with jax.set_mesh(pp_mesh):
         out = run(w)
         gp = jax.grad(lambda w: (run(w) ** 2).mean())(w)
     np.testing.assert_allclose(out, ref(w, x), atol=1e-5)

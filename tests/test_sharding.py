@@ -108,7 +108,7 @@ def test_logical_constraint_partial_manual(eight_devices, monkeypatch):
     (round-1 advisor finding: they were dropped wholesale). A spy on
     with_sharding_constraint pins WHAT was constrained — the numerics alone
     pass either way."""
-    from jimm_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     from jimm_tpu.parallel.sharding import logical_constraint
 

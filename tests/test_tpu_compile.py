@@ -1,0 +1,133 @@
+"""The Pallas kernels, at the widths the presets route through them, compiled
+by the TPU's own compiler for a *described* v5e chip (no chip attached,
+nothing runs). Interpret mode — what every other kernel test uses — cannot
+see what Mosaic refuses: a block that misses the tiling, too much VMEM, a
+layout XLA and Mosaic disagree on. These compiles can, at no chip time.
+
+A compile that passes is not a chip run: `chip_smoke.py` is what runs them.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from flax import nnx
+
+from jimm_tpu.ops import (flash_attention as fa, fp8_matmul as f8,
+                          int8_matmul as i8, layer_norm as ln)
+
+HBM_BYTES = 16 * 1000 ** 3  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """Sharding on one chip of a described v5e 2x2 host, with the persistent
+    compile cache off: such a compile is written to it but cannot be read
+    back without a chip, so the next run would warn on every entry."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed here
+        pytest.skip(f"cannot describe a v5e topology: {e!r}")
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield jax.sharding.SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_enabled)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """The kernels pick interpret mode from ``jax.default_backend()``, which
+    is the CPU here: steer that in the test, not through a program option."""
+    for module in (fa, f8, i8, ln):
+        monkeypatch.setattr(module, "_interpret", lambda: False)
+
+
+def _fwd_bwd(fn):
+    def run(*args):
+        out, vjp = jax.vjp(fn, *args)
+        return out, vjp(jnp.ones_like(out))
+    return run
+
+
+def _flash(shape):
+    return (fa.flash_attention, [(shape, jnp.bfloat16)] * 3)
+
+
+M, K, N = 4096, 768, 3072  # ViT-B MLP up-projection at batch 16 x 256 tokens
+
+KERNEL_CASES = {
+    # ViT-L/16-384 and CLIP-L/14-336: S=577, D=64
+    "flash_s577_d64": _flash((32, 577, 16, 64)),
+    # So400m/14-384: S=729, head width 72 (lane-padded inside the wrapper)
+    "flash_s729_d72": _flash((16, 729, 16, 72)),
+    "flash_masked_s577_d64": (
+        fa.flash_attention_masked,
+        [((32, 577, 16, 64), jnp.bfloat16)] * 3 + [((32, 577), jnp.bool_)]),
+    "layer_norm_768": (ln.layer_norm, [((32768, 768), jnp.bfloat16),
+                                       ((768,), jnp.bfloat16),
+                                       ((768,), jnp.bfloat16)]),
+    "layer_norm_1152": (ln.layer_norm, [((11664, 1152), jnp.bfloat16),
+                                        ((1152,), jnp.bfloat16),
+                                        ((1152,), jnp.bfloat16)]),
+    "fp8_matmul": (f8.fp8_matmul, [((M, K), jnp.bfloat16),
+                                   ((K, N), jnp.bfloat16),
+                                   ((N,), jnp.bfloat16)]),
+    "quantized_linear": (i8.quantized_linear, [((M, K), jnp.bfloat16),
+                                               ((K, N), jnp.int8),
+                                               ((N,), jnp.float32),
+                                               ((N,), jnp.float32)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_kernel_compiles_for_v5e(case, one_chip, compiled_kernels):
+    fn, arg_shapes = KERNEL_CASES[case]
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in arg_shapes]
+    compiled = jax.jit(_fwd_bwd(fn)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.slow
+def test_siglip_b16_256_train_step_fits_one_v5e_chip(one_chip):
+    """The whole contrastive train step of `chip_smoke.py`'s train phase
+    (published widths, bf16, batch 128, remat=dots, donated state) compiles
+    for one chip and asks for less than its 16 GB."""
+    from jimm_tpu import SigLIP, preset
+    from jimm_tpu.configs import parse_remat, with_runtime
+    from jimm_tpu.train import (OptimizerConfig, make_contrastive_train_step,
+                                make_optimizer)
+
+    cfg = with_runtime(preset("siglip-base-patch16-256"),
+                       **parse_remat("dots"), attn_impl="auto",
+                       ln_impl="xla", scan_unroll=1)
+
+    def build():
+        model = SigLIP(cfg, rngs=nnx.Rngs(0), dtype=jnp.bfloat16,
+                       param_dtype=jnp.bfloat16)
+        return model, make_optimizer(model, OptimizerConfig(total_steps=10))
+
+    model, optimizer = nnx.eval_shape(build)
+    for module in (model, optimizer):
+        nnx.update(module, jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip),
+            nnx.state(module)))
+    batch = 128
+    images = jax.ShapeDtypeStruct((batch, 256, 256, 3), jnp.float32,
+                                  sharding=one_chip)
+    text = jax.ShapeDtypeStruct((batch, 64), jnp.int32, sharding=one_chip)
+    step = make_contrastive_train_step("siglip", donate=True)
+    mem = step.lower(model, optimizer, images, text).compile() \
+        .memory_analysis()
+    resident = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+                + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert resident < HBM_BYTES, mem
