@@ -355,7 +355,7 @@ def train(args: argparse.Namespace) -> Any:
     from jimm_tpu import preset
     from jimm_tpu.data import (PrefetchIterator, blob_classification,
                                contrastive_pairs)
-    from jimm_tpu.parallel import PRESET_RULES, shard_batch, use_sharding
+    from jimm_tpu.parallel import PRESET_RULES, use_sharding
     from jimm_tpu.train import (CheckpointManager, MetricsLogger,
                                 OptimizerConfig, StepTimer,
                                 make_classifier_train_step,
@@ -727,14 +727,12 @@ def train(args: argparse.Namespace) -> Any:
                                     accounter=acct)
 
     def place(batch):
-        if mesh is None:
-            # tree-map: a NaFlex batch nests the image triple inside
-            import jax as _jax
-            return _jax.tree.map(jnp.asarray, batch)
-        return shard_batch(batch, mesh, rules)
+        # tree-map: a NaFlex batch nests the image triple inside
+        return jax.tree.map(jnp.asarray, batch)
 
-    data = PrefetchIterator(data, mesh=mesh, rules=rules) \
-        if mesh is not None else map(place, data)
+    if mesh is not None:
+        # places in its own thread; the loop's "place" phase does not exist
+        data = PrefetchIterator(data, mesh=mesh, rules=rules)
     if grain_stream is not None:
         # advance consumed_state batch-by-batch on THIS (consumer) side of
         # the prefetch queue, so checkpoints record the trained-on position
@@ -758,18 +756,22 @@ def train(args: argparse.Namespace) -> Any:
                     from jimm_tpu.train.profile import trace
                     profiler_ctx = trace(args.profile_dir)
                     profiler_ctx.__enter__()
-                with acct.measure("data_wait"):
+                with acct.measure("next_batch"):
                     batch = next(data)
+                if mesh is None:
+                    with acct.measure("place"):
+                        batch = place(batch)
                 # hash before step_fn runs: donated buffers die with the step
                 fp = (_batch_fingerprint(batch)
                       if args.batch_fingerprint else None)
                 # the first step traces + compiles under the same call; it
                 # lands in the "compile" bucket, steady-state in "step"
                 # (timer.stop's device_get sync keeps device time in-bucket)
-                with acct.measure("compile" if step == start_step
-                                  else "step"):
-                    timer.start()
+                bucket = "compile" if step == start_step else "step"
+                timer.start()
+                with acct.measure("dispatch", bucket):
                     metrics = step_fn(model, optimizer, *batch)
+                with acct.measure("device_wait", bucket):
                     dt = timer.stop(metrics["loss"])
                 if profiler_ctx is not None and step == profile_stop:
                     profiler_ctx.__exit__(None, None, None)
@@ -779,7 +781,10 @@ def train(args: argparse.Namespace) -> Any:
                     host_metrics = {k: float(v) for k, v in metrics.items()}
                     if fp is not None:
                         host_metrics["batch_fingerprint"] = fp
-                    logger.log(step, step_time_s=dt, **host_metrics)
+                    # everything measured since the last row: this step's
+                    # phases and the previous step's host_sync / checkpoint
+                    logger.log(step, step_time_s=dt, **host_metrics,
+                               file_only={"phases": acct.drain()})
                 extra = None
                 if ckpt is not None and grain_stream is not None:
                     import base64

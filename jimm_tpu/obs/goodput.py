@@ -29,20 +29,42 @@ Buckets (the fixed vocabulary the docs and CI smoke assert on):
 MFU-adjusted goodput = goodput × MFU: the fraction of *peak hardware* FLOPs
 the whole loop achieves, not just the step function — the number that tells
 you whether to optimize the kernel or the pipeline around it.
+
+Phases (the train loop's finer names under the buckets; ``measure()`` takes
+either, and a bucket's name is a phase of itself):
+
+- ``next_batch``  → ``data_wait``: ``next()`` on the input iterator alone
+- ``place``       → ``data_wait``: dispatch of the host-to-device copy
+- ``dispatch``    → ``step``: the call of the jitted step until it returns
+- ``device_wait`` → ``step``: ``block_until_ready`` on the loss
+
+(the last two land in ``compile`` on the first step: ``bucket=``). Every
+measured region is also kept as ``[phase, start_unix_ns, dur_ns]`` until
+:meth:`GoodputAccounter.drain` hands it out, once: the loop writes them into
+its step's ``--metrics-file`` row, and because ``start`` is ``time.time_ns()``
+they lie on a profiler capture's clock (its ``profile_start_time``) without
+the profiler's host tracer having recorded anything.
 """
 
 from __future__ import annotations
 
+import collections
 import threading
 import time
 from contextlib import contextmanager
 
 from jimm_tpu.obs.registry import MetricRegistry, enabled, get_registry
 
-__all__ = ["BUCKETS", "GoodputAccounter"]
+__all__ = ["BUCKETS", "PHASES", "GoodputAccounter"]
 
 BUCKETS = ("compile", "data_wait", "step", "checkpoint", "host_sync",
            "preemption_save", "lost_work", "replan", "heal")
+#: phase -> the bucket it adds to, unless ``measure(..., bucket=)`` says so
+PHASES = {"next_batch": "data_wait", "place": "data_wait",
+          "dispatch": "step", "device_wait": "step",
+          **{name: name for name in BUCKETS}}
+#: spans kept until the next drain(); bounds an accounter nobody drains
+MAX_UNDRAINED_SPANS = 4096
 
 
 class GoodputAccounter:
@@ -57,6 +79,8 @@ class GoodputAccounter:
     def __init__(self, registry: MetricRegistry | None = None):
         self._lock = threading.Lock()
         self._seconds = {name: 0.0 for name in BUCKETS}
+        self._spans: collections.deque[list] = collections.deque(
+            maxlen=MAX_UNDRAINED_SPANS)
         self._t_start = time.monotonic()
         self.registry = registry if registry is not None \
             else get_registry("jimm_train")
@@ -67,22 +91,39 @@ class GoodputAccounter:
         self.registry.gauge("goodput_wall_s", self.wall_s)
 
     @contextmanager
-    def measure(self, bucket: str):
-        """Attribute the wrapped region's wall time to ``bucket``."""
+    def measure(self, phase: str, bucket: str | None = None):
+        """Attribute the wrapped region's wall time to ``phase``'s bucket
+        (or to ``bucket``), and keep it as a span for :meth:`drain`."""
+        if phase not in PHASES:
+            raise KeyError(f"unknown goodput phase {phase!r}; "
+                           f"expected one of {tuple(PHASES)}")
+        bucket = PHASES[phase] if bucket is None else bucket
         if bucket not in self._seconds:
             raise KeyError(f"unknown goodput bucket {bucket!r}; "
                            f"expected one of {BUCKETS}")
         if not enabled():
             yield
             return
-        t0 = time.perf_counter()
+        # wall clock for where the span lies, monotonic for how long it is
+        start_unix_ns = time.time_ns()
+        t0 = time.perf_counter_ns()
         try:
             yield
         finally:
-            dt = time.perf_counter() - t0
+            dur_ns = time.perf_counter_ns() - t0
+            dt = dur_ns / 1e9
             with self._lock:
                 self._seconds[bucket] += dt
+                self._spans.append([phase, start_unix_ns, dur_ns])
             self._counters[bucket].inc(dt)
+
+    def drain(self) -> list[list]:
+        """Every ``[phase, start_unix_ns, dur_ns]`` measured since the last
+        call, in the order the regions ended; each is handed out once."""
+        with self._lock:
+            spans = list(self._spans)
+            self._spans.clear()
+        return spans
 
     def add(self, bucket: str, seconds: float) -> None:
         """Attribute already-measured time (e.g. a StepTimer reading)."""

@@ -62,8 +62,18 @@ class _JaxProfiler:
     module itself stays importable without jax)."""
 
     def start(self, log_dir: str) -> None:
+        """Device planes only on a TPU: JAX's defaults (Python tracer on,
+        host tracer 2) put traced steps 2.1 s apart instead of 0.27 s, and
+        the host tracer alone slowed a batch's placement from 35 ms to 0.5 s
+        (PERF.md, PR 22, finding 2), so a capture measured its own profiler.
+        The loop's host side is in the same run's ``--metrics-file`` rows
+        (``phases``, on this capture's clock). A CPU runs its operations on
+        host threads, so there the host tracer stays."""
         import jax
-        jax.profiler.start_trace(log_dir)  # jaxlint: disable=JL022 — the sanctioned home: CaptureManager/profiler_session route every capture here
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 0 if jax.default_backend() == "tpu" else 2
+        jax.profiler.start_trace(log_dir, profiler_options=options)  # jaxlint: disable=JL022 — the sanctioned home: CaptureManager/profiler_session route every capture here
 
     def stop(self) -> None:
         import jax

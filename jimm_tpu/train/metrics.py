@@ -96,7 +96,9 @@ class MetricsLogger:
     ``steps_logged_total`` counter, ``step_time_s`` into the
     ``step_time_seconds`` histogram, and every other numeric value as a
     last-value gauge — so the unified ``obs.snapshot()`` carries the same
-    series the JSONL does.
+    series the JSONL does. ``file_only`` fields (the loop's per-step
+    ``phases``: structure, not scalars) go into the JSONL row and nowhere
+    else.
     """
 
     path: str | Path | None = None
@@ -107,8 +109,10 @@ class MetricsLogger:
     _tb: Any = field(default=None, repr=False)
     _step: int = 0
 
-    def log(self, step: int, **metrics: Any) -> None:
-        record = {"step": step, "time": time.time(), **metrics}
+    def log(self, step: int, *, file_only: dict[str, Any] | None = None,
+            **metrics: Any) -> None:
+        record = {"step": step, "time": time.time(), **metrics,
+                  **(file_only or {})}
         if self.path is not None:
             if self._file is None:
                 Path(self.path).parent.mkdir(parents=True, exist_ok=True)

@@ -22,8 +22,9 @@ from jimm_tpu.obs.prof.opstats import op_table
 
 
 @contextmanager
-def trace(log_dir: str | Path, *, host_tracer_level: int = 2):
-    """Capture a device+host trace for the enclosed steps::
+def trace(log_dir: str | Path):
+    """Capture a trace of the enclosed steps (on a TPU the device planes
+    alone: see ``capture._JaxProfiler.start``)::
 
         with trace("/tmp/profile"):
             for _ in range(5):
@@ -32,12 +33,6 @@ def trace(log_dir: str | Path, *, host_tracer_level: int = 2):
     from jimm_tpu.obs.prof.capture import profiler_session
     with profiler_session(log_dir):
         yield
-
-
-def annotate(name: str):
-    """Named region that shows up in the trace timeline."""
-    import jax
-    return jax.profiler.TraceAnnotation(name)
 
 
 # ---------------------------------------------------------------------------

@@ -45,6 +45,66 @@ def test_train_resume(tmp_path):
     assert [r["step"] for r in records] == [2, 3]
 
 
+@pytest.mark.parametrize("mesh", [None, "data=2"])
+def test_train_rows_carry_the_loops_phases(tmp_path, capsys, mesh,
+                                           eight_devices):
+    """Every ``--metrics-file`` row holds what the accounter measured since
+    the row before: its own step's phases and the previous step's host_sync
+    and checkpoint, on the Unix clock, adding up to the goodput buckets."""
+    import time
+    metrics = tmp_path / "metrics.jsonl"
+    argv = ["train", "--preset", "vit-base-patch16-224", "--tiny",
+            "--steps", "5", "--batch-size", "8", "--log-every", "1",
+            "--ckpt-dir", str(tmp_path / "ckpt"), "--save-every", "1",
+            "--metrics-file", str(metrics)]
+    if mesh:
+        argv += ["--mesh", mesh, "--rules", "dp", "--max-devices", "2"]
+    t0 = time.time_ns()
+    assert main(argv) == 0
+    t1 = time.time_ns()
+    out = capsys.readouterr().out
+    assert "phases" not in out, "the console does not show them"
+    goodput = json.loads(next(line for line in out.splitlines()
+                              if line.startswith("goodput: "))[9:])
+    rows = [json.loads(line) for line in metrics.read_text().splitlines()]
+    assert len(rows) == 5 and all("phases" in r for r in rows)
+
+    own = ["next_batch", "dispatch", "device_wait"] if mesh else [
+        "next_batch", "place", "dispatch", "device_wait"]
+    assert [p[0] for p in rows[0]["phases"]] == own
+    for r in rows[1:]:
+        assert [p[0] for p in r["phases"]] == ["host_sync", "checkpoint",
+                                               *own]
+    spans = [p for r in rows for p in r["phases"]]
+    assert t0 <= spans[0][1] and spans[-1][1] + spans[-1][2] <= t1
+    for (_, a0, adur), (_, b0, _) in zip(spans, spans[1:]):
+        assert a0 + adur <= b0, "ordered, and never overlapping"
+
+    def total(row, *names):
+        return sum(dur for name, _, dur in row["phases"]
+                   if name in names) / 1e9
+
+    for r in rows:
+        # StepTimer starts before the dispatch span and stops inside the
+        # device_wait span: the same stretch but for two clock reads
+        assert total(r, "dispatch", "device_wait") == pytest.approx(
+            r["step_time_s"], abs=5e-3)
+        assert r["time"] * 1e9 >= r["phases"][-1][1] + r["phases"][-1][2]
+    # the buckets are the sums of their phases (the line rounds to 0.1 ms)
+    near = dict(abs=3e-4)
+    assert goodput["compile_s"] == pytest.approx(
+        total(rows[0], "dispatch", "device_wait"), **near)
+    assert goodput["step_s"] == pytest.approx(
+        sum(total(r, "dispatch", "device_wait") for r in rows[1:]), **near)
+    assert goodput["data_wait_s"] == pytest.approx(
+        sum(total(r, "next_batch", "place") for r in rows), **near)
+    # the last step's host_sync and checkpoint end after the last row
+    logged = sum(total(r, "host_sync") for r in rows)
+    assert 0 < logged < goodput["host_sync_s"]
+    assert 0 < sum(total(r, "checkpoint") for r in rows) < goodput[
+        "checkpoint_s"]
+
+
 @pytest.mark.slow
 def test_train_sharded_ring_loss(tmp_path, eight_devices, capsys):
     assert main(["train", "--preset", "siglip-base-patch16-256", "--tiny",

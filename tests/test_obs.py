@@ -179,6 +179,109 @@ class TestGoodput:
         assert 0.0 <= snap["goodput_ratio"] <= 1.0
 
 
+class TestGoodputPhases:
+    """The train loop's phases under the buckets, and the per-step spans."""
+
+    @pytest.mark.parametrize("phase, bucket", [
+        ("next_batch", "data_wait"), ("place", "data_wait"),
+        ("dispatch", "step"), ("device_wait", "step"),
+        ("host_sync", "host_sync"), ("checkpoint", "checkpoint")])
+    def test_a_phase_adds_to_its_bucket_and_to_nothing_else(self, phase,
+                                                            bucket):
+        from jimm_tpu.obs.goodput import PHASES
+        assert PHASES[phase] == bucket
+        reg = obs.MetricRegistry(f"t_phase_{phase}")
+        acct = obs.GoodputAccounter(reg)
+        with acct.measure(phase):
+            time.sleep(0.003)
+        secs = acct.seconds(wall=1.0)
+        assert secs.pop(bucket) >= 0.003
+        assert secs.pop("other") > 0
+        assert set(secs.values()) == {0.0}
+        snap = reg.snapshot()
+        assert snap[f"goodput_{bucket}_seconds_total"] >= 0.003
+        assert not any(phase in k for k in snap if phase != bucket), (
+            "a phase is not mirrored to the registry under its own name")
+
+    def test_the_first_steps_phases_can_land_in_compile(self):
+        acct = obs.GoodputAccounter(obs.MetricRegistry("t_phase_compile"))
+        with acct.measure("dispatch", "compile"):
+            time.sleep(0.002)
+        with acct.measure("device_wait", "compile"):
+            time.sleep(0.002)
+        secs = acct.seconds()
+        assert secs["compile"] >= 0.004 and secs["step"] == 0.0
+        assert [s[0] for s in acct.drain()] == ["dispatch", "device_wait"]
+
+    @pytest.mark.parametrize("phase, bucket", [("coffee", None),
+                                               ("dispatch", "coffee")])
+    def test_unknown_phase_or_bucket_raises(self, phase, bucket):
+        acct = obs.GoodputAccounter(obs.MetricRegistry(
+            f"t_phase_unknown_{phase}"))
+        with pytest.raises(KeyError):
+            with acct.measure(phase, bucket):
+                pass
+        assert acct.drain() == []
+
+    def test_draining_returns_each_span_once(self):
+        acct = obs.GoodputAccounter(obs.MetricRegistry("t_phase_drain"))
+        before = time.time_ns()
+        for phase in ("next_batch", "place", "dispatch"):
+            with acct.measure(phase):
+                time.sleep(0.001)
+        after = time.time_ns()
+        spans = acct.drain()
+        assert [s[0] for s in spans] == ["next_batch", "place", "dispatch"]
+        for name, start, dur in spans:
+            assert isinstance(start, int) and isinstance(dur, int)
+            assert before <= start and start + dur <= after
+            assert dur >= 1_000_000
+        assert json.loads(json.dumps(spans)) == spans  # a row's field as is
+        assert acct.drain() == []
+        with acct.measure("host_sync"):
+            pass
+        assert [s[0] for s in acct.drain()] == ["host_sync"]
+        # the spans are the buckets' time, span for span
+        assert acct.seconds()["data_wait"] == pytest.approx(
+            (spans[0][2] + spans[1][2]) / 1e9)
+
+    def test_spans_of_one_thread_never_overlap(self):
+        acct = obs.GoodputAccounter(obs.MetricRegistry("t_phase_overlap"))
+        for _ in range(200):
+            for phase in ("next_batch", "place", "dispatch", "device_wait",
+                          "host_sync"):
+                with acct.measure(phase):
+                    pass
+        spans = acct.drain()
+        assert len(spans) == 1000
+        for (_, a0, adur), (_, b0, _) in zip(spans, spans[1:]):
+            assert a0 + adur <= b0
+
+    def test_an_accounter_nobody_drains_stays_bounded(self):
+        from jimm_tpu.obs.goodput import MAX_UNDRAINED_SPANS
+        acct = obs.GoodputAccounter(obs.MetricRegistry("t_phase_bounded"))
+        for _ in range(MAX_UNDRAINED_SPANS + 10):
+            with acct.measure("step"):
+                pass
+        assert len(acct.drain()) == MAX_UNDRAINED_SPANS
+
+    def test_disabled_records_nothing_and_reads_no_clock(self, monkeypatch):
+        from jimm_tpu.obs import goodput
+        acct = obs.GoodputAccounter(obs.MetricRegistry("t_phase_off"))
+        obs.set_enabled(False)
+
+        def no_clock(*a, **kw):
+            raise AssertionError("JIMM_OBS=0 read a clock")
+
+        for clock in ("time_ns", "perf_counter_ns", "perf_counter", "time"):
+            monkeypatch.setattr(goodput.time, clock, no_clock)
+        with acct.measure("dispatch"):
+            pass
+        monkeypatch.undo()
+        assert acct.drain() == []
+        assert acct.seconds(wall=1.0)["step"] == 0.0
+
+
 class TestExporters:
     def test_prometheus_roundtrip(self):
         series = {"x_total": 3, "y": 1.5, "h_count": 7}
@@ -266,6 +369,20 @@ class TestMetricsLoggerRegistry:
         assert snap["step_time_seconds_count"] == 2
         assert snap["loss"] == 1.0  # last-value gauge
         assert "note" not in snap
+
+    def test_file_only_fields_reach_the_row_and_nothing_else(self, tmp_path,
+                                                             capsys):
+        from jimm_tpu.train.metrics import MetricsLogger
+        reg = obs.MetricRegistry("t_logger_file_only")
+        logger = MetricsLogger(path=tmp_path / "m.jsonl", print_every=1,
+                               registry=reg)
+        phases = [["dispatch", 1790621375151315027, 4111337562]]
+        logger.log(0, loss=2.0, file_only={"phases": phases})
+        logger.close()
+        row = json.loads((tmp_path / "m.jsonl").read_text())
+        assert row["phases"] == phases and row["loss"] == 2.0
+        assert "phases" not in capsys.readouterr().out
+        assert not any("phases" in k for k in reg.snapshot())
 
     def test_no_registry_no_mirroring(self):
         from jimm_tpu.train.metrics import MetricsLogger

@@ -366,3 +366,28 @@ class TestEngineTriggerWiring:
             _prof_trigger("c-x", "slo_fast_burn")  # must be a silent no-op
         finally:
             reset_capture()
+
+
+class TestJaxProfilerOptions:
+    """The one sanctioned ``start_trace``: what ``--profile-dir`` and
+    ``--prof-ring`` captures are taken with."""
+
+    @pytest.mark.parametrize("backend, host_tracer_level",
+                             [("tpu", 0), ("cpu", 2)])
+    def test_python_tracer_off_and_on_a_tpu_the_host_tracer_too(
+            self, monkeypatch, tmp_path, backend, host_tracer_level):
+        import jax
+
+        from jimm_tpu.obs.prof.capture import _JaxProfiler
+        started = []
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        monkeypatch.setattr(
+            jax.profiler, "start_trace",
+            lambda log_dir, **kw: started.append((log_dir, kw)))
+        _JaxProfiler().start(str(tmp_path))
+        (log_dir, kw), = started
+        assert log_dir == str(tmp_path) and set(kw) == {"profiler_options"}
+        options = kw["profiler_options"]
+        assert isinstance(options, jax.profiler.ProfileOptions)
+        assert options.python_tracer_level == 0
+        assert options.host_tracer_level == host_tracer_level
