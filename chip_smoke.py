@@ -22,6 +22,8 @@ route through it (ViT-L/16-384 S=577, So400m/14-384 S=729 D=72, fused
 LayerNorm at 768 and 1152, fp8 and int8 matmuls at ViT-B MLP widths),
 forward and backward, against the plain reference; on the TPU each must have
 lowered to a Mosaic custom call and ``impl="auto"`` must pick flash at S=577.
+Each flash case prints the regime its calls took (``flash_calls_built``) and
+must have built one single-tile forward and one fused backward.
 
 Multichip phase: the same preset over four chips (mesh ``data=2,model=2``,
 rules ``fsdp_tp``, ring sigmoid loss) against the same seed and batches on
@@ -285,6 +287,7 @@ def kernel_phase(args, watch: CompileWatch) -> None:
     import jax.numpy as jnp
 
     from jimm_tpu import tune
+    from jimm_tpu.obs.registry import snapshot
     from jimm_tpu.ops.attention import dot_product_attention
 
     on_tpu = jax.default_backend() == "tpu"
@@ -305,9 +308,18 @@ def kernel_phase(args, watch: CompileWatch) -> None:
             scale = max(scale, float(jnp.max(jnp.abs(w))))
         return err, scale
 
+    def flash_calls_built(before):
+        """The flash family counts each pallas_call it builds by regime:
+        e.g. {"single_tile": 2} is one forward and one fused backward."""
+        return {k[len("jimm_flash_"):-len("_total")]: int(v - before.get(k, 0))
+                for k, v in snapshot().items()
+                if k.startswith("jimm_flash_") and v != before.get(k, 0)}
+
     for name, kernel, reference, inputs, tol in kernel_cases(args):
         try:
+            before = snapshot()
             compiled = jax.jit(kernel).lower(*inputs).compile()  # jaxlint: disable=JL008 a different function each pass, jitted once
+            regime = flash_calls_built(before)
             mosaic = "tpu_custom_call" in compiled.as_text()
             out, grads = compiled(*inputs)
             with jax.default_matmul_precision("highest"):
@@ -315,8 +327,13 @@ def kernel_phase(args, watch: CompileWatch) -> None:
             fwd_err, _ = max_err(out, ref_out)
             bwd_err, ref_scale = max_err(grads, ref_grads)
             ok = fwd_err <= tol and bwd_err <= tol and (mosaic or not on_tpu)
+            if name.startswith("flash_attention"):
+                # both preset lengths are under the single-tile rule: one
+                # forward and ONE backward kernel, no tiled dq + dk/dv pair
+                ok = ok and regime == {"single_tile": 2}
             say(phase="kernels", case=name, ok=ok,
                 lowering="mosaic" if mosaic else "interpreter",
+                **({"flash_calls_built": regime} if regime else {}),
                 fwd_max_err=fwd_err, bwd_max_err=bwd_err, tolerance=tol,
                 err_unit="max abs error over max(1, max|reference|)",
                 max_abs_reference_grad=ref_scale)

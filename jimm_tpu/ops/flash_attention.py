@@ -18,17 +18,30 @@ source) instantiates the members:
   Sigmoid Self-Attention"): the online loop drops the m/l statistics
   entirely, and the backward needs no lse/delta.
 
-Kernel structure (all variants): the kv loop is a GRID dimension, not an
-in-kernel loop over a resident copy — each (head-block, q-block, kv-block)
-grid cell sees one (block_q, d) q tile and one (block_k, d) k/v tile, so
-VMEM holds a single working set while Mosaic's grid pipeline streams the
-next kv block from HBM in parallel with compute. Softmax variants keep the
-flash-attention recurrence in VMEM scratch ((block_q, 128) lane-broadcast
-m/l, fp32 accumulator); the sigmoid variant keeps only the accumulator.
-HBM traffic is O(S*D) and VMEM is O(block^2).
+Two tilings of one algorithm, chosen by a test of shapes alone
+(`_single_tile_hb`; no flag, no `impl` string):
 
-The backward recomputes attention blockwise (from the saved logsumexp for
-softmax kinds; from scratch for sigmoid) — dq kernel plus dk/dv kernel in
+- **single tile** — every image preset (S = 196..729): a head's whole
+  lane-padded sequence is one resident tile, so each row sees all its keys
+  at once. Grid ``(BN/hb,)``; the forward is one exact row softmax per head
+  (no m/l scratch, no rescale, no init/finalize), and the backward is ONE
+  kernel that takes ``delta`` and all three gradients from a single pass
+  over the scores (5 matmuls, 1 exponential per score element). The bias
+  variant stays tiled.
+- **tiled** — longer sequences (and explicit block requests): the kernels
+  described next.
+
+Tiled kernel structure (all variants): the kv loop is a GRID dimension,
+not an in-kernel loop over a resident copy — each (head-block, q-block,
+kv-block) grid cell sees one (block_q, d) q tile and one (block_k, d) k/v
+tile, so VMEM holds a single working set while Mosaic's grid pipeline
+streams the next kv block from HBM in parallel with compute. Softmax
+variants keep the flash-attention recurrence in VMEM scratch ((block_q, 128)
+lane-broadcast m/l, fp32 accumulator); the sigmoid variant keeps only the
+accumulator. HBM traffic is O(S*D) and VMEM is O(block^2).
+
+The tiled backward recomputes attention blockwise (from the saved logsumexp
+for softmax kinds; from scratch for sigmoid) — dq kernel plus dk/dv kernel in
 the flash-attention-2 arrangement, and for the bias variant a third kernel
 whose grid runs batch innermost to accumulate dbias across samples.
 
@@ -116,10 +129,10 @@ def _from_lanes(x: jax.Array) -> jax.Array:
 
 def _scores(q, k, sm_scale, mask_row, bias_tile, pos_mask):
     """One head's fp32 score tile: dot, scale, additive mask/bias, then the
-    positional (padding/causal) mask. q/k stay in their storage dtype
-    (bf16) so the MXU runs at full bf16 rate with fp32 accumulation; the
-    softmax scale is applied to the fp32 logits AFTER the dot (pre-scaling
-    q in bf16 would round)."""
+    positional (padding/causal) mask, if the tile has one. q/k stay in their
+    storage dtype (bf16) so the MXU runs at full bf16 rate with fp32
+    accumulation; the softmax scale is applied to the fp32 logits AFTER the
+    dot (pre-scaling q in bf16 would round)."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * sm_scale
@@ -127,7 +140,7 @@ def _scores(q, k, sm_scale, mask_row, bias_tile, pos_mask):
         s = s + bias_tile
     if mask_row is not None:
         s = s + mask_row
-    return jnp.where(pos_mask, s, NEG_INF)
+    return s if pos_mask is None else jnp.where(pos_mask, s, NEG_INF)
 
 
 # ---------------------------------------------------------------------------
@@ -227,12 +240,15 @@ def _fwd_kernel(*refs, sk_real: int, block_k: int, causal: bool,
 def _ds_tile(spec, s, do, v, lse, delta, logit_bias):
     """Shared backward score-gradient: recompute p from the fp32 score
     tile, then ``ds`` (unscaled — the chain-rule sm_scale lands at the
-    dq/dk finalize, and dbias takes ds as-is). Returns (p, ds)."""
+    dq/dk finalize, and dbias takes ds as-is). ``lse``/``delta`` are the
+    rows' statistics, ``(rows,)`` as the tiled kernels read them or already
+    columns ``(rows, 1)``. Returns (p, ds)."""
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
     if spec.kind == "softmax":
-        p = jnp.exp(s - lse[:, None])
-        ds = p * (dp - delta[:, None])
+        col = (lambda x: x[:, None]) if lse.ndim == 1 else (lambda x: x)
+        p = jnp.exp(s - col(lse))
+        ds = p * (dp - col(delta))
     else:
         p = jax.nn.sigmoid(s + logit_bias)
         ds = p * (1.0 - p) * dp
@@ -406,6 +422,124 @@ def _bwd_dbias_kernel(*refs, sq_real: int, sk_real: int, block_q: int,
 
 
 # ---------------------------------------------------------------------------
+# Single-tile kernels: a head's whole padded sequence is one resident tile
+# ---------------------------------------------------------------------------
+
+_TN = (((0,), (0,)), ((), ()))  # a^T b: contract the row dimension of both
+
+
+def _single_tile_masks(sq_p: int, sk_p: int, sk_real: int, causal: bool):
+    """The masks every head of a single-tile cell shares: the padded keys as
+    an additive ``(1, sk_p)`` row (one add per score, no 2-D iota), and the
+    causal triangle as a 2-D predicate only where it is asked for."""
+    pad_row = None
+    if sk_real < sk_p:
+        k_pos = jax.lax.broadcasted_iota(jnp.int32, (1, sk_p), 1)
+        pad_row = jnp.where(k_pos < sk_real, 0.0, NEG_INF)
+    pos = None
+    if causal:
+        pos = (jax.lax.broadcasted_iota(jnp.int32, (sq_p, sk_p), 1)
+               <= jax.lax.broadcasted_iota(jnp.int32, (sq_p, sk_p), 0))
+    return pad_row, pos
+
+
+def _mask_row(mask_ref, h, pad_row):
+    if mask_ref is None:
+        return pad_row
+    return mask_ref[h] if pad_row is None else mask_ref[h] + pad_row
+
+
+def _fwd_single_kernel(*refs, sk_real: int, causal: bool, sm_scale: float,
+                       logit_bias: float, spec: VariantSpec):
+    """Grid ``(BN/hb,)``: every row sees all its keys at once, so the
+    softmax is one exact pass per head — no running max/sum, no accumulator
+    rescale, no init/finalize steps."""
+    softmax = spec.kind == "softmax"
+    it = iter(refs)
+    q_ref, k_ref, v_ref = next(it), next(it), next(it)
+    mask_ref = next(it) if spec.has_mask else None
+    o_ref = next(it)
+    lse_ref = next(it) if softmax else None
+    hb, sq_p, _ = q_ref.shape
+    pad_row, pos = _single_tile_masks(sq_p, k_ref.shape[1], sk_real, causal)
+
+    def head(h, carry):
+        v = v_ref[h]
+        s = _scores(q_ref[h], k_ref[h], sm_scale,
+                    _mask_row(mask_ref, h, pad_row), None, pos)
+        if softmax:
+            m = jnp.max(s, axis=1, keepdims=True)
+            p = jnp.exp(s - m)
+            # the row's max contributes exp(0): l >= 1, never 0
+            l = jnp.sum(p, axis=1, keepdims=True)
+        else:
+            p = jax.nn.sigmoid(s + logit_bias)
+        o = jax.lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        if softmax:
+            o = o / l
+            lse_ref[h, 0, :] = (m + jnp.log(l))[:, 0]
+        o_ref[h] = o.astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, hb, head, 0)
+
+
+def _bwd_single_kernel(*refs, sk_real: int, causal: bool, sm_scale: float,
+                       logit_bias: float, spec: VariantSpec, has_dlse: bool):
+    """Grid ``(BN/hb,)``, all three gradients from one pass over the scores:
+    5 matmuls and 1 exponential per score element, every operand read once
+    (the tiled dq + dk/dv pair recomputes s, p and dp in each kernel).
+    ``delta = rowsum(do * o)`` is taken here, less the lse cotangent."""
+    softmax = spec.kind == "softmax"
+    it = iter(refs)
+    q_ref, k_ref, v_ref = next(it), next(it), next(it)
+    mask_ref = next(it) if spec.has_mask else None
+    do_ref = next(it)
+    o_ref = next(it) if softmax else None
+    lse_ref = next(it) if softmax else None
+    dlse_ref = next(it) if has_dlse else None
+    dq_ref, dk_ref, dv_ref = next(it), next(it), next(it)
+    hb, sq_p, _ = q_ref.shape
+    pad_row, pos = _single_tile_masks(sq_p, k_ref.shape[1], sk_real, causal)
+
+    def head(h, carry):
+        q, k, do = q_ref[h], k_ref[h], do_ref[h]
+        s = _scores(q, k, sm_scale, _mask_row(mask_ref, h, pad_row), None,
+                    pos)
+        lse = delta = None
+        if softmax:
+            lse = lse_ref[h, 0, :][:, None]
+            delta = jnp.sum(do.astype(jnp.float32)
+                            * o_ref[h].astype(jnp.float32),
+                            axis=1, keepdims=True)
+            if has_dlse:
+                # the lse output adds dlse_i * p_ij to ds_ij, and
+                # ds = p * (dp - delta): delta -= dlse covers it
+                delta = delta - dlse_ref[h, 0, :][:, None]
+        p, ds = _ds_tile(spec, s, do, v_ref[h], lse, delta, logit_bias)
+        # dv = p^T do and dk = ds^T q, taken as (do^T p)^T and (q^T ds)^T:
+        # the operand Mosaic has to transpose is then the (S, D) one and the
+        # (D, S) result is turned back, not the (S, S) tile (1.17 -> 1.03 ms
+        # a call at ViT-L's shapes on the v5e). p is rounded only as dv's
+        # MXU operand; ds comes from the fp32 p.
+        dv_ref[h] = jax.lax.dot_general(
+            do, p.astype(do.dtype), _TN,
+            preferred_element_type=jnp.float32).T.astype(dv_ref.dtype)
+        ds = ds.astype(q.dtype)
+        dk_ref[h] = (jax.lax.dot_general(
+            q, ds, _TN, preferred_element_type=jnp.float32).T
+            * sm_scale).astype(dk_ref.dtype)
+        dq_ref[h] = (jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+            * sm_scale).astype(dq_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, hb, head, 0)
+
+
+# ---------------------------------------------------------------------------
 # Host-side wrappers
 # ---------------------------------------------------------------------------
 
@@ -424,6 +558,12 @@ def _pad_seq(x: jax.Array, target: int) -> jax.Array:
     if pad == 0:
         return x
     return jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+
+
+def _pad_mask(maskadd: jax.Array, sk_p: int) -> jax.Array:
+    """Additive ``(BN, 1, Sk)`` mask rows out to the padded key length (the
+    kernels mask the padded keys themselves)."""
+    return jnp.pad(maskadd, ((0, 0), (0, 0), (0, sk_p - maskadd.shape[2])))
 
 
 def _pad_last(x: jax.Array, target: int) -> jax.Array:
@@ -562,6 +702,138 @@ def _pick_hb(bn: int, block_q: int, block_k: int, d: int,
     return 1
 
 
+#: what the single-tile model below may add up to for one grid cell. A call
+#: asks Mosaic for twice its model as scoped VMEM, so at most 64 MiB of the
+#: v5e's 128 (the 16 MiB default scope is what `_VMEM_BUDGET` was sized for).
+#: It admits S_p <= 1152 for bf16 at D_p 64 and 128; on the v5e the single
+#: tile beat the tiled kernels at every admitted length tried (640, 768, 1024,
+#: 1152; PERF.md, PR 25).
+_SINGLE_TILE_BUDGET = 32 * 1024 * 1024
+
+
+def _single_tile_vmem_bytes(sq_p: int, sk_p: int, d: int, itemsize: int,
+                            spec: VariantSpec) -> tuple[int, int]:
+    """``(per resident head, live temporaries)`` bytes of a single-tile
+    cell, sized on the fused backward (the larger of the two kernels): its
+    q/do/o/dq and k/v/dk/dv tiles with the pipeline's double buffering and
+    the 8-sublane stat/mask rows per head, and one head's fp32 s/p/dp/ds
+    with the two MXU-operand copies, which the head loop reuses."""
+    per_head = 2 * 4 * (sq_p + sk_p) * d * itemsize
+    if spec.kind == "softmax":
+        per_head += 2 * 2 * 8 * sq_p * 4
+    if spec.has_mask:
+        per_head += 2 * 8 * sk_p * 4
+    return per_head, sq_p * sk_p * (4 * 4 + 2 * itemsize)
+
+
+def _single_tile_hb(bn: int, sq_p: int, sk_p: int, d: int, itemsize: int,
+                    spec: VariantSpec) -> int:
+    """THE regime rule, a test of shapes alone: heads per cell if one head's
+    whole padded sequence fits the budget as one resident tile, else 0 (the
+    tiled kernels run). The bias variant stays tiled: its dbias kernel
+    accumulates over the batch, which is another grid."""
+    if spec.has_bias:
+        return 0
+    per_head, live = _single_tile_vmem_bytes(sq_p, sk_p, d, itemsize, spec)
+    for hb in (8, 4, 2, 1):
+        if bn % hb == 0 and hb * per_head + live <= _SINGLE_TILE_BUDGET:
+            return hb
+    return 0
+
+
+def _single_tile_params(hb: int, sq_p: int, sk_p: int, d: int, itemsize: int,
+                        spec: VariantSpec) -> pltpu.CompilerParams:
+    per_head, live = _single_tile_vmem_bytes(sq_p, sk_p, d, itemsize, spec)
+    # twice the model: Mosaic's own matmul and relayout temporaries
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",),
+        vmem_limit_bytes=max(2 * (hb * per_head + live), _SINGLE_TILE_BUDGET))
+
+
+def _count_call(regime: str) -> None:
+    """One count per pallas_call built (trace time, like the tuner's
+    ``jimm_tune_*``): ``jimm_flash_single_tile_total`` /
+    ``jimm_flash_tiled_total``."""
+    from jimm_tpu.obs.registry import get_registry
+    get_registry("jimm_flash").counter(f"{regime}_total").inc()
+
+
+def _fwd_single(qp, kp, vp, maskadd, causal, spec, sm_scale, logit_bias,
+                sk: int, hb: int):
+    """The forward as one resident tile per head: grid ``(BN/hb,)``."""
+    softmax = spec.kind == "softmax"
+    bn, sq_p, d = qp.shape
+    sk_p = kp.shape[1]
+    head3 = lambda h: (h, 0, 0)  # noqa: E731
+    inputs = [qp, kp, vp]
+    in_specs = [pl.BlockSpec((hb, sq_p, d), head3),
+                pl.BlockSpec((hb, sk_p, d), head3),
+                pl.BlockSpec((hb, sk_p, d), head3)]
+    if spec.has_mask:
+        inputs.append(_pad_mask(maskadd, sk_p))
+        in_specs.append(pl.BlockSpec((hb, 1, sk_p), head3))
+    out_specs = [pl.BlockSpec((hb, sq_p, d), head3)]
+    out_shape = [jax.ShapeDtypeStruct((bn, sq_p, d), qp.dtype)]
+    if softmax:
+        out_specs.append(pl.BlockSpec((hb, 1, sq_p), head3))
+        out_shape.append(jax.ShapeDtypeStruct((bn, 1, sq_p), jnp.float32))
+    _count_call("single_tile")
+    outs = pl.pallas_call(
+        partial(_fwd_single_kernel, sk_real=sk, causal=causal,
+                sm_scale=sm_scale, logit_bias=logit_bias, spec=spec),
+        grid=(bn // hb,),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=_single_tile_params(hb, sq_p, sk_p, d,
+                                            qp.dtype.itemsize, spec),
+        interpret=_interpret(),
+    )(*inputs)
+    return outs[0], (outs[1] if softmax else None)
+
+
+def _bwd_single(qp, kp, vp, maskadd, dop, o, lse, dlse, causal, spec,
+                sm_scale, logit_bias, sk: int, hb: int):
+    """dq, dk, dv (padded) from the one fused backward kernel."""
+    softmax = spec.kind == "softmax"
+    bn, sq_p, d = qp.shape
+    sk_p = kp.shape[1]
+    head3 = lambda h: (h, 0, 0)  # noqa: E731
+    q_spec = pl.BlockSpec((hb, sq_p, d), head3)
+    kv_spec = pl.BlockSpec((hb, sk_p, d), head3)
+    stat_spec = pl.BlockSpec((hb, 1, sq_p), head3)
+    inputs = [qp, kp, vp]
+    in_specs = [q_spec, kv_spec, kv_spec]
+    if spec.has_mask:
+        inputs.append(_pad_mask(maskadd, sk_p))
+        in_specs.append(pl.BlockSpec((hb, 1, sk_p), head3))
+    inputs.append(dop)
+    in_specs.append(q_spec)
+    if softmax:
+        stat = lambda x: jnp.pad(  # noqa: E731
+            x.astype(jnp.float32), ((0, 0), (0, sq_p - x.shape[1])))[:, None]
+        inputs += [_pad_seq(o, sq_p), stat(lse)]
+        in_specs += [q_spec, stat_spec]
+        if dlse is not None:
+            inputs.append(stat(dlse))
+            in_specs.append(stat_spec)
+    _count_call("single_tile")
+    return pl.pallas_call(
+        partial(_bwd_single_kernel, sk_real=sk, causal=causal,
+                sm_scale=sm_scale, logit_bias=logit_bias, spec=spec,
+                has_dlse=softmax and dlse is not None),
+        grid=(bn // hb,),
+        in_specs=in_specs,
+        out_specs=[q_spec, kv_spec, kv_spec],
+        out_shape=[jax.ShapeDtypeStruct((bn, sq_p, d), qp.dtype),
+                   jax.ShapeDtypeStruct((bn, sk_p, d), qp.dtype),
+                   jax.ShapeDtypeStruct((bn, sk_p, d), qp.dtype)],
+        compiler_params=_single_tile_params(hb, sq_p, sk_p, d,
+                                            qp.dtype.itemsize, spec),
+        interpret=_interpret(),
+    )(*inputs)
+
+
 def _fwd_pallas(q3, k3, v3, maskadd, bias, causal, spec, sm_scale,
                 logit_bias, block_q, block_k):
     """Assemble and run the forward pallas_call for any variant. Returns
@@ -572,6 +844,11 @@ def _fwd_pallas(q3, k3, v3, maskadd, bias, causal, spec, sm_scale,
     sq_p, sk_p = _ceil_to(sq, block_q), _ceil_to(sk, block_k)
     qp, kp, vp = (_pad_seq(q3, sq_p), _pad_seq(k3, sk_p), _pad_seq(v3, sk_p))
     n_q, n_k = sq_p // block_q, sk_p // block_k
+    if n_q == n_k == 1:
+        hb = _single_tile_hb(bn, sq_p, sk_p, d, q3.dtype.itemsize, spec)
+        if hb:
+            return _fwd_single(qp, kp, vp, maskadd, causal, spec, sm_scale,
+                               logit_bias, sk, hb)
     n_heads = bias.shape[0] if spec.has_bias else bn
     hb = _pick_hb(bn, block_q, block_k, d, spec, n_heads)
     kernel = partial(_fwd_kernel, sk_real=sk, block_k=block_k, causal=causal,
@@ -586,7 +863,7 @@ def _fwd_pallas(q3, k3, v3, maskadd, bias, causal, spec, sm_scale,
         pl.BlockSpec((hb, block_k, d), kv_idx),
     ]
     if spec.has_mask:
-        inputs.append(jnp.pad(maskadd, ((0, 0), (0, 0), (0, sk_p - sk))))
+        inputs.append(_pad_mask(maskadd, sk_p))
         in_specs.append(pl.BlockSpec(
             (hb, 1, block_k), _mask_fwd_index(block_q, block_k, n_k, causal)))
     if spec.has_bias:
@@ -604,6 +881,7 @@ def _fwd_pallas(q3, k3, v3, maskadd, bias, causal, spec, sm_scale,
         out_shape.append(jax.ShapeDtypeStruct((bn, 1, sq_p), jnp.float32))
         scratch = [pltpu.VMEM((hb, block_q, _LANES), jnp.float32),
                    pltpu.VMEM((hb, block_q, _LANES), jnp.float32)] + scratch
+    _count_call("tiled")
     outs = pl.pallas_call(
         kernel,
         grid=(bn // hb, n_q, n_k),
@@ -656,12 +934,19 @@ def _flash_bwd(causal, spec, sm_scale, logit_bias, block_q, block_k, res,
     n_q, n_k = sq_p // block_q, sk_p // block_k
     qp, dop = _pad_seq(q3, sq_p), _pad_seq(do, sq_p)
     kp, vp = _pad_seq(k3, sk_p), _pad_seq(v3, sk_p)
+    if n_q == n_k == 1:
+        hb = _single_tile_hb(bn, sq_p, sk_p, d, q3.dtype.itemsize, spec)
+        if hb:
+            dq, dk, dv = _bwd_single(qp, kp, vp, maskadd, dop, o, lse, dlse,
+                                     causal, spec, sm_scale, logit_bias, sk,
+                                     hb)
+            return (dq[:, :sq], dk[:, :sk], dv[:, :sk],
+                    jnp.zeros_like(maskadd) if spec.has_mask else None, None)
     n_heads = bias.shape[0] if spec.has_bias else bn
     hb = _pick_hb(bn, block_q, block_k, d, spec, n_heads)
     n_hb = n_heads // hb
 
-    mp = (jnp.pad(maskadd, ((0, 0), (0, 0), (0, sk_p - sk)))
-          if spec.has_mask else None)
+    mp = _pad_mask(maskadd, sk_p) if spec.has_mask else None
     bp = (jnp.pad(bias, ((0, 0), (0, sq_p - sq), (0, sk_p - sk)))
           if spec.has_bias else None)
     stats = []
@@ -699,6 +984,7 @@ def _flash_bwd(causal, spec, sm_scale, logit_bias, block_q, block_k, res,
     if softmax:
         dq_inputs += stats
         dq_specs += [stat_spec, stat_spec]
+    _count_call("tiled")
     dq = pl.pallas_call(
         partial(_bwd_dq_kernel, sk_real=sk, block_k=block_k, causal=causal,
                 sm_scale=sm_scale, logit_bias=logit_bias, n_k=n_k, spec=spec),
@@ -734,6 +1020,7 @@ def _flash_bwd(causal, spec, sm_scale, logit_bias, block_q, block_k, res,
         dkv_inputs += stats
         dkv_specs += [pl.BlockSpec((hb, 1, block_q), stat_idx),
                       pl.BlockSpec((hb, 1, block_q), stat_idx)]
+    _count_call("tiled")
     dk, dv = pl.pallas_call(
         partial(_bwd_dkv_kernel, sq_real=sq, block_q=block_q, causal=causal,
                 sm_scale=sm_scale, logit_bias=logit_bias, n_q=n_q, spec=spec),
@@ -779,6 +1066,7 @@ def _flash_bwd(causal, spec, sm_scale, logit_bias, block_q, block_k, res,
             db_inputs += stats
             db_specs += [pl.BlockSpec((hb, 1, block_q), stat_idx4),
                          pl.BlockSpec((hb, 1, block_q), stat_idx4)]
+        _count_call("tiled")
         dbias = pl.pallas_call(
             partial(_bwd_dbias_kernel, sq_real=sq, sk_real=sk,
                     block_q=block_q, block_k=block_k, causal=causal,
@@ -849,19 +1137,33 @@ def _resolve_blocks(q, k, v, block_q, block_k,
             int(block_k if block_k is not None else cfg["block_k"]))
 
 
-def _prologue(q, k, v, block_q, block_k, kernel: str = "flash_attention"):
+def _fit_blocks(sq: int, sk: int, d: int, itemsize: int, spec: VariantSpec,
+                block_q: int, block_k: int, requested: bool = False):
+    """The blocks the kernels run at. Unless the caller ``requested`` its
+    own, a sequence the single-tile rule admits is one block, its whole
+    lane-padded length; every other takes the resolved blocks fitted to its
+    length (`_pick_block`), as the tiled kernels always have."""
+    sq_p, sk_p = _ceil_to(sq, _LANES), _ceil_to(sk, _LANES)
+    if not requested and _single_tile_hb(1, sq_p, sk_p, d, itemsize, spec):
+        return sq_p, sk_p
+    return (min(_pick_block(sq, block_q), sq_p),
+            min(_pick_block(sk, block_k), sk_p))
+
+
+def _prologue(q, k, v, block_q, block_k, kernel: str = "flash_attention",
+              spec: VariantSpec = _SOFTMAX):
     """Shared head-flattening + scale/block selection for every entry
     point. Pads off-tile head dims up (scale still uses the REAL d)."""
     d = q.shape[-1]
     sm_scale = 1.0 / (d ** 0.5)
+    dp = _head_pad_target(d)
+    requested = block_q is not None or block_k is not None
     block_q, block_k = _resolve_blocks(q, k, v, block_q, block_k,
                                        kernel=kernel)
-    block_q = min(_pick_block(q.shape[1], block_q),
-                  _ceil_to(q.shape[1], 128))
-    block_k = min(_pick_block(k.shape[1], block_k),
-                  _ceil_to(k.shape[1], 128))
+    block_q, block_k = _fit_blocks(q.shape[1], k.shape[1], dp,
+                                   q.dtype.itemsize, spec, block_q, block_k,
+                                   requested)
     q3, k3, v3 = map(_flatten_heads, (q, k, v))
-    dp = _head_pad_target(d)
     if dp != d:
         q3, k3, v3 = (_pad_last(x, dp) for x in (q3, k3, v3))
     return q3, k3, v3, sm_scale, block_q, block_k
@@ -927,9 +1229,9 @@ def flash_attention_masked(q: jax.Array, k: jax.Array, v: jax.Array,
     b, _, n, d = q.shape
     sk = k.shape[1]
     maskadd = _expand_mask(_canon_mask(mask, b, sk), n)
-    q3, k3, v3, sm_scale, block_q, block_k = _prologue(
-        q, k, v, block_q, block_k, kernel="flash_attention_masked")
     spec = VariantSpec(kind="softmax", has_mask=True)
+    q3, k3, v3, sm_scale, block_q, block_k = _prologue(
+        q, k, v, block_q, block_k, kernel="flash_attention_masked", spec=spec)
     o = _flash(q3, k3, v3, maskadd, None, is_causal, spec, sm_scale, 0.0,
                block_q, block_k)
     return _unflatten_heads(o, b, n)[..., :d]
@@ -948,9 +1250,9 @@ def flash_attention_bias(q: jax.Array, k: jax.Array, v: jax.Array,
     b, sq, n, d = q.shape
     sk = k.shape[1]
     bias3 = _canon_bias(bias, n, sq, sk)
-    q3, k3, v3, sm_scale, block_q, block_k = _prologue(
-        q, k, v, block_q, block_k, kernel="flash_attention_bias")
     spec = VariantSpec(kind="softmax", has_bias=True)
+    q3, k3, v3, sm_scale, block_q, block_k = _prologue(
+        q, k, v, block_q, block_k, kernel="flash_attention_bias", spec=spec)
     o = _flash(q3, k3, v3, None, bias3, is_causal, spec, sm_scale, 0.0,
                block_q, block_k)
     return _unflatten_heads(o, b, n)[..., :d]
@@ -977,7 +1279,7 @@ def sigmoid_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     maskadd = (_expand_mask(_canon_mask(mask, b, sk), n)
                if mask is not None else None)
     q3, k3, v3, sm_scale, block_q, block_k = _prologue(
-        q, k, v, block_q, block_k, kernel="sigmoid_attention")
+        q, k, v, block_q, block_k, kernel="sigmoid_attention", spec=spec)
     o = _flash(q3, k3, v3, maskadd, None, is_causal, spec, sm_scale,
                float(logit_bias), block_q, block_k)
     return _unflatten_heads(o, b, n)[..., :d]

@@ -410,24 +410,24 @@ def _canon_mask_rows(mask, b: int, sk: int):
     return jnp.where(mask != 0, 0.0, NEG_INF).astype(jnp.float32)
 
 
-def _resolve_ring_blocks(q, k, v, n_dev: int):
+def _resolve_ring_blocks(q, k, v, n_dev: int, kind: str, masked: bool):
     """Per-hop flash block sizes through the tune cache: keyed on the LOCAL
     chunk shapes (what each hop's kernel actually sees), kernel name
-    ``"ring_attention"``. Lookup only — never a measurement."""
+    ``"ring_attention"``. Lookup only — never a measurement. A chunk short
+    enough for the family's single-tile rule is one block, as on one chip."""
     from jimm_tpu.ops.flash_attention import (DEFAULT_BLOCK_K,
-                                              DEFAULT_BLOCK_Q, _ceil_to,
-                                              _pick_block)
+                                              DEFAULT_BLOCK_Q, VariantSpec,
+                                              _fit_blocks)
     from jimm_tpu.tune import best_config
     local = lambda x: (x.shape[0], x.shape[1] // n_dev) + x.shape[2:]  # noqa: E731
     cfg = best_config("ring_attention", (local(q), local(k), local(v)),
                       (q.dtype, k.dtype, v.dtype),
                       default={"block_q": DEFAULT_BLOCK_Q,
                                "block_k": DEFAULT_BLOCK_K})
-    sq = q.shape[1] // n_dev
-    sk = k.shape[1] // n_dev
-    block_q = min(_pick_block(sq, int(cfg["block_q"])), _ceil_to(sq, 128))
-    block_k = min(_pick_block(sk, int(cfg["block_k"])), _ceil_to(sk, 128))
-    return block_q, block_k
+    return _fit_blocks(q.shape[1] // n_dev, k.shape[1] // n_dev, q.shape[-1],
+                       q.dtype.itemsize,
+                       VariantSpec(kind=kind, has_mask=masked),
+                       int(cfg["block_q"]), int(cfg["block_k"]))
 
 
 def _count_permuted_bytes(q, n_dev: int, *, plan: str, masked: bool) -> None:
@@ -481,8 +481,8 @@ def ring_attention_sp(q: jax.Array, k: jax.Array, v: jax.Array, *,
         raise ValueError("the per-hop flash ring is non-causal (the hop "
                          "mask is key-padding rows); causal softmax rings "
                          "go through parallel/ring_attention.py")
-    blocks = (_resolve_ring_blocks(q, k, v, n_dev) if impl == "flash"
-              else (0, 0))
+    blocks = (_resolve_ring_blocks(q, k, v, n_dev, kind, mask is not None)
+              if impl == "flash" else (0, 0))
 
     _count_permuted_bytes(q, n_dev, plan="ring", masked=mask is not None)
     lb = 0.0 if logit_bias is None else float(logit_bias)

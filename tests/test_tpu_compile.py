@@ -64,10 +64,16 @@ def _flash(shape):
 M, K, N = 4096, 768, 3072  # ViT-B MLP up-projection at batch 16 x 256 tokens
 
 KERNEL_CASES = {
-    # ViT-L/16-384 and CLIP-L/14-336: S=577, D=64
+    # ViT-L/16-384 and CLIP-L/14-336: S=577, D=64 — the single-tile forward
+    # and the fused backward
     "flash_s577_d64": _flash((32, 577, 16, 64)),
     # So400m/14-384: S=729, head width 72 (lane-padded inside the wrapper)
     "flash_s729_d72": _flash((16, 729, 16, 72)),
+    # the largest working set the single-tile rule admits (S_p 1152 at 128
+    # lanes, one head per cell): a VMEM refusal shows here, not on the chip
+    "flash_s1152_d128": _flash((4, 1152, 16, 128)),
+    # the first length over the rule: the tiled kernels, as at the parent
+    "flash_s1153_d64": _flash((4, 1153, 16, 64)),
     "flash_masked_s577_d64": (
         fa.flash_attention_masked,
         [((32, 577, 16, 64), jnp.bfloat16)] * 3 + [((32, 577), jnp.bool_)]),
@@ -87,13 +93,23 @@ KERNEL_CASES = {
 }
 
 
+#: Mosaic calls in forward + backward where it is not the tiled three: the
+#: single-tile regime is one forward and ONE fused backward
+SINGLE_TILE_CALLS = {"flash_s577_d64": 2, "flash_s729_d72": 2,
+                     "flash_masked_s577_d64": 2, "flash_s1152_d128": 2,
+                     "flash_s1153_d64": 3}
+
+
 @pytest.mark.parametrize("case", sorted(KERNEL_CASES))
 def test_kernel_compiles_for_v5e(case, one_chip, compiled_kernels):
     fn, arg_shapes = KERNEL_CASES[case]
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
             for shape, dtype in arg_shapes]
-    compiled = jax.jit(_fwd_bwd(fn)).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(_fwd_bwd(fn)).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    if case in SINGLE_TILE_CALLS:
+        assert text.count("custom_call_target=\"tpu_custom_call\"") \
+            == SINGLE_TILE_CALLS[case]
 
 
 @pytest.mark.slow
