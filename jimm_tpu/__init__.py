@@ -23,11 +23,14 @@ __version__ = "0.1.0"
 #: lazily resolved public names -> defining module
 _LAZY = {
     "CLIP": "jimm_tpu.models",
+    "Ouro": "jimm_tpu.models",
     "SigLIP": "jimm_tpu.models",
     "VisionTransformer": "jimm_tpu.models",
     "CLIPConfig": "jimm_tpu.configs",
     "SigLIPConfig": "jimm_tpu.configs",
     "ViTConfig": "jimm_tpu.configs",
+    "OuroConfig": "jimm_tpu.configs",
+    "DecoderConfig": "jimm_tpu.configs",
     "VisionConfig": "jimm_tpu.configs",
     "TextConfig": "jimm_tpu.configs",
     "TransformerConfig": "jimm_tpu.configs",
@@ -38,7 +41,8 @@ _LAZY = {
 }
 
 __all__ = [
-    "CLIP", "SigLIP", "VisionTransformer",
+    "CLIP", "SigLIP", "VisionTransformer", "Ouro",
+    "OuroConfig", "DecoderConfig",
     "CLIPConfig", "SigLIPConfig", "ViTConfig", "VisionConfig", "TextConfig",
     "TransformerConfig", "PRESETS", "preset",
     "RUNTIME_FIELDS", "with_runtime",

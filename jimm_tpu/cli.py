@@ -100,26 +100,43 @@ def _parse_mesh(spec: str | None, max_devices: int | None = None):
 
 
 def _family(preset_name: str) -> str:
-    for fam in ("vit", "clip", "siglip"):
+    for fam in ("vit", "clip", "siglip", "ouro"):
         if preset_name.startswith(fam):
             return fam
     raise SystemExit(f"cannot infer model family from preset {preset_name!r}")
 
 
 def _model_cls(fam: str):
-    from jimm_tpu import CLIP, SigLIP, VisionTransformer
-    return {"vit": VisionTransformer, "clip": CLIP, "siglip": SigLIP}[fam]
+    from jimm_tpu import CLIP, Ouro, SigLIP, VisionTransformer
+    return {"vit": VisionTransformer, "clip": CLIP, "siglip": SigLIP,
+            "ouro": Ouro}[fam]
 
 
 def _replace_towers(cfg: Any, **fields: Any) -> Any:
-    """dataclasses.replace the same fields in the vision (and, if present,
-    text) tower config."""
-    cfg = dataclasses.replace(
-        cfg, vision=dataclasses.replace(cfg.vision, **fields))
-    if hasattr(cfg, "text"):
-        cfg = dataclasses.replace(
-            cfg, text=dataclasses.replace(cfg.text, **fields))
+    """dataclasses.replace the same fields in every tower config the model
+    has: vision (and text), or the language model's decoder."""
+    for tower in ("vision", "text", "decoder"):
+        if not hasattr(cfg, tower):
+            continue
+        try:
+            cfg = dataclasses.replace(cfg, **{tower: dataclasses.replace(
+                getattr(cfg, tower), **fields)})
+        except TypeError as e:
+            raise SystemExit(f"the {tower} stack does not take this: {e}")
     return cfg
+
+
+def _main_tower(cfg: Any) -> Any:
+    """The tower the loop's set-up reads depth and precision from."""
+    return cfg.decoder if hasattr(cfg, "decoder") else cfg.vision
+
+
+# optimizer defaults of `train` (--lr, --warmup-steps), and the families that
+# carry their own: the looped decoder's exit gates saturate within 20 Adam
+# steps at 1e-3 with no warm-up, and three of its four passes then train on no
+# gradient (docs/models/Ouro.md)
+_OPTIMIZER_DEFAULTS = {"lr": 1e-3, "warmup_steps": 0}
+_FAMILY_OPTIMIZER_DEFAULTS = {"ouro": {"lr": 1e-4, "warmup_steps": 20}}
 
 
 def _norm_for(fam: str) -> dict:
@@ -254,7 +271,8 @@ def _restore_run(args: argparse.Namespace):
 
 def _tiny_override(cfg: Any) -> Any:
     """Shrink any preset to CPU-demo size, keeping its architecture class."""
-    from jimm_tpu.configs import CLIPConfig, SigLIPConfig, ViTConfig
+    from jimm_tpu.configs import (CLIPConfig, OuroConfig, SigLIPConfig,
+                                  ViTConfig)
 
     # depth 4 (not 2) so tiny runs can still exercise pipeline stages x
     # virtual-chunk splits (depth % (stages * virtual) == 0 for 2x2)
@@ -272,6 +290,11 @@ def _tiny_override(cfg: Any) -> Any:
         return dataclasses.replace(cfg, vision=shrink_vision(cfg.vision),
                                    text=shrink_text(cfg.text),
                                    projection_dim=64)
+    if isinstance(cfg, OuroConfig):
+        # the passes stay: a tiny looped model is still looped
+        return dataclasses.replace(cfg, decoder=dataclasses.replace(
+            cfg.decoder, vocab_size=512, seq_len=32, width=64, depth=2,
+            num_heads=4, mlp_dim=176))
     raise TypeError(type(cfg))
 
 
@@ -303,6 +326,12 @@ def cmd_presets(args: argparse.Namespace) -> int:
         return f"{n / 1e6:8.1f}M"
 
     for name, cfg in PRESETS.items():
+        if hasattr(cfg, "decoder"):
+            d = cfg.decoder
+            print(f"{name:32s} {params_m(name, cfg)} "
+                  f"decoder(width={d.width} depth={d.depth} x {d.loops} "
+                  f"passes vocab={d.vocab_size} seq={d.seq_len})")
+            continue
         v = cfg.vision
         extra = ""
         if hasattr(cfg, "text"):
@@ -352,16 +381,23 @@ def train(args: argparse.Namespace) -> Any:
     import numpy as np
     from flax import nnx
 
-    from jimm_tpu import preset
+    from jimm_tpu import obs, preset
     from jimm_tpu.data import (PrefetchIterator, blob_classification,
-                               contrastive_pairs)
+                               contrastive_pairs, token_sequences)
     from jimm_tpu.parallel import PRESET_RULES, use_sharding
     from jimm_tpu.train import (CheckpointManager, MetricsLogger,
                                 OptimizerConfig, StepTimer,
                                 make_classifier_train_step,
-                                make_contrastive_train_step, make_optimizer)
+                                make_contrastive_train_step,
+                                make_lm_train_step, make_optimizer)
 
     fam = _family(args.preset)
+    for name, value in {**_OPTIMIZER_DEFAULTS,
+                        **_FAMILY_OPTIMIZER_DEFAULTS.get(fam, {})}.items():
+        if getattr(args, name) is None:
+            setattr(args, name, value)
+            if name == "warmup_steps":  # a default fits the run, quietly
+                args.warmup_steps = min(value, max(args.steps - 1, 0))
     if args.naflex and fam != "siglip":
         raise SystemExit("--naflex trains SigLIP2-style models; "
                          "use a siglip preset")
@@ -374,6 +410,17 @@ def train(args: argparse.Namespace) -> Any:
             raise SystemExit("--tiny conflicts with --from-pretrained "
                              "(the checkpoint defines the architecture)")
         cfg = _tiny_override(cfg)
+    if fam == "ouro":
+        if args.from_pretrained or args.data:
+            raise SystemExit("the language-model family trains from --seed "
+                             "on the program's own token generator: no "
+                             "checkpoint loader and no --data reader yet")
+        lm = {k: v for k, v in (("depth", args.num_layers),
+                                ("seq_len", args.seq_len)) if v}
+        cfg = _replace_towers(cfg, **lm)
+    elif args.num_layers or args.seq_len:
+        raise SystemExit("--num-layers and --seq-len shape a language model "
+                         "(an ouro preset)")
 
     # execution-strategy overrides, built ONCE: the preset path applies
     # them to cfg, the fine-tune path passes them to from_pretrained
@@ -434,7 +481,7 @@ def train(args: argparse.Namespace) -> Any:
         # (a checkpoint's depth is unknown here — explicit unrolls only);
         # an adopted, measured unroll above outranks this heuristic
         if _jax.default_backend() == "tpu":
-            rt.setdefault("scan_unroll", cfg.vision.depth)
+            rt.setdefault("scan_unroll", _main_tower(cfg).depth)
     if rt and not args.from_pretrained:
         cfg = _replace_towers(cfg, **rt)
     def _validate_pp(cfg_obj) -> None:
@@ -508,7 +555,7 @@ def train(args: argparse.Namespace) -> Any:
     # optimizer tracks nnx.Param state, and the fp8 wrapper shares the
     # Linear's kernel/bias Params (amax histories are plain Variables, so
     # they never enter optimizer state)
-    precision = getattr(cfg.vision, "precision", "bf16")
+    precision = getattr(_main_tower(cfg), "precision", "bf16")
     if precision != "bf16":
         from jimm_tpu.quant.policy import apply_precision_policy
         n_lowp = apply_precision_policy(model, precision)
@@ -603,7 +650,20 @@ def train(args: argparse.Namespace) -> Any:
         grain_stream = CheckpointableGrainStream(grain_iter)
         return grain_stream.batches()
 
-    if fam == "vit":
+    lm_counters = None
+    if fam == "ouro":
+        step_fn = make_lm_train_step(donate=True)
+        d = cfg.decoder
+        data = token_sequences(args.batch_size, seq_len=d.seq_len,
+                               vocab_size=d.vocab_size, seed=args.seed)
+        # per step: tokens trained on, and block applications (passes x
+        # layers), the unit a looped model's cost is counted in
+        lm_counters = (
+            (obs.get_registry("jimm_lm").counter("tokens_total"),
+             args.batch_size * d.seq_len),
+            (obs.get_registry("jimm_loop").counter(
+                "block_applications_total"), d.loops * d.depth))
+    elif fam == "vit":
         step_fn = make_classifier_train_step(donate=True)
         if args.data and args.loader == "grain":
             data = _grain_data("classification")
@@ -695,7 +755,6 @@ def train(args: argparse.Namespace) -> Any:
         for _ in range(start_step):
             next(data)
 
-    from jimm_tpu import obs
     logger = MetricsLogger(path=args.metrics_file, print_every=args.log_every,
                            tensorboard_dir=args.tensorboard_dir,
                            registry=obs.get_registry("jimm_train"))
@@ -773,12 +832,17 @@ def train(args: argparse.Namespace) -> Any:
                     metrics = step_fn(model, optimizer, *batch)
                 with acct.measure("device_wait", bucket):
                     dt = timer.stop(metrics["loss"])
+                for counter, per_step in lm_counters or ():
+                    counter.inc(per_step)
                 if profiler_ctx is not None and step == profile_stop:
                     profiler_ctx.__exit__(None, None, None)
                     profiler_ctx = None
                     print(f"profile trace written to {args.profile_dir}")
                 with acct.measure("host_sync"):
-                    host_metrics = {k: float(v) for k, v in metrics.items()}
+                    # one transfer for the whole dict (a looped model's step
+                    # returns nine scalars; one by one they cost 4-8 ms)
+                    host_metrics = {k: float(v) for k, v
+                                    in jax.device_get(metrics).items()}
                     if fp is not None:
                         host_metrics["batch_fingerprint"] = fp
                     # everything measured since the last row: this step's
@@ -1969,9 +2033,18 @@ def build_parser() -> argparse.ArgumentParser:
                     help="grain loader subprocess count (0 = in-process)")
     sp.add_argument("--num-classes", type=int, default=None,
                     help="override classifier width (vit + --data)")
-    sp.add_argument("--lr", type=float, default=1e-3)
+    sp.add_argument("--num-layers", type=int, default=None,
+                    help="language model: train the preset with its first "
+                         "N layers (one pipeline stage's share)")
+    sp.add_argument("--seq-len", type=int, default=None,
+                    help="language model: tokens of a training sequence")
+    sp.add_argument("--lr", type=float, default=None,
+                    help="peak learning rate (default 1e-3; an ouro preset "
+                         "1e-4)")
     sp.add_argument("--weight-decay", type=float, default=1e-4)
-    sp.add_argument("--warmup-steps", type=int, default=0)
+    sp.add_argument("--warmup-steps", type=int, default=None,
+                    help="linear warm-up steps (default 0; an ouro preset "
+                         "20)")
     sp.add_argument("--seed", type=int, default=0)
     sp.add_argument("--bf16", action="store_true")
     sp.add_argument("--compilation-cache-dir", default=None,

@@ -18,7 +18,7 @@ from dataclasses import dataclass, field
 from typing import Any, Literal
 
 Pooling = Literal["cls", "map", "last", "eot", "none"]
-Activation = Literal["gelu", "gelu_tanh", "quick_gelu"]
+Activation = Literal["gelu", "gelu_tanh", "quick_gelu", "silu"]
 AttnImpl = Literal["auto", "xla", "flash", "flash_masked", "flash_bias",
                    "flash_int8", "sigmoid", "ring", "ulysses", "saveable"]
 #: Training precision policy (`jimm_tpu/quant/policy.py`): "bf16" trains
@@ -199,6 +199,27 @@ class TransformerConfig:
     #: config field records intent so measurements and adopted runtimes
     #: carry it; construction itself never reads it.
     precision: Precision = "bf16"
+    # -- the decoder family's block (`DecoderConfig.encoder`); every default
+    # below is the ViT / CLIP / SigLIP block, whose programs do not change
+    #: "rms": ``x / sqrt(mean(x^2) + ln_eps) * w``, no bias, no mean
+    norm: Literal["layer", "rms"] = "layer"
+    #: a second norm on each sub-layer's OUTPUT, before the residual add
+    #: ("sandwich"): ``x + norm(attn(norm(x)))``
+    post_norm: bool = False
+    #: rotary positions on q and k with this base, the rotate-half pairing
+    #: (i, i + head_dim/2) over the whole head; None = positions come from
+    #: the embedding
+    rope_theta: float | None = None
+    #: ``fc2(act(gate(x)) * fc1(x))`` in place of ``fc2(act(fc1(x)))``
+    gated_mlp: bool = False
+    #: biases on the q/k/v/out and MLP projections
+    use_bias: bool = True
+    #: Looped stack: the same ``depth`` blocks run ``loops`` times (weights
+    #: shared between passes), a final norm closes every pass and its output
+    #: feeds the next, and the call returns the ``loops`` pass outputs
+    #: stacked on a leading axis. 0 = a plain stack: one pass, no norm, the
+    #: carry returned.
+    loops: int = 0
 
     @property
     def head_dim(self) -> int:
@@ -318,6 +339,47 @@ class TextConfig:
 
 
 @dataclass(frozen=True)
+class DecoderConfig:
+    """Causal decoder stack of a language model: RMSNorm around every
+    sub-layer, rotary positions, SwiGLU, no biases, and the whole stack run
+    ``loops`` times with shared weights (1 = a plain decoder)."""
+
+    vocab_size: int = 49152
+    #: tokens of a training sequence (the data path draws ``seq_len + 1``
+    #: ids: inputs and the targets shifted by one)
+    seq_len: int = 4096
+    width: int = 2048
+    depth: int = 48
+    num_heads: int = 16
+    mlp_dim: int = 5632
+    act: Activation = "silu"
+    ln_eps: float = 1e-6
+    rope_theta: float = 1e6
+    loops: int = 4
+    # runtime fields, as the towers have them; the pipelined path and the
+    # fused LayerNorm kernel are not built for this block
+    dropout: float = 0.0
+    attn_impl: AttnImpl = "auto"
+    remat: bool = False
+    remat_policy: RematPolicy = "none"
+    fused_qkv: bool = False
+    scan_unroll: int = 1
+    precision: Precision = "bf16"
+
+    def encoder(self) -> TransformerConfig:
+        return TransformerConfig(
+            width=self.width, depth=self.depth, num_heads=self.num_heads,
+            mlp_dim=self.mlp_dim, act=self.act, ln_eps=self.ln_eps,
+            dropout=self.dropout, causal=True, attn_impl=self.attn_impl,
+            remat=self.remat, remat_policy=self.remat_policy,
+            fused_qkv=self.fused_qkv,
+            scan_unroll=self.scan_unroll, precision=self.precision,
+            norm="rms", post_norm=True, rope_theta=self.rope_theta,
+            gated_mlp=True, use_bias=False, loops=self.loops,
+        )
+
+
+@dataclass(frozen=True)
 class ViTConfig:
     """ViT image classifier (ref `models/vit.py:16-103`): post-norm backbone,
     CLS pooling, LN eps 1e-12 (ref `models/vit.py:73`), optional linear head."""
@@ -360,6 +422,18 @@ class SigLIPConfig:
     projection_dim: int = 768
     logit_scale_init: float = 2.3026  # ln(10), SigLIP paper init
     logit_bias_init: float = -10.0
+
+
+@dataclass(frozen=True)
+class OuroConfig:
+    """Ouro looped language model (ByteDance, 2025): token embedding, the
+    looped `DecoderConfig` stack, and after each pass an exit gate
+    (``Linear(width -> 1)``) and the untied head. Trained on the expected
+    loss over the exit distribution less ``exit_beta`` times its entropy
+    (`train/losses.py::expected_exit_loss`)."""
+
+    decoder: DecoderConfig = field(default_factory=DecoderConfig)
+    exit_beta: float = 0.1
 
 
 def _vit(size: str, patch: int, image: int, classes: int = 1000) -> ViTConfig:
@@ -460,6 +534,8 @@ PRESETS: dict[str, Any] = {
     # verified offline — from_pretrained still loads it from the HF config.)
     "siglip2-so400m-patch14-384": _siglip("So400m", 14, 384, vocab=256000),
     "siglip2-so400m-patch16-256": _siglip("So400m", 16, 256, vocab=256000),
+    # Ouro looped LM: the published 48 layers run 4 times (ByteDance/Ouro-2.6B)
+    "ouro-2.6b": OuroConfig(),
 }
 
 

@@ -16,7 +16,8 @@ from jimm_tpu.data.records import (classification_batches, decode_image,
                                    write_classification_records,
                                    write_image_text_records)
 from jimm_tpu.data.synthetic import (blob_classification, contrastive_pairs,
-                                     naflex_contrastive_pairs)
+                                     naflex_contrastive_pairs,
+                                     token_sequences)
 from jimm_tpu.data.webdataset import (iter_wds_examples, resolve_tar_paths,
                                       wds_classification_batches,
                                       wds_image_text_batches, write_wds_shard)
@@ -26,7 +27,7 @@ from jimm_tpu.data.tfrecord import (TFRecordWriter, crc32c, decode_example,
 
 __all__ = [
     "PrefetchIterator", "blob_classification", "contrastive_pairs",
-    "naflex_contrastive_pairs",
+    "naflex_contrastive_pairs", "token_sequences",
     "patchify_naflex", "image_to_patches", "target_size_for_max_patches",
     "preprocess_batch", "to_float_normalized", "resize_bilinear",
     "center_crop", "native_available", "IMAGENET_MEAN", "IMAGENET_STD",

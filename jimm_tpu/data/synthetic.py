@@ -115,3 +115,15 @@ def naflex_contrastive_pairs(batch_size: int, *, patch_size: int = 16,
         step += 1
         yield (patchify_naflex(warped, patch_size=patch_size,
                                max_num_patches=max_num_patches), tokens)
+
+
+def token_sequences(batch_size: int, *, seq_len: int, vocab_size: int,
+                    seed: int = 0) -> Iterator[tuple[np.ndarray]]:
+    """Language-model batches: ``(batch_size, seq_len + 1)`` int32 ids
+    uniform over the vocabulary (inputs are the first ``seq_len``, targets the
+    ids shifted by one; no padding, no document boundaries). One-element
+    tuples, as the train loop unpacks a batch into the step's arguments."""
+    rng = np.random.default_rng(seed)
+    while True:
+        yield (rng.integers(0, vocab_size, (batch_size, seq_len + 1),
+                            dtype=np.int32),)

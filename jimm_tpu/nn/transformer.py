@@ -62,6 +62,39 @@ def _layernorm(dim: int, eps: float, rngs: nnx.Rngs, *, dtype: Dtype,
         rngs=rngs)
 
 
+def _norm(cfg: TransformerConfig, rngs: nnx.Rngs, *, dtype: Dtype,
+          param_dtype) -> nnx.Module:
+    """The block's normalisation: LayerNorm, or RMSNorm for the decoder
+    family (``x / sqrt(mean(x^2) + eps) * w``, statistics in float32)."""
+    if cfg.norm == "rms":
+        return nnx.RMSNorm(
+            cfg.width, epsilon=cfg.ln_eps, dtype=dtype,
+            param_dtype=param_dtype,
+            scale_init=logical(nnx.initializers.ones_init(), "embed"),
+            rngs=rngs)
+    return _layernorm(cfg.width, cfg.ln_eps, rngs, dtype=dtype,
+                      param_dtype=param_dtype, impl=cfg.ln_impl)
+
+
+def rope_tables(seq_len: int, head_dim: int, theta: float
+                ) -> tuple[jax.Array, jax.Array]:
+    """``(cos, sin)`` of the rotary angles, each ``(seq_len, head_dim / 2)``
+    float32: position ``t`` turns pair ``i`` by ``t * theta**(-2i / D)``."""
+    inv_freq = theta ** (-jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                         / head_dim)
+    angles = jnp.arange(seq_len, dtype=jnp.float32)[:, None] * inv_freq
+    return jnp.cos(angles), jnp.sin(angles)
+
+
+def apply_rope(x: jax.Array, rope: tuple[jax.Array, jax.Array]) -> jax.Array:
+    """Rotate ``(B, S, N, D)`` q or k: the rotate-half pairing, element ``i``
+    with ``i + D/2``, in float32, back in ``x``'s dtype."""
+    cos, sin = (t[None, :, None, :] for t in rope)
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
 class Attention(nnx.Module):
     """Multi-head attention with (H, H) q/k/v/out kernels; supports
     self-attention and cross-attention (MAP pooling probe).
@@ -73,7 +106,7 @@ class Attention(nnx.Module):
 
     def __init__(self, width: int, num_heads: int, rngs: nnx.Rngs, *,
                  is_causal: bool = False, impl: str = "auto",
-                 fused_qkv: bool = False,
+                 fused_qkv: bool = False, use_bias: bool = True,
                  dtype: Dtype = None, param_dtype=jnp.float32):
         if width % num_heads:
             raise ValueError(f"width {width} not divisible by heads {num_heads}")
@@ -83,7 +116,8 @@ class Attention(nnx.Module):
         self.impl = impl
         self.fused_qkv = fused_qkv
         self.dtype = dtype
-        lin = partial(_linear, dtype=dtype, param_dtype=param_dtype)
+        lin = partial(_linear, use_bias=use_bias, dtype=dtype,
+                      param_dtype=param_dtype)
         self.q = lin(width, width, ("embed", "heads"), rngs)
         self.k = lin(width, width, ("embed", "heads"), rngs)
         self.v = lin(width, width, ("embed", "heads"), rngs)
@@ -92,14 +126,18 @@ class Attention(nnx.Module):
     def _project_qkv(self, x: jax.Array) -> tuple[jax.Array, ...]:
         w = jnp.concatenate([self.q.kernel[...], self.k.kernel[...],
                              self.v.kernel[...]], axis=1)
-        b = jnp.concatenate([self.q.bias[...], self.k.bias[...],
-                             self.v.bias[...]])
         dtype = self.dtype or x.dtype
-        qkv = x.astype(dtype) @ w.astype(dtype) + b.astype(dtype)
+        qkv = x.astype(dtype) @ w.astype(dtype)
+        if self.q.bias is not None:
+            b = jnp.concatenate([self.q.bias[...], self.k.bias[...],
+                                 self.v.bias[...]])
+            qkv = qkv + b.astype(dtype)
         return tuple(jnp.split(qkv, 3, axis=-1))
 
     def __call__(self, x: jax.Array, kv: jax.Array | None = None,
-                 mask: jax.Array | None = None) -> jax.Array:
+                 mask: jax.Array | None = None,
+                 rope: tuple[jax.Array, jax.Array] | None = None
+                 ) -> jax.Array:
         B, Sq, _ = x.shape
         if kv is None and self.fused_qkv:
             q, k, v = self._project_qkv(x)
@@ -111,22 +149,36 @@ class Attention(nnx.Module):
         q = q.reshape(B, Sq, self.num_heads, self.head_dim)
         k = k.reshape(B, Sk, self.num_heads, self.head_dim)
         v = v.reshape(B, Sk, self.num_heads, self.head_dim)
+        if rope is not None:
+            q, k = apply_rope(q, rope), apply_rope(k, rope)
         o = dot_product_attention(q, k, v, is_causal=self.is_causal,
                                   mask=mask, impl=self.impl)
         return self.out(o.reshape(B, Sq, self.num_heads * self.head_dim))
 
 
 class Mlp(nnx.Module):
+    """``fc2(act(fc1(x)))``; ``gated``: ``fc2(act(gate(x)) * fc1(x))``
+    (SwiGLU with ``act="silu"``: ``fc1`` is the up projection)."""
+
     def __init__(self, width: int, mlp_dim: int, act: str, rngs: nnx.Rngs, *,
+                 gated: bool = False, use_bias: bool = True,
                  dtype: Dtype = None, param_dtype=jnp.float32):
-        lin = partial(_linear, dtype=dtype, param_dtype=param_dtype)
+        lin = partial(_linear, use_bias=use_bias, dtype=dtype,
+                      param_dtype=param_dtype)
         self.fc1 = lin(width, mlp_dim, ("embed", "mlp"), rngs)
         self.fc2 = lin(mlp_dim, width, ("mlp", "embed"), rngs)
+        if gated:
+            self.gate = lin(width, mlp_dim, ("embed", "mlp"), rngs)
+        self.gated = gated
         self.act: Callable = get_activation(act)
 
     def __call__(self, x: jax.Array) -> jax.Array:
+        if self.gated:
+            h = self.act(self.gate(x)) * self.fc1(x)
+        else:
+            h = self.act(self.fc1(x))
         # name is free (identity) unless a "+act" remat policy saves it
-        return self.fc2(checkpoint_name(self.act(self.fc1(x)), "act_out"))
+        return self.fc2(checkpoint_name(h, "act_out"))
 
 
 #: dropout-stream draws per Block.__call__ (attn residual + mlp residual);
@@ -135,35 +187,45 @@ _BLOCK_DROPOUT_DRAWS = 2
 
 
 class Block(nnx.Module):
-    """Pre-LN residual block (ref `common/transformer.py:116-132`)."""
+    """Pre-LN residual block (ref `common/transformer.py:116-132`). With
+    ``cfg.post_norm`` each sub-layer's output is normed once more before
+    the residual add (the decoder family's "sandwich")."""
 
     def __init__(self, cfg: TransformerConfig, rngs: nnx.Rngs, *,
                  dtype: Dtype = None, param_dtype=jnp.float32):
-        self.ln1 = _layernorm(cfg.width, cfg.ln_eps, rngs, dtype=dtype,
-                              param_dtype=param_dtype, impl=cfg.ln_impl)
+        norm = partial(_norm, cfg, rngs, dtype=dtype, param_dtype=param_dtype)
+        self.ln1 = norm()
         self.attn = Attention(cfg.width, cfg.num_heads, rngs,
                               is_causal=cfg.causal, impl=cfg.attn_impl,
-                              fused_qkv=cfg.fused_qkv,
+                              fused_qkv=cfg.fused_qkv, use_bias=cfg.use_bias,
                               dtype=dtype, param_dtype=param_dtype)
-        self.ln2 = _layernorm(cfg.width, cfg.ln_eps, rngs, dtype=dtype,
-                              param_dtype=param_dtype, impl=cfg.ln_impl)
-        self.mlp = Mlp(cfg.width, cfg.mlp_dim, cfg.act, rngs, dtype=dtype,
-                       param_dtype=param_dtype)
+        self.ln2 = norm()
+        self.mlp = Mlp(cfg.width, cfg.mlp_dim, cfg.act, rngs,
+                       gated=cfg.gated_mlp, use_bias=cfg.use_bias,
+                       dtype=dtype, param_dtype=param_dtype)
         self.dropout = nnx.Dropout(cfg.dropout, rngs=rngs)
+        self.post_norm = cfg.post_norm
+        if cfg.post_norm:
+            self.ln1_post = norm()
+            self.ln2_post = norm()
 
-    def __call__(self, x: jax.Array,
-                 mask: jax.Array | None = None) -> jax.Array:
+    def __call__(self, x: jax.Array, mask: jax.Array | None = None,
+                 rope: tuple[jax.Array, jax.Array] | None = None
+                 ) -> jax.Array:
         # ln outputs carry a checkpoint name so "+ln" remat policies can keep
         # them (skipping the LN recompute in the backward); plain identity
         # under every other policy
-        x = x + self.dropout(self.attn(checkpoint_name(self.ln1(x), "ln_out"),
-                                       mask=mask))
-        x = x + self.dropout(self.mlp(checkpoint_name(self.ln2(x), "ln_out")))
+        a = self.attn(checkpoint_name(self.ln1(x), "ln_out"), mask=mask,
+                      rope=rope)
+        x = x + self.dropout(self.ln1_post(a) if self.post_norm else a)
+        m = self.mlp(checkpoint_name(self.ln2(x), "ln_out"))
+        x = x + self.dropout(self.ln2_post(m) if self.post_norm else m)
         return logical_constraint(x, "batch", "seq", None)
 
 
 class Transformer(nnx.Module):
-    """Depth-stacked encoder, scanned over the ``layers`` axis."""
+    """Depth-stacked encoder, scanned over the ``layers`` axis. With
+    ``cfg.loops`` the stack is entered that many times (`_apply_loops`)."""
 
     def __init__(self, cfg: TransformerConfig, rngs: nnx.Rngs, *,
                  dtype: Dtype = None, param_dtype=jnp.float32):
@@ -178,6 +240,12 @@ class Transformer(nnx.Module):
         # the clone keeps the blocks' captured RngState from aliasing the
         # caller's rngs
         self.blocks = create_block(nnx.clone(rngs))
+        if cfg.loops:
+            if cfg.pipeline:
+                raise ValueError("the looped stack has no pipelined path")
+            # closes every pass; its output is the pass's result AND the
+            # next pass's input
+            self.norm = _norm(cfg, rngs, dtype=dtype, param_dtype=param_dtype)
         if cfg.pipeline and cfg.pp_virtual > 1 and cfg.pp_stages:
             # circular placement is baked into STORAGE order once at
             # construction (stored row j = canonical layer order[j]), so the
@@ -228,13 +296,15 @@ class Transformer(nnx.Module):
             jax.checkpoint_policies.save_only_these_names(*names))
 
     def _apply_stack(self, blocks: Block, x: jax.Array,
-                     mask: jax.Array | None = None) -> jax.Array:
+                     mask: jax.Array | None = None,
+                     rope: tuple[jax.Array, jax.Array] | None = None
+                     ) -> jax.Array:
         """Scan ``x`` through a stacked block module (all layers or one
         pipeline stage's local slice). ``mask`` (bool, broadcastable to
-        (B, N, Sq, Sk)) rides into every layer as a closure capture — it is
-        layer-invariant, so it is not a scan carry."""
+        (B, N, Sq, Sk)) and the ``rope`` tables ride into every layer as
+        closure captures — they are layer-invariant, so not scan carries."""
         def body(block: Block, x: jax.Array) -> jax.Array:
-            return block(x, mask=mask)
+            return block(x, mask=mask, rope=rope)
 
         if self.cfg.remat:
             body = nnx.remat(body, policy=self._remat_policy())
@@ -243,10 +313,33 @@ class Transformer(nnx.Module):
                         transform_metadata={nnx.PARTITION_NAME: "layers"})
         return scan(blocks, x)
 
+    def _apply_loops(self, x: jax.Array, mask: jax.Array | None,
+                     rope: tuple[jax.Array, jax.Array] | None) -> jax.Array:
+        """``cfg.loops`` passes through the SAME stacked blocks, as one rolled
+        outer scan whose body is `_apply_stack` (its own scan, unroll and
+        remat policy) and the closing norm. The weights are closed over, not
+        scanned: a shared leaf's gradient is the sum over its passes, and the
+        backward keeps what the remat policy allows for loops x depth block
+        applications. Returns the pass outputs, ``(loops, B, S, width)``."""
+        def one_pass(modules, x):
+            blocks, norm = modules
+            x = norm(self._apply_stack(blocks, x, mask, rope))
+            return x, x
+
+        scan = nnx.scan(one_pass, in_axes=(None, nnx.Carry),
+                        out_axes=(nnx.Carry, 0), length=self.cfg.loops)
+        return scan((self.blocks, self.norm), x)[1]
+
     def __call__(self, x: jax.Array,
                  mask: jax.Array | None = None) -> jax.Array:
+        rope = None
+        if self.cfg.rope_theta is not None:
+            rope = rope_tables(x.shape[1], self.cfg.head_dim,
+                               self.cfg.rope_theta)
+        if self.cfg.loops:
+            return self._apply_loops(x, mask, rope)
         if not self.cfg.pipeline:
-            return self._apply_stack(self.blocks, x, mask)
+            return self._apply_stack(self.blocks, x, mask, rope)
         if mask is not None:
             raise ValueError(
                 "attention masks are not supported on the pipelined path "

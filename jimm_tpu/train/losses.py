@@ -188,3 +188,69 @@ def ring_sigmoid_loss(img: jax.Array, txt: jax.Array, logit_scale: jax.Array,
         out_specs=P(),
         check_vma=False)
     return fn(img, txt, logit_scale, logit_bias)
+
+
+# ---------------------------------------------------------------------------
+# Language-model losses (the looped decoder family, `models/ouro.py`)
+# ---------------------------------------------------------------------------
+
+def blocked_cross_entropy(hidden: jax.Array, head_kernel: jax.Array,
+                          targets: jax.Array, *, block: int = 1024
+                          ) -> jax.Array:
+    """Per-position softmax cross-entropy of ``hidden @ head_kernel`` against
+    ``targets``, float32, without ever holding the logits: positions go
+    through in blocks of ``block``, and each block's ``(block, vocab)``
+    logits are recomputed in the backward (at 4096 positions and a 49152-word
+    vocabulary one pass's float32 logits are 0.8 GB, and a looped model has
+    one set per pass). ``hidden`` is ``(..., width)`` and ``targets``
+    broadcasts against its leading axes. The matmul runs in ``hidden``'s
+    dtype and the softmax in float32."""
+    lead, width = hidden.shape[:-1], hidden.shape[-1]
+    h = hidden.reshape(-1, width)
+    t = jnp.broadcast_to(targets, lead).reshape(-1)
+    n = h.shape[0]
+    block = min(block, n)
+    pad = -n % block
+    if pad:
+        h, t = jnp.pad(h, ((0, pad), (0, 0))), jnp.pad(t, (0, pad))
+    kernel = head_kernel.astype(h.dtype)
+
+    @jax.checkpoint
+    def one_block(args):
+        h_blk, t_blk = args
+        logits = (h_blk @ kernel).astype(jnp.float32)
+        picked = jnp.take_along_axis(logits, t_blk[:, None], axis=-1)[:, 0]
+        return jax.nn.logsumexp(logits, axis=-1) - picked
+
+    ce = jax.lax.map(one_block, (h.reshape(-1, block, width),
+                                 t.reshape(-1, block)))
+    return ce.reshape(-1)[:n].reshape(lead)
+
+
+def exit_distribution(gate_logits: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """``(p, log p)`` of the pass at which a position exits, from the exit
+    gates' logits ``(R, ...)``: ``lam_r = sigmoid(g_r)``, ``p_r = lam_r *
+    prod_{j<r} (1 - lam_j)`` for ``r < R``, and the last pass takes what is
+    left, ``p_R = prod_{j<R} (1 - lam_j)``, so the R masses sum to one."""
+    g = gate_logits.astype(jnp.float32)
+    log_stay = jax.nn.log_sigmoid(-g)                 # log(1 - lam_r)
+    stayed = jnp.cumsum(log_stay, axis=0) - log_stay  # sum over j < r
+    log_exit = jnp.concatenate(
+        [jax.nn.log_sigmoid(g[:-1]), jnp.zeros_like(g[:1])], axis=0)
+    log_p = stayed + log_exit
+    return jnp.exp(log_p), log_p
+
+
+def expected_exit_loss(ce: jax.Array, gate_logits: jax.Array, *,
+                       beta: float) -> tuple[jax.Array, dict[str, jax.Array]]:
+    """The looped model's stage-one objective: the mean over positions of
+    ``sum_r p_r * CE_r - beta * H(p)`` with ``p`` the `exit_distribution`.
+    ``ce`` and ``gate_logits`` are ``(R, ...)``. Also returns, per pass, the
+    mean cross-entropy and the mean exit mass (``(R,)`` each): where a looped
+    model's training is watched."""
+    p, log_p = exit_distribution(gate_logits)
+    expected = jnp.sum(p * ce, axis=0)
+    entropy = -jnp.sum(p * log_p, axis=0)
+    positions = tuple(range(1, ce.ndim))
+    return jnp.mean(expected - beta * entropy), {
+        "ce": jnp.mean(ce, axis=positions), "p": jnp.mean(p, axis=positions)}

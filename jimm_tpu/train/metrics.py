@@ -206,8 +206,24 @@ def text_fwd_flops(t) -> float:
     return _tower_fwd_flops(t.width, t.depth, t.mlp_dim, t.context_length)
 
 
+def decoder_fwd_flops(d, loops: int | None = None) -> float:
+    """Per-sequence forward FLOPs of a looped `DecoderConfig` language model:
+    per token and block application the four attention projections, the three
+    SwiGLU matmuls and causal attention (q k^T and p v over HALF of S^2: the
+    masked half is not work the algorithm needs), times passes x layers; and
+    per pass the gate and the untied head."""
+    loops = d.loops if loops is None else loops
+    block = 2 * (4 * d.width ** 2 + 3 * d.width * d.mlp_dim) \
+        + 2 * d.seq_len * d.width
+    per_pass = d.depth * block + 2 * d.width * (d.vocab_size + 1)
+    return float(loops * per_pass * d.seq_len)
+
+
 def model_fwd_flops(cfg) -> float:
-    """Per-sample forward FLOPs for a ViT/CLIP/SigLIP config."""
+    """Per-sample forward FLOPs for a ViT/CLIP/SigLIP config, per sequence
+    for a language model."""
+    if hasattr(cfg, "decoder"):
+        return decoder_fwd_flops(cfg.decoder)
     total = vision_fwd_flops(cfg.vision)
     if hasattr(cfg, "text"):
         total += text_fwd_flops(cfg.text)

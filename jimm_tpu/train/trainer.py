@@ -20,7 +20,8 @@ import jax.numpy as jnp
 import optax
 from flax import nnx
 
-from jimm_tpu.train.losses import (clip_softmax_loss, ring_clip_infonce_loss,
+from jimm_tpu.train.losses import (blocked_cross_entropy, clip_softmax_loss,
+                                   expected_exit_loss, ring_clip_infonce_loss,
                                    ring_sigmoid_loss, sigmoid_pairwise_loss)
 
 
@@ -181,5 +182,42 @@ def make_contrastive_train_step(kind: str = "siglip_ring", *, mesh=None,
         with jax.named_scope("optimizer_update"):
             optimizer.update(model, grads)
         return {"loss": loss_val}
+
+    return train_step
+
+
+def lm_loss_fn(model, tokens: jax.Array
+               ) -> tuple[jax.Array, dict[str, jax.Array]]:
+    """The looped language model's loss on ``(B, S + 1)`` token ids: inputs
+    are the first S, targets the ids shifted by one; every pass's logits are
+    taken in blocks (`blocked_cross_entropy`). Returns the loss and the
+    per-pass means of `expected_exit_loss`."""
+    inputs, targets = tokens[:, :-1], tokens[:, 1:]
+    hidden = model.hidden_states(inputs)
+    with jax.named_scope("exit_head"):
+        ce = blocked_cross_entropy(hidden, model.head.kernel[...], targets)
+        return expected_exit_loss(ce, model.exit_gates(hidden),
+                                  beta=model.config.exit_beta)
+
+
+def make_lm_train_step(*, donate: bool = False) -> Callable:
+    """Next-token step of the looped language model. The metrics carry, per
+    pass ``r``, ``loss_exit<r>`` (mean cross-entropy of that pass's logits)
+    and ``exit_p<r>`` (mean exit mass). ``donate`` as in
+    ``make_contrastive_train_step``."""
+
+    @partial(nnx.jit, donate_argnums=(0, 1) if donate else ())
+    def train_step(model: nnx.Module, optimizer: nnx.Optimizer,
+                   tokens: jax.Array) -> dict[str, jax.Array]:
+        with jax.named_scope("fwd_bwd"):
+            (loss, per_pass), grads = nnx.value_and_grad(
+                lambda m: lm_loss_fn(m, tokens), has_aux=True)(model)
+        with jax.named_scope("optimizer_update"):
+            optimizer.update(model, grads)
+        metrics = {"loss": loss}
+        for r in range(per_pass["ce"].shape[0]):
+            metrics[f"loss_exit{r + 1}"] = per_pass["ce"][r]
+            metrics[f"exit_p{r + 1}"] = per_pass["p"][r]
+        return metrics
 
     return train_step

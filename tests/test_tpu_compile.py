@@ -57,8 +57,10 @@ def _fwd_bwd(fn):
     return run
 
 
-def _flash(shape):
-    return (fa.flash_attention, [(shape, jnp.bfloat16)] * 3)
+def _flash(shape, **kw):
+    import functools
+    return (functools.partial(fa.flash_attention, **kw),
+            [(shape, jnp.bfloat16)] * 3)
 
 
 M, K, N = 4096, 768, 3072  # ViT-B MLP up-projection at batch 16 x 256 tokens
@@ -74,6 +76,9 @@ KERNEL_CASES = {
     "flash_s1152_d128": _flash((4, 1152, 16, 128)),
     # the first length over the rule: the tiled kernels, as at the parent
     "flash_s1153_d64": _flash((4, 1153, 16, 64)),
+    # the looped decoder's training sequence: causal, 4096 tokens, 16 heads
+    # of 128: the tiled kernels (forward, dq, dk/dv)
+    "flash_causal_s4096_d128": _flash((1, 4096, 16, 128), is_causal=True),
     "flash_masked_s577_d64": (
         fa.flash_attention_masked,
         [((32, 577, 16, 64), jnp.bfloat16)] * 3 + [((32, 577), jnp.bool_)]),
@@ -97,7 +102,7 @@ KERNEL_CASES = {
 #: single-tile regime is one forward and ONE fused backward
 SINGLE_TILE_CALLS = {"flash_s577_d64": 2, "flash_s729_d72": 2,
                      "flash_masked_s577_d64": 2, "flash_s1152_d128": 2,
-                     "flash_s1153_d64": 3}
+                     "flash_s1153_d64": 3, "flash_causal_s4096_d128": 3}
 
 
 @pytest.mark.parametrize("case", sorted(KERNEL_CASES))
