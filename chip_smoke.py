@@ -330,7 +330,7 @@ def kernel_phase(args, watch: CompileWatch) -> None:
             if name.startswith("flash_attention"):
                 # both preset lengths are under the single-tile rule: one
                 # forward and ONE backward kernel, no tiled dq + dk/dv pair
-                ok = ok and regime == {"single_tile": 2}
+                ok = ok and regime == {"single_tile": 2, "direct": 2}
             say(phase="kernels", case=name, ok=ok,
                 lowering="mosaic" if mosaic else "interpreter",
                 **({"flash_calls_built": regime} if regime else {}),

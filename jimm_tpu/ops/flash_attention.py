@@ -23,11 +23,18 @@ Two tilings of one algorithm, chosen by a test of shapes alone
 
 - **single tile** — every image preset (S = 196..729): a head's whole
   lane-padded sequence is one resident tile, so each row sees all its keys
-  at once. Grid ``(BN/hb,)``; the forward is one exact row softmax per head
-  (no m/l scratch, no rescale, no init/finalize), and the backward is ONE
-  kernel that takes ``delta`` and all three gradients from a single pass
-  over the scores (5 matmuls, 1 exponential per score element). The bias
-  variant stays tiled.
+  at once. The kernels read q, k, v (and ``do``, ``o``) and write o (and
+  dq, dk, dv) in the model's own ``(B, S, N*D)`` layout: grid
+  ``(B, N/hb)``, a cell's block is batch row b, the whole sequence as an
+  edge block (S_p rows over an array of S: nothing is padded in HBM, the
+  kernels zero the edge rows in VMEM) and the ``hb*D`` lanes of head group
+  g, a head a static lane slice of it. So no XLA transpose, pad or slice
+  runs around them. The forward is one exact row softmax per head (no m/l
+  scratch, no rescale, no init/finalize), and the backward is ONE kernel
+  that takes ``delta`` and all three gradients from a single pass over the
+  scores (5 matmuls, 1 exponential per score element). The ring's hops,
+  which hold ``(B*N, S, D)`` rows, are the same kernels with one head in a
+  row. The bias variant stays tiled.
 - **tiled** — longer sequences (and explicit block requests): the kernels
   described next.
 
@@ -63,7 +70,8 @@ no such row: zero valid keys simply yields a zero output row.
 
 Head dims that are not one of the tested MXU tiles (64/128/256) are
 zero-padded to the next tile inside the wrappers (the padded lanes
-contribute 0 to every dot product and are sliced off the outputs), so the
+contribute 0 to every dot product and are sliced off the outputs; the
+single-tile regime pads the 4-D view once, the tiled its flattened copy), so the
 dispatch layer no longer falls back to XLA on e.g. d=80 towers — see the
 crossover note in docs/performance.md.
 """
@@ -422,36 +430,85 @@ def _bwd_dbias_kernel(*refs, sq_real: int, sk_real: int, block_q: int,
 
 
 # ---------------------------------------------------------------------------
-# Single-tile kernels: a head's whole padded sequence is one resident tile
+# Single-tile kernels: the caller's own (B', S, N'*D) layout, one resident
+# (S_p, hb*D) slab per operand; a head is a static lane slice of the slab
 # ---------------------------------------------------------------------------
 
 _TN = (((0,), (0,)), ((), ()))  # a^T b: contract the row dimension of both
 
 
-def _single_tile_masks(sq_p: int, sk_p: int, sk_real: int, causal: bool):
-    """The masks every head of a single-tile cell shares: the padded keys as
-    an additive ``(1, sk_p)`` row (one add per score, no 2-D iota), and the
-    causal triangle as a 2-D predicate only where it is asked for."""
-    pad_row = None
+def _single_tile_masks(mask_ref, sq_p: int, sk_p: int, sk_real: int,
+                       causal: bool):
+    """The masks every head of a single-tile cell shares: the additive
+    ``(1, sk_p)`` key row (the sample's key-padding row, NEG_INF past the
+    array's last key; one add per score, no 2-D iota), and the causal
+    triangle as a 2-D predicate only where it is asked for. The row's edge
+    lanes hold whatever the VMEM tile held, so they are selected away, not
+    added to."""
+    key_row = None if mask_ref is None else mask_ref[0]
     if sk_real < sk_p:
         k_pos = jax.lax.broadcasted_iota(jnp.int32, (1, sk_p), 1)
-        pad_row = jnp.where(k_pos < sk_real, 0.0, NEG_INF)
+        key_row = jnp.where(k_pos < sk_real,
+                            0.0 if key_row is None else key_row, NEG_INF)
     pos = None
     if causal:
         pos = (jax.lax.broadcasted_iota(jnp.int32, (sq_p, sk_p), 1)
                <= jax.lax.broadcasted_iota(jnp.int32, (sq_p, sk_p), 0))
-    return pad_row, pos
+    return key_row, pos
 
 
-def _mask_row(mask_ref, h, pad_row):
-    if mask_ref is None:
-        return pad_row
-    return mask_ref[h] if pad_row is None else mask_ref[h] + pad_row
+def _real_rows(s_p: int, s_real: int, lanes: int):
+    """``(s_p, lanes)`` predicate of the rows the array has, None if it has
+    them all. The block's row extent is the lane-padded S_p over an array of
+    S rows: the rows past S are not padded in HBM, their VMEM holds
+    unspecified values (NaN in interpret mode) and their writes are
+    dropped."""
+    if s_real == s_p:
+        return None
+    return jax.lax.broadcasted_iota(jnp.int32, (s_p, lanes), 0) < s_real
 
 
-def _fwd_single_kernel(*refs, sk_real: int, causal: bool, sm_scale: float,
-                       logit_bias: float, spec: VariantSpec):
-    """Grid ``(BN/hb,)``: every row sees all its keys at once, so the
+def _lane_group(ref, lanes, rows=None):
+    """One lane group of a resident slab with its edge rows zeroed: 0 * NaN
+    is NaN, so every operand of a contraction over those rows is cleaned."""
+    x = ref[0, :, lanes]
+    return x if rows is None else jnp.where(rows, x, jnp.zeros_like(x))
+
+
+def _group_lanes(width: int, d: int) -> int:
+    """Lanes a cell's head loop takes per iteration: 256 (four heads of 64,
+    two of 128, one of 256), whole lane tiles so that the rolled loop's
+    dynamic lane offset is tile-aligned; a narrower or odd slab (3 heads of
+    64: the whole row) is one group. Forward + backward a layer at ViT-L's
+    shape on the v5e, and Mosaic's time to compile the pair there: 128 lanes
+    2.80 ms / 2.5 s, 256 lanes 2.71 ms / 4.2 s, all 512 unrolled 2.63 ms /
+    16.4 s (PERF.md, PR 28)."""
+    gw = max(d, 2 * _LANES)
+    return gw if width % gw == 0 else width
+
+
+def _for_lane_groups(width: int, d: int, body) -> None:
+    """``body(first head, lanes)`` per lane group of a ``width``-lane slab."""
+    gw = _group_lanes(width, d)
+    if gw == width:
+        body(0, slice(None))
+        return
+
+    def step(g, carry):
+        body(g * (gw // d), pl.ds(pl.multiple_of(g * gw, gw), gw))
+        return carry
+    jax.lax.fori_loop(0, width // gw, step, 0)
+
+
+def _put_heads(ref, lanes, heads) -> None:
+    """The group's per-head ``(S_p, d)`` results side by side, one store."""
+    ref[0, :, lanes] = (heads[0] if len(heads) == 1
+                        else jnp.concatenate(heads, axis=1)).astype(ref.dtype)
+
+
+def _fwd_single_kernel(*refs, d: int, sq: int, sk: int, causal: bool,
+                       sm_scale: float, logit_bias: float, spec: VariantSpec):
+    """Grid ``(B', N'/hb)``: every row sees all its keys at once, so the
     softmax is one exact pass per head — no running max/sum, no accumulator
     rescale, no init/finalize steps."""
     softmax = spec.kind == "softmax"
@@ -460,37 +517,49 @@ def _fwd_single_kernel(*refs, sk_real: int, causal: bool, sm_scale: float,
     mask_ref = next(it) if spec.has_mask else None
     o_ref = next(it)
     lse_ref = next(it) if softmax else None
-    hb, sq_p, _ = q_ref.shape
-    pad_row, pos = _single_tile_masks(sq_p, k_ref.shape[1], sk_real, causal)
+    (_, sq_p, width), sk_p = q_ref.shape, k_ref.shape[1]
+    gw = _group_lanes(width, d)
+    key_row, pos = _single_tile_masks(mask_ref, sq_p, sk_p, sk, causal)
+    # q's edge rows only so that the lse written for them is finite
+    q_rows = _real_rows(sq_p, sq, gw) if softmax else None
+    k_rows = _real_rows(sk_p, sk, gw)
 
-    def head(h, carry):
-        v = v_ref[h]
-        s = _scores(q_ref[h], k_ref[h], sm_scale,
-                    _mask_row(mask_ref, h, pad_row), None, pos)
-        if softmax:
-            m = jnp.max(s, axis=1, keepdims=True)
-            p = jnp.exp(s - m)
-            # the row's max contributes exp(0): l >= 1, never 0
-            l = jnp.sum(p, axis=1, keepdims=True)
-        else:
-            p = jax.nn.sigmoid(s + logit_bias)
-        o = jax.lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if softmax:
-            o = o / l
-            lse_ref[h, 0, :] = (m + jnp.log(l))[:, 0]
-        o_ref[h] = o.astype(o_ref.dtype)
-        return carry
+    def group(h0, lanes):
+        qg = _lane_group(q_ref, lanes, q_rows)
+        kg, vg = (_lane_group(r, lanes, k_rows) for r in (k_ref, v_ref))
+        outs = []
+        for j in range(gw // d):
+            head = slice(j * d, (j + 1) * d)
+            v = vg[:, head]
+            s = _scores(qg[:, head], kg[:, head], sm_scale, key_row, None,
+                        pos)
+            if softmax:
+                m = jnp.max(s, axis=1, keepdims=True)
+                p = jnp.exp(s - m)
+                # the row's max contributes exp(0): l >= 1, never 0
+                l = jnp.sum(p, axis=1, keepdims=True)
+            else:
+                p = jax.nn.sigmoid(s + logit_bias)
+            o = jax.lax.dot_general(p.astype(v.dtype), v,
+                                    (((1,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            if softmax:
+                o = o / l
+                lse_ref[0, h0 + j, 0, :] = (m + jnp.log(l))[:, 0]
+            outs.append(o)
+        _put_heads(o_ref, lanes, outs)
 
-    jax.lax.fori_loop(0, hb, head, 0)
+    _for_lane_groups(width, d, group)
 
 
-def _bwd_single_kernel(*refs, sk_real: int, causal: bool, sm_scale: float,
-                       logit_bias: float, spec: VariantSpec, has_dlse: bool):
-    """Grid ``(BN/hb,)``, all three gradients from one pass over the scores:
-    5 matmuls and 1 exponential per score element, every operand read once
-    (the tiled dq + dk/dv pair recomputes s, p and dp in each kernel).
-    ``delta = rowsum(do * o)`` is taken here, less the lse cotangent."""
+def _bwd_single_kernel(*refs, d: int, sq: int, sk: int, causal: bool,
+                       sm_scale: float, logit_bias: float, spec: VariantSpec,
+                       has_dlse: bool):
+    """Grid ``(B', N'/hb)``, all three gradients from one pass over the
+    scores: 5 matmuls and 1 exponential per score element, every operand
+    read once (the tiled dq + dk/dv pair recomputes s, p and dp in each
+    kernel). ``delta = rowsum(do * o)`` is taken here, less the lse
+    cotangent."""
     softmax = spec.kind == "softmax"
     it = iter(refs)
     q_ref, k_ref, v_ref = next(it), next(it), next(it)
@@ -500,43 +569,52 @@ def _bwd_single_kernel(*refs, sk_real: int, causal: bool, sm_scale: float,
     lse_ref = next(it) if softmax else None
     dlse_ref = next(it) if has_dlse else None
     dq_ref, dk_ref, dv_ref = next(it), next(it), next(it)
-    hb, sq_p, _ = q_ref.shape
-    pad_row, pos = _single_tile_masks(sq_p, k_ref.shape[1], sk_real, causal)
+    (_, sq_p, width), sk_p = q_ref.shape, k_ref.shape[1]
+    gw = _group_lanes(width, d)
+    key_row, pos = _single_tile_masks(mask_ref, sq_p, sk_p, sk, causal)
+    q_rows, k_rows = _real_rows(sq_p, sq, gw), _real_rows(sk_p, sk, gw)
 
-    def head(h, carry):
-        q, k, do = q_ref[h], k_ref[h], do_ref[h]
-        s = _scores(q, k, sm_scale, _mask_row(mask_ref, h, pad_row), None,
-                    pos)
-        lse = delta = None
+    def group(h0, lanes):
+        qg, dog = (_lane_group(r, lanes, q_rows) for r in (q_ref, do_ref))
+        kg, vg = (_lane_group(r, lanes, k_rows) for r in (k_ref, v_ref))
         if softmax:
-            lse = lse_ref[h, 0, :][:, None]
-            delta = jnp.sum(do.astype(jnp.float32)
-                            * o_ref[h].astype(jnp.float32),
-                            axis=1, keepdims=True)
-            if has_dlse:
-                # the lse output adds dlse_i * p_ij to ds_ij, and
-                # ds = p * (dp - delta): delta -= dlse covers it
-                delta = delta - dlse_ref[h, 0, :][:, None]
-        p, ds = _ds_tile(spec, s, do, v_ref[h], lse, delta, logit_bias)
-        # dv = p^T do and dk = ds^T q, taken as (do^T p)^T and (q^T ds)^T:
-        # the operand Mosaic has to transpose is then the (S, D) one and the
-        # (D, S) result is turned back, not the (S, S) tile (1.17 -> 1.03 ms
-        # a call at ViT-L's shapes on the v5e). p is rounded only as dv's
-        # MXU operand; ds comes from the fp32 p.
-        dv_ref[h] = jax.lax.dot_general(
-            do, p.astype(do.dtype), _TN,
-            preferred_element_type=jnp.float32).T.astype(dv_ref.dtype)
-        ds = ds.astype(q.dtype)
-        dk_ref[h] = (jax.lax.dot_general(
-            q, ds, _TN, preferred_element_type=jnp.float32).T
-            * sm_scale).astype(dk_ref.dtype)
-        dq_ref[h] = (jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-            * sm_scale).astype(dq_ref.dtype)
-        return carry
+            dog32 = dog.astype(jnp.float32)
+            do_o = dog32 * _lane_group(o_ref, lanes, q_rows).astype(
+                jnp.float32)
+        dqs, dks, dvs = [], [], []
+        for j in range(gw // d):
+            head = slice(j * d, (j + 1) * d)
+            q, k, do = qg[:, head], kg[:, head], dog[:, head]
+            s = _scores(q, k, sm_scale, key_row, None, pos)
+            lse = delta = None
+            if softmax:
+                lse = lse_ref[0, h0 + j, 0, :][:, None]
+                delta = jnp.sum(do_o[:, head], axis=1, keepdims=True)
+                if has_dlse:
+                    # the lse output adds dlse_i * p_ij to ds_ij, and
+                    # ds = p * (dp - delta): delta -= dlse covers it
+                    delta = delta - dlse_ref[0, h0 + j, 0, :][:, None]
+            p, ds = _ds_tile(spec, s, do, vg[:, head], lse, delta,
+                             logit_bias)
+            # dv = p^T do and dk = ds^T q, taken as (do^T p)^T and
+            # (q^T ds)^T: the operand Mosaic has to transpose is then the
+            # (S, D) one and the (D, S) result is turned back, not the
+            # (S, S) tile (1.17 -> 1.03 ms a call at ViT-L's shapes on the
+            # v5e). p is rounded only as dv's MXU operand; ds comes from
+            # the fp32 p.
+            dvs.append(jax.lax.dot_general(
+                do, p.astype(do.dtype), _TN,
+                preferred_element_type=jnp.float32).T)
+            ds = ds.astype(q.dtype)
+            dks.append(jax.lax.dot_general(
+                q, ds, _TN, preferred_element_type=jnp.float32).T * sm_scale)
+            dqs.append(jax.lax.dot_general(
+                ds, k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale)
+        for ref, heads in ((dq_ref, dqs), (dk_ref, dks), (dv_ref, dvs)):
+            _put_heads(ref, lanes, heads)
 
-    jax.lax.fori_loop(0, hb, head, 0)
+    _for_lane_groups(width, d, group)
 
 
 # ---------------------------------------------------------------------------
@@ -544,6 +622,8 @@ def _bwd_single_kernel(*refs, sk_real: int, causal: bool, sm_scale: float,
 # ---------------------------------------------------------------------------
 
 def _flatten_heads(x: jax.Array) -> jax.Array:
+    """``(B, S, N, D)`` -> ``(B*N, S, D)``, a transposed copy: the tiled
+    regime's (and the int8 kernels', and the ring's) layout."""
     b, s, n, d = x.shape
     return x.transpose(0, 2, 1, 3).reshape(b * n, s, d)
 
@@ -561,8 +641,8 @@ def _pad_seq(x: jax.Array, target: int) -> jax.Array:
 
 
 def _pad_mask(maskadd: jax.Array, sk_p: int) -> jax.Array:
-    """Additive ``(BN, 1, Sk)`` mask rows out to the padded key length (the
-    kernels mask the padded keys themselves)."""
+    """Tiled regime: additive ``(BN, 1, Sk)`` mask rows out to the padded key
+    length (the kernels mask the padded keys themselves)."""
     return jnp.pad(maskadd, ((0, 0), (0, 0), (0, sk_p - maskadd.shape[2])))
 
 
@@ -570,7 +650,7 @@ def _pad_last(x: jax.Array, target: int) -> jax.Array:
     pad = target - x.shape[-1]
     if pad == 0:
         return x
-    return jnp.pad(x, ((0, 0), (0, 0), (0, pad)))
+    return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, pad),))
 
 
 def _head_pad_target(d: int) -> int:
@@ -726,129 +806,142 @@ def _single_tile_vmem_bytes(sq_p: int, sk_p: int, d: int, itemsize: int,
     return per_head, sq_p * sk_p * (4 * 4 + 2 * itemsize)
 
 
-def _single_tile_hb(bn: int, sq_p: int, sk_p: int, d: int, itemsize: int,
+def _single_tile_hb(n: int, sq_p: int, sk_p: int, d: int, itemsize: int,
                     spec: VariantSpec) -> int:
-    """THE regime rule, a test of shapes alone: heads per cell if one head's
-    whole padded sequence fits the budget as one resident tile, else 0 (the
-    tiled kernels run). The bias variant stays tiled: its dbias kernel
-    accumulates over the batch, which is another grid."""
+    """THE regime rule, a test of shapes alone: heads per cell if the whole
+    padded sequence of a group of the row's ``n`` heads fits the budget as
+    one resident tile, else 0 (the tiled kernels run). A group is a slab of
+    ``hb * d`` lanes of the ``(B', S, n * d)`` array, so it is whole 128-lane
+    tiles or the whole row (D = 64: an even hb). The bias variant stays
+    tiled: its dbias kernel accumulates over the batch, which is another
+    grid."""
     if spec.has_bias:
         return 0
     per_head, live = _single_tile_vmem_bytes(sq_p, sk_p, d, itemsize, spec)
-    for hb in (8, 4, 2, 1):
-        if bn % hb == 0 and hb * per_head + live <= _SINGLE_TILE_BUDGET:
+    for hb in (8, 4, 2, 1, n):
+        if (n % hb == 0 and (hb == n or hb * d % _LANES == 0)
+                and hb * per_head + live <= _SINGLE_TILE_BUDGET):
             return hb
     return 0
 
 
-def _single_tile_params(hb: int, sq_p: int, sk_p: int, d: int, itemsize: int,
-                        spec: VariantSpec) -> pltpu.CompilerParams:
-    per_head, live = _single_tile_vmem_bytes(sq_p, sk_p, d, itemsize, spec)
-    # twice the model: Mosaic's own matmul and relayout temporaries
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel",),
-        vmem_limit_bytes=max(2 * (hb * per_head + live), _SINGLE_TILE_BUDGET))
+def _single_tile_plan(n: int, sq: int, sk: int, d: int, itemsize: int,
+                      spec: VariantSpec, block_q: int, block_k: int):
+    """``(heads per cell or 0, S_q padded, S_k padded)`` at these blocks:
+    the single-tile regime is one block each way that the rule admits."""
+    sq_p, sk_p = _ceil_to(sq, block_q), _ceil_to(sk, block_k)
+    if sq_p != block_q or sk_p != block_k:
+        return 0, sq_p, sk_p
+    return _single_tile_hb(n, sq_p, sk_p, d, itemsize, spec), sq_p, sk_p
 
 
 def _count_call(regime: str) -> None:
     """One count per pallas_call built (trace time, like the tuner's
     ``jimm_tune_*``): ``jimm_flash_single_tile_total`` /
-    ``jimm_flash_tiled_total``."""
+    ``jimm_flash_tiled_total``, and ``jimm_flash_direct_total`` for a call
+    that reads and writes its caller's layout with no XLA transpose, pad or
+    slice of a q-sized array around it."""
     from jimm_tpu.obs.registry import get_registry
     get_registry("jimm_flash").counter(f"{regime}_total").inc()
 
 
-def _fwd_single(qp, kp, vp, maskadd, causal, spec, sm_scale, logit_bias,
-                sk: int, hb: int):
-    """The forward as one resident tile per head: grid ``(BN/hb,)``."""
-    softmax = spec.kind == "softmax"
-    bn, sq_p, d = qp.shape
-    sk_p = kp.shape[1]
-    head3 = lambda h: (h, 0, 0)  # noqa: E731
-    inputs = [qp, kp, vp]
-    in_specs = [pl.BlockSpec((hb, sq_p, d), head3),
-                pl.BlockSpec((hb, sk_p, d), head3),
-                pl.BlockSpec((hb, sk_p, d), head3)]
-    if spec.has_mask:
-        inputs.append(_pad_mask(maskadd, sk_p))
-        in_specs.append(pl.BlockSpec((hb, 1, sk_p), head3))
-    out_specs = [pl.BlockSpec((hb, sq_p, d), head3)]
-    out_shape = [jax.ShapeDtypeStruct((bn, sq_p, d), qp.dtype)]
-    if softmax:
-        out_specs.append(pl.BlockSpec((hb, 1, sq_p), head3))
-        out_shape.append(jax.ShapeDtypeStruct((bn, 1, sq_p), jnp.float32))
+def _single_tile_call(kernel, inputs, outputs, n: int, hb: int, sq_p: int,
+                      sk_p: int, spec: VariantSpec, **static):
+    """The one pallas_call of the single-tile regime. ``inputs`` /
+    ``outputs`` are ``(array or its ShapeDtypeStruct, kind)`` with kind
+    ``"q"`` / ``"k"`` (a ``(B', S, n * d)`` array: batch row b, the whole
+    lane-padded sequence as an edge block over the unpadded array, the lanes
+    of head group g), ``"stat"`` (``(B', n, 1, sq_p)`` f32 rows) or
+    ``"mask"`` (the sample's ``(B', 1, S_k)`` additive row)."""
+    q = inputs[0][0]
+    d = q.shape[2] // n
+    specs = {
+        "q": pl.BlockSpec((1, sq_p, hb * d), lambda b, g: (b, 0, g)),
+        "k": pl.BlockSpec((1, sk_p, hb * d), lambda b, g: (b, 0, g)),
+        "stat": pl.BlockSpec((1, hb, 1, sq_p), lambda b, g: (b, g, 0, 0)),
+        "mask": pl.BlockSpec((1, 1, sk_p), lambda b, g: (b, 0, 0)),
+    }
+    per_head, live = _single_tile_vmem_bytes(sq_p, sk_p, d, q.dtype.itemsize,
+                                             spec)
     _count_call("single_tile")
-    outs = pl.pallas_call(
-        partial(_fwd_single_kernel, sk_real=sk, causal=causal,
-                sm_scale=sm_scale, logit_bias=logit_bias, spec=spec),
-        grid=(bn // hb,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        compiler_params=_single_tile_params(hb, sq_p, sk_p, d,
-                                            qp.dtype.itemsize, spec),
+    _count_call("direct")
+    return pl.pallas_call(
+        partial(kernel, d=d, spec=spec, **static),
+        grid=(q.shape[0], n // hb),
+        in_specs=[specs[kind] for _, kind in inputs],
+        out_specs=[specs[kind] for _, kind in outputs],
+        out_shape=[shape for shape, _ in outputs],
+        # twice the model: Mosaic's own matmul and relayout temporaries
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=max(2 * (hb * per_head + live),
+                                 _SINGLE_TILE_BUDGET)),
         interpret=_interpret(),
-    )(*inputs)
+    )(*(x for x, _ in inputs))
+
+
+def _fwd_single(q, k, v, maskadd, causal, spec, sm_scale, logit_bias,
+                n: int, hb: int, sq_p: int, sk_p: int):
+    """The forward as one resident tile per head group: ``o`` in q's layout
+    and, for the softmax kinds, the lane-padded ``(B', n, 1, sq_p)`` lse."""
+    softmax = spec.kind == "softmax"
+    inputs = [(q, "q"), (k, "k"), (v, "k")]
+    if spec.has_mask:
+        inputs.append((maskadd, "mask"))
+    outputs = [(jax.ShapeDtypeStruct(q.shape, q.dtype), "q")]
+    if softmax:
+        outputs.append((jax.ShapeDtypeStruct((q.shape[0], n, 1, sq_p),
+                                             jnp.float32), "stat"))
+    outs = _single_tile_call(_fwd_single_kernel, inputs, outputs, n, hb, sq_p,
+                             sk_p, spec, sq=q.shape[1], sk=k.shape[1],
+                             causal=causal, sm_scale=sm_scale,
+                             logit_bias=logit_bias)
     return outs[0], (outs[1] if softmax else None)
 
 
-def _bwd_single(qp, kp, vp, maskadd, dop, o, lse, dlse, causal, spec,
-                sm_scale, logit_bias, sk: int, hb: int):
-    """dq, dk, dv (padded) from the one fused backward kernel."""
+def _bwd_single(q, k, v, maskadd, do, o, lse, dlse, causal, spec, sm_scale,
+                logit_bias, n: int, hb: int, sq_p: int, sk_p: int):
+    """dq, dk, dv in q / k / v's layout from the one fused backward kernel.
+    ``lse`` is the forward's padded residual, or the ring's merged
+    ``(B', S_q)`` rows, which are padded here (f32 rows, not a q-sized
+    array)."""
     softmax = spec.kind == "softmax"
-    bn, sq_p, d = qp.shape
-    sk_p = kp.shape[1]
-    head3 = lambda h: (h, 0, 0)  # noqa: E731
-    q_spec = pl.BlockSpec((hb, sq_p, d), head3)
-    kv_spec = pl.BlockSpec((hb, sk_p, d), head3)
-    stat_spec = pl.BlockSpec((hb, 1, sq_p), head3)
-    inputs = [qp, kp, vp]
-    in_specs = [q_spec, kv_spec, kv_spec]
+    inputs = [(q, "q"), (k, "k"), (v, "k")]
     if spec.has_mask:
-        inputs.append(_pad_mask(maskadd, sk_p))
-        in_specs.append(pl.BlockSpec((hb, 1, sk_p), head3))
-    inputs.append(dop)
-    in_specs.append(q_spec)
+        inputs.append((maskadd, "mask"))
+    inputs.append((do, "q"))
     if softmax:
-        stat = lambda x: jnp.pad(  # noqa: E731
-            x.astype(jnp.float32), ((0, 0), (0, sq_p - x.shape[1])))[:, None]
-        inputs += [_pad_seq(o, sq_p), stat(lse)]
-        in_specs += [q_spec, stat_spec]
+        if lse.ndim == 2:
+            lse = jnp.pad(lse.astype(jnp.float32),
+                          ((0, 0), (0, sq_p - lse.shape[1])))[:, None, None]
+        inputs += [(o, "q"), (lse, "stat")]
         if dlse is not None:
-            inputs.append(stat(dlse))
-            in_specs.append(stat_spec)
-    _count_call("single_tile")
-    return pl.pallas_call(
-        partial(_bwd_single_kernel, sk_real=sk, causal=causal,
-                sm_scale=sm_scale, logit_bias=logit_bias, spec=spec,
-                has_dlse=softmax and dlse is not None),
-        grid=(bn // hb,),
-        in_specs=in_specs,
-        out_specs=[q_spec, kv_spec, kv_spec],
-        out_shape=[jax.ShapeDtypeStruct((bn, sq_p, d), qp.dtype),
-                   jax.ShapeDtypeStruct((bn, sk_p, d), qp.dtype),
-                   jax.ShapeDtypeStruct((bn, sk_p, d), qp.dtype)],
-        compiler_params=_single_tile_params(hb, sq_p, sk_p, d,
-                                            qp.dtype.itemsize, spec),
-        interpret=_interpret(),
-    )(*inputs)
+            inputs.append((dlse.astype(jnp.float32), "stat"))
+    return _single_tile_call(
+        _bwd_single_kernel, inputs,
+        [(jax.ShapeDtypeStruct(x.shape, q.dtype), kind)
+         for x, kind in ((q, "q"), (k, "k"), (v, "k"))],
+        n, hb, sq_p, sk_p, spec, sq=q.shape[1], sk=k.shape[1], causal=causal,
+        sm_scale=sm_scale, logit_bias=logit_bias,
+        has_dlse=softmax and dlse is not None)
 
 
 def _fwd_pallas(q3, k3, v3, maskadd, bias, causal, spec, sm_scale,
-                logit_bias, block_q, block_k):
+                logit_bias, block_q, block_k, n):
     """Assemble and run the forward pallas_call for any variant. Returns
-    (o_padded, lse_padded_or_None)."""
+    ``(o, lse or None)`` as the regime keeps them: single-tile in the layout
+    it was given (``n`` heads in a row) with the padded 4-D lse, tiled
+    (``n == 1``: `_prologue` flattened the heads) cut back to S_q rows."""
     softmax = spec.kind == "softmax"
     bn, sq, d = q3.shape
     sk = k3.shape[1]
-    sq_p, sk_p = _ceil_to(sq, block_q), _ceil_to(sk, block_k)
+    hb, sq_p, sk_p = _single_tile_plan(n, sq, sk, d // n, q3.dtype.itemsize,
+                                       spec, block_q, block_k)
+    if hb:
+        return _fwd_single(q3, k3, v3, maskadd, causal, spec, sm_scale,
+                           logit_bias, n, hb, sq_p, sk_p)
     qp, kp, vp = (_pad_seq(q3, sq_p), _pad_seq(k3, sk_p), _pad_seq(v3, sk_p))
     n_q, n_k = sq_p // block_q, sk_p // block_k
-    if n_q == n_k == 1:
-        hb = _single_tile_hb(bn, sq_p, sk_p, d, q3.dtype.itemsize, spec)
-        if hb:
-            return _fwd_single(qp, kp, vp, maskadd, causal, spec, sm_scale,
-                               logit_bias, sk, hb)
     n_heads = bias.shape[0] if spec.has_bias else bn
     hb = _pick_hb(bn, block_q, block_k, d, spec, n_heads)
     kernel = partial(_fwd_kernel, sk_real=sk, block_k=block_k, causal=causal,
@@ -892,56 +985,54 @@ def _fwd_pallas(q3, k3, v3, maskadd, bias, causal, spec, sm_scale,
         compiler_params=_SEMANTICS,
         interpret=_interpret(),
     )(*inputs)
-    return outs[0], (outs[1] if softmax else None)
+    return outs[0][:, :sq], (outs[1][:, 0, :sq] if softmax else None)
 
 
 def _flash_fwd_impl(q3, k3, v3, maskadd, bias, causal, spec, sm_scale,
-                    logit_bias, block_q, block_k):
-    sq = q3.shape[1]
+                    logit_bias, block_q, block_k, n):
     o, lse = _fwd_pallas(q3, k3, v3, maskadd, bias, causal, spec, sm_scale,
-                         logit_bias, block_q, block_k)
+                         logit_bias, block_q, block_k, n)
     # the names make o/lse saveable by remat policies (`"dots"` in
     # `Transformer._remat_policy` saves them): jax.checkpoint traces through
     # custom_vjp fwd rules, and without a saveable mark the whole forward
     # kernel would re-run inside the backward pass of a remat'd layer
-    o = checkpoint_name(o[:, :sq], "flash_o")
+    o = checkpoint_name(o, "flash_o")
     if lse is not None:
-        lse = checkpoint_name(lse[:, 0, :sq], "flash_lse")
+        lse = checkpoint_name(lse, "flash_lse")
     return o, (q3, k3, v3, maskadd, bias, o, lse)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+@partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
 def _flash(q3, k3, v3, maskadd, bias, causal, spec, sm_scale, logit_bias,
-           block_q, block_k):
+           block_q, block_k, n):
     o, _ = _flash_fwd_impl(q3, k3, v3, maskadd, bias, causal, spec,
-                           sm_scale, logit_bias, block_q, block_k)
+                           sm_scale, logit_bias, block_q, block_k, n)
     return o
 
 
 def _flash_fwd(q3, k3, v3, maskadd, bias, causal, spec, sm_scale,
-               logit_bias, block_q, block_k):
+               logit_bias, block_q, block_k, n):
     return _flash_fwd_impl(q3, k3, v3, maskadd, bias, causal, spec,
-                           sm_scale, logit_bias, block_q, block_k)
+                           sm_scale, logit_bias, block_q, block_k, n)
 
 
-def _flash_bwd(causal, spec, sm_scale, logit_bias, block_q, block_k, res,
+def _flash_bwd(causal, spec, sm_scale, logit_bias, block_q, block_k, n, res,
                do, dlse=None):
     softmax = spec.kind == "softmax"
     q3, k3, v3, maskadd, bias, o, lse = res
     bn, sq, d = q3.shape
     sk = k3.shape[1]
-    sq_p, sk_p = _ceil_to(sq, block_q), _ceil_to(sk, block_k)
+    hb, sq_p, sk_p = _single_tile_plan(n, sq, sk, d // n, q3.dtype.itemsize,
+                                       spec, block_q, block_k)
+    if hb:
+        dq, dk, dv = _bwd_single(q3, k3, v3, maskadd, do, o, lse, dlse,
+                                 causal, spec, sm_scale, logit_bias, n, hb,
+                                 sq_p, sk_p)
+        return (dq, dk, dv,
+                jnp.zeros_like(maskadd) if spec.has_mask else None, None)
     n_q, n_k = sq_p // block_q, sk_p // block_k
     qp, dop = _pad_seq(q3, sq_p), _pad_seq(do, sq_p)
     kp, vp = _pad_seq(k3, sk_p), _pad_seq(v3, sk_p)
-    if n_q == n_k == 1:
-        hb = _single_tile_hb(bn, sq_p, sk_p, d, q3.dtype.itemsize, spec)
-        if hb:
-            dq, dk, dv = _bwd_single(qp, kp, vp, maskadd, dop, o, lse, dlse,
-                                     causal, spec, sm_scale, logit_bias, sk,
-                                     hb)
-            return (dq[:, :sq], dk[:, :sk], dv[:, :sk],
-                    jnp.zeros_like(maskadd) if spec.has_mask else None, None)
     n_heads = bias.shape[0] if spec.has_bias else bn
     hb = _pick_hb(bn, block_q, block_k, d, spec, n_heads)
     n_hb = n_heads // hb
@@ -1090,10 +1181,10 @@ def _flash_bwd(causal, spec, sm_scale, logit_bias, block_q, block_k, res,
     return dq, dk[:, :sk], dv[:, :sk], dmask, dbias
 
 
-def _flash_vjp_bwd(causal, spec, sm_scale, logit_bias, block_q, block_k,
+def _flash_vjp_bwd(causal, spec, sm_scale, logit_bias, block_q, block_k, n,
                    res, do):
     return _flash_bwd(causal, spec, sm_scale, logit_bias, block_q, block_k,
-                      res, do)
+                      n, res, do)
 
 
 _flash.defvjp(_flash_fwd, _flash_vjp_bwd)
@@ -1141,8 +1232,10 @@ def _fit_blocks(sq: int, sk: int, d: int, itemsize: int, spec: VariantSpec,
                 block_q: int, block_k: int, requested: bool = False):
     """The blocks the kernels run at. Unless the caller ``requested`` its
     own, a sequence the single-tile rule admits is one block, its whole
-    lane-padded length; every other takes the resolved blocks fitted to its
-    length (`_pick_block`), as the tiled kernels always have."""
+    lane-padded length (an edge block of the kernels' index maps: nothing is
+    padded in HBM); every other takes the resolved blocks fitted to its
+    length (`_pick_block`), which the tiled regime pads its flattened
+    ``(B*N, S, D)`` copies to, as it always has."""
     sq_p, sk_p = _ceil_to(sq, _LANES), _ceil_to(sk, _LANES)
     if not requested and _single_tile_hb(1, sq_p, sk_p, d, itemsize, spec):
         return sq_p, sk_p
@@ -1152,21 +1245,43 @@ def _fit_blocks(sq: int, sk: int, d: int, itemsize: int, spec: VariantSpec,
 
 def _prologue(q, k, v, block_q, block_k, kernel: str = "flash_attention",
               spec: VariantSpec = _SOFTMAX):
-    """Shared head-flattening + scale/block selection for every entry
-    point. Pads off-tile head dims up (scale still uses the REAL d)."""
-    d = q.shape[-1]
+    """Scale, block and layout selection for every entry point: returns
+    ``(q, k, v, sm_scale, block_q, block_k, n)``. Where the single-tile rule
+    admits the shape, q/k/v stay in the model's layout, ``(B, S, N, D)``
+    seen as ``(B, S, N * D_p)`` with ``n = N`` heads in a row (a free
+    reshape; an off-tile head dim is zero-padded once on the 4-D view).
+    Only the tiled regime still flattens the heads to ``(B * N, S, D_p)``
+    rows (a transposed copy of each), ``n = 1``. The scale uses the REAL
+    d."""
+    b, sq, n, d = q.shape
     sm_scale = 1.0 / (d ** 0.5)
     dp = _head_pad_target(d)
     requested = block_q is not None or block_k is not None
     block_q, block_k = _resolve_blocks(q, k, v, block_q, block_k,
                                        kernel=kernel)
-    block_q, block_k = _fit_blocks(q.shape[1], k.shape[1], dp,
-                                   q.dtype.itemsize, spec, block_q, block_k,
-                                   requested)
-    q3, k3, v3 = map(_flatten_heads, (q, k, v))
-    if dp != d:
-        q3, k3, v3 = (_pad_last(x, dp) for x in (q3, k3, v3))
-    return q3, k3, v3, sm_scale, block_q, block_k
+    block_q, block_k = _fit_blocks(sq, k.shape[1], dp, q.dtype.itemsize, spec,
+                                   block_q, block_k, requested)
+    if _single_tile_plan(n, sq, k.shape[1], dp, q.dtype.itemsize, spec,
+                         block_q, block_k)[0]:
+        q3, k3, v3 = (_pad_last(x, dp).reshape(*x.shape[:2], n * dp)
+                      for x in (q, k, v))
+        return q3, k3, v3, sm_scale, block_q, block_k, n
+    q3, k3, v3 = (_pad_last(_flatten_heads(x), dp) for x in (q, k, v))
+    return q3, k3, v3, sm_scale, block_q, block_k, 1
+
+
+def _epilogue(o: jax.Array, b: int, n: int, d: int, n_row: int) -> jax.Array:
+    """`_prologue`'s layout (``n_row`` heads in a row) back to
+    ``(B, S, N, D)``."""
+    if n_row == n:
+        return o.reshape(b, o.shape[1], n, -1)[..., :d]
+    return _unflatten_heads(o, b, n)[..., :d]
+
+
+def _lse_rows(lse: jax.Array, sq: int) -> jax.Array:
+    """The lse residual as rows of S_q, whichever regime kept it (the
+    single-tile regime keeps the kernels' lane-padded ``(B', n, 1, S_p)``)."""
+    return lse[:, :, 0, :sq] if lse.ndim == 4 else lse
 
 
 def _canon_mask(mask: jax.Array, b: int, sk: int) -> jax.Array:
@@ -1186,8 +1301,10 @@ def _canon_mask(mask: jax.Array, b: int, sk: int) -> jax.Array:
 
 
 def _expand_mask(mask: jax.Array, n: int) -> jax.Array:
-    """(B, Sk) bool -> (B*N, 1, Sk) additive f32 rows (0 keep / NEG_INF
-    drop), replicated per head in `_flatten_heads` row order."""
+    """(B, Sk) bool -> (B*n, 1, Sk) additive f32 rows (0 keep / NEG_INF
+    drop), one per row of the kernels' batch: ``n = 1`` in the model's
+    layout (the single-tile cells index the sample's row), ``n = N`` copies
+    in `_flatten_heads` row order for the flattened one."""
     b, sk = mask.shape
     add = jnp.where(mask, 0.0, NEG_INF).astype(jnp.float32)
     return jnp.broadcast_to(add[:, None, None, :],
@@ -1209,11 +1326,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     CPU tests exercise the same code path. Block sizes default to the tune
     cache's answer for these shapes (falling back to ``DEFAULT_BLOCK_*``)."""
     b, _, n, d = q.shape
-    q3, k3, v3, sm_scale, block_q, block_k = _prologue(q, k, v, block_q,
-                                                       block_k)
+    q3, k3, v3, sm_scale, block_q, block_k, n_row = _prologue(
+        q, k, v, block_q, block_k)
     o = _flash(q3, k3, v3, None, None, is_causal, _SOFTMAX, sm_scale, 0.0,
-               block_q, block_k)
-    return _unflatten_heads(o, b, n)[..., :d]
+               block_q, block_k, n_row)
+    return _epilogue(o, b, n, d, n_row)
 
 
 def flash_attention_masked(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -1227,14 +1344,13 @@ def flash_attention_masked(q: jax.Array, k: jax.Array, v: jax.Array,
     and zero gradient. Rows with NO valid key produce finite garbage (see
     module docstring) — mask them downstream, as NaFlex pooling does."""
     b, _, n, d = q.shape
-    sk = k.shape[1]
-    maskadd = _expand_mask(_canon_mask(mask, b, sk), n)
     spec = VariantSpec(kind="softmax", has_mask=True)
-    q3, k3, v3, sm_scale, block_q, block_k = _prologue(
+    q3, k3, v3, sm_scale, block_q, block_k, n_row = _prologue(
         q, k, v, block_q, block_k, kernel="flash_attention_masked", spec=spec)
+    maskadd = _expand_mask(_canon_mask(mask, b, k.shape[1]), n // n_row)
     o = _flash(q3, k3, v3, maskadd, None, is_causal, spec, sm_scale, 0.0,
-               block_q, block_k)
-    return _unflatten_heads(o, b, n)[..., :d]
+               block_q, block_k, n_row)
+    return _epilogue(o, b, n, d, n_row)
 
 
 def flash_attention_bias(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -1251,11 +1367,11 @@ def flash_attention_bias(q: jax.Array, k: jax.Array, v: jax.Array,
     sk = k.shape[1]
     bias3 = _canon_bias(bias, n, sq, sk)
     spec = VariantSpec(kind="softmax", has_bias=True)
-    q3, k3, v3, sm_scale, block_q, block_k = _prologue(
+    q3, k3, v3, sm_scale, block_q, block_k, n_row = _prologue(
         q, k, v, block_q, block_k, kernel="flash_attention_bias", spec=spec)
     o = _flash(q3, k3, v3, None, bias3, is_causal, spec, sm_scale, 0.0,
-               block_q, block_k)
-    return _unflatten_heads(o, b, n)[..., :d]
+               block_q, block_k, n_row)
+    return _epilogue(o, b, n, d, n_row)
 
 
 def sigmoid_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -1276,39 +1392,40 @@ def sigmoid_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if logit_bias is None:
         logit_bias = -math.log(max(sk, 1))
     spec = VariantSpec(kind="sigmoid", has_mask=mask is not None)
-    maskadd = (_expand_mask(_canon_mask(mask, b, sk), n)
-               if mask is not None else None)
-    q3, k3, v3, sm_scale, block_q, block_k = _prologue(
+    q3, k3, v3, sm_scale, block_q, block_k, n_row = _prologue(
         q, k, v, block_q, block_k, kernel="sigmoid_attention", spec=spec)
+    maskadd = (_expand_mask(_canon_mask(mask, b, sk), n // n_row)
+               if mask is not None else None)
     o = _flash(q3, k3, v3, maskadd, None, is_causal, spec, sm_scale,
-               float(logit_bias), block_q, block_k)
-    return _unflatten_heads(o, b, n)[..., :d]
+               float(logit_bias), block_q, block_k, n_row)
+    return _epilogue(o, b, n, d, n_row)
 
 
 # ---------------------------------------------------------------------------
 # (o, lse) variant — building block for cross-chip ring attention
 # ---------------------------------------------------------------------------
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_lse(q3, k3, v3, causal, sm_scale, block_q, block_k):
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_lse(q3, k3, v3, causal, sm_scale, block_q, block_k, n):
     o, res = _flash_fwd_impl(q3, k3, v3, None, None, causal, _SOFTMAX,
-                             sm_scale, 0.0, block_q, block_k)
+                             sm_scale, 0.0, block_q, block_k, n)
     return o, res[6]
 
 
-def _flash_lse_fwd(q3, k3, v3, causal, sm_scale, block_q, block_k):
+def _flash_lse_fwd(q3, k3, v3, causal, sm_scale, block_q, block_k, n):
     o, res = _flash_fwd_impl(q3, k3, v3, None, None, causal, _SOFTMAX,
-                             sm_scale, 0.0, block_q, block_k)
+                             sm_scale, 0.0, block_q, block_k, n)
     return (o, res[6]), res
 
 
-def _flash_lse_bwd(causal, sm_scale, block_q, block_k, res, cts):
+def _flash_lse_bwd(causal, sm_scale, block_q, block_k, n, res, cts):
     do, dlse = cts
     # The lse cotangent is exact and free: it folds into the delta term of
     # the standard flash backward (see _flash_bwd) — no extra passes, no
-    # materialized attention matrix.
+    # materialized attention matrix. It comes in the residual's own form
+    # (`_lse_rows` is differentiated outside), so padded where that is.
     dq, dk, dv, _, _ = _flash_bwd(causal, _SOFTMAX, sm_scale, 0.0, block_q,
-                                  block_k, res, do, dlse)
+                                  block_k, n, res, do, dlse)
     return dq, dk, dv
 
 
@@ -1324,10 +1441,12 @@ def flash_attention_lse(q: jax.Array, k: jax.Array, v: jax.Array, *,
     ``(B, N, S)`` so partial results over kv chunks can be merged exactly
     (the ring-attention combine)."""
     b, sq, n, d = q.shape
-    q3, k3, v3, sm_scale, block_q, block_k = _prologue(q, k, v, block_q,
-                                                       block_k)
-    o3, lse3 = _flash_lse(q3, k3, v3, is_causal, sm_scale, block_q, block_k)
-    return _unflatten_heads(o3, b, n)[..., :d], lse3.reshape(b, n, sq)
+    q3, k3, v3, sm_scale, block_q, block_k, n_row = _prologue(
+        q, k, v, block_q, block_k)
+    o3, lse3 = _flash_lse(q3, k3, v3, is_causal, sm_scale, block_q, block_k,
+                          n_row)
+    return (_epilogue(o3, b, n, d, n_row),
+            _lse_rows(lse3, sq).reshape(b, n, sq))
 
 
 # ---------------------------------------------------------------------------
@@ -1337,14 +1456,16 @@ def flash_attention_lse(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 def ring_hop_fwd(q3, k3, v3, maskadd, spec, sm_scale, logit_bias,
                  block_q, block_k):
-    """One ring-hop forward in flattened-heads ``(B*N, S, D)`` space:
-    returns ``(o, lse)`` for the hop's local (q × visiting-KV) product
-    (``lse`` is None for the sigmoid kind, which keeps no normalizer).
-    The caller owns the cross-hop merge and differentiation — this is a
-    plain function, not a custom_vjp."""
+    """One ring-hop forward in flattened-heads ``(B*N, S, D)`` space (to the
+    kernels: rows of one head, ``n = 1``): returns ``(o, lse)`` for the
+    hop's local (q × visiting-KV) product (``lse`` is None for the sigmoid
+    kind, which keeps no normalizer). The caller owns the cross-hop merge
+    and differentiation — this is a plain function, not a custom_vjp."""
     o, res = _flash_fwd_impl(q3, k3, v3, maskadd, None, False, spec,
-                             sm_scale, logit_bias, block_q, block_k)
-    return o, res[6]
+                             sm_scale, logit_bias, block_q, block_k, 1)
+    lse = res[6]
+    sq = q3.shape[1]
+    return o, None if lse is None else _lse_rows(lse, sq).reshape(-1, sq)
 
 
 def ring_hop_bwd(q3, k3, v3, maskadd, o3, lse3, do3, spec, sm_scale,
@@ -1356,6 +1477,6 @@ def ring_hop_bwd(q3, k3, v3, maskadd, o3, lse3, do3, spec, sm_scale,
     gradients — summing them over hops reproduces the unsharded backward.
     (Sigmoid ignores o3/lse3: no normalizer, no delta.)"""
     dq, dk, dv, _, _ = _flash_bwd(False, spec, sm_scale, logit_bias,
-                                  block_q, block_k,
+                                  block_q, block_k, 1,
                                   (q3, k3, v3, maskadd, None, o3, lse3), do3)
     return dq, dk, dv

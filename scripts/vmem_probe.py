@@ -42,8 +42,8 @@ def probe(bn: int, seq: int, d: int, budget_deadline: float) -> None:
 
     # the REAL call path's block selection (incl. the ceil-to-128 cap) and
     # the REAL per-head formula — the probe must validate what ships
-    _, _, _, _, block_q, block_k = fa._prologue(q, k, v, fa.DEFAULT_BLOCK_Q,
-                                                fa.DEFAULT_BLOCK_K)
+    *_, block_q, block_k, _ = fa._prologue(q, k, v, fa.DEFAULT_BLOCK_Q,
+                                           fa.DEFAULT_BLOCK_K)
     chosen = fa._pick_hb(bn, block_q, block_k, d)
     est = fa._per_head_vmem_bytes(block_q, block_k, d)
 

@@ -152,14 +152,14 @@ def test_long_sequence_streams(rng):
 # The short-sequence regime: one resident tile per head, one fused backward
 # ---------------------------------------------------------------------------
 
-def _calls(fn, *args):
-    """(single-tile, tiled) pallas_calls that tracing ``fn`` builds."""
+def _calls(fn, *args, regimes=("single_tile", "tiled")):
+    """(single-tile, tiled) pallas_calls that tracing ``fn`` builds, or the
+    counts of whichever ``jimm_flash_<regime>_total`` are asked for."""
     reg = get_registry("jimm_flash")
-    single, tiled = (reg.counter("single_tile_total"),
-                     reg.counter("tiled_total"))
-    before = single.value, tiled.value
+    counters = [reg.counter(f"{r}_total") for r in regimes]
+    before = [c.value for c in counters]
     jax.make_jaxpr(fn)(*args)
-    return int(single.value - before[0]), int(tiled.value - before[1])
+    return tuple(int(c.value - b) for c, b in zip(counters, before))
 
 
 def _grids(fn, *args):
@@ -186,11 +186,13 @@ def _grad_err(flash_loss, ref_loss, args):
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("d", [64, 72, 80])
+@pytest.mark.parametrize("d", [64, 72, 80, 128])
 @pytest.mark.parametrize("s", [257, 577, 729])
 def test_single_tile_matches_reference(rng, s, d, dtype):
     """Forward and gradients of the single-tile kernels at the image
-    presets' lengths and head widths (72 and 80 lane-pad to 128)."""
+    presets' lengths and head widths (72 and 80 lane-pad to 128), read from
+    and written to the ``(B, S, N * D)`` layout: the interpreter fills the
+    edge block's rows past S with NaN, as the chip leaves them unspecified."""
     q32 = qkv(rng, b=1, s=s, n=2, d=d)
     q, k, v = args = tuple(x.astype(dtype) for x in q32)
     fwd_tol, grad_tol = (2e-5, 5e-4) if dtype == "float32" else (2e-2, 6e-2)
@@ -235,7 +237,7 @@ SINGLE_VARIANTS = {
 }
 
 
-@pytest.mark.parametrize("s,d", [(577, 64), (257, 72)])
+@pytest.mark.parametrize("s,d", [(577, 64), (257, 72), (729, 128)])
 @pytest.mark.parametrize("variant", sorted(SINGLE_VARIANTS))
 def test_single_tile_variants_match_reference(rng, variant, s, d):
     """Mask, causal and sigmoid go through the same `_scores` / `_ds_tile`
@@ -257,7 +259,7 @@ def _ref_lse(q, k, v):
     return jax.nn.logsumexp(logits, axis=-1)
 
 
-@pytest.mark.parametrize("s,d", [(577, 64), (257, 80)])
+@pytest.mark.parametrize("s,d", [(577, 64), (257, 80), (729, 128)])
 def test_single_tile_lse_cotangent(rng, s, d):
     """`flash_attention_lse` with a NON-ZERO lse cotangent: the fused
     backward folds it into its in-kernel delta (the ring's merge
@@ -361,7 +363,7 @@ def test_regime_rule_bound_is_monotone_and_stays_tiled_above():
 
     assert [len(g) for g in _grids(grads, spec, spec, spec)] == [3, 3, 3]
     under = jax.ShapeDtypeStruct((1, bound - 128, 2, 64), jnp.bfloat16)
-    assert [len(g) for g in _grids(grads, under, under, under)] == [1, 1]
+    assert [len(g) for g in _grids(grads, under, under, under)] == [2, 2]
 
 
 def test_bias_variant_stays_tiled(rng):
@@ -382,3 +384,237 @@ def test_regime_counters_are_published():
     after = snapshot()
     for name in ("jimm_flash_single_tile_total", "jimm_flash_tiled_total"):
         assert after[name] - before.get(name, 0) == 1
+
+
+# ---------------------------------------------------------------------------
+# The single-tile regime reads and writes its caller's layout
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("shape,kw,want", [
+    ((2, 577, 16, 64), {}, (2, 2, 0)),            # ViT-L: forward + backward
+    ((2, 729, 16, 72), {}, (2, 2, 0)),            # So400m: D padded once
+    ((1, 197, 3, 64), {}, (2, 2, 0)),             # 3 heads: the whole row
+    ((1, 4096, 16, 128), {"is_causal": True}, (0, 0, 3)),   # the LM cell
+    ((1, 577, 2, 64), {"block_q": 128, "block_k": 128}, (0, 0, 3)),
+])
+def test_direct_counter(shape, kw, want):
+    """`jimm_flash_direct_total`: every single-tile call built reads the
+    model's layout; the tiled regime counts none."""
+    spec = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    assert _calls(jax.grad(lambda *a: jnp.sum(
+        flash_attention(*a, **kw).astype(jnp.float32)), argnums=(0, 1, 2)),
+        spec, spec, spec,
+        regimes=("direct", "single_tile", "tiled")) == want
+
+
+@pytest.mark.parametrize("n,d,dtype,hb", [
+    (16, 64, 2, 8), (12, 64, 2, 4), (6, 64, 2, 2), (3, 64, 2, 3),
+    (1, 64, 2, 1), (16, 128, 2, 8), (5, 128, 4, 1), (7, 64, 4, 7),
+])
+def test_head_group_is_whole_lane_tiles_or_the_row(n, d, dtype, hb):
+    """A cell's slab of ``hb * d`` lanes is whole 128-lane tiles or the
+    array's whole last dimension: D = 64 takes an even hb or every head."""
+    got = fa._single_tile_hb(n, 640, 640, d, dtype, fa._SOFTMAX)
+    assert got == hb and (got * d % 128 == 0 or got == n)
+
+
+@pytest.mark.parametrize("n,d,groups", [(8, 64, 2), (16, 64, 2), (4, 128, 2),
+                                        (4, 64, 1), (3, 64, 1)])
+def test_head_loop_over_lane_groups(rng, n, d, groups):
+    """A cell's slab is walked 256 lanes at a time by a rolled loop (4 heads
+    of 64 or 2 of 128 an iteration); a narrower slab is one group. Values,
+    lse and gradients of every head against the reference."""
+    hb = fa._single_tile_hb(n, 256, 256, d, 4, fa._SOFTMAX)
+    assert hb * d // fa._group_lanes(hb * d, d) == groups
+    q, k, v = args = qkv(rng, b=1, s=150, n=n, d=d)
+    w = jnp.asarray(rng.randn(1, n, 150).astype(np.float32))
+
+    def flash_loss(q, k, v):
+        o, lse = flash_attention_lse(q, k, v)
+        return jnp.sum(o ** 2) + jnp.sum(lse * w)
+
+    def ref_loss(q, k, v):
+        return (jnp.sum(reference_attention(q, k, v) ** 2)
+                + jnp.sum(_ref_lse(q, k, v) * w))
+
+    o, lse = flash_attention_lse(q, k, v)
+    np.testing.assert_allclose(o, reference_attention(q, k, v), atol=2e-5)
+    np.testing.assert_allclose(lse, _ref_lse(q, k, v), atol=2e-5)
+    assert _grad_err(flash_loss, ref_loss, args) <= 5e-4
+
+
+def _tpu_text(fn, *specs):
+    """StableHLO of ``fn`` lowered for the TPU with the kernels as Mosaic
+    calls (their serialized bodies cut out: they carry source lines)."""
+    import re
+    text = jax.jit(fn).trace(*specs).lower(
+        lowering_platforms=("tpu",)).as_text()
+    return re.sub(r'backend_config = "[^"]*"', "backend_config = <kernel>",
+                  text)
+
+
+def _grads_of_model_layout(n, **kw):
+    """Gradients of flash attention as `Attention` calls it: q, k, v are
+    ``(B, S, N * D)`` matmul outputs, reshaped (free) to 4-D and back."""
+    def loss(q, k, v):
+        b, s, w = q.shape
+        heads = lambda x: x.reshape(b, x.shape[1], n, w // n)  # noqa: E731
+        o = flash_attention(heads(q), heads(k), heads(v), **kw)
+        return jnp.sum(o.reshape(b, s, w).astype(jnp.float32))
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("s,n,d", [(577, 16, 64), (257, 4, 128),
+                                   (197, 3, 64)])
+def test_single_tile_text_moves_no_qkv_sized_array(monkeypatch, s, n, d,
+                                                   dtype):
+    """Forward + backward of a single-tile shape: two Mosaic calls and no
+    transpose, pad or slice at all around them (the parent ran 9 pads, 4
+    slices and 8 transposes a layer there)."""
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    spec = jax.ShapeDtypeStruct((2, s, n * d), jnp.dtype(dtype))
+    text = _tpu_text(_grads_of_model_layout(n), spec, spec, spec)
+    assert text.count("@tpu_custom_call") == 2
+    for op in ("stablehlo.transpose", "stablehlo.pad", "stablehlo.slice",
+               "stablehlo.dynamic_slice", "stablehlo.concatenate"):
+        assert op not in text, op
+
+
+def test_off_tile_head_width_is_padded_once_and_never_transposed(monkeypatch):
+    """D = 72 (So400m): one pad of q, k, v (and, under AD, of ``do``) on
+    the last axis of the 4-D view, one slice of o (and of dq, dk, dv); the
+    sequence is not padded and nothing is transposed."""
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    spec = jax.ShapeDtypeStruct((2, 729, 16 * 72), jnp.bfloat16)
+    text = _tpu_text(_grads_of_model_layout(16), spec, spec, spec)
+    assert text.count("@tpu_custom_call") == 2
+    assert "stablehlo.transpose" not in text
+    # q, k, v and, where the cotangent is not a constant, `do`; the slices
+    # of o, dq, dk, dv lower to calls of one shared function
+    assert text.count("stablehlo.pad") in (3, 4)
+    assert text.count("stablehlo.slice") in (1, 4)
+    # the lane-padded S_p is the lse rows' extent and no q-sized array's
+    assert "x768xf32" in text and "x768x" not in text.replace(
+        "x768xf32", "")
+
+
+#: sha256 of the TPU lowering (kernel bodies cut out) of the gradients at
+#: shapes over the rule, taken at the commit before the single-tile regime
+#: moved to the model's layout (PR 27's tree): the tiled regime's wrappers
+#: (flatten, pad to the blocks, slice back) are not this PR's to move
+TILED_TEXT_SHA = {
+    ((1, 1153, 2, 64), False): "799339d336efd84e20bb31a7e9f6146c4a7c0338ddb1e4011a724800748ba267",
+    ((1, 4096, 4, 128), True): "193b84c195d5c1f0d1b3cf7c32ef5c9e74aa03bdd47fd18146aa8a32efd4ce1a",
+}
+
+
+@pytest.mark.parametrize("shape,causal", sorted(TILED_TEXT_SHA))
+def test_tiled_regime_text_is_the_parents(monkeypatch, shape, causal):
+    import hashlib
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    spec = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    text = _tpu_text(jax.grad(lambda *a: jnp.sum(flash_attention(
+        *a, is_causal=causal).astype(jnp.float32)), argnums=(0, 1, 2)),
+        spec, spec, spec)
+    assert text.count("@tpu_custom_call") == 3
+    assert (hashlib.sha256(text.encode()).hexdigest()
+            == TILED_TEXT_SHA[shape, causal])
+
+
+@pytest.mark.parametrize("poison", ["inf", "-inf", "3e38", "0"])
+@pytest.mark.parametrize("variant", ["plain", "causal", "masked", "sigmoid",
+                                     "lse"])
+def test_edge_rows_hold_anything(rng, monkeypatch, variant, poison):
+    """The rows of the edge block past S are unspecified on the chip. The
+    interpreter fills them with NaN (every other test here runs so); with
+    +-inf, the largest floats or zeros in their place the results are the
+    same to the bit, and a kernel whose row clean-up is taken out is
+    caught."""
+    from jax._src.pallas import primitives
+    q, k, v = args = qkv(rng, b=1, s=200, n=2, d=64)
+    m = _masked(rng, 1, 200)
+    w = jnp.asarray(rng.randn(1, 2, 200).astype(np.float32))
+
+    def lse_loss(q, k, v):
+        o, lse = flash_attention_lse(q, k, v)
+        return jnp.sum(o ** 2) + jnp.sum(lse * w)
+
+    loss = {
+        "plain": lambda *a: jnp.sum(flash_attention(*a) ** 2),
+        "causal": lambda *a: jnp.sum(
+            flash_attention(*a, is_causal=True) ** 2),
+        "masked": lambda *a: jnp.sum(flash_attention_masked(*a, m) ** 2),
+        "sigmoid": lambda *a: jnp.sum(sigmoid_attention(*a, mask=m) ** 2),
+        "lse": lse_loss,
+    }[variant]
+
+    def run():
+        jax.clear_caches()  # the fill value is baked in when a call lowers
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(*args)
+
+    want = run()  # edge rows NaN
+    assert all(bool(jnp.all(jnp.isfinite(x))) for x in jax.tree.leaves(want))
+    original = primitives.uninitialized_value
+
+    def filled(shape, dtype):
+        if jnp.issubdtype(dtype, jnp.floating):
+            return jnp.full(shape, float(poison), dtype)
+        return original(shape, dtype)
+
+    monkeypatch.setattr(primitives, "uninitialized_value", filled)
+    got = run()
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(a, b)
+    if poison == "inf":
+        # the control: without the row clean-up the poison reaches the sums
+        monkeypatch.setattr(fa, "_real_rows", lambda *a: None)
+        bad = run()
+        assert not all(bool(jnp.all(jnp.isfinite(x)))
+                       for x in jax.tree.leaves(bad))
+    monkeypatch.undo()
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("kind,masked", [("softmax", False),
+                                         ("softmax", True),
+                                         ("sigmoid", False)])
+def test_one_head_rows_entry_matches_model_layout(rng, kind, masked):
+    """The ring's hops hand the kernels ``(B * N, S, D)`` rows: the same
+    wrapper pair with one head in a row (``hb * D`` = the whole last
+    dimension). Values, lse and all three gradients against the
+    ``(B, S, N * D)`` entry."""
+    b, s, n, d = 2, 200, 2, 64
+    q, k, v = qkv(rng, b=b, s=s, n=n, d=d)
+    do = jnp.asarray(rng.randn(b, s, n, d).astype(np.float32))
+    mask = _masked(rng, b, s) if masked else None
+    spec = fa.VariantSpec(kind=kind, has_mask=masked)
+    sm_scale, bias = 1.0 / d ** 0.5, (-np.log(s) if kind == "sigmoid" else 0.0)
+    if kind == "sigmoid":
+        model = lambda *a: sigmoid_attention(*a, mask=mask)  # noqa: E731
+    elif masked:
+        model = lambda *a: flash_attention_masked(*a, mask)  # noqa: E731
+    else:
+        model = flash_attention
+    want_o, vjp = jax.vjp(model, q, k, v)
+    want_g = vjp(do)
+
+    q3, k3, v3, do3 = map(fa._flatten_heads, (q, k, v, do))
+    mask3 = fa._expand_mask(mask, n) if masked else None
+    before = snapshot()
+    o3, lse3 = fa.ring_hop_fwd(q3, k3, v3, mask3, spec, sm_scale, bias,
+                               256, 256)
+    got_g = fa.ring_hop_bwd(q3, k3, v3, mask3, o3, lse3, do3, spec, sm_scale,
+                            bias, 256, 256)
+    after = snapshot()
+    assert after["jimm_flash_direct_total"] \
+        - before.get("jimm_flash_direct_total", 0) == 2
+    np.testing.assert_allclose(fa._unflatten_heads(o3, b, n), want_o,
+                               atol=2e-6)
+    if kind == "softmax" and not masked:
+        assert lse3.shape == (b * n, s)
+        np.testing.assert_allclose(lse3.reshape(b, n, s),
+                                   flash_attention_lse(q, k, v)[1], atol=2e-6)
+    for a, w_ in zip(got_g, want_g):
+        np.testing.assert_allclose(fa._unflatten_heads(a, b, n), w_,
+                                   atol=2e-5)

@@ -74,6 +74,9 @@ KERNEL_CASES = {
     # the largest working set the single-tile rule admits (S_p 1152 at 128
     # lanes, one head per cell): a VMEM refusal shows here, not on the chip
     "flash_s1152_d128": _flash((4, 1152, 16, 128)),
+    # ViT-Ti/16: 3 heads of 64 are no whole lane tile, so a cell holds the
+    # whole 192-lane row; S=197 is an odd row count under the edge block
+    "flash_s197_d64_whole_row": _flash((64, 197, 3, 64)),
     # the first length over the rule: the tiled kernels, as at the parent
     "flash_s1153_d64": _flash((4, 1153, 16, 64)),
     # the looped decoder's training sequence: causal, 4096 tokens, 16 heads
@@ -102,6 +105,7 @@ KERNEL_CASES = {
 #: single-tile regime is one forward and ONE fused backward
 SINGLE_TILE_CALLS = {"flash_s577_d64": 2, "flash_s729_d72": 2,
                      "flash_masked_s577_d64": 2, "flash_s1152_d128": 2,
+                     "flash_s197_d64_whole_row": 2,
                      "flash_s1153_d64": 3, "flash_causal_s4096_d128": 3}
 
 
