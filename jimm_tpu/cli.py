@@ -45,7 +45,7 @@ def _configure_backend(args: argparse.Namespace) -> None:
     # there would silently train an independent copy per host. The pod
     # path uses jax's argless auto-detect (metadata server), whose failure
     # mode on a NON-pod TPU host is a hang — so markers that single-host
-    # environments also set must not trigger it (ADVICE r4):
+    # environments also set must not trigger it:
     # TPU_WORKER_HOSTNAMES counts only with >1 hosts (single-host VMs set it
     # to one name), TPU_WORKER_ID alone never counts, and an explicit
     # non-TPU --platform skips cluster join entirely.
@@ -360,6 +360,58 @@ def _batch_fingerprint(batch) -> int:
     return int(h.hexdigest()[:12], 16)
 
 
+def resolve_runtime(args: argparse.Namespace, cfg: Any, mesh: Any,
+                    backend: str) -> dict[str, Any]:
+    """How this train run executes: the ``with_runtime`` fields that the
+    flags, the mesh and the platform decide. Nothing else decides: an unset
+    flag leaves its field at the config's default on every backend, but for
+    the layer loop's unroll, which on a TPU is the main tower's depth."""
+    rt: dict[str, Any] = {}
+    if args.attn_impl:
+        rt["attn_impl"] = args.attn_impl
+    if args.remat:
+        from jimm_tpu.configs import parse_remat
+        try:
+            rt.update(parse_remat(args.remat))
+        except ValueError as e:
+            raise SystemExit(f"--remat: {e}")
+    if args.ln_impl:
+        rt["ln_impl"] = args.ln_impl
+    if args.fused_qkv:
+        rt["fused_qkv"] = True
+    if args.precision:
+        rt["precision"] = args.precision
+    pp_extra = {}
+    if args.pipeline_virtual > 1:
+        if args.rules != "pp":
+            raise SystemExit("--pipeline-virtual needs --rules pp")
+        # bake circular placement into storage when the stage count is
+        # known from --mesh (avoids a per-step cross-stage all-to-all)
+        stages = dict(mesh.shape).get("stage", 0) if mesh is not None else 0
+        pp_extra = dict(pp_virtual=args.pipeline_virtual, pp_stages=stages)
+    if args.pipeline_microbatches:
+        if args.pipeline_microbatches < 1:
+            raise SystemExit("--pipeline-microbatches must be >= 1")
+        if args.rules != "pp":
+            raise SystemExit("--pipeline-microbatches needs --rules pp "
+                             "(layers sharded over the 'stage' mesh axis)")
+        rt.update(pipeline=True, **pp_extra,
+                  pp_microbatches=args.pipeline_microbatches)
+    elif args.rules == "pp":
+        # --rules pp without the flag: default to the config's microbatch
+        # count rather than silently running the unpipelined scan with
+        # stage-sharded params (correct but all-gathers every layer)
+        rt.update(pipeline=True, **pp_extra)
+    if args.scan_unroll >= 1:  # any explicit value wins, including 1
+        rt["scan_unroll"] = args.scan_unroll
+    elif (args.scan_unroll == 0 and not args.from_pretrained
+            and backend == "tpu"):
+        # auto: full unroll on TPU, resolved against the preset's depth
+        # (a checkpoint's depth is unknown here — explicit unrolls only)
+        rt["scan_unroll"] = _main_tower(cfg).depth
+    return rt
+
+
 def cmd_train(args: argparse.Namespace) -> int:
     train(args)
     return 0
@@ -422,66 +474,11 @@ def train(args: argparse.Namespace) -> Any:
         raise SystemExit("--num-layers and --seq-len shape a language model "
                          "(an ouro preset)")
 
-    # execution-strategy overrides, built ONCE: the preset path applies
-    # them to cfg, the fine-tune path passes them to from_pretrained
-    rt: dict[str, Any] = {}
-    if args.attn_impl:
-        rt["attn_impl"] = args.attn_impl
-    if args.remat:
-        from jimm_tpu.configs import parse_remat
-        try:
-            rt.update(parse_remat(args.remat))
-        except ValueError as e:
-            raise SystemExit(f"--remat: {e}")
-    if args.ln_impl:
-        rt["ln_impl"] = args.ln_impl
-    if args.fused_qkv:
-        rt["fused_qkv"] = True
-    if args.precision:
-        rt["precision"] = args.precision
     mesh = _parse_mesh(args.mesh, max_devices=args.max_devices)
-    pp_extra = {}
-    if args.pipeline_virtual > 1:
-        if args.rules != "pp":
-            raise SystemExit("--pipeline-virtual needs --rules pp")
-        # bake circular placement into storage when the stage count is
-        # known from --mesh (avoids a per-step cross-stage all-to-all)
-        stages = dict(mesh.shape).get("stage", 0) if mesh is not None else 0
-        pp_extra = dict(pp_virtual=args.pipeline_virtual, pp_stages=stages)
-    if args.pipeline_microbatches:
-        if args.pipeline_microbatches < 1:
-            raise SystemExit("--pipeline-microbatches must be >= 1")
-        if args.rules != "pp":
-            raise SystemExit("--pipeline-microbatches needs --rules pp "
-                             "(layers sharded over the 'stage' mesh axis)")
-        rt.update(pipeline=True, **pp_extra,
-                  pp_microbatches=args.pipeline_microbatches)
-    elif args.rules == "pp":
-        # --rules pp without the flag: default to the config's microbatch
-        # count rather than silently running the unpipelined scan with
-        # stage-sharded params (correct but all-gathers every layer)
-        rt.update(pipeline=True, **pp_extra)
-    # fill knobs the user left unset from the measured-best adopted runtime
-    # (`scripts/adopt_sweep.py --apply`, jimm_tpu/adopted_runtime.json);
-    # explicit flags above always win, the TPU-measured choices are not
-    # imposed on other backends, and the adoption only holds for the exact
-    # architecture it was measured on — a --tiny shrink or a checkpoint of
-    # unknown shape must not inherit e.g. a flash kernel choice or an
-    # unroll that its shapes never validated
-    import jax as _jax
-    if (_jax.default_backend() == "tpu" and not args.tiny
-            and not args.from_pretrained):
-        from jimm_tpu.configs import adopted_runtime
-        for k, v in adopted_runtime(args.preset).items():
-            rt.setdefault(k, v)
-    if args.scan_unroll >= 1:  # any explicit value wins, including 1
-        rt["scan_unroll"] = args.scan_unroll
-    elif args.scan_unroll == 0 and not args.from_pretrained:
-        # auto: full unroll on TPU, resolved against the preset's depth
-        # (a checkpoint's depth is unknown here — explicit unrolls only);
-        # an adopted, measured unroll above outranks this heuristic
-        if _jax.default_backend() == "tpu":
-            rt.setdefault("scan_unroll", _main_tower(cfg).depth)
+    import jax
+    # built ONCE: the preset path applies the fields to cfg, the fine-tune
+    # path passes them to from_pretrained
+    rt = resolve_runtime(args, cfg, mesh, jax.default_backend())
     if rt and not args.from_pretrained:
         cfg = _replace_towers(cfg, **rt)
     def _validate_pp(cfg_obj) -> None:
@@ -496,7 +493,7 @@ def train(args: argparse.Namespace) -> Any:
         if args.batch_size % data_axis:
             # floor division below would validate a WRONG local batch and
             # let a config pass (or fail confusingly) that the real
-            # shard-map trace rejects minutes later (ADVICE r4)
+            # shard-map trace rejects minutes later
             raise SystemExit(f"--batch-size {args.batch_size} is not "
                              f"divisible by the data mesh axis ({data_axis})")
         local_batch = args.batch_size // data_axis
@@ -572,8 +569,6 @@ def train(args: argparse.Namespace) -> Any:
             learning_rate=args.lr, weight_decay=args.weight_decay,
             warmup_steps=args.warmup_steps, total_steps=args.steps,
             moment_dtype=moment_dtype))
-
-    import jax
 
     # deterministic fault drill: --fake-failure-at-step N is historical
     # sugar for the crash@N entry of the general --inject-faults plan
@@ -896,9 +891,9 @@ def train(args: argparse.Namespace) -> Any:
         achieved_mfu = _mfu(
             train_step_flops(cfg, args.batch_size), dt,
             n_devices=mesh.devices.size if mesh is not None else 1)
-    # precision + moment_dtype ride the goodput line so measurement
-    # consumers (lowp_train_smoke) can attribute MFU/img/s deltas to the
-    # policy that produced them
+    # precision + moment_dtype ride the goodput line so that its readers
+    # (scripts/lowp_train_smoke.py) can put a difference down to the policy
+    # that produced it
     print("goodput: " + _json.dumps({
         **acct.report(mfu=achieved_mfu),
         "precision": precision,
@@ -968,8 +963,7 @@ def cmd_supervise(args: argparse.Namespace) -> int:
     if args.adapt:
         from jimm_tpu.resilience import GoodputAdvisor
 
-        # seed the knobs from the train command itself (which already
-        # folded in any adopted_runtime pick): adopted-plus-adapted
+        # seed the knobs from the train command itself
         advisor = GoodputAdvisor(knobs={
             "save_every": int(_argv_flag_value(cmd, "--save-every", 50)),
             "grace_steps": int(_argv_flag_value(cmd, "--grace-steps", 1)),

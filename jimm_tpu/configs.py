@@ -12,8 +12,6 @@ Parity-critical defaults are documented per field with the reference citation.
 from __future__ import annotations
 
 import dataclasses
-import json
-import pathlib
 from dataclasses import dataclass, field
 from typing import Any, Literal
 
@@ -74,8 +72,8 @@ def check_pp_schedule(M: int, V: int, *, n_stages: int | None = None,
 
 def validate_pipeline(tower, *, n_stages: int, local_batch: int | None = None,
                       tower_name: str | None = None) -> None:
-    """Surface the pipeline constraints at config/CLI parse time (VERDICT r3
-    weak #6: a user used to reach them minutes into a compile). The same
+    """Surface the pipeline constraints at config/CLI parse time (a user
+    used to reach them minutes into a compile). The same
     function runs inside `nn/transformer.py`'s pipeline dispatch, and the
     microbatch checks are shared with `parallel/pipeline.py` via
     ``check_pp_schedule`` — one implementation, both paths."""
@@ -196,8 +194,8 @@ class TransformerConfig:
     scan_unroll: int = 1
     #: Training precision policy, applied to the built model by
     #: `quant.policy.apply_precision_policy` (trainer/CLI plumbing) — the
-    #: config field records intent so measurements and adopted runtimes
-    #: carry it; construction itself never reads it.
+    #: config field records intent, so that a run's logs and reports carry
+    #: it; construction itself never reads it.
     precision: Precision = "bf16"
     # -- the decoder family's block (`DecoderConfig.encoder`); every default
     # below is the ViT / CLIP / SigLIP block, whose programs do not change
@@ -500,7 +498,7 @@ def _clip(vision_size: str, patch: int, image: int = 224) -> CLIPConfig:
         projection_dim=proj)
 
 
-#: Named presets covering the BASELINE.json tracked configs.
+#: Named presets: the published shapes of each family.
 PRESETS: dict[str, Any] = {
     # ViT
     "vit-tiny-patch16-224": _vit("T", 16, 224),
@@ -543,77 +541,3 @@ def preset(name: str, **overrides: Any):
     """Fetch a named preset, optionally overriding top-level fields."""
     cfg = PRESETS[name]
     return dataclasses.replace(cfg, **overrides) if overrides else cfg
-
-
-# ---------------------------------------------------------------------------
-# Adopted runtime: measured-best execution config per preset
-# ---------------------------------------------------------------------------
-
-#: Written by `scripts/adopt_sweep.py --apply` from real TPU sweep records
-#: (committed with provenance); consumed by the CLI train path and bench.py
-#: so presets run the measured-best execution config by default.
-ADOPTED_RUNTIME_PATH = (pathlib.Path(__file__).resolve().parent
-                        / "adopted_runtime.json")
-
-
-def _check_runtime_fields(fields: Any) -> None:
-    """Raise on anything `with_runtime` would reject or a jit trace would
-    choke on minutes in: unknown field names, or out-of-domain values."""
-    if not isinstance(fields, dict):
-        raise TypeError(f"runtime entry must be a dict, got {type(fields)}")
-    bad = set(fields) - RUNTIME_FIELDS
-    if bad:
-        raise ValueError(f"non-runtime fields {sorted(bad)}")
-    def _int_ge(v: Any, lo: int) -> bool:
-        return isinstance(v, int) and not isinstance(v, bool) and v >= lo
-    for k, v in fields.items():
-        ok = True
-        if k == "attn_impl":
-            from typing import get_args
-            ok = v in get_args(AttnImpl)
-        elif k == "ln_impl":
-            ok = v in ("xla", "fused")
-        elif k in ("fused_qkv", "remat", "pipeline"):
-            ok = isinstance(v, bool)
-        elif k == "remat_policy":
-            remat_policy_parts(str(v))  # raises on malformed spec
-            ok = isinstance(v, str)
-        elif k in ("scan_unroll", "pp_microbatches", "pp_virtual"):
-            ok = _int_ge(v, 1)
-        elif k == "pp_stages":
-            ok = _int_ge(v, 0)
-        elif k == "dropout":
-            ok = isinstance(v, (int, float)) and 0.0 <= v <= 1.0
-        elif k == "precision":
-            from typing import get_args
-            ok = v in get_args(Precision)
-        if not ok:
-            raise ValueError(f"bad value for runtime field {k!r}: {v!r}")
-
-
-def adopted_runtime(preset_name: str) -> dict[str, Any]:
-    """Measured-best `with_runtime` kwargs for ``preset_name`` ({} when no
-    sweep result has been adopted). Field names are checked against
-    RUNTIME_FIELDS and values against their domains; a file that fails
-    validation degrades to {} with a warning, so a corrupted or hand-edited
-    adopted_runtime.json can neither crash the CLI nor burn a TPU window
-    failing deep inside the first jit trace."""
-    try:
-        data = json.loads(ADOPTED_RUNTIME_PATH.read_text())
-        fields = (data.get("presets", {}).get(preset_name, {})
-                  .get("runtime", {}))
-    except (OSError, json.JSONDecodeError):
-        return {}
-    except (AttributeError, TypeError) as e:  # valid JSON, wrong containers
-        import warnings
-        warnings.warn(f"ignoring malformed adopted_runtime.json: {e}",
-                      stacklevel=2)
-        return {}
-    try:
-        _check_runtime_fields(fields)
-    except (TypeError, ValueError) as e:
-        import warnings
-        warnings.warn(f"ignoring adopted runtime for {preset_name!r}: {e}",
-                      stacklevel=2)
-        return {}
-    return dict(fields)

@@ -3,8 +3,7 @@
 Drop-in for ``nnx.LayerNorm`` (same ``scale``/``bias`` param names, so
 checkpoint mappings are unchanged) that can route through the fused Pallas
 kernel (`jimm_tpu/ops/layer_norm.py`) — one pass over HBM for the backward
-instead of XLA's multi-fusion LN bwd (profiled at ~340 GB/s,
-docs/performance.md)."""
+instead of XLA's multi-fusion LN bwd."""
 
 from __future__ import annotations
 

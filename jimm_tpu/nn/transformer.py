@@ -270,7 +270,7 @@ class Transformer(nnx.Module):
         # elementwise ops; "none" is classic full rematerialization.
         # "+ln" / "+act" additionally keep the LayerNorm / MLP-activation
         # outputs — a bit more HBM for one less elementwise recompute pass
-        # each (the step is bandwidth-bound; see docs/performance.md).
+        # each (half of SigLIP-B's `fwd_bwd` is not matmul time, PERF.md §5).
         from jimm_tpu.configs import remat_policy_parts
         policy = self.cfg.remat_policy
         if policy == "none":

@@ -17,8 +17,6 @@ Disable all optional instrumentation with ``JIMM_OBS=0`` (or
 registries keep counting (serve counters are product behavior).
 """
 
-from jimm_tpu.obs.baseline import (BaselineStore, check_rows, is_fallback,
-                                   row_key)
 from jimm_tpu.obs.exporters import (JsonlExporter, console_table,
                                     diff_snapshots, parse_prometheus_text,
                                     render_prometheus_text)

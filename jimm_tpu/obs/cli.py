@@ -1,11 +1,11 @@
-"""``jimm-tpu obs`` — tail, snapshot, diff, timeline, regress, and prof.
+"""``jimm-tpu obs`` — tail, snapshot, diff, timeline, and prof.
 
-Six verbs over the exporter formats (stdlib only, no jax import):
+Five verbs over the exporter formats (stdlib only, no jax import):
 
 - ``snapshot`` — fetch a ``/metrics`` endpoint (or read a saved dump) and
   print it as a console table, JSON, or raw Prometheus text; ``-o`` saves
   the parsed snapshot as JSON for a later ``diff``.
-- ``tail``     — follow a MEASUREMENTS.jsonl-style ledger (``tail -f`` with
+- ``tail``     — follow a JSONL file of one object a line (``tail -f`` with
   JSON pretty-keys), or poll a ``/metrics`` URL and print only the series
   that changed between polls; ``--traces`` polls a serving server's
   ``/debug/traces`` ring and prints each request's phase decomposition.
@@ -14,9 +14,6 @@ Six verbs over the exporter formats (stdlib only, no jax import):
 - ``timeline`` — merge a flight-recorder journal (plus optional serve
   traces and a goodput report) into Chrome trace-event JSON loadable in
   Perfetto / ``chrome://tracing``.
-- ``regress``  — gate fresh MEASUREMENTS.jsonl rows against adopted
-  per-(workload,backend,preset) baselines; fallback rows are excluded
-  from comparison and ``--adopt`` records new baselines.
 - ``prof``     — the continuous-profiling ring: ``ls`` committed captures,
   ``show`` a per-op table, ``diff`` two captures direction-aware (exit 1
   on regression), and ``trigger`` a deep capture on a running server.
@@ -332,63 +329,6 @@ def _cmd_prof_trigger(args) -> int:
     return 0 if body.get("triggered") else 1
 
 
-def _cmd_regress(args) -> int:
-    from jimm_tpu.obs.baseline import (BaselineStore, check_rows, is_fallback,
-                                       summarize)
-
-    rows = []
-    with open(args.measurements) as f:
-        for line in f:
-            line = line.strip()
-            if not line.startswith("{"):
-                continue
-            try:
-                rec = json.loads(line)
-            except json.JSONDecodeError:
-                continue
-            if isinstance(rec, dict):
-                rows.append(rec)
-    store = BaselineStore(args.baselines)
-    if args.adopt:
-        adopted = store.adopt_rows(rows, note=args.note)
-        store.save()
-        print(f"adopted {len(adopted)} baseline(s) into {args.baselines}")
-        for name in adopted:
-            print(f"  + {name}")
-        return 0
-    verdicts = check_rows(store, rows, threshold=args.threshold)
-    counts = summarize(verdicts)
-    if args.json:
-        print(json.dumps({"verdicts": verdicts, "summary": counts},
-                         indent=2))
-    else:
-        for v in verdicts:
-            if v["status"] == "fallback_excluded":
-                print(f"! {v['key']}: fallback row excluded from gating")
-            elif v["status"] == "no_baseline":
-                print(f"? {v['key']} {v['metric']}={v['fresh']} "
-                      f"(no baseline; run with --adopt)")
-            else:
-                mark = {"ok": "=", "improved": "+",
-                        "regression": "REGRESSION"}[v["status"]]
-                print(f"{mark} {v['key']} {v['metric']}: {v['fresh']} vs "
-                      f"baseline {v['baseline']} "
-                      f"({v['delta_frac']:+.1%})")
-        print(f"summary: {counts['ok']} ok, {counts['improved']} improved, "
-              f"{counts['regression']} regression(s), "
-              f"{counts['no_baseline']} unbaselined, "
-              f"{counts['fallback_excluded']} fallback-excluded "
-              f"(threshold {args.threshold:.0%})")
-    if counts["regression"]:
-        return 1
-    if args.fail_on_fallback and counts["fallback_excluded"]:
-        n_real = sum(1 for r in rows if not is_fallback(r))
-        print(f"fallback rows present ({counts['fallback_excluded']}) with "
-              f"--fail-on-fallback ({n_real} real rows)", file=sys.stderr)
-        return 1
-    return 0
-
-
 def add_obs_parser(subparsers) -> None:
     """Attach the ``obs`` subcommand tree to the main CLI's subparsers."""
     p = subparsers.add_parser(
@@ -483,23 +423,6 @@ def add_obs_parser(subparsers) -> None:
                      help="incident correlation id to tag the capture with")
     ptr.add_argument("--reason", default="manual")
     ptr.set_defaults(obs_func=_cmd_prof_trigger)
-
-    pr = sub.add_parser(
-        "regress",
-        help="gate MEASUREMENTS.jsonl rows against adopted baselines")
-    pr.add_argument("--measurements", default="MEASUREMENTS.jsonl")
-    pr.add_argument("--baselines", default="BASELINES.json")
-    pr.add_argument("--threshold", type=float, default=0.20,
-                    help="max tolerated fractional regression (0.20 = 20%%)")
-    pr.add_argument("--adopt", action="store_true",
-                    help="adopt the rows' metrics as new baselines "
-                         "instead of gating")
-    pr.add_argument("--note", default=None,
-                    help="provenance note stored with adopted baselines")
-    pr.add_argument("--fail-on-fallback", action="store_true",
-                    help="exit nonzero when fallback rows are present")
-    pr.add_argument("--json", action="store_true")
-    pr.set_defaults(obs_func=_cmd_regress)
 
 
 def cmd_obs(args) -> int:

@@ -4,9 +4,9 @@ Three sinks for one snapshot:
 
 - :func:`render_prometheus_text` — the text exposition format the serve
   ``/metrics`` endpoint has always spoken, generalized to any flat dict.
-- :class:`JsonlExporter` — MEASUREMENTS.jsonl-compatible lines
-  (``{"ts": ..., "phase": ..., **series}``), appendable to the repo ledger
-  or tailed by ``jimm-tpu obs tail``.
+- :class:`JsonlExporter` — one JSON object a line
+  (``{"ts": ..., "phase": ..., **series}``), tailed by ``jimm-tpu obs
+  tail``.
 - :func:`console_table` — aligned two-column dump for humans.
 
 Plus the inverse (:func:`parse_prometheus_text`) and a structural diff
@@ -58,12 +58,9 @@ def parse_prometheus_text(text: str) -> dict[str, float]:
 
 
 class JsonlExporter:
-    """Append unified snapshots as MEASUREMENTS.jsonl-format lines.
-
-    Each line carries the same ``ts``/``phase`` provenance keys the training
-    and serve benches write, so ``jimm-tpu obs tail`` and the existing
-    ledger tooling read both interchangeably.
-    """
+    """Append unified snapshots to a file, one JSON object a line, each
+    with ``ts`` / ``phase`` provenance keys; ``jimm-tpu obs tail`` reads
+    them."""
 
     def __init__(self, path: str, phase: str = "obs"):
         self.path = path

@@ -9,7 +9,7 @@ turns those into:
   IO-accounting argument turned into a runtime artifact;
 - a **direction-aware diff** between two captures (``diff_ops``): op time
   is lower-better, so a positive delta is a regression and a negative one
-  an improvement, feeding the same verdict vocabulary as ``obs regress``.
+  an improvement.
 
 Everything here is stdlib-only so ``jimm-tpu obs prof ls/show/diff`` stays
 usable on a machine (or in a CI lane) with no accelerator stack installed.
@@ -147,10 +147,9 @@ def diff_ops(before: list[dict], after: list[dict], *,
 
     Op time is lower-better: an op whose ``total_us`` grew by more than
     ``threshold`` (fractionally) is a *regression*, one that shrank is an
-    *improvement* — the same vocabulary ``obs regress`` gates on. Ops
-    below ``min_us`` in both tables are noise and skipped. The overall
-    ``verdict`` is ``"regression"`` when total device-op time grew past
-    the threshold, else ``"ok"``."""
+    *improvement*. Ops below ``min_us`` in both tables are noise and
+    skipped. The overall ``verdict`` is ``"regression"`` when total
+    device-op time grew past the threshold, else ``"ok"``."""
     b = {r["name"]: r for r in before}
     a = {r["name"]: r for r in after}
     regressions, improvements, added, removed = [], [], [], []

@@ -56,11 +56,12 @@ def _default_backend() -> str:
 
 
 def _flash_eligible(q: jax.Array, k: jax.Array) -> bool:
-    # measured crossover on v5e (scripts/attn_crossover.py): XLA's fused
-    # attention wins below seq 512 (grid-step overhead dominates the Pallas
-    # kernel at small tiles); flash wins from 512 up and scales to long
-    # context where XLA's materialized S^2 probabilities drown in HBM
-    # traffic. Head dims are NOT gated here anymore: off-tile D (e.g. 80,
+    # the threshold predates the single-tile regime of the flash kernels
+    # (PERF.md §7, candidate (2), has the readings that question it): when
+    # it was set, XLA's fused attention won below seq 512 (grid-step
+    # overhead dominated the tiled Pallas kernel at small tiles); flash wins
+    # from 512 up and scales to long context where XLA's materialized S^2
+    # probabilities drown in HBM traffic. Head dims are NOT gated here anymore: off-tile D (e.g. 80,
     # 96) is lane-padded to the next supported tile inside the flash
     # wrapper. Measured on v5e: padding D=80 -> 128 costs ~1.25x the
     # D=128 kernel's matmul FLOPs but still beats XLA's dense path past

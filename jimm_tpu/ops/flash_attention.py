@@ -682,8 +682,8 @@ def _causal_kv_index(block_q: int, block_k: int, n_k: int):
     """kv-block index map for causal grids ordered (heads, q, kv): blocks
     strictly above the diagonal (kernel skips them via ``pl.when``) are
     clamped to the q row's last needed block, so the pipeline sees the same
-    index twice and elides the HBM->VMEM copy (VERDICT r2 weak #4 — the
-    skipped blocks' DMAs used to run anyway)."""
+    index twice and elides the HBM->VMEM copy (the skipped blocks' DMAs
+    used to run anyway)."""
     def idx(h, i, j):
         jmax = jnp.minimum(n_k - 1, ((i + 1) * block_q - 1) // block_k)
         return (h, jnp.minimum(j, jmax), 0)

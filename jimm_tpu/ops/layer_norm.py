@@ -1,8 +1,8 @@
 """Pallas TPU fused LayerNorm (forward + custom-VJP backward).
 
-XLA's LayerNorm backward materializes several row-stat intermediates and ran
-at ~340 GB/s in the SigLIP train-step profile (vs ~800 GB/s streaming ops —
-see docs/performance.md). This kernel computes dx and the dscale/dbias
+XLA's LayerNorm backward materializes several row-stat intermediates (an
+early SigLIP train-step profile had it at ~340 GB/s; the benchmark has no
+reading of either path, PERF.md). This kernel computes dx and the dscale/dbias
 row-partials in ONE pass over (rows, features) tiles: each tensor is read
 exactly once.
 
@@ -12,8 +12,8 @@ multiple with the statistics masked to the real width, rows are padded to a
 sublane-aligned block multiple, and the per-row mean/rstd are stored
 lane-broadcast like the flash-attention stats. Nothing relies on the
 "block equals array" escape hatch, which older kernels leaned on and which
-stricter Mosaic versions reject (the recorded ``ln=fused`` sweep failures
-on siglip_b16_256 in MEASUREMENTS.jsonl).
+stricter Mosaic versions reject (``ln_impl="fused"`` once failed to compile
+for SigLIP-B/16-256 that way).
 
 The row-block size resolves through `jimm_tpu.tune.best_config` when not
 given explicitly: a tuned value if the persistent cache has one for this

@@ -13,9 +13,8 @@ package) that turn the static supervise loop into an adaptive one:
   (``obs.goodput`` bucket deltas, including ``preemption_save`` and
   ``lost_work``) over a sliding window and adjusts the runtime knobs the
   next attempt launches with — checkpoint cadence, preemption grace steps,
-  layer-scan unroll. This is the "adopted-plus-adapted" runtime: the
-  measured ``adopted_runtime.json`` pick seeds the knobs, live goodput
-  revises them.
+  layer-scan unroll: the train command's own flags seed the knobs, live
+  goodput revises them.
 
 Every advisor decision is **bounded** (hard per-knob clamps), **hysteretic**
 (windowed means with a cooldown between decisions and a dead band between

@@ -22,8 +22,8 @@ metric series the obs docs list:
   ``jimm_retrieval_ivf_candidate_frac`` /
   ``jimm_retrieval_ivf_recall_proxy`` gauges tracking the most recent
   search: probe width, fraction of the corpus rescored, and the fill
-  ratio (results found / k — a cheap online recall proxy; the measured
-  recall@10 lives in MEASUREMENTS.jsonl via ``scripts/ann_frontier.py``),
+  ratio (results found / k — a cheap online recall proxy;
+  ``scripts/ann_frontier.py`` measures recall@10 against the exact oracle),
 - the ``retrieval_topk`` / ``retrieval_ivf`` span around every scoring
   call (device scan + host merge), in ``jimm_spans_*`` like every span.
 

@@ -285,8 +285,8 @@ class Searcher:
 
     def resident_bytes(self) -> int:
         """Device-resident corpus bytes (padded blocks + offset table) —
-        the same accounting the tiered searcher reports, so serve_bench
-        rows compare across index modes."""
+        the same accounting the tiered searcher reports, so the number
+        compares across index modes."""
         return int(self._blocks.nbytes) + int(self._offsets.nbytes)
 
     # -- AOT keys ---------------------------------------------------------

@@ -7,9 +7,6 @@ traffic). The fix is the same one the linter's JLT103 trace check certifies
 from the model side: declare a small, fixed set of batch buckets up front,
 pad every micro-batch up to the nearest bucket, and warm-compile each bucket
 once at startup. After warmup the engine never sees a new shape.
-
-``scripts/inference_bench.py`` reads the same table, so the bench times the
-exact compiled programs the server dispatches.
 """
 
 from __future__ import annotations
@@ -48,8 +45,8 @@ class BucketTable:
     """An ascending, de-duplicated set of allowed batch sizes, tagged with
     the serving precision. The dtype rides the table (not the engine)
     because it is part of the same compile-shape contract: one warm
-    executable per (bucket, dtype), and MEASUREMENTS rows / ready lines
-    report both axes."""
+    executable per (bucket, dtype), and the ready line reports both
+    axes."""
 
     sizes: tuple[int, ...]
     dtype: str = "float32"

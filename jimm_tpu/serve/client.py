@@ -45,8 +45,8 @@ class CascadeInfo:
     """Escalation metadata a cascade-routed response carried: which models
     the request tried (cheapest first), which one answered, and the
     calibrated confidence the final decision rode on (None when the
-    terminal stage accepted by fiat). This is what serve_bench bills
-    cost/request from — no server log scraping."""
+    terminal stage accepted by fiat). A client bills cost/request from
+    this — no server log scraping."""
 
     models_tried: tuple[str, ...]
     model: str

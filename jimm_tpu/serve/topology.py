@@ -84,8 +84,7 @@ class TopologyPlan:
                 for group in self.device_groups]
 
     def describe(self) -> dict:
-        """Flat JSON-able summary for ready lines, healthz, and the
-        MEASUREMENTS.jsonl topology fields."""
+        """Flat JSON-able summary for ready lines and healthz."""
         return {"n_devices": self.n_devices, "replicas": self.replicas,
                 "model_parallel": self.model_parallel,
                 "seq_parallel": self.seq_parallel,

@@ -1,7 +1,7 @@
 """Profiling hooks (SURVEY §5 tracing row): `jax.profiler` trace capture
 around training steps, viewable in TensorBoard / Perfetto — plus an
-offline per-op analyzer so a capture can be read without TensorBoard (the
-workflow behind docs/performance.md; `python -m jimm_tpu profile-analyze`).
+offline per-op analyzer so a capture can be read without TensorBoard
+(`python -m jimm_tpu profile-analyze`).
 
 Since the continuous profiler landed, :func:`trace` delegates to
 :func:`jimm_tpu.obs.prof.capture.profiler_session` — the process-wide

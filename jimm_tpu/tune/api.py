@@ -13,8 +13,8 @@ The contract the ops hot path relies on:
   up on the first metrics dump.
 
 The process-wide cache defaults to ``JIMM_TUNE_CACHE`` or
-``~/.cache/jimm_tpu/tune``; ``serve --tune-cache`` / ``bench.py
---tune-cache`` repoint it via `configure`.
+``~/.cache/jimm_tpu/tune``; ``serve --tune-cache`` repoints it via
+`configure`.
 """
 
 from __future__ import annotations
@@ -181,10 +181,10 @@ def _ln_default(shapes: Shapes, dtypes: Dtypes) -> dict:
     return {"block_rows": DEFAULT_BLOCK_ROWS}
 
 
-def _ln_bench(shapes: Shapes, dtypes: Dtypes,
-              config: Mapping[str, int]) -> Callable[[], Any]:
+def _layer_norm_bench(shapes: Shapes, dtypes: Dtypes,
+                      config: Mapping[str, int]) -> Callable[[], Any]:
     """Timed closure: fused LN fwd+bwd (the backward is the kernel's whole
-    reason to exist — see docs/performance.md)."""
+    reason to exist)."""
     import jax
     import jax.numpy as jnp
 
@@ -434,7 +434,8 @@ KERNELS: dict[str, KernelSpec] = {
                                     default=_flash_default,
                                     bench=_sigmoid_bench),
     "layer_norm": KernelSpec(version=1, space=ln_space,
-                             default=_ln_default, bench=_ln_bench),
+                             default=_ln_default,
+                             bench=_layer_norm_bench),
     "retrieval_topk": KernelSpec(version=1, space=retrieval_space,
                                  default=_retrieval_default,
                                  bench=_retrieval_bench),
@@ -479,7 +480,7 @@ def get_cache() -> TuneCache:
 
 def configure(root: str | os.PathLike | None) -> TuneCache:
     """Point the process-wide tune cache at ``root`` (``serve --tune-cache``
-    and ``bench.py --tune-cache`` call this before any kernel traces)."""
+    and the benchmark's harness call this before any kernel traces)."""
     global _cache
     _cache = TuneCache(root)
     return _cache

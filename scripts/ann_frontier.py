@@ -7,19 +7,17 @@ corpus, then sweeps ``nprobe`` measuring, per point:
   number that makes approximate retrieval a feature instead of a silent
   regression — see ISSUE/ROADMAP),
 - **QPS** of the warm fused two-stage program (closed loop, single
-  client: this is the kernel frontier, not the HTTP path —
-  ``serve_bench --search`` owns that),
+  client: this is the kernel frontier, not the HTTP path); off the TPU the
+  field is ``cpu_qps``, a host number that says nothing about the chip,
 - **candidate_frac**, the fraction of the corpus the probe actually
   rescored (the work knob recall is being traded against).
 
-An exact-mode row per corpus anchors the frontier at recall 1.0. With
-``--record``, every point lands in MEASUREMENTS.jsonl with ``index_mode``
-/ ``nprobe`` / ``recall_at_10`` fields; ``recall_at_10`` is
-direction-aware in the obs baselines (higher is better), so an adopted
-frontier point gates recall drops ≥ 20% like a throughput drop.
+An exact-mode row per corpus anchors the frontier at recall 1.0. Every
+point is one JSON line with ``backend`` / ``index_mode`` / ``nprobe`` /
+``recall_at_10`` fields.
 
 Usage:
-    JAX_PLATFORMS=cpu python -m scripts.ann_frontier --record
+    JAX_PLATFORMS=cpu python -m scripts.ann_frontier
     python -m scripts.ann_frontier --corpus-sizes 200000 \
         --nprobes 1,2,4,8,16,32   # on a real TPU backend
 """
@@ -32,7 +30,7 @@ import sys
 import time
 
 
-def frontier(args) -> list[dict]:
+def frontier(args) -> None:
     import jax
     import numpy as np
 
@@ -45,7 +43,6 @@ def frontier(args) -> list[dict]:
     backend = jax.default_backend()
     dim = args.dim or (512 if on_tpu else 64)
     nprobes = [int(x) for x in args.nprobes.split(",")]
-    rows: list[dict] = []
 
     for n in (int(s) for s in args.corpus_sizes.split(",")):
         centers = max(8, n // 256)
@@ -85,9 +82,9 @@ def frontier(args) -> list[dict]:
                 done += len(batch)
             return (args.queries / (time.perf_counter() - t0)), id_rows
 
+        qps_field = "qps" if on_tpu else "cpu_qps"
         base = {
-            "metric": ("ann_frontier" if on_tpu
-                       else "ann_frontier (cpu smoke)"),
+            "metric": "ann_frontier",
             "workload": "ann_frontier", "backend": backend,
             "corpus_rows": n, "dim": dim, "clusters": clusters, "k": k,
             "block_n": searcher.block_n, "queries": args.queries,
@@ -99,12 +96,11 @@ def frontier(args) -> list[dict]:
             recall = float(np.mean([
                 len({int(r[1:]) for r in row} & oracle_sets[i]) / k
                 for i, row in enumerate(id_rows)]))
-            rows.append({**base, "index_mode": "ivf", "nprobe": np_eff,
-                         "recall_at_10": round(recall, 4),
-                         "qps": round(qps, 2),
-                         "candidate_frac": searcher.last_stats.get(
-                             "candidate_frac")})
-            print(json.dumps(rows[-1]), flush=True)
+            print(json.dumps({**base, "index_mode": "ivf", "nprobe": np_eff,
+                              "recall_at_10": round(recall, 4),
+                              qps_field: round(qps, 2),
+                              "candidate_frac": searcher.last_stats.get(
+                                  "candidate_frac")}), flush=True)
         exact = IndexSearcher(index, k=k, buckets=(bucket,),
                               block_n=args.block_n)
         exact.warmup()
@@ -112,11 +108,10 @@ def frontier(args) -> list[dict]:
         recall = float(np.mean([
             len({int(r[1:]) for r in row} & oracle_sets[i]) / k
             for i, row in enumerate(id_rows)]))
-        rows.append({**base, "index_mode": "exact", "nprobe": None,
-                     "recall_at_10": round(recall, 4),
-                     "qps": round(qps, 2), "candidate_frac": 1.0})
-        print(json.dumps(rows[-1]), flush=True)
-    return rows
+        print(json.dumps({**base, "index_mode": "exact", "nprobe": None,
+                          "recall_at_10": round(recall, 4),
+                          qps_field: round(qps, 2), "candidate_frac": 1.0}),
+              flush=True)
 
 
 def main() -> int:
@@ -127,8 +122,7 @@ def main() -> int:
     p.add_argument("--corpus-sizes", default="50000",
                    help='comma-separated corpus sizes, e.g. "50000,200000"')
     p.add_argument("--nprobes", default="1,2,4,8,16",
-                   help="comma-separated nprobe sweep (≥3 points for an "
-                        "adoptable frontier)")
+                   help="comma-separated nprobe sweep")
     p.add_argument("--dim", type=int, default=None,
                    help="embedding dim (default: 512 on TPU, 64 off-TPU)")
     p.add_argument("--queries", type=int, default=256)
@@ -137,20 +131,9 @@ def main() -> int:
     p.add_argument("--block-n", type=int, default=None,
                    help="rescore block size (default: tuner best_config)")
     p.add_argument("--warmup-reps", type=int, default=2)
-    p.add_argument("--record", action="store_true",
-                   help="append every point to MEASUREMENTS.jsonl")
     args = p.parse_args()
 
-    rows = frontier(args)
-    if args.record:
-        from scripts._measurements import MEASUREMENTS
-        ts = time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime())
-        with open(MEASUREMENTS, "a") as f:
-            for rec in rows:
-                f.write(json.dumps(
-                    {"ts": ts, "phase": "ann_frontier", **rec}) + "\n")
-        print(json.dumps({"recorded": len(rows),
-                          "path": str(MEASUREMENTS)}), flush=True)
+    frontier(args)
     return 0
 
 

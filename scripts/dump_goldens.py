@@ -1,7 +1,6 @@
-"""One-time, network-gated golden recorder for real-checkpoint parity
-(VERDICT r3 item 4).
+"""One-time, network-gated golden recorder for real-checkpoint parity.
 
-Runs the HF torch oracles for the BASELINE tracked checkpoints
+Runs the HF torch oracles for the tracked checkpoints
 (`tests/golden_util.GOLDEN_SPECS`: google/vit-base-patch16-224,
 openai/clip-vit-base-patch32, google/siglip-base-patch16-256) on the
 deterministic golden inputs and records logits + tower embeddings into
@@ -14,8 +13,7 @@ elsewhere; it is written defensively and prints exactly what it produced.
 
 Every invocation appends a dated per-checkpoint outcome to
 ``tests/goldens/ATTEMPTS.log`` (committed), so a blocked-egress attempt
-leaves auditable evidence distinguishable from "never tried"
-(VERDICT r4 item 4).
+leaves auditable evidence distinguishable from "never tried".
 
 Usage:
     python -m scripts.dump_goldens --all          [--out tests/goldens]

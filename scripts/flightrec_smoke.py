@@ -1,6 +1,6 @@
 """CI drill for flight-recorder observability (ISSUE 13).
 
-One journal, four legs, all through shipped code paths:
+One journal, three legs, all through shipped code paths:
 
 **Train leg — correlated preemption chain.** ``supervise --elastic
 --shrink-plan 8,4`` with ``preempt@2`` injected (the ISSUE-12 kill-drill).
@@ -23,10 +23,6 @@ the committed artifact's ``meta.json`` carries the cid.
 **Timeline leg.** ``export_timeline`` over the full journal plus the
 engine's ``recent_traces`` must validate with zero problems and cover both
 incidents (both root cids appear in the trace's args).
-
-**Regress leg.** ``jimm-tpu obs regress`` adopts synthetic baselines, must
-pass on unchanged rows (exit 0), must flag a 20%-injected throughput drop
-(exit 1), and must exclude fallback rows from gating.
 
 Exits nonzero with a JSON error line on any violation.
 
@@ -253,34 +249,6 @@ def timeline_leg(tmp: Path, journal: Path, rows: list[dict],
                   "path": str(out)}
 
 
-def regress_leg(tmp: Path) -> tuple[str | None, dict]:
-    from jimm_tpu.obs.cli import main as obs_main
-
-    row = {"ts": "t", "phase": "serve_bench", "backend": "cpu",
-           "preset": "vit-tiny", "qps": 500.0, "latency_p99_ms": 12.0}
-    baselines = tmp / "BASELINES.json"
-    fresh = tmp / "m_fresh.jsonl"
-    fresh.write_text(json.dumps(row) + "\n")
-    if obs_main(["obs", "regress", "--measurements", str(fresh),
-                 "--baselines", str(baselines), "--adopt",
-                 "--note", "flightrec smoke seed"]) != 0:
-        return "baseline adoption failed", {}
-    if obs_main(["obs", "regress", "--measurements", str(fresh),
-                 "--baselines", str(baselines)]) != 0:
-        return "unchanged rows flagged as regression", {}
-    hurt = tmp / "m_hurt.jsonl"
-    hurt.write_text(json.dumps(dict(row, qps=row["qps"] * 0.8)) + "\n")
-    if obs_main(["obs", "regress", "--measurements", str(hurt),
-                 "--baselines", str(baselines)]) != 1:
-        return "injected 20% throughput drop was NOT flagged", {}
-    fb = tmp / "m_fb.jsonl"
-    fb.write_text(json.dumps(dict(row, qps=1.0, fallback=True)) + "\n")
-    if obs_main(["obs", "regress", "--measurements", str(fb),
-                 "--baselines", str(baselines)]) != 0:
-        return "fallback row gated instead of excluded", {}
-    return None, {"threshold": 0.20}
-
-
 def main() -> int:
     # must land before jax initializes its backends
     os.environ.setdefault("XLA_FLAGS",
@@ -308,13 +276,9 @@ def main() -> int:
         tmp, journal, rows, [train_summary["cid"], serve_summary["cid"]])
     if err:
         return fail(f"timeline leg: {err}")
-    err, regress_summary = regress_leg(tmp)
-    if err:
-        return fail(f"regress leg: {err}")
     print(json.dumps({"metric": "flightrec_smoke", "value": 1.0,
                       "train": train_summary, "serve": serve_summary,
-                      "timeline": timeline_summary,
-                      "regress": regress_summary}), flush=True)
+                      "timeline": timeline_summary}), flush=True)
     return 0
 
 

@@ -16,14 +16,10 @@ Three gates, end to end through the real ``jimm-tpu train`` CLI on CPU
    lookup only, and a re-tune here would mean the fp8 kernels' keys churn
    per process.
 
-``--record`` appends one MEASUREMENTS.jsonl row (``"phase":
-"lowp_train_smoke"``) carrying ``precision``, per-variant losses, and the
-goodput/MFU readout, so precision sweeps land beside bench rows.
-
 Exits nonzero (with a JSON error line) on any violation.
 
 Usage:
-    JAX_PLATFORMS=cpu python -m scripts.lowp_train_smoke [--record]
+    JAX_PLATFORMS=cpu python -m scripts.lowp_train_smoke
 """
 
 from __future__ import annotations
@@ -35,7 +31,6 @@ import pathlib
 import subprocess
 import sys
 import tempfile
-import time
 
 PRESET = "vit-tiny-patch16-224"
 STEPS = 6
@@ -79,22 +74,12 @@ def read_metrics(metrics_file: pathlib.Path) -> list[dict]:
     return [r for r in rows if "loss" in r]
 
 
-def imgs_per_sec(rows: list[dict]) -> float | None:
-    """Steady-state throughput: first step carries trace+compile, so it is
-    excluded; the rest average out interpreter jitter."""
-    times = [r["step_time_s"] for r in rows[1:] if r.get("step_time_s")]
-    return round(BATCH * len(times) / sum(times), 4) if times else None
-
-
 def cache_entries(root: pathlib.Path) -> set[str]:
     return {str(p.relative_to(root)) for p in root.rglob("*") if p.is_file()}
 
 
 def main() -> int:
-    parser = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    parser.add_argument("--record", action="store_true",
-                        help="append the result to MEASUREMENTS.jsonl")
-    args = parser.parse_args()
+    argparse.ArgumentParser(description=__doc__.splitlines()[0]).parse_args()
 
     with tempfile.TemporaryDirectory(prefix="lowp_smoke_") as tmp:
         tmpdir = pathlib.Path(tmp)
@@ -102,7 +87,7 @@ def main() -> int:
         cache.mkdir()
 
         # --- bf16 control, then fp8 life 1 (cold cache, may tune) --------
-        control_goodput = run_train("bf16", tmpdir / "bf16.jsonl", None)
+        run_train("bf16", tmpdir / "bf16.jsonl", None)
         run_train("fp8_hybrid", tmpdir / "fp8_life1.jsonl", cache)
         warm = cache_entries(cache)
 
@@ -143,22 +128,9 @@ def main() -> int:
         "moment_dtype": fp8_goodput.get("moment_dtype"),
         "steps": STEPS, "batch_size": BATCH,
         "loss_bf16": loss_c, "loss_fp8": loss_l, "loss_rel_diff": rel,
-        "mfu_bf16": control_goodput.get("mfu"),
-        "mfu_fp8": fp8_goodput.get("mfu"),
-        "img_s_bf16": imgs_per_sec(control),
-        "img_s_fp8": imgs_per_sec(lowp),
         "tune_entries": len(warm),
     }
     print(json.dumps(result), flush=True)
-
-    if args.record:
-        from scripts._measurements import MEASUREMENTS
-        ts = time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime())
-        with open(MEASUREMENTS, "a") as f:
-            f.write(json.dumps({"ts": ts, "phase": "lowp_train_smoke",
-                                **{k: v for k, v in result.items()
-                                   if k not in ("metric", "value")}})
-                    + "\n")
     return 0
 
 

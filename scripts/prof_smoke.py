@@ -20,8 +20,8 @@ include ``prof_capture_committed``.
 
 **Overhead leg.** Interleaved ring-on / ring-off tiny-train pairs; the
 minimum over pairs of (median on-step time / median off-step time) must
-be <= 1.01 — the <=1% overhead budget the ring ships under. Appends a
-``phase=prof_overhead`` row to MEASUREMENTS.jsonl.
+be <= 1.01 — the <=1% overhead budget the ring ships under. The ratio is
+of CPU step times and is printed in the summary's ``overhead`` field.
 
 Exits nonzero with a JSON error line on any violation.
 
@@ -303,19 +303,6 @@ def main() -> int:
     err, hbm_summary = hbm_leg()
     if err:
         return fail(f"hbm leg: {err}")
-
-    row = {"ts": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
-           "phase": "prof_overhead",
-           "metric": "prof_ring_overhead (cpu smoke)",
-           "value": overhead_summary["min_ratio"],
-           "unit": "x step time vs ring off (min over pairs of medians)",
-           "workload": "vit_tiny_train", "backend": "cpu",
-           **{k: v for k, v in overhead_summary.items()
-              if k != "min_ratio"}}
-    measurements = Path(__file__).resolve().parent.parent \
-        / "MEASUREMENTS.jsonl"
-    with open(measurements, "a") as f:
-        f.write(json.dumps(row) + "\n")
 
     print(json.dumps({"metric": "prof_smoke", "value": 1.0,
                       "ring": ring_summary, "diff": diff_summary,

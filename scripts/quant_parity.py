@@ -10,15 +10,15 @@ serving fast path actually costs in accuracy:
   normalized class matrix is unchanged (the zero-shot proxy the serving
   path cares about),
 - **imgs_per_sec**: steady-state throughput of the jitted f32 and int8
-  forwards over the same batch.
+  forwards over the same batch (off the TPU the fields are
+  ``cpu_imgs_per_sec_*``: host numbers that say nothing about the chip).
 
-Prints one MEASUREMENTS.jsonl-format JSON line (``--record`` appends it),
-with ``"phase": "quant_parity"`` and a ``dtype`` field per variant so
-these and the serving rows stay join-able.
+Prints one JSON line with ``"phase": "quant_parity"``, the ``backend`` it
+ran on and a ``dtype`` field per variant.
 
 Usage:
     JAX_PLATFORMS=cpu python -m scripts.quant_parity --preset tiny
-    python -m scripts.quant_parity --preset clip-vit-base-patch16 --record
+    python -m scripts.quant_parity --preset clip-vit-base-patch16
 """
 
 from __future__ import annotations
@@ -89,8 +89,6 @@ def main(argv=None) -> int:
     ap.add_argument("--iters", type=int, default=3,
                     help="timed forward passes per variant")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--record", action="store_true",
-                    help="append the result line to MEASUREMENTS.jsonl")
     args = ap.parse_args(argv)
 
     import jax
@@ -109,6 +107,8 @@ def main(argv=None) -> int:
     emb_q = np.asarray(fwd_q(x))
 
     cos = cosine_rows(emb_q, emb_f32)
+    rate = ("imgs_per_sec" if jax.default_backend() == "tpu"
+            else "cpu_imgs_per_sec")
     rec = {
         "phase": "quant_parity",
         "preset": args.preset,
@@ -121,15 +121,10 @@ def main(argv=None) -> int:
         "cosine_mean": round(float(cos.mean()), 6),
         "top1_agreement": round(top1_agreement(
             emb_q, emb_f32, args.classes, args.seed), 4),
-        "imgs_per_sec_f32": round(throughput(fwd_f32, x, args.iters), 2),
-        "imgs_per_sec_int8": round(throughput(fwd_q, x, args.iters), 2),
+        f"{rate}_f32": round(throughput(fwd_f32, x, args.iters), 2),
+        f"{rate}_int8": round(throughput(fwd_q, x, args.iters), 2),
     }
     print(json.dumps(rec), flush=True)
-    if args.record:
-        from scripts._measurements import MEASUREMENTS
-        with open(MEASUREMENTS, "a") as f:
-            f.write(json.dumps({"ts": time.strftime("%Y-%m-%dT%H:%M:%S"),
-                                **rec}) + "\n")
     return 0
 
 

@@ -1,6 +1,6 @@
-"""Validate `_pick_hb`'s VMEM model against compiled reality (VERDICT r3
-weak #5: the 8 MB budget and per-head byte estimate were never checked on
-TPU — an overestimate silently halves head batching, an underestimate would
+"""Validate `_pick_hb`'s VMEM model against compiled reality (the 8 MB
+budget and per-head byte estimate were never checked on TPU — an
+overestimate silently halves head batching, an underestimate would
 OOM at exotic shapes).
 
 Method: for each shipped (bn, seq, d) combination, force the heads-per-cell

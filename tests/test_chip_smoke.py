@@ -1,5 +1,5 @@
-"""`chip_smoke.py` and `bench.py` off the chip: they refuse to run without a
-TPU, and a phase that raises fails the script. The rehearsals themselves are
+"""`chip_smoke.py` off the chip: it refuses to run without a TPU, and a
+phase that raises fails the script. The rehearsals themselves are
 in `test_zz_chip_rehearsal.py`."""
 
 import importlib.util
@@ -20,8 +20,7 @@ def _load(name):
 
 
 @pytest.mark.parametrize("script, argv", [("chip_smoke", []),
-                                          ("chip_smoke", ["--multichip"]),
-                                          ("bench", [])])
+                                          ("chip_smoke", ["--multichip"])])
 def test_without_a_tpu_nothing_runs_and_nothing_is_printed(script, argv,
                                                            capsys):
     """No TPU and no explicit rehearsal: non-zero exit, no result line, no

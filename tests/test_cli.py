@@ -6,11 +6,13 @@ inspect safetensors files.
 """
 
 import json
+import pathlib
 
 import numpy as np
 import pytest
 
 from jimm_tpu.cli import main
+from jimm_tpu.configs import PRESETS
 from jimm_tpu.weights.safetensors_io import save_file
 
 
@@ -134,3 +136,117 @@ def test_train_profile_capture(tmp_path, capsys):
                  "--profile-dir", str(tmp_path / "prof")]) == 0
     assert "profile trace written" in capsys.readouterr().out
     assert (tmp_path / "prof" / "plugins" / "profile").is_dir()
+
+
+# ---------------------------------------------------------------------------
+# resolve_runtime: how a train run executes, asked on the CPU what the TPU gets
+# ---------------------------------------------------------------------------
+
+REPO = pathlib.Path(__file__).resolve().parents[1]
+CELLS = sorted(p.stem for p in (REPO / "benchmarks/workloads").glob("*.json"))
+
+
+def _resolve(argv, backend):
+    from jimm_tpu import cli, preset
+    args = cli.build_parser().parse_args(argv)
+    cfg = preset(args.preset)
+    if args.num_layers:  # as `train` shapes a language model before it asks
+        cfg = cli._replace_towers(cfg, depth=args.num_layers)
+    return cli.resolve_runtime(args, cfg, None, backend)
+
+
+@pytest.mark.parametrize("name", sorted(PRESETS))
+def test_unflagged_runtime_is_shape_and_platform_only(name):
+    """No runtime flag: nothing is chosen for the run but the unroll, from
+    the model's depth, on the TPU. No preset's name chooses anything."""
+    cfg = PRESETS[name]
+    main_tower = cfg.decoder if hasattr(cfg, "decoder") else cfg.vision
+    argv = ["train", "--preset", name]
+    assert _resolve(argv, "tpu") == {"scan_unroll": main_tower.depth}
+    assert _resolve(argv, "cpu") == {}
+
+
+#: `scan_unroll` of each cell's `resolved_runtime` line (ledger, PR 28); a
+#: new cell gets its row here
+LEDGER_SCAN_UNROLL = {"siglip_b16_256.train": 12, "vit_l16_384.train": 24,
+                      "ouro_2_6b.train": 8}
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_argv_resolves_as_the_ledger_says(cell):
+    """The benchmark's cells, argv as `benchmarks/drivers` build it from the
+    cell's and the configuration's files (read here, never edited): the
+    `resolved_runtime` the ledger's rows were measured under."""
+    workload = json.loads(
+        (REPO / "benchmarks/workloads" / f"{cell}.json").read_text())
+    config = json.loads((REPO / "benchmarks/configs"
+                         / f"{workload['config']}.json").read_text())
+    traffic = workload["traffic_params"]
+    argv = ["train", "--preset", config["preset"], "--seed", "1",
+            "--batch-size", str(traffic["batch_size"]), "--steps", "40",
+            "--log-every", "1", "--metrics-file", "m.jsonl",
+            *traffic["cli_args"]]
+    if workload["driver"] == "train_lm":
+        argv += ["--num-layers", str(config["num_layers"]),
+                 "--seq-len", str(traffic["seq_len"])]
+    assert _resolve(argv, "tpu") == {"remat": True, "remat_policy": "dots",
+                                     "scan_unroll": LEDGER_SCAN_UNROLL[cell]}
+
+
+def test_explicit_flags_and_a_checkpoint_outrank_the_platform():
+    assert _resolve(["train", "--preset", "vit-base-patch16-224",
+                     "--scan-unroll", "1"], "tpu") == {"scan_unroll": 1}
+    # a checkpoint's depth is not known when the run is resolved
+    assert _resolve(["train", "--preset", "vit-base-patch16-224",
+                     "--from-pretrained", "x"], "tpu") == {}
+    assert _resolve(["train", "--preset", "vit-base-patch16-224", "--rules",
+                     "pp", "--pipeline-microbatches", "4"], "cpu") == {
+        "pipeline": True, "pp_microbatches": 4}
+    with pytest.raises(SystemExit, match="--pipeline-virtual needs"):
+        _resolve(["train", "--preset", "vit-base-patch16-224",
+                  "--pipeline-virtual", "2"], "cpu")
+    with pytest.raises(SystemExit, match="--remat: "):
+        _resolve(["train", "--preset", "vit-base-patch16-224", "--remat",
+                  "dots+nosuch"], "cpu")
+
+
+# what `benchmarks/drivers/train_cli.py::resolved_runtime` and
+# `train_lm.py::resolved_runtime` read off `model.config.<tower>`, and what
+# `benchmarks/reference/parity*.py` read to size their references. The
+# drivers are the yardstick: a PR that is not a `benchmark` PR cannot edit
+# them, so a refactor that renames one of these finds out here, in seconds,
+# and not on the chip (ROADMAP, Design: named debts).
+SURFACE = {
+    "vision": ("siglip-base-patch16-256",
+               ["attn_impl", "scan_unroll", "remat", "remat_policy",
+                "ln_impl", "precision", "num_heads", "ln_eps", "act",
+                "patch_size", "width", "mlp_dim", "depth", "image_size",
+                "channels"]),
+    "text": ("siglip-base-patch16-256",
+             ["context_length", "vocab_size", "num_heads", "ln_eps", "act",
+              "width", "mlp_dim", "depth"]),
+    "decoder": ("ouro-2.6b",
+                ["attn_impl", "scan_unroll", "remat", "remat_policy",
+                 "precision", "depth", "loops", "seq_len", "width",
+                 "mlp_dim", "num_heads", "vocab_size", "ln_eps",
+                 "rope_theta", "act"]),
+}
+
+
+@pytest.mark.parametrize("tower", sorted(SURFACE))
+def test_config_surface_the_benchmark_reads(tower):
+    from jimm_tpu import preset
+    name, fields = SURFACE[tower]
+    cfg = getattr(preset(name), tower)
+    assert [f for f in fields if not hasattr(cfg, f)] == []
+    # and the names the harness, the drivers and tests/benchmark import
+    from jimm_tpu import Ouro, cli, obs, tune  # noqa: F401
+    from jimm_tpu.aot.export import enable_persistent_cache
+    from jimm_tpu.parallel import shard_batch, use_sharding
+    from jimm_tpu.train.metrics import train_step_flops
+    from jimm_tpu.train.trainer import contrastive_loss_fn, lm_loss_fn
+    for fn in (cli._tiny_override, cli._model_cls, cli.build_parser,
+               cli.train, tune.configure, enable_persistent_cache,
+               obs.snapshot, train_step_flops, shard_batch, use_sharding,
+               contrastive_loss_fn, lm_loss_fn):
+        assert callable(fn)

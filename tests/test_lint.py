@@ -104,7 +104,7 @@ class TestRuleFixtures:
         assert check_bare_print(tree, "jimm_tpu/obs/cli.py") == []
         assert check_bare_print(tree, "jimm_tpu/__main__.py") == []
         assert check_bare_print(tree, "jimm_tpu/launch.py") == []
-        assert check_bare_print(tree, "scripts/serve_bench.py") == []
+        assert check_bare_print(tree, "scripts/obs_smoke.py") == []
         assert check_bare_print(tree, "tests/test_obs.py") == []
         # library modules are not
         assert check_bare_print(tree, "jimm_tpu/train/metrics.py") != []

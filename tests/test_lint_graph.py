@@ -161,9 +161,9 @@ class TestConcurrencyRules:
 
     @pytest.mark.slow
     def test_full_tree_build_within_budget(self):
-        # the hard 10 s wall-time gate runs in scripts/lint_bench.py on a
-        # quiet runner; in-suite, allow 2x for contention with the rest of
-        # the tests so this asserts "same order of magnitude", not luck
+        # the full tree takes a few seconds on a quiet machine; 20 s allows
+        # for contention with the rest of the tests, so this asserts "same
+        # order of magnitude", not luck
         t0 = time.perf_counter()
         files = collect_files([str(REPO / "jimm_tpu"), str(REPO / "tests")])
         g = ProjectGraph.build(files)

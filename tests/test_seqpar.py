@@ -16,7 +16,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from jimm_tpu.obs.baseline import row_key
 from jimm_tpu.ops.attention import (dot_product_attention,
                                     reference_attention,
                                     reference_sigmoid_attention)
@@ -494,33 +493,6 @@ class TestRingTune:
                           (jnp.float32,) * 3,
                           default={"block_q": 128, "block_k": 512})
         assert cfg == {"block_q": 128, "block_k": 512}
-
-
-# ---------------------------------------------------------------------------
-# Baseline keys segment on sequence identity
-# ---------------------------------------------------------------------------
-
-class TestBaselineSeqKeys:
-    BASE = {"phase": "serve_bench", "backend": "cpu", "preset": "p"}
-
-    def test_legacy_rows_keep_their_keys(self):
-        assert row_key(self.BASE) == "serve_bench/cpu/p"
-
-    def test_seq_len_segments(self):
-        assert row_key({**self.BASE, "seq_len": 1568}) == \
-            "serve_bench/cpu/p/seq1568"
-
-    def test_seq_parallel_segments_only_above_one(self):
-        rec = {**self.BASE, "seq_len": 1568, "seq_parallel": 4}
-        assert row_key(rec) == "serve_bench/cpu/p/seq1568/sp4"
-        # a stamped-but-degenerate run keeps the single-chip key
-        rec["seq_parallel"] = 1
-        assert row_key(rec) == "serve_bench/cpu/p/seq1568"
-
-    def test_ring_run_never_gates_against_single_chip_baseline(self):
-        single = row_key({**self.BASE, "seq_len": 196, "seq_parallel": 1})
-        ring = row_key({**self.BASE, "seq_len": 196, "seq_parallel": 8})
-        assert single != ring
 
 
 # ---------------------------------------------------------------------------
