@@ -27,6 +27,7 @@ mesh + sharding rules, jitted step, checkpoint/resume, metrics JSONL.
 from __future__ import annotations
 
 import argparse
+import collections
 import dataclasses
 import sys
 import types
@@ -796,7 +797,49 @@ def train(args: argparse.Namespace) -> Any:
     # whole run when it is shorter than that
     profile_start = min(start_step + 2, max(args.steps - 1, start_step))
     profile_stop = min(start_step + 4, args.steps - 1)
-    dt = batch = None
+    ahead_total = obs.get_registry("jimm_train").counter(
+        "steps_dispatched_ahead_total")
+    ahead_before = ahead_total.value  # the registry outlives a run
+    # step_time_s of the newest steps after the compiling one, for the MFU
+    steady_times: collections.deque[float] = collections.deque(maxlen=128)
+    batch = None
+    # the step in flight: dispatched, its loss not yet waited for. The loop
+    # launches the next step before it reads this one's loss, so the device
+    # always has a program queued behind the running one and every host
+    # phase (next_batch, place, dispatch, the wake-up, the log) runs behind
+    # a program. Its ``phases`` are the spans of its row so far.
+    flight = None
+
+    def finish_step_in_flight():
+        """Wait for the step in flight, then write its row."""
+        nonlocal flight, profiler_ctx
+        f, flight = flight, None
+        timer.start()
+        with acct.measure("device_wait", f.bucket):
+            # the host's time on this step: its call and its wait, which no
+            # longer touch (the next step's call lies between them). From
+            # call to arrival would span two programs.
+            dt = f.dispatch_s + timer.stop(f.metrics["loss"])
+        if f.bucket == "step":
+            steady_times.append(dt)
+        for counter, per_step in lm_counters or ():
+            counter.inc(per_step)
+        if profiler_ctx is not None and f.step == profile_stop:
+            profiler_ctx.__exit__(None, None, None)
+            profiler_ctx = None
+            print(f"profile trace written to {args.profile_dir}")
+        with acct.measure("host_sync"):
+            # one transfer for the whole dict (a looped model's step
+            # returns nine scalars; one by one they cost 4-8 ms)
+            host_metrics = {k: float(v) for k, v
+                            in jax.device_get(f.metrics).items()}
+            if f.fp is not None:
+                host_metrics["batch_fingerprint"] = f.fp
+            # grouped by step, not by the clock: what the next step measured
+            # before this row (its next_batch .. dispatch) waits for its own
+            logger.log(f.step, step_time_s=dt, **host_metrics,
+                       file_only={"phases": f.phases + acct.drain()})
+
     try:
         with use_sharding(mesh, rules):
             for step in range(start_step, args.steps):
@@ -815,35 +858,31 @@ def train(args: argparse.Namespace) -> Any:
                 if mesh is None:
                     with acct.measure("place"):
                         batch = place(batch)
-                # hash before step_fn runs: donated buffers die with the step
+                # hash before step_fn runs (it donates the state, nothing
+                # of the batch)
                 fp = (_batch_fingerprint(batch)
                       if args.batch_fingerprint else None)
-                # the first step traces + compiles under the same call; it
-                # lands in the "compile" bucket, steady-state in "step"
-                # (timer.stop's device_get sync keeps device time in-bucket)
+                # the first step traces + compiles under the same call; its
+                # dispatch and its wait land in the "compile" bucket,
+                # steady-state in "step"
                 bucket = "compile" if step == start_step else "step"
                 timer.start()
                 with acct.measure("dispatch", bucket):
                     metrics = step_fn(model, optimizer, *batch)
-                with acct.measure("device_wait", bucket):
-                    dt = timer.stop(metrics["loss"])
-                for counter, per_step in lm_counters or ():
-                    counter.inc(per_step)
-                if profiler_ctx is not None and step == profile_stop:
-                    profiler_ctx.__exit__(None, None, None)
-                    profiler_ctx = None
-                    print(f"profile trace written to {args.profile_dir}")
-                with acct.measure("host_sync"):
-                    # one transfer for the whole dict (a looped model's step
-                    # returns nine scalars; one by one they cost 4-8 ms)
-                    host_metrics = {k: float(v) for k, v
-                                    in jax.device_get(metrics).items()}
-                    if fp is not None:
-                        host_metrics["batch_fingerprint"] = fp
-                    # everything measured since the last row: this step's
-                    # phases and the previous step's host_sync / checkpoint
-                    logger.log(step, step_time_s=dt, **host_metrics,
-                               file_only={"phases": acct.drain()})
+                dispatch_s = timer.stop()
+                own = acct.drain()
+                if flight is not None:
+                    if not flight.metrics["loss"].is_ready():
+                        # launched behind a running program: the device
+                        # goes from one to the next without the host
+                        ahead_total.inc()
+                    finish_step_in_flight()
+                # model / optimizer hold the state after `step` from here to
+                # the next dispatch. Its row begins with the host_sync of the
+                # row before, then its own phases.
+                flight = types.SimpleNamespace(
+                    step=step, metrics=metrics, fp=fp, bucket=bucket,
+                    dispatch_s=dispatch_s, phases=acct.drain() + own)
                 extra = None
                 if ckpt is not None and grain_stream is not None:
                     import base64
@@ -853,10 +892,13 @@ def train(args: argparse.Namespace) -> Any:
                 if ckpt is not None and (preempt is None
                                          or not preempt.draining):
                     # while the grace save drains, later per-step saves are
-                    # pointless — nothing after it survives the restart
+                    # pointless — nothing after it survives the restart. A
+                    # save blocks on this step's arrays: the steps that save
+                    # give up the overlap.
                     with acct.measure("checkpoint"):
                         saved_now = ckpt.save(step, model, optimizer,
                                               extra=extra)
+                    flight.phases += acct.drain()
                 if fault_plan is not None:
                     # drill events for this step (stall/corrupt/preempt/
                     # crash); a preempt's SIGTERM lands before the guard
@@ -866,19 +908,25 @@ def train(args: argparse.Namespace) -> Any:
                     preempt.after_step(step, model, optimizer, extra=extra,
                                        already_saved=saved_now)
     finally:
-        if guard is not None:
-            guard.uninstall()
-        if profiler_ctx is not None:
-            # crash mid-profile: still flush what was captured
-            profiler_ctx.__exit__(None, None, None)
-            print(f"profile trace written to {args.profile_dir}")
-        if prof_ring is not None:
-            # commit a half-open window so the newest capture survives a
-            # crash — the whole point of a flight-recorder ring
-            prof_ring.close()
-        # a mid-run crash must not strand buffered TensorBoard events (the
-        # EventFileWriter queue flushes on close, not per event)
-        logger.close()
+        try:
+            # every way out (the end, PreemptedError, a drill's crash, an
+            # exception) leaves one row per dispatched step
+            if flight is not None:
+                finish_step_in_flight()
+        finally:
+            if guard is not None:
+                guard.uninstall()
+            if profiler_ctx is not None:
+                # crash mid-profile: still flush what was captured
+                profiler_ctx.__exit__(None, None, None)
+                print(f"profile trace written to {args.profile_dir}")
+            if prof_ring is not None:
+                # commit a half-open window so the newest capture survives
+                # a crash — the whole point of a flight-recorder ring
+                prof_ring.close()
+            # a mid-run crash must not strand buffered TensorBoard events
+            # (the EventFileWriter queue flushes on close, not per event)
+            logger.close()
     if ckpt is not None:
         ckpt.wait()
         ckpt.close()
@@ -887,15 +935,24 @@ def train(args: argparse.Namespace) -> Any:
     from jimm_tpu.train.metrics import mfu as _mfu, train_step_flops
     # an MFU exists only against a chip's published peak: no TPU, no MFU
     achieved_mfu = None
-    if dt is not None and jax.default_backend() == "tpu":
+    if steady_times and jax.default_backend() == "tpu":
+        # the median, because the last step waits through the whole of its
+        # program: no call follows it
+        import statistics
         achieved_mfu = _mfu(
-            train_step_flops(cfg, args.batch_size), dt,
+            train_step_flops(cfg, args.batch_size),
+            statistics.median(steady_times),
             n_devices=mesh.devices.size if mesh is not None else 1)
     # precision + moment_dtype ride the goodput line so that its readers
     # (scripts/lowp_train_smoke.py) can put a difference down to the policy
     # that produced it
     print("goodput: " + _json.dumps({
         **acct.report(mfu=achieved_mfu),
+        # share of the run's steps launched while the step before was still
+        # running: (steps - 1) / steps where the host keeps up
+        "dispatched_ahead_frac": round(
+            (ahead_total.value - ahead_before)
+            / max(args.steps - start_step, 1), 4),
         "precision": precision,
         "moment_dtype": moment_dtype or "param",
     }))

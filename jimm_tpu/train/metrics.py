@@ -71,8 +71,13 @@ def mfu(flops_per_step: float | None, step_time_s: float,
 
 @dataclass
 class StepTimer:
-    """Wall-clock step timing with device sync (``block_until_ready``) on
-    the boundaries."""
+    """Wall-clock timing of one stretch, with a device sync
+    (``block_until_ready``) on each boundary it is given arrays for. A loop
+    that reads every loss before the next launch wraps the whole step in one
+    stretch. ``jimm-tpu train`` keeps a step in flight, so it times the
+    step's call (no sync) and, after the next step's call, the wait for its
+    loss, and logs their sum as ``step_time_s``: from call to arrival would
+    span two programs."""
 
     t0: float = 0.0
 

@@ -50,13 +50,16 @@ def test_train_resume(tmp_path):
 @pytest.mark.parametrize("mesh", [None, "data=2"])
 def test_train_rows_carry_the_loops_phases(tmp_path, capsys, mesh,
                                            eight_devices):
-    """Every ``--metrics-file`` row holds what the accounter measured since
-    the row before: its own step's phases and the previous step's host_sync
-    and checkpoint, on the Unix clock, adding up to the goodput buckets."""
+    """Every ``--metrics-file`` row holds its own step's phases after the
+    ``host_sync`` of the step before, on the Unix clock, adding up to the
+    goodput buckets. The loop keeps one step in flight, so the rows group by
+    step and not by the clock: step i+1 is dispatched before step i's loss
+    is waited for."""
     import time
+    steps = 5
     metrics = tmp_path / "metrics.jsonl"
     argv = ["train", "--preset", "vit-base-patch16-224", "--tiny",
-            "--steps", "5", "--batch-size", "8", "--log-every", "1",
+            "--steps", str(steps), "--batch-size", "8", "--log-every", "1",
             "--ckpt-dir", str(tmp_path / "ckpt"), "--save-every", "1",
             "--metrics-file", str(metrics)]
     if mesh:
@@ -69,29 +72,53 @@ def test_train_rows_carry_the_loops_phases(tmp_path, capsys, mesh,
     goodput = json.loads(next(line for line in out.splitlines()
                               if line.startswith("goodput: "))[9:])
     rows = [json.loads(line) for line in metrics.read_text().splitlines()]
-    assert len(rows) == 5 and all("phases" in r for r in rows)
+    assert [r["step"] for r in rows] == list(range(steps))
+    assert all("phases" in r for r in rows)
 
-    own = ["next_batch", "dispatch", "device_wait"] if mesh else [
-        "next_batch", "place", "dispatch", "device_wait"]
+    # what stands before the first next_batch / place / dispatch belongs to
+    # the step before (benchmarks/trace/host_join.py::host_spans); the
+    # step's checkpoint runs after its dispatch and so stands after it
+    own = ["next_batch", "dispatch", "checkpoint", "device_wait"] if mesh \
+        else ["next_batch", "place", "dispatch", "checkpoint", "device_wait"]
     assert [p[0] for p in rows[0]["phases"]] == own
     for r in rows[1:]:
-        assert [p[0] for p in r["phases"]] == ["host_sync", "checkpoint",
-                                               *own]
+        assert [p[0] for p in r["phases"]] == ["host_sync", *own]
+
+    def span(row, name):
+        (found,) = [p for p in row["phases"] if p[0] == name]
+        return found
+
     spans = [p for r in rows for p in r["phases"]]
-    assert t0 <= spans[0][1] and spans[-1][1] + spans[-1][2] <= t1
-    for (_, a0, adur), (_, b0, _) in zip(spans, spans[1:]):
-        assert a0 + adur <= b0, "ordered, and never overlapping"
+    assert t0 <= min(p[1] for p in spans)
+    assert max(p[1] + p[2] for p in spans) <= t1
+    # no two spans of the run overlap (one thread measured them all) ...
+    by_clock = sorted(spans, key=lambda p: p[1])
+    for (_, a0, adur), (_, b0, _) in zip(by_clock, by_clock[1:]):
+        assert a0 + adur <= b0, "never overlapping"
+    # ... and those of one step are in order in its row
+    for r in rows:
+        mine = [p for p in r["phases"] if p[0] != "host_sync"]
+        assert mine == sorted(mine, key=lambda p: p[1])
+    # one step in flight: the next step's dispatch begins before this
+    # step's loss is waited for, and ends before it too
+    for r, nxt in zip(rows, rows[1:]):
+        ahead, wait = span(nxt, "dispatch"), span(r, "device_wait")
+        assert ahead[1] + ahead[2] <= wait[1]
+        # the host_sync that opens a row is the one that wrote the row before
+        log = span(nxt, "host_sync")
+        assert wait[1] + wait[2] <= log[1] <= r["time"] * 1e9 <= (
+            log[1] + log[2])
 
     def total(row, *names):
         return sum(dur for name, _, dur in row["phases"]
                    if name in names) / 1e9
 
     for r in rows:
-        # StepTimer starts before the dispatch span and stops inside the
-        # device_wait span: the same stretch but for two clock reads
+        # the host's time on the step: its call and its wait, which no
+        # longer touch; the same stretches but for two clock reads each
         assert total(r, "dispatch", "device_wait") == pytest.approx(
             r["step_time_s"], abs=5e-3)
-        assert r["time"] * 1e9 >= r["phases"][-1][1] + r["phases"][-1][2]
+        assert r["time"] * 1e9 >= max(p[1] + p[2] for p in r["phases"])
     # the buckets are the sums of their phases (the line rounds to 0.1 ms)
     near = dict(abs=3e-4)
     assert goodput["compile_s"] == pytest.approx(
@@ -100,11 +127,11 @@ def test_train_rows_carry_the_loops_phases(tmp_path, capsys, mesh,
         sum(total(r, "dispatch", "device_wait") for r in rows[1:]), **near)
     assert goodput["data_wait_s"] == pytest.approx(
         sum(total(r, "next_batch", "place") for r in rows), **near)
-    # the last step's host_sync and checkpoint end after the last row
+    # every checkpoint is in a row; the last step's host_sync ends after it
+    assert goodput["checkpoint_s"] == pytest.approx(
+        sum(total(r, "checkpoint") for r in rows), **near)
     logged = sum(total(r, "host_sync") for r in rows)
     assert 0 < logged < goodput["host_sync_s"]
-    assert 0 < sum(total(r, "checkpoint") for r in rows) < goodput[
-        "checkpoint_s"]
 
 
 @pytest.mark.slow
