@@ -24,12 +24,15 @@ __version__ = "0.1.0"
 _LAZY = {
     "CLIP": "jimm_tpu.models",
     "Ouro": "jimm_tpu.models",
+    "Kanana": "jimm_tpu.models.kanana",
     "SigLIP": "jimm_tpu.models",
     "VisionTransformer": "jimm_tpu.models",
     "CLIPConfig": "jimm_tpu.configs",
     "SigLIPConfig": "jimm_tpu.configs",
     "ViTConfig": "jimm_tpu.configs",
     "OuroConfig": "jimm_tpu.configs",
+    "KananaConfig": "jimm_tpu.configs",
+    "MoEDecoderConfig": "jimm_tpu.configs",
     "DecoderConfig": "jimm_tpu.configs",
     "VisionConfig": "jimm_tpu.configs",
     "TextConfig": "jimm_tpu.configs",
@@ -41,8 +44,8 @@ _LAZY = {
 }
 
 __all__ = [
-    "CLIP", "SigLIP", "VisionTransformer", "Ouro",
-    "OuroConfig", "DecoderConfig",
+    "CLIP", "SigLIP", "VisionTransformer", "Ouro", "Kanana",
+    "OuroConfig", "DecoderConfig", "KananaConfig", "MoEDecoderConfig",
     "CLIPConfig", "SigLIPConfig", "ViTConfig", "VisionConfig", "TextConfig",
     "TransformerConfig", "PRESETS", "preset",
     "RUNTIME_FIELDS", "with_runtime",

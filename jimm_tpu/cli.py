@@ -100,17 +100,52 @@ def _parse_mesh(spec: str | None, max_devices: int | None = None):
     return make_mesh(axes, devices=devices)
 
 
+#: model family (the prefix of its presets' names) -> its class in `jimm_tpu`
+_FAMILIES = {"vit": "VisionTransformer", "clip": "CLIP", "siglip": "SigLIP",
+             "ouro": "Ouro", "kanana": "Kanana"}
+
+
+def _lm_counters(cfg: Any, batch_size: int) -> list[tuple[str, str, Any]]:
+    """A language model's per-step registry counters, ``(registry, counter,
+    amount)``: ``amount`` is a number, or the name of the step's metric to
+    add."""
+    d = cfg.decoder
+    counters = [("jimm_lm", "tokens_total", batch_size * d.seq_len)]
+    if hasattr(d, "loops"):
+        # block applications (passes x layers), the unit a looped model's
+        # cost is counted in
+        counters.append(("jimm_loop", "block_applications_total",
+                         d.loops * d.depth))
+    if hasattr(d, "moe"):
+        counters += [
+            ("jimm_moe", "assignments_total", batch_size * d.seq_len
+             * d.moe.top_k * (d.depth - d.dense_layers)),
+            ("jimm_moe", "held_assignments_total", "moe_held_rows")]
+    return counters
+
+
+#: the language-model families: trained from --seed on the program's own
+#: token generator by `make_lm_train_step(<family>)`, each with its optimizer
+#: defaults of `train` (--lr, --warmup-steps) where the loop's own do not do.
+#: The looped decoder's exit gates saturate within 20 Adam steps at 1e-3 with
+#: no warm-up, and three of its four passes then train on no gradient
+#: (docs/models/Ouro.md); the sparse decoder starts as gently (no reading at
+#: 1e-3: 20 Adam steps of that size move a 2048-wide decoder's every weight
+#: by about its own spread, routers included)
+LM_FAMILIES = {"ouro": {"lr": 1e-4, "warmup_steps": 20},
+               "kanana": {"lr": 1e-4, "warmup_steps": 20}}
+
+
 def _family(preset_name: str) -> str:
-    for fam in ("vit", "clip", "siglip", "ouro"):
+    for fam in _FAMILIES:
         if preset_name.startswith(fam):
             return fam
     raise SystemExit(f"cannot infer model family from preset {preset_name!r}")
 
 
 def _model_cls(fam: str):
-    from jimm_tpu import CLIP, Ouro, SigLIP, VisionTransformer
-    return {"vit": VisionTransformer, "clip": CLIP, "siglip": SigLIP,
-            "ouro": Ouro}[fam]
+    import jimm_tpu
+    return getattr(jimm_tpu, _FAMILIES[fam])
 
 
 def _replace_towers(cfg: Any, **fields: Any) -> Any:
@@ -132,12 +167,9 @@ def _main_tower(cfg: Any) -> Any:
     return cfg.decoder if hasattr(cfg, "decoder") else cfg.vision
 
 
-# optimizer defaults of `train` (--lr, --warmup-steps), and the families that
-# carry their own: the looped decoder's exit gates saturate within 20 Adam
-# steps at 1e-3 with no warm-up, and three of its four passes then train on no
-# gradient (docs/models/Ouro.md)
+# optimizer defaults of `train` (--lr, --warmup-steps); `LM_FAMILIES` carry
+# their own
 _OPTIMIZER_DEFAULTS = {"lr": 1e-3, "warmup_steps": 0}
-_FAMILY_OPTIMIZER_DEFAULTS = {"ouro": {"lr": 1e-4, "warmup_steps": 20}}
 
 
 def _norm_for(fam: str) -> dict:
@@ -272,8 +304,8 @@ def _restore_run(args: argparse.Namespace):
 
 def _tiny_override(cfg: Any) -> Any:
     """Shrink any preset to CPU-demo size, keeping its architecture class."""
-    from jimm_tpu.configs import (CLIPConfig, OuroConfig, SigLIPConfig,
-                                  ViTConfig)
+    from jimm_tpu.configs import (CLIPConfig, KananaConfig, MLAConfig,
+                                  OuroConfig, SigLIPConfig, ViTConfig)
 
     # depth 4 (not 2) so tiny runs can still exercise pipeline stages x
     # virtual-chunk splits (depth % (stages * virtual) == 0 for 2x2)
@@ -296,6 +328,15 @@ def _tiny_override(cfg: Any) -> Any:
         return dataclasses.replace(cfg, decoder=dataclasses.replace(
             cfg.decoder, vocab_size=512, seq_len=32, width=64, depth=2,
             num_heads=4, mlp_dim=176))
+    if isinstance(cfg, KananaConfig):
+        # one dense layer and two sparse ones; 4 of 16 experts held, top-2
+        return dataclasses.replace(cfg, decoder=dataclasses.replace(
+            cfg.decoder, vocab_size=512, seq_len=32, width=64, depth=3,
+            num_heads=4, mlp_dim=176,
+            mla=MLAConfig(kv_lora_rank=32, qk_nope_dim=16, qk_rope_dim=8,
+                          v_head_dim=16),
+            moe=dataclasses.replace(cfg.decoder.moe, num_experts=16, top_k=2,
+                                    expert_dim=48, held_experts=4)))
     raise TypeError(type(cfg))
 
 
@@ -329,9 +370,11 @@ def cmd_presets(args: argparse.Namespace) -> int:
     for name, cfg in PRESETS.items():
         if hasattr(cfg, "decoder"):
             d = cfg.decoder
+            kind = (f"x {d.loops} passes" if hasattr(d, "loops") else
+                    f"experts={d.moe.held_experts}/{d.moe.num_experts} held")
             print(f"{name:32s} {params_m(name, cfg)} "
-                  f"decoder(width={d.width} depth={d.depth} x {d.loops} "
-                  f"passes vocab={d.vocab_size} seq={d.seq_len})")
+                  f"decoder(width={d.width} depth={d.depth} {kind} "
+                  f"vocab={d.vocab_size} seq={d.seq_len})")
             continue
         v = cfg.vision
         extra = ""
@@ -446,7 +489,7 @@ def train(args: argparse.Namespace) -> Any:
 
     fam = _family(args.preset)
     for name, value in {**_OPTIMIZER_DEFAULTS,
-                        **_FAMILY_OPTIMIZER_DEFAULTS.get(fam, {})}.items():
+                        **LM_FAMILIES.get(fam, {})}.items():
         if getattr(args, name) is None:
             setattr(args, name, value)
             if name == "warmup_steps":  # a default fits the run, quietly
@@ -463,7 +506,7 @@ def train(args: argparse.Namespace) -> Any:
             raise SystemExit("--tiny conflicts with --from-pretrained "
                              "(the checkpoint defines the architecture)")
         cfg = _tiny_override(cfg)
-    if fam == "ouro":
+    if fam in LM_FAMILIES:
         if args.from_pretrained or args.data:
             raise SystemExit("the language-model family trains from --seed "
                              "on the program's own token generator: no "
@@ -473,7 +516,7 @@ def train(args: argparse.Namespace) -> Any:
         cfg = _replace_towers(cfg, **lm)
     elif args.num_layers or args.seq_len:
         raise SystemExit("--num-layers and --seq-len shape a language model "
-                         "(an ouro preset)")
+                         "(an ouro preset, a kanana preset)")
 
     mesh = _parse_mesh(args.mesh, max_devices=args.max_devices)
     import jax
@@ -646,19 +689,15 @@ def train(args: argparse.Namespace) -> Any:
         grain_stream = CheckpointableGrainStream(grain_iter)
         return grain_stream.batches()
 
-    lm_counters = None
-    if fam == "ouro":
-        step_fn = make_lm_train_step(donate=True)
+    lm_counters = ()
+    if fam in LM_FAMILIES:
+        step_fn = make_lm_train_step(fam, donate=True)
         d = cfg.decoder
         data = token_sequences(args.batch_size, seq_len=d.seq_len,
                                vocab_size=d.vocab_size, seed=args.seed)
-        # per step: tokens trained on, and block applications (passes x
-        # layers), the unit a looped model's cost is counted in
-        lm_counters = (
-            (obs.get_registry("jimm_lm").counter("tokens_total"),
-             args.batch_size * d.seq_len),
-            (obs.get_registry("jimm_loop").counter(
-                "block_applications_total"), d.loops * d.depth))
+        lm_counters = [
+            (obs.get_registry(registry).counter(name), amount)
+            for registry, name, amount in _lm_counters(cfg, args.batch_size)]
     elif fam == "vit":
         step_fn = make_classifier_train_step(donate=True)
         if args.data and args.loader == "grain":
@@ -822,8 +861,6 @@ def train(args: argparse.Namespace) -> Any:
             dt = f.dispatch_s + timer.stop(f.metrics["loss"])
         if f.bucket == "step":
             steady_times.append(dt)
-        for counter, per_step in lm_counters or ():
-            counter.inc(per_step)
         if profiler_ctx is not None and f.step == profile_stop:
             profiler_ctx.__exit__(None, None, None)
             profiler_ctx = None
@@ -833,6 +870,9 @@ def train(args: argparse.Namespace) -> Any:
             # returns nine scalars; one by one they cost 4-8 ms)
             host_metrics = {k: float(v) for k, v
                             in jax.device_get(f.metrics).items()}
+            for counter, amount in lm_counters:
+                counter.inc(host_metrics[amount] if isinstance(amount, str)
+                            else amount)
             if f.fp is not None:
                 host_metrics["batch_fingerprint"] = f.fp
             # grouped by step, not by the clock: what the next step measured
@@ -2090,12 +2130,12 @@ def build_parser() -> argparse.ArgumentParser:
     sp.add_argument("--seq-len", type=int, default=None,
                     help="language model: tokens of a training sequence")
     sp.add_argument("--lr", type=float, default=None,
-                    help="peak learning rate (default 1e-3; an ouro preset "
-                         "1e-4)")
+                    help="peak learning rate (default 1e-3; a language-model "
+                         "preset 1e-4)")
     sp.add_argument("--weight-decay", type=float, default=1e-4)
     sp.add_argument("--warmup-steps", type=int, default=None,
-                    help="linear warm-up steps (default 0; an ouro preset "
-                         "20)")
+                    help="linear warm-up steps (default 0; a language-model "
+                         "preset 20)")
     sp.add_argument("--seed", type=int, default=0)
     sp.add_argument("--bf16", action="store_true")
     sp.add_argument("--compilation-cache-dir", default=None,

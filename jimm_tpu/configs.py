@@ -146,6 +146,34 @@ def with_runtime(cfg, **fields):
 
 
 @dataclass(frozen=True)
+class MLAConfig:
+    """Latent attention: q heads of ``qk_nope_dim + qk_rope_dim``, keys and
+    values rebuilt from a ``kv_lora_rank``-wide latent and ONE rotary key of
+    ``qk_rope_dim`` shared by all heads, values ``v_head_dim`` wide."""
+
+    kv_lora_rank: int = 512
+    qk_nope_dim: int = 128
+    qk_rope_dim: int = 64
+    v_head_dim: int = 128
+
+
+@dataclass(frozen=True)
+class MoEConfig:
+    """A sparse expert layer as ONE chip of an expert-parallel group sees
+    it: the router is whole (``num_experts`` wide, ``top_k`` chosen), the
+    chip holds experts ``first_expert .. first_expert + held_experts`` and
+    computes their part of the result."""
+
+    num_experts: int = 128
+    top_k: int = 6
+    expert_dim: int = 768
+    shared_experts: int = 2
+    routed_scale: float = 2.448
+    held_experts: int = 128
+    first_expert: int = 0
+
+
+@dataclass(frozen=True)
 class TransformerConfig:
     """Shared encoder-stack hyperparameters (vision or text tower)."""
 
@@ -218,10 +246,21 @@ class TransformerConfig:
     #: stacked on a leading axis. 0 = a plain stack: one pass, no norm, the
     #: carry returned.
     loops: int = 0
+    #: latent attention (`nn/mla.py`) in place of `Attention`; None = the
+    #: heads above
+    mla: MLAConfig | None = None
+    #: a sparse expert layer (`nn/moe.py`) in place of `Mlp`; the block then
+    #: returns its routing choices beside the carry
+    moe: MoEConfig | None = None
 
     @property
     def head_dim(self) -> int:
         return self.width // self.num_heads
+
+    @property
+    def rope_dim(self) -> int:
+        """How many dims of a head the rotary tables turn."""
+        return self.mla.qk_rope_dim if self.mla is not None else self.head_dim
 
 
 @dataclass(frozen=True)
@@ -378,6 +417,49 @@ class DecoderConfig:
 
 
 @dataclass(frozen=True)
+class MoEDecoderConfig:
+    """Causal decoder stack with latent attention in every layer, a dense
+    SwiGLU in the first ``dense_layers`` and a sparse expert layer (`MoEConfig`)
+    in the rest: pre-norm RMS blocks, rotary on the rotary dims alone, no
+    biases. ``depth`` counts both kinds."""
+
+    vocab_size: int = 16032
+    seq_len: int = 8192
+    width: int = 2048
+    depth: int = 48
+    dense_layers: int = 1
+    num_heads: int = 32
+    mlp_dim: int = 6144
+    act: Activation = "silu"
+    ln_eps: float = 1e-6
+    rope_theta: float = 1e6
+    mla: MLAConfig = field(default_factory=MLAConfig)
+    moe: MoEConfig = field(default_factory=lambda: MoEConfig(held_experts=16))
+    # runtime fields, as `DecoderConfig` has them
+    dropout: float = 0.0
+    attn_impl: AttnImpl = "auto"
+    remat: bool = False
+    remat_policy: RematPolicy = "none"
+    scan_unroll: int = 1
+    precision: Precision = "bf16"
+
+    def encoder(self, *, sparse: bool) -> TransformerConfig:
+        """The dense stack's block, or the sparse stack's."""
+        return TransformerConfig(
+            width=self.width,
+            depth=(self.depth - self.dense_layers if sparse
+                   else self.dense_layers),
+            num_heads=self.num_heads, mlp_dim=self.mlp_dim, act=self.act,
+            ln_eps=self.ln_eps, dropout=self.dropout, causal=True,
+            attn_impl=self.attn_impl, remat=self.remat,
+            remat_policy=self.remat_policy, scan_unroll=self.scan_unroll,
+            precision=self.precision, norm="rms", rope_theta=self.rope_theta,
+            gated_mlp=True, use_bias=False, mla=self.mla,
+            moe=self.moe if sparse else None,
+        )
+
+
+@dataclass(frozen=True)
 class ViTConfig:
     """ViT image classifier (ref `models/vit.py:16-103`): post-norm backbone,
     CLS pooling, LN eps 1e-12 (ref `models/vit.py:73`), optional linear head."""
@@ -432,6 +514,19 @@ class OuroConfig:
 
     decoder: DecoderConfig = field(default_factory=DecoderConfig)
     exit_beta: float = 0.1
+
+
+@dataclass(frozen=True)
+class KananaConfig:
+    """kanana-2-30b-a3b (kakaocorp, ``model_type`` deepseek_v3) as one chip of
+    an eight-way expert-parallel group holds it: token embedding, the
+    `MoEDecoderConfig` stack, a final RMSNorm and the untied head, trained on
+    the mean next-token cross-entropy. After each step the routers'
+    selection biases move by ``bias_update_rate`` toward balance (the
+    auxiliary-loss-free balancing of the DeepSeek-V3 paper)."""
+
+    decoder: MoEDecoderConfig = field(default_factory=MoEDecoderConfig)
+    bias_update_rate: float = 1e-3
 
 
 def _vit(size: str, patch: int, image: int, classes: int = 1000) -> ViTConfig:
@@ -534,6 +629,10 @@ PRESETS: dict[str, Any] = {
     "siglip2-so400m-patch16-256": _siglip("So400m", 16, 256, vocab=256000),
     # Ouro looped LM: the published 48 layers run 4 times (ByteDance/Ouro-2.6B)
     "ouro-2.6b": OuroConfig(),
+    # kanana-2-30b-a3b-instruct-2601: latent attention, 128 experts top-6 and
+    # two shared; the preset is ONE chip's share of an eight-way
+    # expert-parallel layer (experts 0-15, an eighth of the vocabulary)
+    "kanana-2-30b-a3b": KananaConfig(),
 }
 
 

@@ -195,14 +195,26 @@ class Block(nnx.Module):
                  dtype: Dtype = None, param_dtype=jnp.float32):
         norm = partial(_norm, cfg, rngs, dtype=dtype, param_dtype=param_dtype)
         self.ln1 = norm()
-        self.attn = Attention(cfg.width, cfg.num_heads, rngs,
-                              is_causal=cfg.causal, impl=cfg.attn_impl,
-                              fused_qkv=cfg.fused_qkv, use_bias=cfg.use_bias,
-                              dtype=dtype, param_dtype=param_dtype)
+        if cfg.mla is not None:
+            from jimm_tpu.nn.mla import LatentAttention
+            self.attn = LatentAttention(cfg, rngs, dtype=dtype,
+                                        param_dtype=param_dtype)
+        else:
+            self.attn = Attention(cfg.width, cfg.num_heads, rngs,
+                                  is_causal=cfg.causal, impl=cfg.attn_impl,
+                                  fused_qkv=cfg.fused_qkv,
+                                  use_bias=cfg.use_bias,
+                                  dtype=dtype, param_dtype=param_dtype)
         self.ln2 = norm()
-        self.mlp = Mlp(cfg.width, cfg.mlp_dim, cfg.act, rngs,
-                       gated=cfg.gated_mlp, use_bias=cfg.use_bias,
-                       dtype=dtype, param_dtype=param_dtype)
+        self.sparse = cfg.moe is not None
+        if self.sparse:
+            from jimm_tpu.nn.moe import SparseMoe
+            self.mlp = SparseMoe(cfg, rngs, dtype=dtype,
+                                 param_dtype=param_dtype)
+        else:
+            self.mlp = Mlp(cfg.width, cfg.mlp_dim, cfg.act, rngs,
+                           gated=cfg.gated_mlp, use_bias=cfg.use_bias,
+                           dtype=dtype, param_dtype=param_dtype)
         self.dropout = nnx.Dropout(cfg.dropout, rngs=rngs)
         self.post_norm = cfg.post_norm
         if cfg.post_norm:
@@ -211,7 +223,9 @@ class Block(nnx.Module):
 
     def __call__(self, x: jax.Array, mask: jax.Array | None = None,
                  rope: tuple[jax.Array, jax.Array] | None = None
-                 ) -> jax.Array:
+                 ) -> jax.Array | tuple[jax.Array, jax.Array]:
+        """The block's output; a sparse block returns ``(output, the experts
+        each token chose)``."""
         # ln outputs carry a checkpoint name so "+ln" remat policies can keep
         # them (skipping the LN recompute in the backward); plain identity
         # under every other policy
@@ -219,13 +233,18 @@ class Block(nnx.Module):
                       rope=rope)
         x = x + self.dropout(self.ln1_post(a) if self.post_norm else a)
         m = self.mlp(checkpoint_name(self.ln2(x), "ln_out"))
+        if self.sparse:
+            m, chosen = m
         x = x + self.dropout(self.ln2_post(m) if self.post_norm else m)
-        return logical_constraint(x, "batch", "seq", None)
+        x = logical_constraint(x, "batch", "seq", None)
+        return (x, chosen) if self.sparse else x
 
 
 class Transformer(nnx.Module):
     """Depth-stacked encoder, scanned over the ``layers`` axis. With
-    ``cfg.loops`` the stack is entered that many times (`_apply_loops`)."""
+    ``cfg.loops`` the stack is entered that many times (`_apply_loops`); with
+    ``cfg.moe`` the call returns ``(output, the experts each token chose
+    (depth, tokens, top_k))``."""
 
     def __init__(self, cfg: TransformerConfig, rngs: nnx.Rngs, *,
                  dtype: Dtype = None, param_dtype=jnp.float32):
@@ -308,7 +327,9 @@ class Transformer(nnx.Module):
 
         if self.cfg.remat:
             body = nnx.remat(body, policy=self._remat_policy())
-        scan = nnx.scan(body, in_axes=(0, nnx.Carry), out_axes=nnx.Carry,
+        # a sparse block's routing choices come out stacked by layer
+        out_axes = (nnx.Carry, 0) if self.cfg.moe is not None else nnx.Carry
+        scan = nnx.scan(body, in_axes=(0, nnx.Carry), out_axes=out_axes,
                         unroll=self.cfg.scan_unroll,
                         transform_metadata={nnx.PARTITION_NAME: "layers"})
         return scan(blocks, x)
@@ -334,7 +355,7 @@ class Transformer(nnx.Module):
                  mask: jax.Array | None = None) -> jax.Array:
         rope = None
         if self.cfg.rope_theta is not None:
-            rope = rope_tables(x.shape[1], self.cfg.head_dim,
+            rope = rope_tables(x.shape[1], self.cfg.rope_dim,
                                self.cfg.rope_theta)
         if self.cfg.loops:
             return self._apply_loops(x, mask, rope)

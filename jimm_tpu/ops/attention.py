@@ -213,6 +213,13 @@ def dot_product_attention(
         return seq_parallel_attention(q, k, v, mask=mask, axis_name=axis,
                                       is_causal=is_causal, plan=impl)
     if impl == "xla":
+        d_v = v.shape[-1]
+        if d_v != q.shape[-1]:
+            # XLA's op wants one head width: zero columns of v give zero
+            # columns of the output, cut off again
+            v = jnp.pad(v, ((0, 0),) * 3 + ((0, q.shape[-1] - d_v),))
+            return jax.nn.dot_product_attention(
+                q, k, v, bias=bias, mask=mask, is_causal=is_causal)[..., :d_v]
         return jax.nn.dot_product_attention(q, k, v, bias=bias, mask=mask,
                                             is_causal=is_causal)
     if impl == "saveable":

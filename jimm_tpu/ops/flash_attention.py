@@ -934,10 +934,10 @@ def _fwd_pallas(q3, k3, v3, maskadd, bias, causal, spec, sm_scale,
     (``n == 1``: `_prologue` flattened the heads) cut back to S_q rows."""
     softmax = spec.kind == "softmax"
     bn, sq, d = q3.shape
-    sk = k3.shape[1]
+    sk, dv = k3.shape[1], v3.shape[2]
     hb, sq_p, sk_p = _single_tile_plan(n, sq, sk, d // n, q3.dtype.itemsize,
                                        spec, block_q, block_k)
-    if hb:
+    if hb and dv == d:  # the single-tile kernels know one head width
         return _fwd_single(q3, k3, v3, maskadd, causal, spec, sm_scale,
                            logit_bias, n, hb, sq_p, sk_p)
     qp, kp, vp = (_pad_seq(q3, sq_p), _pad_seq(k3, sk_p), _pad_seq(v3, sk_p))
@@ -953,7 +953,7 @@ def _fwd_pallas(q3, k3, v3, maskadd, bias, causal, spec, sm_scale,
     in_specs = [
         pl.BlockSpec((hb, block_q, d), lambda h, i, j: (h, i, 0)),
         pl.BlockSpec((hb, block_k, d), kv_idx),
-        pl.BlockSpec((hb, block_k, d), kv_idx),
+        pl.BlockSpec((hb, block_k, dv), kv_idx),
     ]
     if spec.has_mask:
         inputs.append(_pad_mask(maskadd, sk_p))
@@ -965,9 +965,9 @@ def _fwd_pallas(q3, k3, v3, maskadd, bias, causal, spec, sm_scale,
         in_specs.append(pl.BlockSpec(
             (hb, block_q, block_k),
             _bias_fwd_index(block_q, block_k, n_k, n_heads // hb, causal)))
-    out_specs = [pl.BlockSpec((hb, block_q, d), lambda h, i, j: (h, i, 0))]
-    out_shape = [jax.ShapeDtypeStruct((bn, sq_p, d), q3.dtype)]
-    scratch = [pltpu.VMEM((hb, block_q, d), jnp.float32)]
+    out_specs = [pl.BlockSpec((hb, block_q, dv), lambda h, i, j: (h, i, 0))]
+    out_shape = [jax.ShapeDtypeStruct((bn, sq_p, dv), q3.dtype)]
+    scratch = [pltpu.VMEM((hb, block_q, dv), jnp.float32)]
     if softmax:
         out_specs.append(pl.BlockSpec((hb, 1, block_q),
                                       lambda h, i, j: (h, 0, i)))
@@ -1021,10 +1021,10 @@ def _flash_bwd(causal, spec, sm_scale, logit_bias, block_q, block_k, n, res,
     softmax = spec.kind == "softmax"
     q3, k3, v3, maskadd, bias, o, lse = res
     bn, sq, d = q3.shape
-    sk = k3.shape[1]
+    sk, d_v = k3.shape[1], v3.shape[2]
     hb, sq_p, sk_p = _single_tile_plan(n, sq, sk, d // n, q3.dtype.itemsize,
                                        spec, block_q, block_k)
-    if hb:
+    if hb and d_v == d:
         dq, dk, dv = _bwd_single(q3, k3, v3, maskadd, do, o, lse, dlse,
                                  causal, spec, sm_scale, logit_bias, n, hb,
                                  sq_p, sk_p)
@@ -1060,7 +1060,7 @@ def _flash_bwd(causal, spec, sm_scale, logit_bias, block_q, block_k, n, res,
     stat_spec = pl.BlockSpec((hb, 1, block_q), lambda h, i, j: (h, 0, i))
     dq_inputs = [qp, kp, vp]
     dq_specs = [q_spec, pl.BlockSpec((hb, block_k, d), kv_idx),
-                pl.BlockSpec((hb, block_k, d), kv_idx)]
+                pl.BlockSpec((hb, block_k, d_v), kv_idx)]
     if spec.has_mask:
         dq_inputs.append(mp)
         dq_specs.append(pl.BlockSpec(
@@ -1071,7 +1071,8 @@ def _flash_bwd(causal, spec, sm_scale, logit_bias, block_q, block_k, n, res,
             (hb, block_q, block_k),
             _bias_fwd_index(block_q, block_k, n_k, n_hb, causal)))
     dq_inputs.append(dop)
-    dq_specs.append(q_spec)
+    dq_specs.append(pl.BlockSpec((hb, block_q, d_v),
+                                 lambda h, i, j: (h, i, 0)))
     if softmax:
         dq_inputs += stats
         dq_specs += [stat_spec, stat_spec]
@@ -1093,9 +1094,10 @@ def _flash_bwd(causal, spec, sm_scale, logit_bias, block_q, block_k, n, res,
              else (lambda h, j, i: (h, i, 0)))
     stat_idx = (_causal_q_index(block_q, block_k, lse_layout=True) if causal
                 else (lambda h, j, i: (h, 0, i)))
-    kv_spec = pl.BlockSpec((hb, block_k, d), lambda h, j, i: (h, j, 0))
+    k_spec = pl.BlockSpec((hb, block_k, d), lambda h, j, i: (h, j, 0))
+    v_spec = pl.BlockSpec((hb, block_k, d_v), lambda h, j, i: (h, j, 0))
     dkv_inputs = [qp, kp, vp]
-    dkv_specs = [pl.BlockSpec((hb, block_q, d), q_idx), kv_spec, kv_spec]
+    dkv_specs = [pl.BlockSpec((hb, block_q, d), q_idx), k_spec, v_spec]
     if spec.has_mask:
         dkv_inputs.append(mp)
         dkv_specs.append(pl.BlockSpec((hb, 1, block_k),
@@ -1106,7 +1108,7 @@ def _flash_bwd(causal, spec, sm_scale, logit_bias, block_q, block_k, n, res,
             (hb, block_q, block_k),
             _bias_dkv_index(block_q, block_k, n_hb, causal)))
     dkv_inputs.append(dop)
-    dkv_specs.append(pl.BlockSpec((hb, block_q, d), q_idx))
+    dkv_specs.append(pl.BlockSpec((hb, block_q, d_v), q_idx))
     if softmax:
         dkv_inputs += stats
         dkv_specs += [pl.BlockSpec((hb, 1, block_q), stat_idx),
@@ -1117,17 +1119,14 @@ def _flash_bwd(causal, spec, sm_scale, logit_bias, block_q, block_k, n, res,
                 sm_scale=sm_scale, logit_bias=logit_bias, n_q=n_q, spec=spec),
         grid=(bn // hb, n_k, n_q),
         in_specs=dkv_specs,
-        out_specs=[
-            pl.BlockSpec((hb, block_k, d), lambda h, j, i: (h, j, 0)),
-            pl.BlockSpec((hb, block_k, d), lambda h, j, i: (h, j, 0)),
-        ],
+        out_specs=[k_spec, v_spec],
         out_shape=[
             jax.ShapeDtypeStruct((bn, sk_p, d), q3.dtype),
-            jax.ShapeDtypeStruct((bn, sk_p, d), q3.dtype),
+            jax.ShapeDtypeStruct((bn, sk_p, d_v), q3.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((hb, block_k, d), jnp.float32),
-            pltpu.VMEM((hb, block_k, d), jnp.float32),
+            pltpu.VMEM((hb, block_k, d_v), jnp.float32),
         ],
         compiler_params=_SEMANTICS,
         interpret=_interpret(),
@@ -1143,7 +1142,7 @@ def _flash_bwd(causal, spec, sm_scale, logit_bias, block_q, block_k, n, res,
         db_inputs = [qp, kp, vp]
         db_specs = [pl.BlockSpec((hb, block_q, d), q_idx4),
                     pl.BlockSpec((hb, block_k, d), kv_idx4),
-                    pl.BlockSpec((hb, block_k, d), kv_idx4)]
+                    pl.BlockSpec((hb, block_k, d_v), kv_idx4)]
         if spec.has_mask:
             db_inputs.append(mp)
             db_specs.append(pl.BlockSpec(
@@ -1152,7 +1151,7 @@ def _flash_bwd(causal, spec, sm_scale, logit_bias, block_q, block_k, n, res,
         db_specs.append(pl.BlockSpec((hb, block_q, block_k),
                                      lambda h, i, j, b: (h, i, j)))
         db_inputs.append(dop)
-        db_specs.append(pl.BlockSpec((hb, block_q, d), q_idx4))
+        db_specs.append(pl.BlockSpec((hb, block_q, d_v), q_idx4))
         if softmax:
             db_inputs += stats
             db_specs += [pl.BlockSpec((hb, 1, block_q), stat_idx4),
@@ -1263,16 +1262,20 @@ def _prologue(q, k, v, block_q, block_k, kernel: str = "flash_attention",
                                    block_q, block_k, requested)
     if _single_tile_plan(n, sq, k.shape[1], dp, q.dtype.itemsize, spec,
                          block_q, block_k)[0]:
+        # one head width here: a narrower v is padded out to q's
         q3, k3, v3 = (_pad_last(x, dp).reshape(*x.shape[:2], n * dp)
                       for x in (q, k, v))
         return q3, k3, v3, sm_scale, block_q, block_k, n
-    q3, k3, v3 = (_pad_last(_flatten_heads(x), dp) for x in (q, k, v))
+    # the tiled kernels take v (and o, do, dv) at its own tile: latent
+    # attention's values are 128 wide beside q and k of 192
+    q3, k3, v3 = (_pad_last(_flatten_heads(x), _head_pad_target(x.shape[-1]))
+                  for x in (q, k, v))
     return q3, k3, v3, sm_scale, block_q, block_k, 1
 
 
 def _epilogue(o: jax.Array, b: int, n: int, d: int, n_row: int) -> jax.Array:
     """`_prologue`'s layout (``n_row`` heads in a row) back to
-    ``(B, S, N, D)``."""
+    ``(B, S, N, D)``, ``d`` the width of v."""
     if n_row == n:
         return o.reshape(b, o.shape[1], n, -1)[..., :d]
     return _unflatten_heads(o, b, n)[..., :d]
@@ -1321,7 +1324,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     is_causal: bool = False,
                     block_q: int | None = None,
                     block_k: int | None = None) -> jax.Array:
-    """Flash attention over ``(B, S, N, D)`` q/k/v. Scale is 1/sqrt(D) like
+    """Flash attention over ``(B, S, N, D)`` q/k/v; v's heads may have a
+    width of their own (the output then has it). Scale is 1/sqrt(D) of q like
     `jax.nn.dot_product_attention`. Runs the Pallas interpreter off-TPU so
     CPU tests exercise the same code path. Block sizes default to the tune
     cache's answer for these shapes (falling back to ``DEFAULT_BLOCK_*``)."""
@@ -1330,7 +1334,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         q, k, v, block_q, block_k)
     o = _flash(q3, k3, v3, None, None, is_causal, _SOFTMAX, sm_scale, 0.0,
                block_q, block_k, n_row)
-    return _epilogue(o, b, n, d, n_row)
+    return _epilogue(o, b, n, v.shape[-1], n_row)
 
 
 def flash_attention_masked(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -1350,7 +1354,7 @@ def flash_attention_masked(q: jax.Array, k: jax.Array, v: jax.Array,
     maskadd = _expand_mask(_canon_mask(mask, b, k.shape[1]), n // n_row)
     o = _flash(q3, k3, v3, maskadd, None, is_causal, spec, sm_scale, 0.0,
                block_q, block_k, n_row)
-    return _epilogue(o, b, n, d, n_row)
+    return _epilogue(o, b, n, v.shape[-1], n_row)
 
 
 def flash_attention_bias(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -1371,7 +1375,7 @@ def flash_attention_bias(q: jax.Array, k: jax.Array, v: jax.Array,
         q, k, v, block_q, block_k, kernel="flash_attention_bias", spec=spec)
     o = _flash(q3, k3, v3, None, bias3, is_causal, spec, sm_scale, 0.0,
                block_q, block_k, n_row)
-    return _epilogue(o, b, n, d, n_row)
+    return _epilogue(o, b, n, v.shape[-1], n_row)
 
 
 def sigmoid_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -1398,7 +1402,7 @@ def sigmoid_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                if mask is not None else None)
     o = _flash(q3, k3, v3, maskadd, None, is_causal, spec, sm_scale,
                float(logit_bias), block_q, block_k, n_row)
-    return _epilogue(o, b, n, d, n_row)
+    return _epilogue(o, b, n, v.shape[-1], n_row)
 
 
 # ---------------------------------------------------------------------------
@@ -1445,7 +1449,7 @@ def flash_attention_lse(q: jax.Array, k: jax.Array, v: jax.Array, *,
         q, k, v, block_q, block_k)
     o3, lse3 = _flash_lse(q3, k3, v3, is_causal, sm_scale, block_q, block_k,
                           n_row)
-    return (_epilogue(o3, b, n, d, n_row),
+    return (_epilogue(o3, b, n, v.shape[-1], n_row),
             _lse_rows(lse3, sq).reshape(b, n, sq))
 
 

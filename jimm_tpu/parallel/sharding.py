@@ -60,6 +60,9 @@ class ShardingRules:
     batch: MeshAxis = None
     seq: MeshAxis = None
     pos: MeshAxis = None
+    #: the leading axis of a sparse layer's stacked expert weights
+    #: (`nn/moe.py`): no preset maps it, the exchange across chips is not built
+    expert: MeshAxis = None
 
     def to_flax_rules(self) -> tuple[tuple[str, MeshAxis], ...]:
         return tuple((f.name, getattr(self, f.name))

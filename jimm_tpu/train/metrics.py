@@ -224,10 +224,35 @@ def decoder_fwd_flops(d, loops: int | None = None) -> float:
     return float(loops * per_pass * d.seq_len)
 
 
+def moe_decoder_fwd_flops(d) -> float:
+    """Per-sequence forward FLOPs of a `MoEDecoderConfig` language model as
+    one chip holds it: per token and layer the latent attention's four
+    projections and causal attention over half of S^2 (q k^T at the q/k
+    width, p v at v's); the dense layers' SwiGLU; in a sparse layer the
+    router, the shared experts and the routed experts at the expected
+    ``top_k * held / num_experts`` applications a token; the untied head."""
+    a, e = d.mla, d.moe
+    qk = a.qk_nope_dim + a.qk_rope_dim
+    mla = 2 * (d.width * d.num_heads * qk
+               + d.width * (a.kv_lora_rank + a.qk_rope_dim)
+               + a.kv_lora_rank * d.num_heads * (a.qk_nope_dim + a.v_head_dim)
+               + d.num_heads * a.v_head_dim * d.width) \
+        + d.seq_len * d.num_heads * (qk + a.v_head_dim)
+    swiglu = 2 * 3 * d.width * e.expert_dim
+    sparse = 2 * d.width * e.num_experts + swiglu * (
+        e.shared_experts + e.top_k * e.held_experts / e.num_experts)
+    per_token = (d.depth * mla + d.dense_layers * 2 * 3 * d.width * d.mlp_dim
+                 + (d.depth - d.dense_layers) * sparse
+                 + 2 * d.width * d.vocab_size)
+    return float(per_token * d.seq_len)
+
+
 def model_fwd_flops(cfg) -> float:
     """Per-sample forward FLOPs for a ViT/CLIP/SigLIP config, per sequence
     for a language model."""
     if hasattr(cfg, "decoder"):
+        if hasattr(cfg.decoder, "moe"):
+            return moe_decoder_fwd_flops(cfg.decoder)
         return decoder_fwd_flops(cfg.decoder)
     total = vision_fwd_flops(cfg.vision)
     if hasattr(cfg, "text"):

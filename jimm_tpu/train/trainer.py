@@ -200,24 +200,82 @@ def lm_loss_fn(model, tokens: jax.Array
                                   beta=model.config.exit_beta)
 
 
-def make_lm_train_step(*, donate: bool = False) -> Callable:
-    """Next-token step of the looped language model. The metrics carry, per
-    pass ``r``, ``loss_exit<r>`` (mean cross-entropy of that pass's logits)
-    and ``exit_p<r>`` (mean exit mass). ``donate`` as in
-    ``make_contrastive_train_step``."""
+def _exit_metrics(model, per_pass: dict) -> dict[str, jax.Array]:
+    """Per pass ``r``: ``loss_exit<r>`` (mean cross-entropy of that pass's
+    logits) and ``exit_p<r>`` (mean exit mass)."""
+    metrics = {}
+    for r in range(per_pass["ce"].shape[0]):
+        metrics[f"loss_exit{r + 1}"] = per_pass["ce"][r]
+        metrics[f"exit_p{r + 1}"] = per_pass["p"][r]
+    return metrics
+
+
+def moe_lm_forward(model, tokens: jax.Array
+                   ) -> tuple[jax.Array, tuple[jax.Array, jax.Array]]:
+    """The sparse decoder on ``(B, S + 1)`` token ids: the mean next-token
+    cross-entropy (the logits taken in blocks) and, of the same pass, the
+    final hidden state after its norm and the experts each token chose
+    ``(layers, B * S, top_k)``. A second pass compiled apart from this one
+    rounds differently and breaks the router's near-ties another way, so
+    whoever compares gradients with routing takes both from here."""
+    inputs, targets = tokens[:, :-1], tokens[:, 1:]
+    hidden, chosen = model.hidden_states(inputs)
+    with jax.named_scope("lm_head"):
+        normed = model.norm(hidden)
+        ce = blocked_cross_entropy(normed, model.head.kernel[...], targets)
+    return jnp.mean(ce), (normed, chosen)
+
+
+def moe_lm_loss_fn(model, tokens: jax.Array
+                   ) -> tuple[jax.Array, jax.Array]:
+    """The sparse decoder's loss (`moe_lm_forward`) and the sparse layers'
+    routing counts ``(layers, num_experts)``."""
+    from jimm_tpu.nn.moe import routing_counts
+    loss, (_, chosen) = moe_lm_forward(model, tokens)
+    return loss, routing_counts(
+        chosen, model.config.decoder.moe.num_experts)
+
+
+def _routing_metrics(model, counts: jax.Array) -> dict[str, jax.Array]:
+    """Moves the routers' selection biases by this step's ``counts`` and
+    reports the step's routing: ``moe_held_rows`` (assignments to the experts
+    held here, summed over the sparse layers), ``moe_load_max_over_mean``
+    (over the held experts, worst layer) and ``router_bias_absmax``."""
+    model.update_router_bias(counts)
+    moe = model.config.decoder.moe
+    held = counts[:, moe.first_expert:moe.first_expert + moe.held_experts] \
+        .astype(jnp.float32)
+    bias = model.sparse.blocks.mlp.router_bias[...]
+    return {"moe_held_rows": jnp.sum(held),
+            "moe_load_max_over_mean": jnp.max(
+                jnp.max(held, axis=-1)
+                / jnp.maximum(jnp.mean(held, axis=-1), 1.0)),
+            "router_bias_absmax": jnp.max(jnp.abs(bias))}
+
+
+#: a language-model family's step: its loss ``(model, tokens) -> (loss, aux)``
+#: and what follows the optimizer's update, ``(model, aux) -> metrics``
+LM_STEPS: dict[str, tuple[Callable, Callable]] = {
+    "ouro": (lm_loss_fn, _exit_metrics),
+    "kanana": (moe_lm_loss_fn, _routing_metrics),
+}
+
+
+def make_lm_train_step(family: str = "ouro", *, donate: bool = False
+                       ) -> Callable:
+    """Next-token step of a language-model ``family`` (`LM_STEPS`): the
+    looped decoder's metrics carry `_exit_metrics`, the sparse decoder's
+    `_routing_metrics`. ``donate`` as in ``make_contrastive_train_step``."""
+    loss_fn, after_update = LM_STEPS[family]
 
     @partial(nnx.jit, donate_argnums=(0, 1) if donate else ())
     def train_step(model: nnx.Module, optimizer: nnx.Optimizer,
                    tokens: jax.Array) -> dict[str, jax.Array]:
         with jax.named_scope("fwd_bwd"):
-            (loss, per_pass), grads = nnx.value_and_grad(
-                lambda m: lm_loss_fn(m, tokens), has_aux=True)(model)
+            (loss, aux), grads = nnx.value_and_grad(
+                lambda m: loss_fn(m, tokens), has_aux=True)(model)
         with jax.named_scope("optimizer_update"):
             optimizer.update(model, grads)
-        metrics = {"loss": loss}
-        for r in range(per_pass["ce"].shape[0]):
-            metrics[f"loss_exit{r + 1}"] = per_pass["ce"][r]
-            metrics[f"exit_p{r + 1}"] = per_pass["p"][r]
-        return metrics
+        return {"loss": loss, **after_update(model, aux)}
 
     return train_step

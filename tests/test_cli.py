@@ -196,7 +196,7 @@ def test_unflagged_runtime_is_shape_and_platform_only(name):
 #: `scan_unroll` of each cell's `resolved_runtime` line (ledger, PR 28); a
 #: new cell gets its row here
 LEDGER_SCAN_UNROLL = {"siglip_b16_256.train": 12, "vit_l16_384.train": 24,
-                      "ouro_2_6b.train": 8}
+                      "ouro_2_6b.train": 8, "kanana_2_30b_a3b.train": 6}
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -213,7 +213,7 @@ def test_cell_argv_resolves_as_the_ledger_says(cell):
             "--batch-size", str(traffic["batch_size"]), "--steps", "40",
             "--log-every", "1", "--metrics-file", "m.jsonl",
             *traffic["cli_args"]]
-    if workload["driver"] == "train_lm":
+    if workload["driver"] in ("train_lm", "train_moe_lm"):
         argv += ["--num-layers", str(config["num_layers"]),
                  "--seq-len", str(traffic["seq_len"])]
     assert _resolve(argv, "tpu") == {"remat": True, "remat_policy": "dots",
@@ -257,6 +257,12 @@ SURFACE = {
                  "precision", "depth", "loops", "seq_len", "width",
                  "mlp_dim", "num_heads", "vocab_size", "ln_eps",
                  "rope_theta", "act"]),
+    # what train_moe_lm.py and parity_moe_lm.py read off the sparse decoder
+    "decoder_moe": ("kanana-2-30b-a3b",
+                    ["attn_impl", "scan_unroll", "remat", "remat_policy",
+                     "precision", "depth", "dense_layers", "seq_len", "width",
+                     "mlp_dim", "num_heads", "vocab_size", "ln_eps",
+                     "rope_theta", "act", "mla", "moe"]),
 }
 
 
@@ -264,8 +270,16 @@ SURFACE = {
 def test_config_surface_the_benchmark_reads(tower):
     from jimm_tpu import preset
     name, fields = SURFACE[tower]
-    cfg = getattr(preset(name), tower)
+    cfg = getattr(preset(name), tower.split("_")[0])
     assert [f for f in fields if not hasattr(cfg, f)] == []
+    if tower == "decoder_moe":
+        assert [f for f in ("kv_lora_rank", "qk_nope_dim", "qk_rope_dim",
+                            "v_head_dim") if not hasattr(cfg.mla, f)] == []
+        assert [f for f in ("num_experts", "top_k", "expert_dim",
+                            "shared_experts", "routed_scale", "held_experts",
+                            "first_expert") if not hasattr(cfg.moe, f)] == []
+        from jimm_tpu.train.trainer import moe_lm_loss_fn
+        assert callable(moe_lm_loss_fn)
     # and the names the harness, the drivers and tests/benchmark import
     from jimm_tpu import Ouro, cli, obs, tune  # noqa: F401
     from jimm_tpu.aot.export import enable_persistent_cache

@@ -618,3 +618,84 @@ def test_one_head_rows_entry_matches_model_layout(rng, kind, masked):
     for a, w_ in zip(got_g, want_g):
         np.testing.assert_allclose(fa._unflatten_heads(a, b, n), w_,
                                    atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# A value head width of its own (latent attention: q/k 192, v 128)
+# ---------------------------------------------------------------------------
+
+def _qkv_split(rng, b, s, n, d_qk, d_v, dtype=np.float32):
+    q, k = (jnp.asarray(rng.randn(b, s, n, d_qk).astype(dtype) * 0.5)
+            for _ in range(2))
+    return q, k, jnp.asarray(rng.randn(b, s, n, d_v).astype(dtype) * 0.5)
+
+
+@pytest.mark.parametrize("s,d_qk,d_v,blocks,regime", [
+    (1280, 192, 128, None, "tiled"),   # over the single-tile rule, causal
+    (384, 24, 16, 128, "tiled"),       # asked-for blocks, off-tile widths
+    (256, 192, 128, None, "single"),   # under the rule: v padded to q's
+])
+def test_value_width_of_its_own_matches_reference(rng, s, d_qk, d_v, blocks,
+                                                  regime):
+    """Forward and all three gradients; the output and dv are ``d_v`` wide
+    and the scale is 1/sqrt(d_qk)."""
+    q, k, v = _qkv_split(rng, 1, s, 2, d_qk, d_v)
+    probe = jnp.asarray(rng.randn(1, s, 2, d_v).astype(np.float32))
+    counters = get_registry("jimm_flash")
+    before = {r: counters.counter(f"{r}_total").value
+              for r in ("tiled", "single_tile")}
+
+    def f(attn, **kw):
+        return lambda q, k, v: jnp.sum(
+            attn(q, k, v, is_causal=True, **kw) * probe)
+
+    kw = {} if blocks is None else {"block_q": blocks, "block_k": blocks}
+    out = flash_attention(q, k, v, is_causal=True, **kw)
+    assert out.shape == (1, s, 2, d_v)
+    np.testing.assert_allclose(out, reference_attention(q, k, v,
+                                                        is_causal=True),
+                               rtol=2e-4, atol=2e-5)
+    got = jax.grad(f(flash_attention, **kw), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(f(reference_attention), argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", got, want, strict=True):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-4,
+                                   err_msg=f"d{name}")
+    grew = {r: counters.counter(f"{r}_total").value - before[r]
+            for r in before}
+    assert (grew["tiled"] > 0) == (regime == "tiled")
+    assert (grew["single_tile"] > 0) == (regime == "single")
+
+
+def test_tiled_kernels_keep_v_at_its_own_tile(monkeypatch):
+    """q and k blocks 256 lanes (192 padded), v, o, do and dv blocks 128: the
+    TPU lowering holds no 256-wide copy of v."""
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    q = jax.ShapeDtypeStruct((1, 2048, 2, 192), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, 2048, 2, 128), jnp.bfloat16)
+    text = _tpu_text(jax.grad(lambda *a: jnp.sum(flash_attention(
+        *a, is_causal=True).astype(jnp.float32)), argnums=(0, 1, 2)), q, q, v)
+    assert text.count("@tpu_custom_call") == 3
+    assert "tensor<2x2048x256xbf16>" in text      # q, k, dq, dk
+    assert "tensor<2x2048x128xbf16>" in text      # v, o, do, dv
+    calls = [ln for ln in text.splitlines() if "@tpu_custom_call" in ln]
+    fwd = calls[0]
+    assert fwd.count("tensor<2x2048x256xbf16>") == 2  # q and k in
+    assert fwd.count("tensor<2x2048x128xbf16>") == 2  # v in, o out
+
+
+def test_auto_reaches_flash_with_unequal_widths(rng, monkeypatch):
+    """``impl="auto"`` on a TPU sends S >= 512 to the flash kernels whatever
+    the value width; off the TPU XLA's op gets v padded and cut back."""
+    from jimm_tpu.ops import attention
+    q, k, v = _qkv_split(rng, 1, 512, 2, 24, 16)
+    want = reference_attention(q, k, v, is_causal=True)
+    np.testing.assert_allclose(
+        attention.dot_product_attention(q, k, v, is_causal=True), want,
+        rtol=2e-4, atol=2e-5)
+    seen = []
+    monkeypatch.setattr(attention, "_default_backend", lambda: "tpu")
+    monkeypatch.setattr(fa, "flash_attention",
+                        lambda q, k, v, **kw: seen.append(v.shape) or want)
+    attention.dot_product_attention(q, k, v, is_causal=True)
+    assert seen == [(1, 512, 2, 16)]

@@ -7,6 +7,7 @@ layout XLA and Mosaic disagree on. These compiles can, at no chip time.
 A compile that passes is not a chip run: `chip_smoke.py` is what runs them.
 """
 
+import functools
 import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
@@ -58,7 +59,6 @@ def _fwd_bwd(fn):
 
 
 def _flash(shape, **kw):
-    import functools
     return (functools.partial(fa.flash_attention, **kw),
             [(shape, jnp.bfloat16)] * 3)
 
@@ -82,6 +82,13 @@ KERNEL_CASES = {
     # the looped decoder's training sequence: causal, 4096 tokens, 16 heads
     # of 128: the tiled kernels (forward, dq, dk/dv)
     "flash_causal_s4096_d128": _flash((1, 4096, 16, 128), is_causal=True),
+    # latent attention at the sparse decoder's training size: 8192 tokens,
+    # 32 heads, q/k 192 wide (256 lanes), v 128: the tiled kernels with v, o,
+    # do and dv at their own tile
+    "flash_causal_s8192_qk192_v128": (
+        functools.partial(fa.flash_attention, is_causal=True),
+        [((2, 8192, 32, 192), jnp.bfloat16)] * 2
+        + [((2, 8192, 32, 128), jnp.bfloat16)]),
     "flash_masked_s577_d64": (
         fa.flash_attention_masked,
         [((32, 577, 16, 64), jnp.bfloat16)] * 3 + [((32, 577), jnp.bool_)]),
@@ -106,7 +113,8 @@ KERNEL_CASES = {
 SINGLE_TILE_CALLS = {"flash_s577_d64": 2, "flash_s729_d72": 2,
                      "flash_masked_s577_d64": 2, "flash_s1152_d128": 2,
                      "flash_s197_d64_whole_row": 2,
-                     "flash_s1153_d64": 3, "flash_causal_s4096_d128": 3}
+                     "flash_s1153_d64": 3, "flash_causal_s4096_d128": 3,
+                     "flash_causal_s8192_qk192_v128": 3}
 
 
 @pytest.mark.parametrize("case", sorted(KERNEL_CASES))

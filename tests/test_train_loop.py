@@ -80,6 +80,9 @@ LOWERED_STEP = {
         "c9b165063b385bd35f2328f33713f9fc7747c10ead8bd6e680e5154b2fd2d3bf",
     "vit_l16_384.train":
         "2cade23434015a53dc351cba448c8791c66560469040d0ef6a12e6358f699b6b",
+    # the sparse decoder's, as the PR that brought it lowers it (PR 32)
+    "kanana_2_30b_a3b.train":
+        "1071f27374ed9239273af12b3c0b3ade93dd45e9524fcc4f3509585d6309c5f2",
 }
 
 
