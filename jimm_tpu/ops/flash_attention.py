@@ -45,7 +45,15 @@ tile, so VMEM holds a single working set while Mosaic's grid pipeline
 streams the next kv block from HBM in parallel with compute. Softmax
 variants keep the flash-attention recurrence in VMEM scratch ((block_q, 128)
 lane-broadcast m/l, fp32 accumulator); the sigmoid variant keeps only the
-accumulator. HBM traffic is O(S*D) and VMEM is O(block^2).
+accumulator. HBM traffic is O(S*D) and VMEM is O(block^2). A grid cell
+holds ``hb`` heads (`_pick_hb`: as many as `_per_head_vmem_bytes` fits into
+`_VMEM_BUDGET`, which the call states to Mosaic as ``vmem_limit_bytes``),
+walked by one straight-line loop so that one head's softmax runs beside
+another head's matmuls. A causal call's grid is not the ``(q, kv)``
+rectangle but the list of its live block pairs (`_live_pairs`, two
+scalar-prefetched int32 tables: row-major for the forward and dq,
+column-major for dk/dv), so no grid step is taken for a block above the
+diagonal.
 
 The tiled backward recomputes attention blockwise (from the saved logsumexp
 for softmax kinds; from scratch for sigmoid) — dq kernel plus dk/dv kernel in
@@ -92,7 +100,11 @@ NEG_INF = -1e30
 #: default per-grid-cell tile extents. 512 amortizes grid-step overhead
 #: (measured ~2x faster than 128 at seq 256-1k on v5e) while the fp32
 #:  (block_q, block_k) logits tile stays ~1MB — far under VMEM; _prologue
-#: clamps to the padded sequence so short sequences use one tile.
+#: clamps to the padded sequence so short sequences use one tile. Blocks of
+#: 1024 and 2048 (tried from a scratch copy; `_pick_block` stops at 512)
+#: lost to 512 with four heads a cell on the v5e: forward + backward a
+#: layer at (2, 8192, 32, 192 | 128) causal 53.9 ms against 57.4-66.1, at
+#: (1, 4096, 16, 128) 2.74 against 3.14-3.69 (PERF.md, PR 33).
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 _LANES = 128  # scratch m/l are lane-broadcast for Mosaic-friendly layout
@@ -152,14 +164,53 @@ def _scores(q, k, sm_scale, mask_row, bias_tile, pos_mask):
 
 
 # ---------------------------------------------------------------------------
+# What a tiled grid step is: its block pair and the scores that exist in it
+# ---------------------------------------------------------------------------
+
+def _last_kv(qi, block_q: int, block_k: int, n_k: int, causal: bool):
+    """The last kv block a q block's row visits: under ``causal`` the one
+    that holds the row's last query's own key."""
+    if not causal:
+        return n_k - 1
+    return jnp.minimum(n_k - 1, ((qi + 1) * block_q - 1) // block_k)
+
+
+def _block_ids(refs, causal: bool, kv_major: bool = False):
+    """``(q block, kv block, the operands' refs)`` of a grid step. A causal
+    grid is ``(heads, live pairs)`` and reads the step's block pair from the
+    two prefetched tables of `_live_pairs`; any other is the rectangle
+    ``(heads, q, kv)``, or ``(heads, kv, q)`` for dk/dv."""
+    if causal:
+        qi_tab, kj_tab, *refs = refs
+        t = pl.program_id(1)
+        return qi_tab[t], kj_tab[t], refs
+    a, b = pl.program_id(1), pl.program_id(2)
+    return (b, a, refs) if kv_major else (a, b, refs)
+
+
+def _pos_mask(qi, kj, block_q: int, block_k: int, causal: bool, *,
+              sq_real: int | None = None, sk_real: int | None = None):
+    """``(block_q, block_k)`` predicate of the scores that exist: keys (or,
+    for dk/dv, queries) the array has, and under ``causal`` keys at or left
+    of the query. Head-independent: built once per grid step."""
+    k_pos = kj * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 1)
+    q_pos = None
+    if causal or sq_real is not None:
+        q_pos = qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+    pos = k_pos < sk_real if sk_real is not None else q_pos < sq_real
+    return pos & (k_pos <= q_pos) if causal else pos
+
+
+# ---------------------------------------------------------------------------
 # Forward kernel (template)
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(*refs, sk_real: int, block_k: int, causal: bool,
                 sm_scale: float, logit_bias: float, n_k: int,
                 spec: VariantSpec):
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
+    qi, kj, refs = _block_ids(refs, causal)
     softmax = spec.kind == "softmax"
     it = iter(refs)
     q_ref, k_ref, v_ref = next(it), next(it), next(it)
@@ -179,56 +230,36 @@ def _fwd_kernel(*refs, sk_real: int, block_k: int, causal: bool,
             l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    def compute():
-        # position mask is head-independent: build once, reuse per head
-        k_pos = kj * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, block_k), 1)
-        pos = k_pos < sk_real
-        if causal:
-            q_pos = qi * bq + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 0)
-            pos = pos & (k_pos <= q_pos)
-        # static loop over the hb heads resident in this grid cell — one
-        # cell amortizes grid-step overhead over hb MXU calls (the d=64
-        # per-head matmuls are too small to hide it one at a time)
-        for h in range(hb):
-            v = v_ref[h]
-            s = _scores(q_ref[h], k_ref[h], sm_scale,
-                        mask_ref[h] if spec.has_mask else None,
-                        bias_ref[h] if spec.has_bias else None, pos)
-            if softmax:
-                m_prev = _from_lanes(m_scr[h])
-                l_prev = _from_lanes(l_scr[h])
-                m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-                p = jnp.exp(s - m_new[:, None])
-                corr = jnp.exp(m_prev - m_new)
-                l_new = l_prev * corr + jnp.sum(p, axis=1)
-                acc_scr[h] = acc_scr[h] * corr[:, None] + jax.lax.dot_general(
-                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                m_scr[h] = _bcast_lanes(m_new)
-                l_scr[h] = _bcast_lanes(l_new)
-            else:
-                # no normalizer, no running statistics: each kv block's
-                # sigmoid scores contribute independently to the sum
-                p = jax.nn.sigmoid(s + logit_bias)
-                acc_scr[h] += jax.lax.dot_general(
-                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
+    # position mask is head-independent: build once, reuse per head
+    pos = _pos_mask(qi, kj, bq, block_k, causal, sk_real=sk_real)
+    # static loop over the hb heads resident in this grid cell: one straight
+    # body, in which a head's softmax runs beside another head's matmuls
+    for h in range(hb):
+        v = v_ref[h]
+        s = _scores(q_ref[h], k_ref[h], sm_scale,
+                    mask_ref[h] if spec.has_mask else None,
+                    bias_ref[h] if spec.has_bias else None, pos)
+        if softmax:
+            m_prev = _from_lanes(m_scr[h])
+            l_prev = _from_lanes(l_scr[h])
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+            p = jnp.exp(s - m_new[:, None])
+            corr = jnp.exp(m_prev - m_new)
+            l_new = l_prev * corr + jnp.sum(p, axis=1)
+            acc_scr[h] = acc_scr[h] * corr[:, None] + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[h] = _bcast_lanes(m_new)
+            l_scr[h] = _bcast_lanes(l_new)
+        else:
+            # no normalizer, no running statistics: each kv block's
+            # sigmoid scores contribute independently to the sum
+            p = jax.nn.sigmoid(s + logit_bias)
+            acc_scr[h] += jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
-    if causal:
-        # kv blocks strictly above the diagonal contribute nothing: the
-        # block is needed iff its first key position <= the block's last
-        # query position. Their DMA is elided too: the host-side index map
-        # clamps skipped cells to the last needed block, so Mosaic's
-        # pipeline sees a repeated index and issues no copy.
-        pl.when(kj * block_k <= (qi + 1) * bq - 1)(compute)
-        last_j = jnp.minimum(n_k - 1, ((qi + 1) * bq - 1) // block_k)
-    else:
-        compute()
-        last_j = n_k - 1
-
-    @pl.when(kj == last_j)
+    @pl.when(kj == _last_kv(qi, bq, block_k, n_k, causal))
     def _finalize():
         for h in range(hb):
             if softmax:
@@ -266,8 +297,7 @@ def _ds_tile(spec, s, do, v, lse, delta, logit_bias):
 def _bwd_dq_kernel(*refs, sk_real: int, block_k: int, causal: bool,
                    sm_scale: float, logit_bias: float, n_k: int,
                    spec: VariantSpec):
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
+    qi, kj, refs = _block_ids(refs, causal)
     softmax = spec.kind == "softmax"
     it = iter(refs)
     q_ref, k_ref, v_ref = next(it), next(it), next(it)
@@ -284,33 +314,21 @@ def _bwd_dq_kernel(*refs, sk_real: int, block_k: int, causal: bool,
     def _init():
         dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
 
-    def compute():
-        k_pos = kj * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, block_k), 1)
-        pos = k_pos < sk_real
-        if causal:
-            q_pos = qi * bq + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 0)
-            pos = pos & (k_pos <= q_pos)
-        for h in range(hb):
-            k = k_ref[h]
-            s = _scores(q_ref[h], k, sm_scale,
-                        mask_ref[h] if spec.has_mask else None,
-                        bias_ref[h] if spec.has_bias else None, pos)
-            _, ds = _ds_tile(spec, s, do_ref[h], v_ref[h],
-                             lse_ref[h, 0, :] if softmax else None,
-                             delta_ref[h, 0, :] if softmax else None,
-                             logit_bias)
-            dq_scr[h] += jax.lax.dot_general(
-                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+    pos = _pos_mask(qi, kj, bq, block_k, causal, sk_real=sk_real)
+    for h in range(hb):
+        k = k_ref[h]
+        s = _scores(q_ref[h], k, sm_scale,
+                    mask_ref[h] if spec.has_mask else None,
+                    bias_ref[h] if spec.has_bias else None, pos)
+        _, ds = _ds_tile(spec, s, do_ref[h], v_ref[h],
+                         lse_ref[h, 0, :] if softmax else None,
+                         delta_ref[h, 0, :] if softmax else None,
+                         logit_bias)
+        dq_scr[h] += jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    if causal:
-        pl.when(kj * block_k <= (qi + 1) * bq - 1)(compute)
-    else:
-        compute()
-
-    @pl.when(kj == n_k - 1)
+    @pl.when(kj == _last_kv(qi, bq, block_k, n_k, causal))
     def _finalize():
         dq_ref[...] = (dq_scr[...] * sm_scale).astype(dq_ref.dtype)
 
@@ -318,8 +336,7 @@ def _bwd_dq_kernel(*refs, sk_real: int, block_k: int, causal: bool,
 def _bwd_dkv_kernel(*refs, sq_real: int, block_q: int, causal: bool,
                     sm_scale: float, logit_bias: float, n_q: int,
                     spec: VariantSpec):
-    kj = pl.program_id(1)
-    qi = pl.program_id(2)
+    qi, kj, refs = _block_ids(refs, causal, kv_major=True)
     softmax = spec.kind == "softmax"
     it = iter(refs)
     q_ref, k_ref, v_ref = next(it), next(it), next(it)
@@ -334,44 +351,33 @@ def _bwd_dkv_kernel(*refs, sq_real: int, block_q: int, causal: bool,
     dv_scr = next(it)
     hb, bk, d = k_ref.shape
 
-    @pl.when(qi == 0)
+    # a causal column starts at the first q block that reaches it
+    @pl.when(qi == (jnp.minimum(kj * bk // block_q, n_q - 1) if causal
+                    else 0))
     def _init():
         dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
         dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
 
-    def compute():
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, bk), 0)
-        pos = q_pos < sq_real
-        if causal:
-            k_pos = kj * bk + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, bk), 1)
-            pos = pos & (k_pos <= q_pos)
-        for h in range(hb):
-            q = q_ref[h]
-            do = do_ref[h]
-            s = _scores(q, k_ref[h], sm_scale,
-                        mask_ref[h] if spec.has_mask else None,
-                        bias_ref[h] if spec.has_bias else None, pos)
-            p, ds = _ds_tile(spec, s, do, v_ref[h],
-                             lse_ref[h, 0, :] if softmax else None,
-                             delta_ref[h, 0, :] if softmax else None,
-                             logit_bias)
-            # dv's MXU input is a rounded copy; ds keeps the fp32 p
-            # (matching the dq kernel) so dk isn't computed from a
-            # double-rounded p
-            dv_scr[h] += jax.lax.dot_general(
-                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dk_scr[h] += jax.lax.dot_general(
-                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-
-    if causal:
-        # q blocks whose last row is left of this kv block never land
-        pl.when((qi + 1) * block_q - 1 >= kj * bk)(compute)
-    else:
-        compute()
+    pos = _pos_mask(qi, kj, block_q, bk, causal, sq_real=sq_real)
+    for h in range(hb):
+        q = q_ref[h]
+        do = do_ref[h]
+        s = _scores(q, k_ref[h], sm_scale,
+                    mask_ref[h] if spec.has_mask else None,
+                    bias_ref[h] if spec.has_bias else None, pos)
+        p, ds = _ds_tile(spec, s, do, v_ref[h],
+                         lse_ref[h, 0, :] if softmax else None,
+                         delta_ref[h, 0, :] if softmax else None,
+                         logit_bias)
+        # dv's MXU input is a rounded copy; ds keeps the fp32 p
+        # (matching the dq kernel) so dk isn't computed from a
+        # double-rounded p
+        dv_scr[h] += jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dk_scr[h] += jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
     @pl.when(qi == n_q - 1)
     def _finalize():
@@ -407,13 +413,7 @@ def _bwd_dbias_kernel(*refs, sq_real: int, sk_real: int, block_q: int,
     def _init():
         db_scr[...] = jnp.zeros(db_scr.shape, jnp.float32)
 
-    k_pos = kj * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (bq, block_k), 1)
-    pos = k_pos < sk_real
-    if causal:
-        q_pos = qi * bq + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, block_k), 0)
-        pos = pos & (k_pos <= q_pos)
+    pos = _pos_mask(qi, kj, bq, block_k, causal, sk_real=sk_real)
     for h in range(hb):
         s = _scores(q_ref[h], k_ref[h], sm_scale,
                     mask_ref[h] if spec.has_mask else None,
@@ -671,74 +671,106 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-_SEMANTICS = pltpu.CompilerParams(
-    dimension_semantics=("parallel", "parallel", "arbitrary"))
-#: the dbias grid: batch innermost so the bias tile accumulates in scratch
-_SEMANTICS4 = pltpu.CompilerParams(
-    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
+def _live_pairs(n_q: int, n_k: int, block_q: int, block_k: int,
+                kv_major: bool = False):
+    """The causal grid: int32 tables ``(q block, kv block)`` of the block
+    pairs that hold a score, i.e. whose first key is at or left of their last
+    query, row-major for the forward and dq (a q row's pairs follow each
+    other, kv block 0 first, the diagonal's last) or, ``kv_major``, column-
+    major for dk/dv. There a kv column right of every query (S_k > S_q) keeps
+    the pair of the last q block, which the mask empties, so that its dk/dv
+    blocks are still written. Returns the two tables and the number of
+    pairs that hold a score (all of them but those). The tables are scalar-
+    prefetched into SMEM, 8 bytes a pair: 136 pairs at 8192 tokens in blocks
+    of 512, 32,896 (263 KB) at 131,072, the longest the described v5e
+    compiles in `tests/test_tpu_compile.py`."""
+    import numpy as np
+    pairs = [(i, j) for i in range(n_q) for j in range(n_k)
+             if j * block_k <= (i + 1) * block_q - 1]
+    scored = len(pairs)
+    if kv_major:
+        reached = {j for _, j in pairs}
+        pairs += [(n_q - 1, j) for j in range(n_k) if j not in reached]
+        pairs.sort(key=lambda p: (p[1], p[0]))
+    qi, kj = zip(*pairs)
+    return np.asarray(qi, np.int32), np.asarray(kj, np.int32), scored
 
 
-def _causal_kv_index(block_q: int, block_k: int, n_k: int):
-    """kv-block index map for causal grids ordered (heads, q, kv): blocks
-    strictly above the diagonal (kernel skips them via ``pl.when``) are
-    clamped to the q row's last needed block, so the pipeline sees the same
-    index twice and elides the HBM->VMEM copy (the skipped blocks' DMAs
-    used to run anyway)."""
-    def idx(h, i, j):
-        jmax = jnp.minimum(n_k - 1, ((i + 1) * block_q - 1) // block_k)
-        return (h, jnp.minimum(j, jmax), 0)
-    return idx
+class _TiledGrid(NamedTuple):
+    """One tiled call's grid: its extents, the scalar-prefetch tables (none
+    for the rectangle), ``index(f)`` that turns ``f(head block, q block, kv
+    block)`` into the grid's index map, and the steps that compute."""
+
+    grid: tuple
+    tables: tuple
+    index: object
+    live_steps: int
 
 
-def _causal_q_index(block_q: int, block_k: int, lse_layout: bool = False):
-    """q-side index maps for the causal dk/dv grid ordered (heads, kv, q):
-    q blocks entirely left of the diagonal are clamped up to the kv row's
-    first needed block — same DMA-eliding trick as `_causal_kv_index`."""
-    def idx(h, j, i):
-        imin = (j * block_k) // block_q
-        i = jnp.maximum(i, imin)
-        return (h, 0, i) if lse_layout else (h, i, 0)
-    return idx
+def _tiled_grid(n_h: int, n_q: int, n_k: int, block_q: int, block_k: int,
+                causal: bool, kv_major: bool = False) -> _TiledGrid:
+    """Forward and dq walk a q block's kv blocks innermost; dk/dv
+    (``kv_major``) a kv block's q blocks. Causal: the live pairs only."""
+    if causal:
+        qi, kj, scored = _live_pairs(n_q, n_k, block_q, block_k, kv_major)
+        return _TiledGrid(
+            (n_h, len(qi)), (jnp.asarray(qi), jnp.asarray(kj)),
+            lambda f: lambda h, t, qi, kj: f(h, qi[t], kj[t]), n_h * scored)
+    if kv_major:
+        return _TiledGrid((n_h, n_k, n_q), (),
+                          lambda f: lambda h, j, i: f(h, i, j),
+                          n_h * n_q * n_k)
+    return _TiledGrid((n_h, n_q, n_k), (), lambda f: f, n_h * n_q * n_k)
 
 
-def _mask_fwd_index(block_q: int, block_k: int, n_k: int, causal: bool):
-    """Additive-mask rows live in lse layout (heads, 1, Sk); clamp the kv
-    index exactly like `_causal_kv_index` so skipped cells elide DMAs."""
-    if not causal:
-        return lambda h, i, j: (h, 0, j)
-
-    def idx(h, i, j):
-        jmax = jnp.minimum(n_k - 1, ((i + 1) * block_q - 1) // block_k)
-        return (h, 0, jnp.minimum(j, jmax))
-    return idx
-
-
-def _bias_fwd_index(block_q: int, block_k: int, n_k: int, n_hb: int,
-                    causal: bool):
-    """Bias tiles are per-HEAD (no batch dim): flattened head-block h of
-    the (B*N)-row grid maps to bias head-block ``h % (N/hb)``."""
-    if not causal:
-        return lambda h, i, j: (h % n_hb, i, j)
-
-    def idx(h, i, j):
-        jmax = jnp.minimum(n_k - 1, ((i + 1) * block_q - 1) // block_k)
-        return (h % n_hb, i, jnp.minimum(j, jmax))
-    return idx
+def _tiled_specs(g: _TiledGrid, hb: int, block_q: int, block_k: int, d: int,
+                 d_v: int, n_hb: int) -> dict:
+    """The BlockSpecs of a tiled call's operands by kind: ``q`` (and dq),
+    ``k`` (dk), ``v`` (dv), ``o`` (do), the rows' ``stat`` (lse, delta), the
+    ``mask`` rows, the ``bias`` tiles. Bias tiles are per HEAD (no batch
+    dim): flattened head-block h of the (B*N)-row grid maps to bias
+    head-block ``h % (N/hb)``."""
+    def at(shape, f):
+        return pl.BlockSpec(shape, g.index(f))
+    return {
+        "q": at((hb, block_q, d), lambda h, i, j: (h, i, 0)),
+        "k": at((hb, block_k, d), lambda h, i, j: (h, j, 0)),
+        "v": at((hb, block_k, d_v), lambda h, i, j: (h, j, 0)),
+        "o": at((hb, block_q, d_v), lambda h, i, j: (h, i, 0)),
+        "stat": at((hb, 1, block_q), lambda h, i, j: (h, 0, i)),
+        "mask": at((hb, 1, block_k), lambda h, i, j: (h, 0, j)),
+        "bias": at((hb, block_q, block_k), lambda h, i, j: (h % n_hb, i, j)),
+    }
 
 
-def _bias_dkv_index(block_q: int, block_k: int, n_hb: int, causal: bool):
-    if not causal:
-        return lambda h, j, i: (h % n_hb, i, j)
+def _tiled_call(kernel, g: _TiledGrid, in_specs, out_specs, out_shape,
+                scratch, vmem_limit: int, inputs):
+    """The one pallas_call of the tiled regime's forward, dq and dk/dv."""
+    _count_call("tiled", steps=math.prod(g.grid), live_steps=g.live_steps)
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel",) * (len(g.grid) - 1)
+        + ("arbitrary",), vmem_limit_bytes=vmem_limit)
+    if not g.tables:
+        return pl.pallas_call(
+            kernel, grid=g.grid, in_specs=in_specs, out_specs=out_specs,
+            out_shape=out_shape, scratch_shapes=scratch,
+            compiler_params=params, interpret=_interpret())(*inputs)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(g.tables), grid=g.grid,
+            in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch),
+        out_shape=out_shape, compiler_params=params,
+        interpret=_interpret())(*g.tables, *inputs)
 
-    def idx(h, j, i):
-        i = jnp.maximum(i, (j * block_k) // block_q)
-        return (h % n_hb, i, j)
-    return idx
 
-
-#: VMEM budget for one grid cell's resident tiles (of ~16MB/core), leaving
-#: room for Mosaic's input double-buffering and intermediates
-_VMEM_BUDGET = 8 * 1024 * 1024
+#: what `_per_head_vmem_bytes` x heads may add up to for one tiled grid
+#: cell. A call asks Mosaic for twice its model as scoped VMEM
+#: (`_tiled_vmem_limit`: the pipeline's second buffers, Mosaic's matmul and
+#: relayout temporaries), so at most 64 MiB of the v5e's 128, like the
+#: single-tile calls; the 8 MiB this was before was sized for the 16 MiB
+#: default scope and held a cell of 512 x 512 blocks at one or two heads.
+_VMEM_BUDGET = 32 * 1024 * 1024
 
 
 def _per_head_vmem_bytes(block_q: int, block_k: int, d: int, *,
@@ -746,13 +778,15 @@ def _per_head_vmem_bytes(block_q: int, block_k: int, d: int, *,
                          has_bias: bool = False) -> int:
     """Estimated resident VMEM per head in one grid cell — the model behind
     `_pick_hb`, exposed for `scripts/vmem_probe.py` to validate against
-    Mosaic's compile-time accounting (one shared formula, no drift). The
-    per-variant terms are mirrored jax-free in `tune/space.py`
-    (sync-tested in tests/test_tune.py)."""
+    Mosaic's compile-time accounting (one shared formula, no drift). Sized
+    on the backward, which holds the most: the fp32 s, p, dp and ds tiles
+    and the two MXU-operand copies of p and ds (20 bytes a score: 20 MB at
+    1024 x 1024). The per-variant terms are mirrored jax-free in
+    `tune/space.py` (sync-tested in tests/test_tune.py)."""
     n = (3 * block_k * d * 2            # k/v in + one of q/do
          + 2 * block_q * d * 2          # q tile + bf16 out tile
          + 2 * block_q * d * 4          # fp32 accumulators
-         + block_q * block_k * 6)       # s fp32 + p bf16 intermediate
+         + block_q * block_k * 20)      # s, p, dp, ds fp32 + 2 bf16 copies
     if kind == "softmax":
         n += 2 * block_q * _LANES * 4   # m/l stats scratch (sigmoid: none)
     if has_mask:
@@ -762,16 +796,29 @@ def _per_head_vmem_bytes(block_q: int, block_k: int, d: int, *,
     return n
 
 
+def _spec_vmem_bytes(block_q: int, block_k: int, d: int,
+                     spec: VariantSpec) -> int:
+    return _per_head_vmem_bytes(block_q, block_k, d, kind=spec.kind,
+                                has_mask=spec.has_mask,
+                                has_bias=spec.has_bias)
+
+
 def _pick_hb(bn: int, block_q: int, block_k: int, d: int,
              spec: VariantSpec = _SOFTMAX, n_heads: int | None = None) -> int:
     """Heads per grid cell: the per-head (S, 64) matmuls are too small to
     hide the ~us grid-step sequencing cost, so each cell processes `hb`
-    heads back to back (measured ~2x on ViT-shape attention on v5e). The
-    bias variant additionally needs hb | N so a head block never straddles
-    two samples' rows (its bias index map divides by N/hb)."""
-    per_head = _per_head_vmem_bytes(block_q, block_k, d, kind=spec.kind,
-                                    has_mask=spec.has_mask,
-                                    has_bias=spec.has_bias)
+    heads back to back (measured ~2x on ViT-shape attention on v5e), and a
+    head's vector work (the softmax over a 1 MB score tile) overlaps
+    another head's matmuls only inside one straight-line body: at
+    (2, 8192, 32, 192 | 128) causal forward + backward a layer read 59.4 /
+    57.0 / 54.1 / 57.6 ms at 1 / 2 / 4 / 8 heads, at (1, 4096, 16, 128)
+    2.88 / 2.92 / 2.75 / 2.66 (PERF.md, PR 33; eight heads of 512 x 512,
+    over the budget, take Mosaic 17-22 s to compile against 2-4; the eight
+    of 256 x 256 the budget admits 2 s, and read 4-8 % under four at 1280
+    tokens). The bias variant additionally needs hb | N so
+    a head block never straddles two samples' rows (its bias index map
+    divides by N/hb)."""
+    per_head = _spec_vmem_bytes(block_q, block_k, d, spec)
     for hb in (8, 4, 2):
         if bn % hb:
             continue
@@ -780,6 +827,16 @@ def _pick_hb(bn: int, block_q: int, block_k: int, d: int,
         if hb * per_head <= _VMEM_BUDGET:
             return hb
     return 1
+
+
+def _tiled_vmem_limit(hb: int, block_q: int, block_k: int, d: int,
+                      spec: VariantSpec) -> int:
+    """The scoped VMEM a tiled call states (forward, dq, dk/dv and dbias
+    alike): twice its model and never under the budget, as
+    `_single_tile_call` does. `_pick_hb` holds the model under the budget, so
+    at most 64 MiB."""
+    return max(2 * hb * _spec_vmem_bytes(block_q, block_k, d, spec),
+               _VMEM_BUDGET)
 
 
 #: what the single-tile model below may add up to for one grid cell. A call
@@ -835,14 +892,22 @@ def _single_tile_plan(n: int, sq: int, sk: int, d: int, itemsize: int,
     return _single_tile_hb(n, sq_p, sk_p, d, itemsize, spec), sq_p, sk_p
 
 
-def _count_call(regime: str) -> None:
+def _count_call(regime: str, steps: int = 0, live_steps: int = 0) -> None:
     """One count per pallas_call built (trace time, like the tuner's
     ``jimm_tune_*``): ``jimm_flash_single_tile_total`` /
     ``jimm_flash_tiled_total``, and ``jimm_flash_direct_total`` for a call
     that reads and writes its caller's layout with no XLA transpose, pad or
-    slice of a q-sized array around it."""
+    slice of a q-sized array around it. A tiled call adds the grid steps one
+    execution of it takes and those of them that compute
+    (``jimm_flash_tiled_grid_steps_total`` /
+    ``jimm_flash_tiled_live_steps_total``: equal but for the empty pairs
+    `_live_pairs` keeps where keys lie right of every query)."""
     from jimm_tpu.obs.registry import get_registry
-    get_registry("jimm_flash").counter(f"{regime}_total").inc()
+    registry = get_registry("jimm_flash")
+    registry.counter(f"{regime}_total").inc()
+    if steps:
+        registry.counter(f"{regime}_grid_steps_total").inc(steps)
+        registry.counter(f"{regime}_live_steps_total").inc(live_steps)
 
 
 def _single_tile_call(kernel, inputs, outputs, n: int, hb: int, sq_p: int,
@@ -944,47 +1009,31 @@ def _fwd_pallas(q3, k3, v3, maskadd, bias, causal, spec, sm_scale,
     n_q, n_k = sq_p // block_q, sk_p // block_k
     n_heads = bias.shape[0] if spec.has_bias else bn
     hb = _pick_hb(bn, block_q, block_k, d, spec, n_heads)
+    g = _tiled_grid(bn // hb, n_q, n_k, block_q, block_k, causal)
+    sp = _tiled_specs(g, hb, block_q, block_k, d, dv, n_heads // hb)
     kernel = partial(_fwd_kernel, sk_real=sk, block_k=block_k, causal=causal,
                      sm_scale=sm_scale, logit_bias=logit_bias, n_k=n_k,
                      spec=spec)
-    kv_idx = (_causal_kv_index(block_q, block_k, n_k) if causal
-              else (lambda h, i, j: (h, j, 0)))
     inputs = [qp, kp, vp]
-    in_specs = [
-        pl.BlockSpec((hb, block_q, d), lambda h, i, j: (h, i, 0)),
-        pl.BlockSpec((hb, block_k, d), kv_idx),
-        pl.BlockSpec((hb, block_k, dv), kv_idx),
-    ]
+    in_specs = [sp["q"], sp["k"], sp["v"]]
     if spec.has_mask:
         inputs.append(_pad_mask(maskadd, sk_p))
-        in_specs.append(pl.BlockSpec(
-            (hb, 1, block_k), _mask_fwd_index(block_q, block_k, n_k, causal)))
+        in_specs.append(sp["mask"])
     if spec.has_bias:
         inputs.append(jnp.pad(bias, ((0, 0), (0, sq_p - sq),
                                      (0, sk_p - sk))))
-        in_specs.append(pl.BlockSpec(
-            (hb, block_q, block_k),
-            _bias_fwd_index(block_q, block_k, n_k, n_heads // hb, causal)))
-    out_specs = [pl.BlockSpec((hb, block_q, dv), lambda h, i, j: (h, i, 0))]
+        in_specs.append(sp["bias"])
+    out_specs = [sp["o"]]
     out_shape = [jax.ShapeDtypeStruct((bn, sq_p, dv), q3.dtype)]
     scratch = [pltpu.VMEM((hb, block_q, dv), jnp.float32)]
     if softmax:
-        out_specs.append(pl.BlockSpec((hb, 1, block_q),
-                                      lambda h, i, j: (h, 0, i)))
+        out_specs.append(sp["stat"])
         out_shape.append(jax.ShapeDtypeStruct((bn, 1, sq_p), jnp.float32))
         scratch = [pltpu.VMEM((hb, block_q, _LANES), jnp.float32),
                    pltpu.VMEM((hb, block_q, _LANES), jnp.float32)] + scratch
-    _count_call("tiled")
-    outs = pl.pallas_call(
-        kernel,
-        grid=(bn // hb, n_q, n_k),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        compiler_params=_SEMANTICS,
-        interpret=_interpret(),
-    )(*inputs)
+    outs = _tiled_call(kernel, g, in_specs, out_specs, out_shape, scratch,
+                       _tiled_vmem_limit(hb, block_q, block_k, d, spec),
+                       inputs)
     return outs[0][:, :sq], (outs[1][:, 0, :sq] if softmax else None)
 
 
@@ -1053,84 +1102,50 @@ def _flash_bwd(causal, spec, sm_scale, logit_bias, block_q, block_k, n, res,
         delta_p = jnp.pad(delta, ((0, 0), (0, sq_p - delta.shape[1])))[:, None]
         stats = [lse_p, delta_p]
 
+    vmem_limit = _tiled_vmem_limit(hb, block_q, block_k, d, spec)
+    static = dict(causal=causal, sm_scale=sm_scale, logit_bias=logit_bias,
+                  spec=spec)
+
+    def operands(kv_major):
+        """A backward call's grid, its operands' specs and the inputs dq and
+        dk/dv share: q, k, v, the mask rows, the bias tiles, do and the
+        rows' statistics."""
+        g = _tiled_grid(bn // hb, n_q, n_k, block_q, block_k, causal,
+                        kv_major)
+        sp = _tiled_specs(g, hb, block_q, block_k, d, d_v, n_hb)
+        inputs, specs = [qp, kp, vp], [sp["q"], sp["k"], sp["v"]]
+        if spec.has_mask:
+            inputs.append(mp)
+            specs.append(sp["mask"])
+        if spec.has_bias:
+            inputs.append(bp)
+            specs.append(sp["bias"])
+        inputs.append(dop)
+        specs.append(sp["o"])
+        if softmax:
+            inputs += stats
+            specs += [sp["stat"], sp["stat"]]
+        return g, sp, inputs, specs
+
     # ---- dq ---------------------------------------------------------------
-    kv_idx = (_causal_kv_index(block_q, block_k, n_k) if causal
-              else (lambda h, i, j: (h, j, 0)))
-    q_spec = pl.BlockSpec((hb, block_q, d), lambda h, i, j: (h, i, 0))
-    stat_spec = pl.BlockSpec((hb, 1, block_q), lambda h, i, j: (h, 0, i))
-    dq_inputs = [qp, kp, vp]
-    dq_specs = [q_spec, pl.BlockSpec((hb, block_k, d), kv_idx),
-                pl.BlockSpec((hb, block_k, d_v), kv_idx)]
-    if spec.has_mask:
-        dq_inputs.append(mp)
-        dq_specs.append(pl.BlockSpec(
-            (hb, 1, block_k), _mask_fwd_index(block_q, block_k, n_k, causal)))
-    if spec.has_bias:
-        dq_inputs.append(bp)
-        dq_specs.append(pl.BlockSpec(
-            (hb, block_q, block_k),
-            _bias_fwd_index(block_q, block_k, n_k, n_hb, causal)))
-    dq_inputs.append(dop)
-    dq_specs.append(pl.BlockSpec((hb, block_q, d_v),
-                                 lambda h, i, j: (h, i, 0)))
-    if softmax:
-        dq_inputs += stats
-        dq_specs += [stat_spec, stat_spec]
-    _count_call("tiled")
-    dq = pl.pallas_call(
-        partial(_bwd_dq_kernel, sk_real=sk, block_k=block_k, causal=causal,
-                sm_scale=sm_scale, logit_bias=logit_bias, n_k=n_k, spec=spec),
-        grid=(bn // hb, n_q, n_k),
-        in_specs=dq_specs,
-        out_specs=pl.BlockSpec((hb, block_q, d), lambda h, i, j: (h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bn, sq_p, d), q3.dtype),
-        scratch_shapes=[pltpu.VMEM((hb, block_q, d), jnp.float32)],
-        compiler_params=_SEMANTICS,
-        interpret=_interpret(),
-    )(*dq_inputs)[:, :sq]
+    g, sp, inputs, specs = operands(kv_major=False)
+    dq = _tiled_call(
+        partial(_bwd_dq_kernel, sk_real=sk, block_k=block_k, n_k=n_k,
+                **static),
+        g, specs, sp["q"], jax.ShapeDtypeStruct((bn, sq_p, d), q3.dtype),
+        [pltpu.VMEM((hb, block_q, d), jnp.float32)], vmem_limit,
+        inputs)[:, :sq]
 
     # ---- dk / dv ----------------------------------------------------------
-    q_idx = (_causal_q_index(block_q, block_k) if causal
-             else (lambda h, j, i: (h, i, 0)))
-    stat_idx = (_causal_q_index(block_q, block_k, lse_layout=True) if causal
-                else (lambda h, j, i: (h, 0, i)))
-    k_spec = pl.BlockSpec((hb, block_k, d), lambda h, j, i: (h, j, 0))
-    v_spec = pl.BlockSpec((hb, block_k, d_v), lambda h, j, i: (h, j, 0))
-    dkv_inputs = [qp, kp, vp]
-    dkv_specs = [pl.BlockSpec((hb, block_q, d), q_idx), k_spec, v_spec]
-    if spec.has_mask:
-        dkv_inputs.append(mp)
-        dkv_specs.append(pl.BlockSpec((hb, 1, block_k),
-                                      lambda h, j, i: (h, 0, j)))
-    if spec.has_bias:
-        dkv_inputs.append(bp)
-        dkv_specs.append(pl.BlockSpec(
-            (hb, block_q, block_k),
-            _bias_dkv_index(block_q, block_k, n_hb, causal)))
-    dkv_inputs.append(dop)
-    dkv_specs.append(pl.BlockSpec((hb, block_q, d_v), q_idx))
-    if softmax:
-        dkv_inputs += stats
-        dkv_specs += [pl.BlockSpec((hb, 1, block_q), stat_idx),
-                      pl.BlockSpec((hb, 1, block_q), stat_idx)]
-    _count_call("tiled")
-    dk, dv = pl.pallas_call(
-        partial(_bwd_dkv_kernel, sq_real=sq, block_q=block_q, causal=causal,
-                sm_scale=sm_scale, logit_bias=logit_bias, n_q=n_q, spec=spec),
-        grid=(bn // hb, n_k, n_q),
-        in_specs=dkv_specs,
-        out_specs=[k_spec, v_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((bn, sk_p, d), q3.dtype),
-            jax.ShapeDtypeStruct((bn, sk_p, d_v), q3.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((hb, block_k, d), jnp.float32),
-            pltpu.VMEM((hb, block_k, d_v), jnp.float32),
-        ],
-        compiler_params=_SEMANTICS,
-        interpret=_interpret(),
-    )(*dkv_inputs)
+    g, sp, inputs, specs = operands(kv_major=True)
+    dk, dv = _tiled_call(
+        partial(_bwd_dkv_kernel, sq_real=sq, block_q=block_q, n_q=n_q,
+                **static),
+        g, specs, [sp["k"], sp["v"]],
+        [jax.ShapeDtypeStruct((bn, sk_p, d), q3.dtype),
+         jax.ShapeDtypeStruct((bn, sk_p, d_v), q3.dtype)],
+        [pltpu.VMEM((hb, block_k, d), jnp.float32),
+         pltpu.VMEM((hb, block_k, d_v), jnp.float32)], vmem_limit, inputs)
 
     # ---- dbias ------------------------------------------------------------
     dbias = None
@@ -1156,7 +1171,8 @@ def _flash_bwd(causal, spec, sm_scale, logit_bias, block_q, block_k, n, res,
             db_inputs += stats
             db_specs += [pl.BlockSpec((hb, 1, block_q), stat_idx4),
                          pl.BlockSpec((hb, 1, block_q), stat_idx4)]
-        _count_call("tiled")
+        steps = n_hb * n_q * n_k * n_b  # the rectangle: every step computes
+        _count_call("tiled", steps=steps, live_steps=steps)
         dbias = pl.pallas_call(
             partial(_bwd_dbias_kernel, sq_real=sq, sk_real=sk,
                     block_q=block_q, block_k=block_k, causal=causal,
@@ -1169,7 +1185,10 @@ def _flash_bwd(causal, spec, sm_scale, logit_bias, block_q, block_k, n, res,
             out_shape=jax.ShapeDtypeStruct((n_heads, sq_p, sk_p),
                                            jnp.float32),
             scratch_shapes=[pltpu.VMEM((hb, block_q, block_k), jnp.float32)],
-            compiler_params=_SEMANTICS4,
+            # batch innermost so the bias tile accumulates in scratch
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",) * 3 + ("arbitrary",),
+                vmem_limit_bytes=vmem_limit),
             interpret=_interpret(),
         )(*db_inputs)[:, :sq, :sk]
 

@@ -45,18 +45,44 @@ from jax.experimental.pallas import tpu as pltpu
 
 from jax.ad_checkpoint import checkpoint_name
 
-from jimm_tpu.ops.flash_attention import (NEG_INF, _LANES, _SEMANTICS,
-                                          _bcast_lanes, _causal_kv_index,
-                                          _causal_q_index, _ceil_to,
-                                          _flatten_heads, _from_lanes,
-                                          _interpret, _pad_seq, _pick_block,
-                                          _unflatten_heads)
+from jimm_tpu.ops.flash_attention import (NEG_INF, _LANES, _bcast_lanes,
+                                          _ceil_to, _flatten_heads,
+                                          _from_lanes, _interpret, _pad_seq,
+                                          _pick_block, _unflatten_heads)
 
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 
-#: same per-cell budget as the f32 kernel (of ~16MB/core VMEM)
+#: per-cell budget under Mosaic's default 16 MiB scope, which these calls
+#: run in (the bf16 family's tiled calls state their own limit since PR 33)
 _VMEM_BUDGET = 8 * 1024 * 1024
+
+_SEMANTICS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+def _causal_kv_index(block_q: int, block_k: int, n_k: int):
+    """kv-block index map for causal grids ordered (heads, q, kv): blocks
+    strictly above the diagonal (kernel skips them via ``pl.when``) are
+    clamped to the q row's last needed block, so the pipeline sees the same
+    index twice and elides the HBM->VMEM copy. (The bf16 family's causal
+    grid holds the live blocks only; these kernels still walk the
+    rectangle.)"""
+    def idx(h, i, j):
+        jmax = jnp.minimum(n_k - 1, ((i + 1) * block_q - 1) // block_k)
+        return (h, jnp.minimum(j, jmax), 0)
+    return idx
+
+
+def _causal_q_index(block_q: int, block_k: int, lse_layout: bool = False):
+    """q-side index maps for the causal dk/dv grid ordered (heads, kv, q):
+    q blocks entirely left of the diagonal are clamped up to the kv row's
+    first needed block — same DMA-eliding trick as `_causal_kv_index`."""
+    def idx(h, j, i):
+        imin = (j * block_k) // block_q
+        i = jnp.maximum(i, imin)
+        return (h, 0, i) if lse_layout else (h, i, 0)
+    return idx
 
 
 def _per_head_vmem_bytes(block_q: int, block_k: int, d: int) -> int:

@@ -12,7 +12,8 @@ from __future__ import annotations
 
 from typing import Any, Sequence
 
-__all__ = ["FLASH_BLOCKS", "FP8_MATMUL_BLOCK_M", "FP8_MATMUL_BLOCK_N",
+__all__ = ["FLASH_BLOCKS", "FLASH_VMEM_BUDGET", "FP8_MATMUL_BLOCK_M",
+           "FP8_MATMUL_BLOCK_N",
            "INT8_FLASH_BLOCKS", "INT8_MATMUL_BLOCK_M",
            "INT8_MATMUL_BLOCK_N", "LN_BLOCK_ROWS", "RETRIEVAL_BLOCK_N",
            "VMEM_BUDGET", "bias_flash_space", "bias_flash_vmem_bytes",
@@ -30,12 +31,21 @@ _LANES = 128
 _SUBLANES = 8
 _INT8_SUBLANES = 32
 
-#: mirrors ops.flash_attention._VMEM_BUDGET (sync-tested)
+#: the per-cell budget of the matmul, LayerNorm and retrieval kernels, which
+#: run under Mosaic's default 16 MiB scope (mirrors ``_VMEM_BUDGET`` of
+#: ops.int8_matmul / fp8_matmul / flash_attention_int8; sync-tested)
 VMEM_BUDGET = 8 * 1024 * 1024
+
+#: mirrors ops.flash_attention._VMEM_BUDGET (sync-tested): the tiled flash
+#: calls state their own scoped VMEM (twice their model, at most 64 MiB of
+#: the v5e's 128), so a cell's working set may reach 32 MiB
+FLASH_VMEM_BUDGET = 32 * 1024 * 1024
 
 #: the flash grid tiles Mosaic handles well: lane-aligned powers of two.
 #: `_pick_block` in the kernel clamps to the padded sequence, so candidates
 #: larger than the (128-padded) sequence are redundant and pruned here.
+#: Blocks of 1024 and 2048 lost to 512 at every shape measured (PERF.md,
+#: PR 33) and are not offered.
 FLASH_BLOCKS = (128, 256, 512)
 
 #: LN row-block candidates — sublane-aligned, from minimum tile to the
@@ -60,7 +70,7 @@ def flash_vmem_bytes(block_q: int, block_k: int, d: int) -> int:
         + 2 * block_q * d * 2
         + 2 * block_q * _LANES * 4
         + 2 * block_q * d * 4
-        + block_q * block_k * 6)
+        + block_q * block_k * 20)  # s, p, dp, ds fp32 + two bf16 MXU copies
 
 
 def _attn_space(shapes: Sequence[Sequence[int]], vmem_fn) -> list[dict]:
@@ -75,7 +85,7 @@ def _attn_space(shapes: Sequence[Sequence[int]], vmem_fn) -> list[dict]:
         for bk in FLASH_BLOCKS:
             if bk > _ceil_to(sk, _LANES):
                 continue
-            if vmem_fn(bq, bk, d) > VMEM_BUDGET:
+            if vmem_fn(bq, bk, d) > FLASH_VMEM_BUDGET:
                 continue
             out.append({"block_q": bq, "block_k": bk})
     return out or [{"block_q": FLASH_BLOCKS[0], "block_k": FLASH_BLOCKS[0]}]

@@ -1,5 +1,6 @@
-"""Validate `_pick_hb`'s VMEM model against compiled reality (the 8 MB
-budget and per-head byte estimate were never checked on TPU — an
+"""Validate `_pick_hb`'s VMEM model against compiled reality (the budget,
+32 MiB a cell under a limit the call states, and the per-head byte estimate
+are checked on the chip only at the shapes of PERF.md's PR 33 entry — an
 overestimate silently halves head batching, an underestimate would
 OOM at exotic shapes).
 

@@ -500,12 +500,17 @@ def test_off_tile_head_width_is_padded_once_and_never_transposed(monkeypatch):
 
 
 #: sha256 of the TPU lowering (kernel bodies cut out) of the gradients at
-#: shapes over the rule, taken at the commit before the single-tile regime
-#: moved to the model's layout (PR 27's tree): the tiled regime's wrappers
-#: (flatten, pad to the blocks, slice back) are not this PR's to move
+#: shapes over the rule. The non-causal case is the text of the commit before
+#: the single-tile regime moved to the model's layout (PR 27's tree): the
+#: tiled regime's wrappers (flatten, pad to the blocks, slice back) have not
+#: moved since, and PR 33 changed no operand of its three calls. The causal
+#: case was re-pinned by PR 33, by design: its calls take the two int32
+#: tables of live block pairs as their first operands (36 pairs at 4096
+#: tokens in blocks of 512), and nothing else in the text changed (the
+#: wrappers around them are the parent's; it read 193b84c1... before).
 TILED_TEXT_SHA = {
     ((1, 1153, 2, 64), False): "799339d336efd84e20bb31a7e9f6146c4a7c0338ddb1e4011a724800748ba267",
-    ((1, 4096, 4, 128), True): "193b84c195d5c1f0d1b3cf7c32ef5c9e74aa03bdd47fd18146aa8a32efd4ce1a",
+    ((1, 4096, 4, 128), True): "e26f2d15704afba952f950d447821b08c99c7526649e2bddf1edfc0f01a0d03d",
 }
 
 
@@ -634,37 +639,221 @@ def _qkv_split(rng, b, s, n, d_qk, d_v, dtype=np.float32):
     (1280, 192, 128, None, "tiled"),   # over the single-tile rule, causal
     (384, 24, 16, 128, "tiled"),       # asked-for blocks, off-tile widths
     (256, 192, 128, None, "single"),   # under the rule: v padded to q's
+    # the causal grid of live blocks (PR 33). The last block of each side
+    # holds the diagonal AND the padded tail of S:
+    (2000, 192, 128, None, "tiled"),
+    (2000, 192, 128, (256, 256), "tiled"),
+    # block_q over block_k and the reverse: a diagonal block is crossed by
+    # several of the other side's
+    (2048, 128, 128, (512, 256), "tiled"),
+    (2048, 128, 128, (256, 512), "tiled"),
+    (700, 64, 64, (256, 128), "tiled"),
+    (700, 64, 64, (128, 256), "tiled"),
 ])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_value_width_of_its_own_matches_reference(rng, s, d_qk, d_v, blocks,
-                                                  regime):
-    """Forward and all three gradients; the output and dv are ``d_v`` wide
-    and the scale is 1/sqrt(d_qk)."""
-    q, k, v = _qkv_split(rng, 1, s, 2, d_qk, d_v)
-    probe = jnp.asarray(rng.randn(1, s, 2, d_v).astype(np.float32))
+                                                  regime, dtype):
+    """Causal forward and all three gradients against the reference; the
+    output and dv are ``d_v`` wide and the scale is 1/sqrt(d_qk). Four heads
+    in two samples: the tiled cells hold ``hb`` > 1 of them."""
+    if dtype == "bfloat16" and regime == "single":
+        pytest.skip("the single-tile regime's bf16 cases are above")
+    q32 = _qkv_split(rng, 2, s, 2, d_qk, d_v)
+    q, k, v = (x.astype(dtype) for x in q32)
+    probe = jnp.asarray(rng.randn(2, s, 2, d_v).astype(np.float32))
     counters = get_registry("jimm_flash")
     before = {r: counters.counter(f"{r}_total").value
               for r in ("tiled", "single_tile")}
 
     def f(attn, **kw):
         return lambda q, k, v: jnp.sum(
-            attn(q, k, v, is_causal=True, **kw) * probe)
+            attn(q, k, v, is_causal=True, **kw).astype(jnp.float32) * probe)
 
-    kw = {} if blocks is None else {"block_q": blocks, "block_k": blocks}
+    kw = {}
+    if blocks is not None:
+        bq, bk = blocks if isinstance(blocks, tuple) else (blocks, blocks)
+        kw = {"block_q": bq, "block_k": bk}
+    else:
+        assert fa._pick_hb(4, 512, 512, fa._head_pad_target(d_qk)) == 4
     out = flash_attention(q, k, v, is_causal=True, **kw)
-    assert out.shape == (1, s, 2, d_v)
-    np.testing.assert_allclose(out, reference_attention(q, k, v,
-                                                        is_causal=True),
-                               rtol=2e-4, atol=2e-5)
+    assert out.shape == (2, s, 2, d_v) and out.dtype == q.dtype
+    # the reference differentiates in fp32 from the same (rounded) inputs
+    in32 = tuple(x.astype(jnp.float32) for x in (q, k, v))
+    rtol, atol, grtol, gatol = ((2e-4, 2e-5, 2e-3, 2e-4)
+                                if dtype == "float32"
+                                else (2e-2, 2e-2, 5e-2, 2e-1))
+    np.testing.assert_allclose(out.astype(np.float32),
+                               reference_attention(*in32, is_causal=True),
+                               rtol=rtol, atol=atol)
     got = jax.grad(f(flash_attention, **kw), argnums=(0, 1, 2))(q, k, v)
-    want = jax.grad(f(reference_attention), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(f(reference_attention), argnums=(0, 1, 2))(*in32)
     for name, a, b in zip("qkv", got, want, strict=True):
         assert a.shape == b.shape
-        np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-4,
-                                   err_msg=f"d{name}")
+        np.testing.assert_allclose(a.astype(np.float32), b, rtol=grtol,
+                                   atol=gatol, err_msg=f"d{name}")
     grew = {r: counters.counter(f"{r}_total").value - before[r]
             for r in before}
     assert (grew["tiled"] > 0) == (regime == "tiled")
     assert (grew["single_tile"] > 0) == (regime == "single")
+
+
+# ---------------------------------------------------------------------------
+# The tiled causal schedule: blocks from the shapes, a grid of live blocks
+# ---------------------------------------------------------------------------
+
+def _rectangle_admits(i, j, block_q, block_k):
+    """The parent's ``pl.when``: a kv block is needed iff its first key is
+    at or left of the q block's last query."""
+    return j * block_k <= (i + 1) * block_q - 1
+
+
+@pytest.mark.parametrize("block_q", [128, 256, 512, 1024, 2048])
+@pytest.mark.parametrize("block_k", [128, 256, 512, 1024, 2048])
+def test_live_pairs_are_the_rectangles_live_blocks(block_q, block_k):
+    """Both tables enumerate exactly the blocks the rectangle computed, for
+    every padded length: row-major with a row's pairs together, kv block 0
+    first and `_last_kv` last (where the forward and dq init and finalize),
+    column-major with a column's first pair where dk/dv init and the last q
+    block last."""
+    for s in (128, 640, 1024, 2048, 4096, 8192):
+        n_q = fa._ceil_to(s, block_q) // block_q
+        n_k = fa._ceil_to(s, block_k) // block_k
+        want = {(i, j) for i in range(n_q) for j in range(n_k)
+                if _rectangle_admits(i, j, block_q, block_k)}
+        qi, kj, scored = fa._live_pairs(n_q, n_k, block_q, block_k)
+        rows = list(zip(qi.tolist(), kj.tolist()))
+        assert qi.dtype == kj.dtype == np.int32
+        assert set(rows) == want and len(rows) == len(want) == scored
+        assert rows == sorted(rows)
+        for i in range(n_q):
+            row = [j for a, j in rows if a == i]
+            assert row[0] == 0 and row == list(range(len(row)))
+            assert row[-1] == int(fa._last_kv(i, block_q, block_k, n_k, True))
+        qi, kj, scored = fa._live_pairs(n_q, n_k, block_q, block_k,
+                                        kv_major=True)
+        cols = list(zip(kj.tolist(), qi.tolist()))
+        assert {(i, j) for j, i in cols} == want
+        assert len(cols) == len(want) == scored
+        assert cols == sorted(cols)
+        for j in range(n_k):
+            col = [i for b, i in cols if b == j]
+            assert col[0] == min(j * block_k // block_q, n_q - 1)
+            assert col[-1] == n_q - 1
+
+
+def test_live_pairs_keep_a_pair_for_keys_right_of_every_query():
+    """S_k over S_q: the forward never visits the kv blocks right of the
+    last query; dk/dv still write those blocks (zeros), from one pair the
+    mask empties."""
+    qi, kj, scored = fa._live_pairs(2, 5, 128, 128)
+    assert set(kj.tolist()) == {0, 1} and scored == len(qi) == 3
+    qi, kj, scored = fa._live_pairs(2, 5, 128, 128, kv_major=True)
+    assert list(zip(kj.tolist(), qi.tolist())) == [
+        (0, 0), (0, 1), (1, 1), (2, 1), (3, 1), (4, 1)]
+    assert scored == 3
+
+
+@pytest.mark.parametrize("sq,sk", [(300, 700), (700, 300)])
+def test_causal_with_unequal_lengths_matches_reference(rng, sq, sk):
+    q = jnp.asarray(rng.randn(1, sq, 2, 64).astype(np.float32) * 0.5)
+    k, v = (jnp.asarray(rng.randn(1, sk, 2, 64).astype(np.float32) * 0.5)
+            for _ in range(2))
+    kw = {"block_q": 128, "block_k": 128}
+    np.testing.assert_allclose(
+        flash_attention(q, k, v, is_causal=True, **kw),
+        reference_attention(q, k, v, is_causal=True), atol=2e-5)
+    err = _grad_err(
+        lambda *a: jnp.sum(flash_attention(*a, is_causal=True, **kw) ** 2),
+        lambda *a: jnp.sum(reference_attention(*a, is_causal=True) ** 2),
+        (q, k, v))
+    assert err <= 5e-4
+
+
+#: (S, D, spec) -> the blocks and heads per cell an untuned call runs at
+RESOLVED = [
+    # the two LM cells (kanana's latent attention at 256 lanes, Ouro): the
+    # parent's blocks, four heads a cell where the 8 MiB budget held 1 and 2
+    (8192, 192, fa._SOFTMAX, (512, 512), 4),
+    (4096, 128, fa._SOFTMAX, (512, 512), 4),
+    # a non-causal length over the single-tile rule (the parent: 2 heads)
+    (2048, 64, fa._SOFTMAX, (512, 512), 4),
+    (2048, 64, fa.VariantSpec(has_mask=True), (512, 512), 4),
+    # `_pick_block`'s first rule: never a block that pads the sequence
+    # further
+    (1153, 64, fa._SOFTMAX, (256, 256), 8),
+    (1280, 64, fa._SOFTMAX, (256, 256), 8),
+    (1536, 128, fa._SOFTMAX, (512, 512), 4),
+    # the bias variant: its fp32 bias and dbias tiles are in the model
+    (577, 64, fa.VariantSpec(has_bias=True), (128, 128), 8),
+    (4096, 64, fa.VariantSpec(has_bias=True), (512, 512), 2),
+    (2048, 128, fa.VariantSpec(has_bias=True), (512, 512), 2),
+]
+
+
+@pytest.mark.parametrize("s,d,spec,blocks,hb", RESOLVED)
+def test_resolved_blocks_and_heads(s, d, spec, blocks, hb):
+    """Blocks and heads per cell are a function of the call's shapes and
+    variant: the blocks are the parent's, the heads follow the VMEM model
+    under the budget the call states."""
+    dp = fa._head_pad_target(d)
+    assert fa._fit_blocks(s, s, dp, 2, spec, fa.DEFAULT_BLOCK_Q,
+                          fa.DEFAULT_BLOCK_K) == blocks
+    assert fa._pick_hb(32, *blocks, dp, spec, 16) == hb
+    assert hb * fa._spec_vmem_bytes(*blocks, dp, spec) <= fa._VMEM_BUDGET
+    limit = fa._tiled_vmem_limit(hb, *blocks, dp, spec)
+    assert fa._VMEM_BUDGET <= limit <= 64 * 1024 * 1024
+    # an asked-for or tuned block wins up to 512, as it always has
+    assert fa._fit_blocks(8192, 4096, dp, 2, spec, 2048, 256,
+                          requested=True) == (512, 256)
+
+
+def test_single_tile_and_int8_blocks_are_the_parents():
+    """577 tokens are one resident tile of 640; the int8 kernels keep their
+    own default of 512 and budget."""
+    from jimm_tpu.ops import flash_attention_int8 as fi
+    assert fa._fit_blocks(577, 577, 64, 2, fa._SOFTMAX, 512, 512) \
+        == (640, 640)
+    assert (fi.DEFAULT_BLOCK_Q, fi.DEFAULT_BLOCK_K) == (512, 512)
+    assert fi._VMEM_BUDGET == 8 * 1024 * 1024
+    for s, want in ((577, 128), (1153, 256), (1280, 256), (2048, 512),
+                    (4096, 512), (8192, 512)):
+        assert fa._pick_block(s, fi.DEFAULT_BLOCK_Q) == want
+    assert fi._pick_hb(32, 512, 512, 128) == 2
+    assert fi._pick_hb(32, 512, 512, 128, fi._per_head_bwd_vmem_bytes) == 1
+
+
+@pytest.mark.parametrize("shape,causal,steps", [
+    # kanana's attention call: 16 cells of 4 heads x 136 live blocks of 512
+    # x 3 kernels (the parent: 64 x 16 x 16 x 3 = 49,152 steps, 26,112 live)
+    ((2, 8192, 32, 192), True, 16 * 136 * 3),
+    ((1, 4096, 16, 128), True, 4 * 36 * 3),     # the parent: 8 x 8 x 8 x 3
+    ((2, 2048, 16, 64), False, 8 * 4 * 4 * 3),  # the rectangle, all live
+])
+def test_tiled_step_counters(shape, causal, steps):
+    """`jimm_flash_tiled_grid_steps_total` / `_live_steps_total`: the grid
+    steps one execution of each built call takes, and those that compute.
+    A causal grid holds no other."""
+    spec = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    got = _calls(jax.grad(lambda *a: jnp.sum(flash_attention(
+        *a, is_causal=causal).astype(jnp.float32)), argnums=(0, 1, 2)),
+        spec, spec, spec,
+        regimes=("tiled", "tiled_grid_steps", "tiled_live_steps"))
+    assert got == (3, steps, steps)
+    after = snapshot()
+    assert "jimm_flash_tiled_grid_steps_total" in after
+    assert "jimm_flash_tiled_live_steps_total" in after
+
+
+def test_dead_pair_counts_as_a_step_that_does_not_compute():
+    """S_k over S_q under causal: dk/dv keep one pair a kv column that no
+    query reaches (so its blocks are written); it is a grid step and not a
+    live one."""
+    q = jax.ShapeDtypeStruct((1, 256, 1, 64), jnp.float32)
+    k = jax.ShapeDtypeStruct((1, 640, 1, 64), jnp.float32)
+    got = _calls(jax.grad(lambda *a: jnp.sum(flash_attention(
+        *a, is_causal=True, block_q=128, block_k=128)), argnums=(0, 1, 2)),
+        q, k, k, regimes=("tiled_grid_steps", "tiled_live_steps"))
+    assert got == (3 + 3 + 6, 3 + 3 + 3)
 
 
 def test_tiled_kernels_keep_v_at_its_own_tile(monkeypatch):

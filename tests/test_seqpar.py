@@ -477,14 +477,14 @@ class TestRingTune:
                         fa._per_head_vmem_bytes(bq, bk, d, has_mask=True)
 
     def test_ring_space_keys_on_local_chunks(self):
-        from jimm_tpu.tune.space import VMEM_BUDGET, ring_space, \
+        from jimm_tpu.tune.space import FLASH_VMEM_BUDGET, ring_space, \
             ring_vmem_bytes
         local = (4, 512, 8, 64)  # (B, S/p, N, D)
         cands = ring_space((local, local, local))
         assert cands, "no feasible ring hop configs for a 512-token chunk"
         for c in cands:
             assert ring_vmem_bytes(c["block_q"], c["block_k"], 64) \
-                <= VMEM_BUDGET
+                <= FLASH_VMEM_BUDGET
 
     def test_best_config_resolves_ring_default(self):
         from jimm_tpu.tune import best_config

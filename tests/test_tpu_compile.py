@@ -89,6 +89,27 @@ KERNEL_CASES = {
         functools.partial(fa.flash_attention, is_causal=True),
         [((2, 8192, 32, 192), jnp.bfloat16)] * 2
         + [((2, 8192, 32, 128), jnp.bfloat16)]),
+    # a non-causal length over the rule: four heads of 64 a cell at blocks of
+    # 512 under the VMEM limit the call states
+    "flash_s2048_d64": _flash((2, 2048, 16, 64)),
+    # the bias variant over the rule, blocks of 512 at two heads a cell: the
+    # fourth call, dbias, holds the fp32 bias tile, its output and its
+    # scratch beside the score tiles (16.9 MB by the model, over the default
+    # 16 MiB scope) and states its limit like the other three
+    "flash_bias_s2048_d64": (
+        fa.flash_attention_bias,
+        [((2, 2048, 16, 64), jnp.bfloat16)] * 3
+        + [((16, 2048, 2048), jnp.float32)]),
+    # causal at 256 lanes: Mosaic refuses this dbias call in its default scope
+    "flash_bias_causal_s2048_d256": (
+        functools.partial(fa.flash_attention_bias, is_causal=True),
+        [((2, 2048, 16, 256), jnp.bfloat16)] * 3
+        + [((16, 2048, 2048), jnp.float32)]),
+    # the longest causal length compiled: 256 blocks of 512 a side, 32,896
+    # live pairs, so 263 KB of scalar-prefetched tables in SMEM
+    "flash_causal_s131072_d128": (
+        functools.partial(fa.flash_attention, is_causal=True),
+        [((1, 131072, 4, 128), jnp.bfloat16)] * 3),
     "flash_masked_s577_d64": (
         fa.flash_attention_masked,
         [((32, 577, 16, 64), jnp.bfloat16)] * 3 + [((32, 577), jnp.bool_)]),
@@ -114,7 +135,10 @@ SINGLE_TILE_CALLS = {"flash_s577_d64": 2, "flash_s729_d72": 2,
                      "flash_masked_s577_d64": 2, "flash_s1152_d128": 2,
                      "flash_s197_d64_whole_row": 2,
                      "flash_s1153_d64": 3, "flash_causal_s4096_d128": 3,
-                     "flash_causal_s8192_qk192_v128": 3}
+                     "flash_causal_s8192_qk192_v128": 3,
+                     "flash_s2048_d64": 3, "flash_bias_s2048_d64": 4,
+                     "flash_bias_causal_s2048_d256": 4,
+                     "flash_causal_s131072_d128": 3}
 
 
 @pytest.mark.parametrize("case", sorted(KERNEL_CASES))

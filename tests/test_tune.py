@@ -227,13 +227,40 @@ class TestSpace:
         # kernel's working-set formula changes, this fails and space.py
         # follows
         from jimm_tpu.ops import flash_attention as fa
-        from jimm_tpu.tune.space import VMEM_BUDGET, flash_vmem_bytes
-        assert VMEM_BUDGET == fa._VMEM_BUDGET
-        for bq in (128, 256, 512):
-            for bk in (128, 256, 512):
-                for d in (64, 128):
+        from jimm_tpu.tune.space import (FLASH_BLOCKS, FLASH_VMEM_BUDGET,
+                                         flash_vmem_bytes)
+        assert FLASH_VMEM_BUDGET == fa._VMEM_BUDGET
+        # every block `_pick_block` can hand out is a candidate, and no other
+        assert {fa._pick_block(1 << 20, b) for b in FLASH_BLOCKS} \
+            == set(FLASH_BLOCKS)
+        assert fa._pick_block(1 << 20, 1 << 20) == max(FLASH_BLOCKS)
+        assert fa.DEFAULT_BLOCK_Q in FLASH_BLOCKS
+        for bq in FLASH_BLOCKS:
+            for bk in FLASH_BLOCKS:
+                for d in (64, 128, 256):
                     assert flash_vmem_bytes(bq, bk, d) == \
                         fa._per_head_vmem_bytes(bq, bk, d)
+        # the backward's four fp32 score tiles and two MXU copies: 5 MB
+        assert flash_vmem_bytes(512, 512, 128) \
+            - flash_vmem_bytes(512, 0, 128) - 3 * 512 * 128 * 2 \
+            == 20 * 512 * 512
+
+    def test_flash_space_offers_what_the_kernel_resolves(self):
+        """The blocks an untuned call runs at are a point of the tuner's
+        space for the same shapes; nothing over 512 is offered, and a
+        request over it is fitted down as it always was."""
+        from jimm_tpu.ops import flash_attention as fa
+        for shape, d in (((2, 8192, 32, 192), 256), ((1, 4096, 16, 128), 128),
+                         ((2, 2048, 16, 64), 64), ((1, 1280, 12, 64), 64)):
+            space = kernel_space("flash_attention", (shape[:3] + (d,),) * 3,
+                                 ("bfloat16",) * 3)
+            bq, bk = fa._fit_blocks(shape[1], shape[1], d, 2, fa._SOFTMAX,
+                                    fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K)
+            assert {"block_q": bq, "block_k": bk} in space
+            assert max(c["block_q"] for c in space) == 512
+            assert len(space) == 9
+        assert fa._fit_blocks(8192, 8192, 128, 2, fa._SOFTMAX, 2048, 1024,
+                              requested=True) == (512, 512)
 
     def test_ln_space_clamps_to_row_count(self):
         cands = kernel_space("layer_norm", ((16, 128),), ("float32",))
