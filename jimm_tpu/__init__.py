@@ -25,6 +25,7 @@ _LAZY = {
     "CLIP": "jimm_tpu.models",
     "Ouro": "jimm_tpu.models",
     "Kanana": "jimm_tpu.models.kanana",
+    "Trinity": "jimm_tpu.models.trinity",
     "SigLIP": "jimm_tpu.models",
     "VisionTransformer": "jimm_tpu.models",
     "CLIPConfig": "jimm_tpu.configs",
@@ -32,6 +33,7 @@ _LAZY = {
     "ViTConfig": "jimm_tpu.configs",
     "OuroConfig": "jimm_tpu.configs",
     "KananaConfig": "jimm_tpu.configs",
+    "TrinityConfig": "jimm_tpu.configs",
     "MoEDecoderConfig": "jimm_tpu.configs",
     "DecoderConfig": "jimm_tpu.configs",
     "VisionConfig": "jimm_tpu.configs",
@@ -44,8 +46,9 @@ _LAZY = {
 }
 
 __all__ = [
-    "CLIP", "SigLIP", "VisionTransformer", "Ouro", "Kanana",
-    "OuroConfig", "DecoderConfig", "KananaConfig", "MoEDecoderConfig",
+    "CLIP", "SigLIP", "VisionTransformer", "Ouro", "Kanana", "Trinity",
+    "OuroConfig", "DecoderConfig", "KananaConfig", "TrinityConfig",
+    "MoEDecoderConfig",
     "CLIPConfig", "SigLIPConfig", "ViTConfig", "VisionConfig", "TextConfig",
     "TransformerConfig", "PRESETS", "preset",
     "RUNTIME_FIELDS", "with_runtime",

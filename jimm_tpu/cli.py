@@ -102,7 +102,7 @@ def _parse_mesh(spec: str | None, max_devices: int | None = None):
 
 #: model family (the prefix of its presets' names) -> its class in `jimm_tpu`
 _FAMILIES = {"vit": "VisionTransformer", "clip": "CLIP", "siglip": "SigLIP",
-             "ouro": "Ouro", "kanana": "Kanana"}
+             "ouro": "Ouro", "kanana": "Kanana", "trinity": "Trinity"}
 
 
 def _lm_counters(cfg: Any, batch_size: int) -> list[tuple[str, str, Any]]:
@@ -133,7 +133,8 @@ def _lm_counters(cfg: Any, batch_size: int) -> list[tuple[str, str, Any]]:
 #: 1e-3: 20 Adam steps of that size move a 2048-wide decoder's every weight
 #: by about its own spread, routers included)
 LM_FAMILIES = {"ouro": {"lr": 1e-4, "warmup_steps": 20},
-               "kanana": {"lr": 1e-4, "warmup_steps": 20}}
+               "kanana": {"lr": 1e-4, "warmup_steps": 20},
+               "trinity": {"lr": 1e-4, "warmup_steps": 20}}
 
 
 def _family(preset_name: str) -> str:
@@ -305,7 +306,8 @@ def _restore_run(args: argparse.Namespace):
 def _tiny_override(cfg: Any) -> Any:
     """Shrink any preset to CPU-demo size, keeping its architecture class."""
     from jimm_tpu.configs import (CLIPConfig, KananaConfig, MLAConfig,
-                                  OuroConfig, SigLIPConfig, ViTConfig)
+                                  OuroConfig, SigLIPConfig, TrinityConfig,
+                                  ViTConfig)
 
     # depth 4 (not 2) so tiny runs can still exercise pipeline stages x
     # virtual-chunk splits (depth % (stages * virtual) == 0 for 2x2)
@@ -336,6 +338,17 @@ def _tiny_override(cfg: Any) -> Any:
             mla=MLAConfig(kv_lora_rank=32, qk_nope_dim=16, qk_rope_dim=8,
                           v_head_dim=16),
             moe=dataclasses.replace(cfg.decoder.moe, num_experts=16, top_k=2,
+                                    expert_dim=48, held_experts=4)))
+    if isinstance(cfg, TrinityConfig):
+        # layers 5-8 of the pattern: one dense layer (windowed) and three
+        # sparse ones (windowed, full, windowed); 4 query heads over 2
+        # key/value heads, a window of 8 under 32 tokens, 4 of 8 experts held
+        return dataclasses.replace(cfg, decoder=dataclasses.replace(
+            cfg.decoder, vocab_size=512, seq_len=32, width=64, depth=4,
+            num_heads=4, mlp_dim=176,
+            gqa=dataclasses.replace(cfg.decoder.gqa, head_dim=32, kv_heads=2,
+                                    window=8),
+            moe=dataclasses.replace(cfg.decoder.moe, num_experts=8, top_k=2,
                                     expert_dim=48, held_experts=4)))
     raise TypeError(type(cfg))
 
@@ -516,7 +529,8 @@ def train(args: argparse.Namespace) -> Any:
         cfg = _replace_towers(cfg, **lm)
     elif args.num_layers or args.seq_len:
         raise SystemExit("--num-layers and --seq-len shape a language model "
-                         "(an ouro preset, a kanana preset)")
+                         "(an ouro preset, a kanana preset, a trinity "
+                         "preset)")
 
     mesh = _parse_mesh(args.mesh, max_devices=args.max_devices)
     import jax

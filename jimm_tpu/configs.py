@@ -174,6 +174,34 @@ class MoEConfig:
 
 
 @dataclass(frozen=True)
+class GQAConfig:
+    """Grouped-query attention with heads of a width of their own:
+    ``num_heads`` query heads of ``head_dim`` read ``kv_heads`` key/value
+    heads; ``qk_norm``: an RMSNorm over each head of q and of k (one learned
+    ``head_dim``-vector each, shared by the heads); ``gate``: the output is
+    multiplied by ``sigmoid(x W_gate)`` before the output projection.
+    ``window``: a layer sees the newest ``window`` keys of each query (its own
+    counted) and takes the stack's rotary; every ``full_every``-th layer of
+    the model, counted from ``first_layer`` (this stack's first layer's index
+    in the whole model), sees everything to its left and takes NO position
+    signal. ``full_every`` 0: every layer is windowed."""
+
+    head_dim: int = 128
+    kv_heads: int = 8
+    qk_norm: bool = True
+    gate: bool = True
+    window: int | None = 4096
+    full_every: int = 4
+    first_layer: int = 0
+
+    def full_layers(self, depth: int) -> tuple[bool, ...]:
+        """Which of a stack's ``depth`` layers are full-attention layers."""
+        return tuple(bool(self.full_every)
+                     and (self.first_layer + i + 1) % self.full_every == 0
+                     for i in range(depth))
+
+
+@dataclass(frozen=True)
 class TransformerConfig:
     """Shared encoder-stack hyperparameters (vision or text tower)."""
 
@@ -232,6 +260,9 @@ class TransformerConfig:
     #: a second norm on each sub-layer's OUTPUT, before the residual add
     #: ("sandwich"): ``x + norm(attn(norm(x)))``
     post_norm: bool = False
+    #: what the scales of those second norms start at (the first norms' start
+    #: at 1): a depth-scaled sandwich gives 1 / sqrt(layers of the model)
+    post_norm_gain: float = 1.0
     #: rotary positions on q and k with this base, the rotate-half pairing
     #: (i, i + head_dim/2) over the whole head; None = positions come from
     #: the embedding
@@ -252,9 +283,15 @@ class TransformerConfig:
     #: a sparse expert layer (`nn/moe.py`) in place of `Mlp`; the block then
     #: returns its routing choices beside the carry
     moe: MoEConfig | None = None
+    #: grouped-query attention in `Attention` (heads of their own width,
+    #: fewer key/value heads, qk-norm, an output gate, windowed layers beside
+    #: full ones); None = ``num_heads`` heads of ``width / num_heads`` each way
+    gqa: GQAConfig | None = None
 
     @property
     def head_dim(self) -> int:
+        if self.gqa is not None:
+            return self.gqa.head_dim
         return self.width // self.num_heads
 
     @property
@@ -418,10 +455,14 @@ class DecoderConfig:
 
 @dataclass(frozen=True)
 class MoEDecoderConfig:
-    """Causal decoder stack with latent attention in every layer, a dense
-    SwiGLU in the first ``dense_layers`` and a sparse expert layer (`MoEConfig`)
-    in the rest: pre-norm RMS blocks, rotary on the rotary dims alone, no
-    biases. ``depth`` counts both kinds."""
+    """Causal decoder stack with latent attention (``mla``) or grouped-query
+    attention (``gqa``) in every layer, a dense SwiGLU in the first
+    ``dense_layers`` and a sparse expert layer (`MoEConfig`) in the rest:
+    pre-norm RMS blocks (``post_norm``: a second norm on each sub-layer's
+    output, its scale starting at ``post_norm_gain``), rotary on the rotary
+    dims alone, no biases. ``depth`` counts both
+    kinds; ``first_layer`` is the index, in the whole published model, of the
+    first layer held here (`GQAConfig.full_layers` counts from it)."""
 
     vocab_size: int = 16032
     seq_len: int = 8192
@@ -433,8 +474,12 @@ class MoEDecoderConfig:
     act: Activation = "silu"
     ln_eps: float = 1e-6
     rope_theta: float = 1e6
-    mla: MLAConfig = field(default_factory=MLAConfig)
+    mla: MLAConfig | None = field(default_factory=MLAConfig)
     moe: MoEConfig = field(default_factory=lambda: MoEConfig(held_experts=16))
+    gqa: GQAConfig | None = None
+    post_norm: bool = False
+    post_norm_gain: float = 1.0
+    first_layer: int = 0
     # runtime fields, as `DecoderConfig` has them
     dropout: float = 0.0
     attn_impl: AttnImpl = "auto"
@@ -443,8 +488,20 @@ class MoEDecoderConfig:
     scan_unroll: int = 1
     precision: Precision = "bf16"
 
+    @property
+    def full_layers(self) -> tuple[bool, ...]:
+        """Which of the ``depth`` held layers are full-attention layers
+        (none without ``gqa``)."""
+        if self.gqa is None:
+            return (False,) * self.depth
+        return dataclasses.replace(
+            self.gqa, first_layer=self.first_layer).full_layers(self.depth)
+
     def encoder(self, *, sparse: bool) -> TransformerConfig:
         """The dense stack's block, or the sparse stack's."""
+        gqa = self.gqa and dataclasses.replace(
+            self.gqa, first_layer=self.first_layer
+            + (self.dense_layers if sparse else 0))
         return TransformerConfig(
             width=self.width,
             depth=(self.depth - self.dense_layers if sparse
@@ -455,7 +512,8 @@ class MoEDecoderConfig:
             remat_policy=self.remat_policy, scan_unroll=self.scan_unroll,
             precision=self.precision, norm="rms", rope_theta=self.rope_theta,
             gated_mlp=True, use_bias=False, mla=self.mla,
-            moe=self.moe if sparse else None,
+            moe=self.moe if sparse else None, gqa=gqa,
+            post_norm=self.post_norm, post_norm_gain=self.post_norm_gain,
         )
 
 
@@ -526,6 +584,31 @@ class KananaConfig:
     auxiliary-loss-free balancing of the DeepSeek-V3 paper)."""
 
     decoder: MoEDecoderConfig = field(default_factory=MoEDecoderConfig)
+    bias_update_rate: float = 1e-3
+
+
+def _trinity_decoder() -> MoEDecoderConfig:
+    return MoEDecoderConfig(
+        vocab_size=25024, seq_len=8192, width=3072, depth=55, dense_layers=1,
+        first_layer=5, num_heads=48, mlp_dim=12288, ln_eps=1e-5,
+        rope_theta=1e4, mla=None, gqa=GQAConfig(), post_norm=True,
+        post_norm_gain=60 ** -0.5,  # depth-scaled: 60 published layers
+        moe=MoEConfig(num_experts=256, top_k=4, expert_dim=3072,
+                      shared_experts=1, routed_scale=2.448, held_experts=8))
+
+
+@dataclass(frozen=True)
+class TrinityConfig:
+    """Trinity-Large-Preview (arcee-ai, ``model_type`` afmoe) from its last
+    dense layer on (published layers 5-59: one dense layer, then the sparse
+    ones), as one chip of a 32-way expert-parallel group holds it (8 of 256
+    routed experts a layer, an eighth of the vocabulary): grouped-query
+    attention with qk-norm and an output gate, a 4096-token window with rotary
+    on three layers of four and position-free full attention on the fourth,
+    sandwich RMSNorms, the embedding scaled by ``sqrt(width)``. Trained like
+    `KananaConfig`'s model; ``bias_update_rate`` as there."""
+
+    decoder: MoEDecoderConfig = field(default_factory=_trinity_decoder)
     bias_update_rate: float = 1e-3
 
 
@@ -633,6 +716,11 @@ PRESETS: dict[str, Any] = {
     # two shared; the preset is ONE chip's share of an eight-way
     # expert-parallel layer (experts 0-15, an eighth of the vocabulary)
     "kanana-2-30b-a3b": KananaConfig(),
+    # Trinity-Large-Preview: grouped-query attention (48 heads over 8), a
+    # window on three layers of four, 256 experts top-4 and one shared; the
+    # preset is ONE chip's share of 32-way expert parallelism from the last
+    # dense layer on (experts 0-7, an eighth of the vocabulary)
+    "trinity-large": TrinityConfig(),
 }
 
 

@@ -16,7 +16,7 @@ exchange, and nothing stands in for it.
 No assignment is dropped. The (token, slot) assignments are sorted by held
 expert (the others sort to the end), and the held ones go through the three
 grouped products in chunks of ``chunk_rows`` rows, four thirds of the expected
-load ``tokens * top_k * held / num_experts``: the first chunk always runs, as
+load ``tokens * top_k * held / num_experts`` and no fewer than 4096: the first chunk always runs, as
 straight-line code; the later ones sit behind `_overflow`, a loop that runs
 only the chunks that start before the last held assignment and, with a
 derivative rule of its own, costs nothing forward or backward while the first
@@ -33,6 +33,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from flax import nnx
+from jax.ad_checkpoint import checkpoint_name
 
 from jimm_tpu.configs import TransformerConfig
 from jimm_tpu.ops.activations import get_activation
@@ -44,12 +45,33 @@ class RouterBias(nnx.Variable):
     `Kanana.update_router_bias` moves it after each step."""
 
 
+#: a chunk's floor. A third over the expected load holds a large load (16,384
+#: rows hold 12,288 expected ones whatever the seed), not a small one: a router
+#: at its random start sent 400-2,300 assignments a layer to 8 held experts
+#: that expect 1,024, and every step that ran a second chunk was 3 % longer,
+#: which spread five seeds' throughput by 3.6 % (PERF.md, PR 34). A chunk's
+#: rows cost a gather, two masks and a scatter; its matmul tiles follow the
+#: actual counts, so room costs little
+_MIN_CHUNK_ROWS = 4096
+
+
 def chunk_rows(tokens: int, top_k: int, held: int, num_experts: int) -> int:
-    """Rows of one chunk of the grouped products: 4/3 of the expected load,
-    a multiple of 512, at most every assignment."""
+    """Rows of one chunk of the grouped products: 4/3 of the expected load
+    and no fewer than `_MIN_CHUNK_ROWS`, a multiple of 512, at most every
+    assignment."""
     expected = tokens * top_k * held / num_experts
-    rows = math.ceil(expected * 4 / 3 / 512) * 512
+    rows = math.ceil(max(expected * 4 / 3, _MIN_CHUNK_ROWS) / 512) * 512
     return min(rows, tokens * top_k)
+
+
+def row_tile(tokens: int, top_k: int, num_experts: int) -> int:
+    """The grouped products' tile of rows: every group's rows end in a tile
+    of their own, so no tile larger than a group is expected to be, between
+    the MXU's 128 and `_GMM_TILING`'s 512."""
+    tile = _GMM_TILING[0]
+    while tile > 128 and tile > tokens * top_k // num_experts:
+        tile //= 2
+    return tile
 
 
 def routing_counts(chosen: jax.Array, num_experts: int) -> jax.Array:
@@ -64,12 +86,35 @@ def routing_counts(chosen: jax.Array, num_experts: int) -> jax.Array:
 #: megablox tile sizes (rows, contraction, columns), the fastest of five
 #: tried on the v5e at (16384, 2048) x (16, 2048, 768) and back: 4.67 ms for
 #: the three products forward and backward, `jax.lax.ragged_dot` (XLA's own
-#: Mosaic kernels) 6.12, the default 128^3 tiling 36.7 (PERF.md, PR 32)
+#: Mosaic kernels) 6.12, the default 128^3 tiling 36.7 (PERF.md, PR 32). The
+#: tile of rows follows the groups (`row_tile`): every group's rows end
+#: in a tile of their own, so 8 groups of 128 rows each of (1536, 3072) x
+#: (8, 3072, 3072) take 4.36 ms in tiles of 512 rows, 2.58 at 256 and 2.16 at
+#: 128, where reading the weights alone takes 1.66 (PERF.md, PR 34)
 _GMM_TILING = (512, 1024, 768)
 
+#: where the groups are as small as the MXU's 128 rows the products are bound
+#: by reading the experts' weights, and a group larger than expected reads
+#: them once more for each further tile of rows: with the whole contraction
+#: in one tile (up to 3072) an expert's weights stay in VMEM over its row
+#: tiles. One layer's three products forward and backward over 8 experts of
+#: 3072 x 3072, at 1,024 | 1,660 | 3,300 rows: 2.73 | 4.1-4.3 | 5.83 ms
+#: against `_GMM_TILING`'s contraction and columns 2.75 | 5.2 | 7.97, so a
+#: row costs 1.4 us instead of 2.3 (PERF.md, PR 34)
+_SMALL_GROUP_TILING = (128, 3072, 512)
 
-def grouped_matmul(lhs: jax.Array, rhs: jax.Array, sizes: jax.Array
-                   ) -> jax.Array:
+
+def tiling(tile_m: int, itemsize: int) -> tuple[int, int, int]:
+    """The grouped products' tiles for rows in tiles of ``tile_m``."""
+    tile_k, tile_n = (_SMALL_GROUP_TILING if tile_m == _SMALL_GROUP_TILING[0]
+                      else _GMM_TILING)[1:]
+    if itemsize > 2:  # float32 tiles of that size overflow VMEM
+        tile_k //= 2
+    return tile_m, tile_k, tile_n
+
+
+def grouped_matmul(lhs: jax.Array, rhs: jax.Array, sizes: jax.Array,
+                   tile_m: int | None = None) -> jax.Array:
     """``lhs[rows of group g] @ rhs[g]`` for rows sorted by group (megablox
     ``gmm``: its tiles follow the groups' actual sizes). The rows past the
     last group form one more group that ``rhs`` has no weights for, which the
@@ -78,11 +123,10 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, sizes: jax.Array
     from jax.experimental.pallas.ops.tpu.megablox import gmm
     rows = lhs.shape[0]
     rest = rows - jnp.sum(sizes, keepdims=True)
-    tile_m, tile_k, tile_n = _GMM_TILING
+    tile_m, tile_k, tile_n = tiling(tile_m or _GMM_TILING[0],
+                                    lhs.dtype.itemsize)
     if rows % tile_m:
         tile_m = rows
-    if lhs.dtype.itemsize > 2:  # float32 tiles of that size overflow VMEM
-        tile_k //= 2
     return gmm(lhs, rhs, jnp.concatenate([sizes, rest]).astype(jnp.int32),
                lhs.dtype, (tile_m, tile_k, tile_n),
                interpret=jax.default_backend() != "tpu")
@@ -128,6 +172,11 @@ class SparseMoe(nnx.Module):
             precision=jax.lax.Precision.HIGHEST))
         _, chosen = jax.lax.top_k(
             scores + jax.lax.stop_gradient(self.router_bias[...]), m.top_k)
+        # kept under every remat policy (`Transformer._remat_policy`): a
+        # recomputed forward rounds its own way and breaks the router's
+        # near-ties differently (1 % of the choices), and the backward would
+        # then run other routes than the forward whose loss it differentiates
+        chosen = checkpoint_name(chosen, "moe_chosen")
         picked = jnp.take_along_axis(scores, chosen, axis=-1)
         weights = m.routed_scale * picked / (
             jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
@@ -153,7 +202,8 @@ class SparseMoe(nnx.Module):
                 order = jnp.pad(order, (0, -order.shape[0] % rows))
                 ends = jnp.cumsum(jax.lax.dynamic_slice_in_dim(
                     counts, m.first_expert, m.held_experts))
-            static = (m.top_k, self.act, rows)
+            static = (m.top_k, self.act, rows,
+                      row_tile(tokens, m.top_k, m.num_experts))
             operands = (order, ends, xt, weights.reshape(-1),
                         *(p[...].astype(dtype)
                           for p in (self.gate, self.up, self.down)))
@@ -172,7 +222,7 @@ def _chunk(static, lo, order, ends, xt, flat_weights, gate, up, down):
     """What the held assignments ``lo .. lo + rows`` of the sorted ``order``
     add to the layer's result, ``(tokens, width)`` float32: gather, the three
     grouped products, weighted scatter."""
-    top_k, act, rows = static
+    top_k, act, rows, tile_m = static
     with jax.named_scope("moe_route"):
         sel = jax.lax.dynamic_slice_in_dim(order, lo, rows)
         tok = sel // top_k
@@ -183,9 +233,9 @@ def _chunk(static, lo, order, ends, xt, flat_weights, gate, up, down):
         # of no group, forward or backward, must reach no token
         xs = jnp.where(live[:, None], xt[tok], 0)
     with jax.named_scope("moe_experts"):
-        h = act(grouped_matmul(xs, gate, sizes)) \
-            * grouped_matmul(xs, up, sizes)
-        ys = grouped_matmul(h, down, sizes)
+        h = act(grouped_matmul(xs, gate, sizes, tile_m)) \
+            * grouped_matmul(xs, up, sizes, tile_m)
+        ys = grouped_matmul(h, down, sizes, tile_m)
     with jax.named_scope("moe_route"):
         w = jnp.where(live, flat_weights[sel], 0.0)
         ys = jnp.where(live[:, None], ys.astype(jnp.float32) * w[:, None], 0.0)
@@ -223,12 +273,48 @@ def _overflow_fwd(static, order, ends, *operands):
     return _overflow(static, order, ends, *operands), (order, ends, operands)
 
 
+#: `_overflow`'s backward differentiates `_later_chunks` as it stands while
+#: the copies of the expert weights that keeps, one a later chunk (the loop's
+#: residuals: a `cond` inside a `scan` hands its operands on per iteration),
+#: stay under this; over it, it walks the chunks itself (`_later_chunks_bwd`).
+#: XLA plans a branch's buffers whether or not the branch ever runs: 16
+#: experts of 2048 x 768 in 5 later chunks are 0.75 GB of plan, 8 of 3072 x
+#: 3072 in 21 are 9.1 GB, more than a 16 GB chip has left beside 1.6 B
+#: parameters' state (PERF.md, PR 34)
+_LOOP_RESIDUAL_LIMIT = 1 << 30
+
+
+def _later_chunks_bwd(static, order, ends, operands, g):
+    """The cotangents `_later_chunks` hands its ``operands`` for ``g``, one
+    chunk at a time: each chunk that ran is run again and differentiated
+    inside the loop, so nothing is kept from one chunk to the next but the
+    sums."""
+    rows = static[2]
+
+    def one(sums, lo):
+        def add():
+            grads = jax.vjp(lambda *ops: _chunk(static, lo, order, ends, *ops),
+                            *operands)[1](g)
+            return tuple(s + d for s, d in zip(sums, grads, strict=True))
+        return jax.lax.cond(lo < ends[-1], add, lambda: sums), None
+
+    return jax.lax.scan(one, tuple(jnp.zeros_like(o) for o in operands),
+                        jnp.arange(rows, order.shape[0], rows))[0]
+
+
 def _overflow_bwd(static, residuals, g):
     order, ends, operands = residuals
+    later = len(range(static[2], order.shape[0], static[2]))
+    kept = later * sum(o.size * o.dtype.itemsize for o in operands[2:])
+    if kept > _LOOP_RESIDUAL_LIMIT:
+        def ran():
+            return _later_chunks_bwd(static, order, ends, operands, g)
+    else:
+        def ran():
+            return jax.vjp(partial(_later_chunks, static, order, ends),
+                           *operands)[1](g)
     grads = jax.lax.cond(
-        ends[-1] > static[2],
-        lambda: jax.vjp(partial(_later_chunks, static, order, ends),
-                        *operands)[1](g),
+        ends[-1] > static[2], ran,
         lambda: tuple(jnp.zeros_like(o) for o in operands))
     return (None, None, *grads)
 

@@ -63,14 +63,16 @@ def _layernorm(dim: int, eps: float, rngs: nnx.Rngs, *, dtype: Dtype,
 
 
 def _norm(cfg: TransformerConfig, rngs: nnx.Rngs, *, dtype: Dtype,
-          param_dtype) -> nnx.Module:
+          param_dtype, gain: float = 1.0) -> nnx.Module:
     """The block's normalisation: LayerNorm, or RMSNorm for the decoder
-    family (``x / sqrt(mean(x^2) + eps) * w``, statistics in float32)."""
+    family (``x / sqrt(mean(x^2) + eps) * w``, statistics in float32; ``w``
+    starts at ``gain``)."""
     if cfg.norm == "rms":
+        start = (nnx.initializers.ones_init() if gain == 1.0
+                 else nnx.initializers.constant(gain))
         return nnx.RMSNorm(
             cfg.width, epsilon=cfg.ln_eps, dtype=dtype,
-            param_dtype=param_dtype,
-            scale_init=logical(nnx.initializers.ones_init(), "embed"),
+            param_dtype=param_dtype, scale_init=logical(start, "embed"),
             rngs=rngs)
     return _layernorm(cfg.width, cfg.ln_eps, rngs, dtype=dtype,
                       param_dtype=param_dtype, impl=cfg.ln_impl)
@@ -102,26 +104,53 @@ class Attention(nnx.Module):
     ``fused_qkv`` computes the three projections as one ``(H, 3H)`` matmul
     by concatenating the kernels at call time — parameters (and therefore
     checkpoints) stay separate, the concat is tiny next to the matmul, and
-    gradients flow back through the slice."""
+    gradients flow back through the slice.
+
+    With ``gqa`` (`GQAConfig`) the heads have a width of their own and k and
+    v fewer heads than q:
+
+        q = x W_q -> (B, S, N, D);  k, v = x W_k, x W_v -> (B, S, N_kv, D)
+        q, k = RMS_D(q), RMS_D(k)          one learned D-vector each (qk_norm)
+        a windowed layer: rotary on q and k, key j visible to query i iff
+            0 <= i - j < window;  a full layer: no rotary, every j <= i
+        out = (softmax(q k^T / sqrt(D)) v * sigmoid(x W_gate)) W_o   (gate)
+    """
 
     def __init__(self, width: int, num_heads: int, rngs: nnx.Rngs, *,
                  is_causal: bool = False, impl: str = "auto",
                  fused_qkv: bool = False, use_bias: bool = True,
+                 gqa=None, ln_eps: float = 1e-6,
                  dtype: Dtype = None, param_dtype=jnp.float32):
-        if width % num_heads:
+        if gqa is None and width % num_heads:
             raise ValueError(f"width {width} not divisible by heads {num_heads}")
+        if gqa is not None and (fused_qkv or not is_causal):
+            raise ValueError("grouped-query attention is causal and keeps "
+                             "its three projections apart")
         self.num_heads = num_heads
-        self.head_dim = width // num_heads
+        self.head_dim = gqa.head_dim if gqa else width // num_heads
+        self.kv_heads = gqa.kv_heads if gqa else num_heads
         self.is_causal = is_causal
         self.impl = impl
         self.fused_qkv = fused_qkv
         self.dtype = dtype
+        self.gqa = gqa
         lin = partial(_linear, use_bias=use_bias, dtype=dtype,
                       param_dtype=param_dtype)
-        self.q = lin(width, width, ("embed", "heads"), rngs)
-        self.k = lin(width, width, ("embed", "heads"), rngs)
-        self.v = lin(width, width, ("embed", "heads"), rngs)
-        self.out = lin(width, width, ("heads", "embed"), rngs)
+        inner, kv_inner = (n * self.head_dim
+                           for n in (num_heads, self.kv_heads))
+        self.q = lin(width, inner, ("embed", "heads"), rngs)
+        self.k = lin(width, kv_inner, ("embed", "heads"), rngs)
+        self.v = lin(width, kv_inner, ("embed", "heads"), rngs)
+        self.out = lin(inner, width, ("heads", "embed"), rngs)
+        if gqa is not None and gqa.gate:
+            self.gate = lin(width, inner, ("embed", "heads"), rngs)
+        if gqa is not None and gqa.qk_norm:
+            def head_norm():
+                return nnx.RMSNorm(
+                    self.head_dim, epsilon=ln_eps, dtype=dtype,
+                    param_dtype=param_dtype, scale_init=logical(
+                        nnx.initializers.ones_init(), None), rngs=rngs)
+            self.q_norm, self.k_norm = head_norm(), head_norm()
 
     def _project_qkv(self, x: jax.Array) -> tuple[jax.Array, ...]:
         w = jnp.concatenate([self.q.kernel[...], self.k.kernel[...],
@@ -134,10 +163,43 @@ class Attention(nnx.Module):
             qkv = qkv + b.astype(dtype)
         return tuple(jnp.split(qkv, 3, axis=-1))
 
+    def _grouped(self, x: jax.Array, mask, rope, full) -> jax.Array:
+        """The ``gqa`` forward; ``full``: this layer is a full-attention
+        layer (a Python bool, or a traced one where a stack mixes the kinds:
+        then both calls are built once and a `lax.cond` picks)."""
+        g = self.gqa
+        b, s, _ = x.shape
+        with jax.named_scope("attn"):
+            q = self.q(x).reshape(b, s, self.num_heads, self.head_dim)
+            k = self.k(x).reshape(b, s, self.kv_heads, self.head_dim)
+            v = self.v(x).reshape(b, s, self.kv_heads, self.head_dim)
+            if g.qk_norm:
+                q, k = self.q_norm(q), self.k_norm(k)
+
+            def attend(full: bool) -> jax.Array:
+                qr, kr = (q, k) if full or rope is None else (
+                    apply_rope(q, rope), apply_rope(k, rope))
+                with jax.named_scope("attn_full" if full else "attn_window"):
+                    return dot_product_attention(
+                        qr, kr, v, is_causal=True, mask=mask, impl=self.impl,
+                        window=None if full else g.window)
+
+            if isinstance(full, bool):
+                o = attend(full)
+            else:
+                o = jax.lax.cond(full, lambda: attend(True),
+                                 lambda: attend(False))
+            o = o.reshape(b, s, self.num_heads * self.head_dim)
+            if g.gate:
+                o = o * jax.nn.sigmoid(self.gate(x))
+            return self.out(o)
+
     def __call__(self, x: jax.Array, kv: jax.Array | None = None,
                  mask: jax.Array | None = None,
-                 rope: tuple[jax.Array, jax.Array] | None = None
-                 ) -> jax.Array:
+                 rope: tuple[jax.Array, jax.Array] | None = None,
+                 full: bool | jax.Array = False) -> jax.Array:
+        if self.gqa is not None:
+            return self._grouped(x, mask, rope, full)
         B, Sq, _ = x.shape
         if kv is None and self.fused_qkv:
             q, k, v = self._project_qkv(x)
@@ -203,8 +265,10 @@ class Block(nnx.Module):
             self.attn = Attention(cfg.width, cfg.num_heads, rngs,
                                   is_causal=cfg.causal, impl=cfg.attn_impl,
                                   fused_qkv=cfg.fused_qkv,
-                                  use_bias=cfg.use_bias,
+                                  use_bias=cfg.use_bias, gqa=cfg.gqa,
+                                  ln_eps=cfg.ln_eps,
                                   dtype=dtype, param_dtype=param_dtype)
+        self.layer_kinds = cfg.gqa is not None  # windowed or full, per call
         self.ln2 = norm()
         self.sparse = cfg.moe is not None
         if self.sparse:
@@ -218,19 +282,21 @@ class Block(nnx.Module):
         self.dropout = nnx.Dropout(cfg.dropout, rngs=rngs)
         self.post_norm = cfg.post_norm
         if cfg.post_norm:
-            self.ln1_post = norm()
-            self.ln2_post = norm()
+            self.ln1_post = norm(gain=cfg.post_norm_gain)
+            self.ln2_post = norm(gain=cfg.post_norm_gain)
 
     def __call__(self, x: jax.Array, mask: jax.Array | None = None,
-                 rope: tuple[jax.Array, jax.Array] | None = None
+                 rope: tuple[jax.Array, jax.Array] | None = None,
+                 full: bool | jax.Array = False
                  ) -> jax.Array | tuple[jax.Array, jax.Array]:
         """The block's output; a sparse block returns ``(output, the experts
-        each token chose)``."""
+        each token chose)``. ``full``: under `GQAConfig`, whether this layer
+        is a full-attention layer."""
         # ln outputs carry a checkpoint name so "+ln" remat policies can keep
         # them (skipping the LN recompute in the backward); plain identity
         # under every other policy
         a = self.attn(checkpoint_name(self.ln1(x), "ln_out"), mask=mask,
-                      rope=rope)
+                      rope=rope, **({"full": full} if self.layer_kinds else {}))
         x = x + self.dropout(self.ln1_post(a) if self.post_norm else a)
         m = self.mlp(checkpoint_name(self.ln2(x), "ln_out"))
         if self.sparse:
@@ -293,6 +359,11 @@ class Transformer(nnx.Module):
         from jimm_tpu.configs import remat_policy_parts
         policy = self.cfg.remat_policy
         if policy == "none":
+            # everything is recomputed, but a sparse layer's routing choices
+            # (`nn/moe.py::SparseMoe.route`): a top-k is not continuous
+            if self.cfg.moe is not None:
+                return jax.checkpoint_policies.save_only_these_names(
+                    "moe_chosen")
             return None
         parts = remat_policy_parts(policy)
         names = ["flash_o", "flash_lse"]
@@ -322,17 +393,31 @@ class Transformer(nnx.Module):
         pipeline stage's local slice). ``mask`` (bool, broadcastable to
         (B, N, Sq, Sk)) and the ``rope`` tables ride into every layer as
         closure captures — they are layer-invariant, so not scan carries."""
+        # a sparse block's routing choices come out stacked by layer
+        out_axes = (nnx.Carry, 0) if self.cfg.moe is not None else nnx.Carry
+        scanned = partial(nnx.scan, out_axes=out_axes,
+                          unroll=self.cfg.scan_unroll,
+                          transform_metadata={nnx.PARTITION_NAME: "layers"})
+        full = (self.cfg.gqa.full_layers(self.cfg.depth)
+                if self.cfg.gqa is not None else ())
+        if len(set(full)) > 1:
+            # windowed layers beside full ones in one stack: the layer's kind
+            # rides the scan beside its weights
+            def body(block: Block, x: jax.Array, full: jax.Array):
+                return block(x, mask=mask, rope=rope, full=full)
+
+            if self.cfg.remat:
+                body = nnx.remat(body, policy=self._remat_policy())
+            return scanned(body, in_axes=(0, nnx.Carry, 0))(
+                blocks, x, jnp.asarray(full))
+
         def body(block: Block, x: jax.Array) -> jax.Array:
-            return block(x, mask=mask, rope=rope)
+            return block(x, mask=mask, rope=rope,
+                         **({"full": full[0]} if full else {}))
 
         if self.cfg.remat:
             body = nnx.remat(body, policy=self._remat_policy())
-        # a sparse block's routing choices come out stacked by layer
-        out_axes = (nnx.Carry, 0) if self.cfg.moe is not None else nnx.Carry
-        scan = nnx.scan(body, in_axes=(0, nnx.Carry), out_axes=out_axes,
-                        unroll=self.cfg.scan_unroll,
-                        transform_metadata={nnx.PARTITION_NAME: "layers"})
-        return scan(blocks, x)
+        return scanned(body, in_axes=(0, nnx.Carry))(blocks, x)
 
     def _apply_loops(self, x: jax.Array, mask: jax.Array | None,
                      rope: tuple[jax.Array, jax.Array] | None) -> jax.Array:

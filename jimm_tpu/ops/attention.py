@@ -99,17 +99,44 @@ def _is_key_padding_mask(mask: jax.Array) -> bool:
     return mask.ndim == 4 and mask.shape[1] == 1 and mask.shape[2] == 1
 
 
+#: the paths that take grouped key/value heads and a causal window
+_GROUPED_WINDOW_IMPLS = ("auto", "flash", "xla", "einsum")
+
+
 def dot_product_attention(
     q: jax.Array,  # (B, Sq, N, D)
-    k: jax.Array,  # (B, Sk, N, D)
-    v: jax.Array,  # (B, Sk, N, D)
+    k: jax.Array,  # (B, Sk, N or N_kv, D)
+    v: jax.Array,  # (B, Sk, N or N_kv, D)
     *,
     is_causal: bool = False,
+    window: int | None = None,
     mask: jax.Array | None = None,  # broadcastable to (B, N, Sq, Sk), bool
     bias: jax.Array | None = None,  # additive logits bias
     impl: str = "auto",
 ) -> jax.Array:
-    """Scaled dot-product attention over (batch, seq, heads, head_dim)."""
+    """Scaled dot-product attention over (batch, seq, heads, head_dim).
+
+    **Grouped key/value heads**: k and v may have ``N_kv`` heads, a divisor
+    of q's ``N``; query head ``h`` then reads key/value head
+    ``h // (N / N_kv)``. The flash path fetches k and v at their own heads
+    (no repeated copy, forward or backward) and returns dk and dv at them.
+    **window** (with ``is_causal``): key ``j`` is visible to query ``i`` iff
+    ``0 <= i - j < window``, the query's own position counted. A window that
+    reaches over all ``Sk`` keys hides nothing and is dropped before dispatch,
+    so such a call IS the plain causal call (same kernels, same cache key).
+    Both run on the ``flash`` (a plain call: no mask, no bias), ``xla`` and
+    ``einsum`` paths and through ``auto``; every other ``impl`` refuses
+    them."""
+    from jimm_tpu.ops.flash_attention import kv_group, live_window
+    window = live_window(window, is_causal, k.shape[1])
+    grouped = kv_group(q, k, v) > 1
+    if (grouped or window is not None) and (
+            impl not in _GROUPED_WINDOW_IMPLS
+            or impl == "flash" and (mask is not None or bias is not None)):
+        raise ValueError(
+            f"impl={impl!r} takes neither grouped key/value heads nor a "
+            "window (with a mask or a bias: impl='xla'); use one of "
+            f"{_GROUPED_WINDOW_IMPLS}")
     if impl == "auto":
         # Sequence parallelism first: when the ambient mesh carries a live
         # seq axis the activations are (or are about to be) sharded along
@@ -117,7 +144,7 @@ def dot_product_attention(
         # full S — route to the seq-parallel schemes instead. Sq != Sk
         # (e.g. the MAP-pooling 1-row probe) or non-divisible lengths fall
         # through to the single-chip paths below.
-        sp = (None if bias is not None
+        sp = (None if bias is not None or grouped or window is not None
               or (mask is not None and not _is_key_padding_mask(mask))
               else _ambient_seq_axis())
         if (sp is not None and q.shape[1] == k.shape[1]
@@ -133,7 +160,7 @@ def dot_product_attention(
                 impl = "xla"
             elif mask is None:
                 impl = "flash"
-            elif _is_key_padding_mask(mask):
+            elif _is_key_padding_mask(mask) and not grouped and window is None:
                 impl = "flash_masked"
             else:
                 impl = "xla"
@@ -151,7 +178,8 @@ def dot_product_attention(
             impl = "flash_bias"
         else:
             from jimm_tpu.ops.flash_attention import flash_attention
-            return flash_attention(q, k, v, is_causal=is_causal)
+            return flash_attention(q, k, v, is_causal=is_causal,
+                                   window=window)
     if impl == "flash_masked":
         if bias is not None:
             raise ValueError("flash_masked does not take a bias; use "
@@ -214,20 +242,25 @@ def dot_product_attention(
                                       is_causal=is_causal, plan=impl)
     if impl == "xla":
         d_v = v.shape[-1]
+        # XLA's op takes grouped heads as they are; its window is (keys left
+        # of the query, keys right of it), the query's own not counted
+        local = {} if window is None else {
+            "local_window_size": (window - 1, 0)}
         if d_v != q.shape[-1]:
             # XLA's op wants one head width: zero columns of v give zero
             # columns of the output, cut off again
             v = jnp.pad(v, ((0, 0),) * 3 + ((0, q.shape[-1] - d_v),))
             return jax.nn.dot_product_attention(
-                q, k, v, bias=bias, mask=mask, is_causal=is_causal)[..., :d_v]
+                q, k, v, bias=bias, mask=mask, is_causal=is_causal,
+                **local)[..., :d_v]
         return jax.nn.dot_product_attention(q, k, v, bias=bias, mask=mask,
-                                            is_causal=is_causal)
+                                            is_causal=is_causal, **local)
     if impl == "saveable":
         return saveable_attention(q, k, v, is_causal=is_causal, mask=mask,
                                   bias=bias)
     if impl == "einsum":  # reference semantics, fp32 softmax; used in tests
         return reference_attention(q, k, v, is_causal=is_causal, mask=mask,
-                                   bias=bias)
+                                   bias=bias, window=window)
     raise ValueError(f"unknown attention impl {impl!r}")
 
 
@@ -256,10 +289,16 @@ def saveable_attention(q, k, v, *, is_causal=False, mask=None, bias=None):
     return jnp.einsum("bnqk,bknd->bqnd", probs, v)
 
 
-def reference_attention(q, k, v, *, is_causal=False, mask=None, bias=None):
-    """Plain einsum attention with fp32 softmax — numerical oracle for tests."""
+def reference_attention(q, k, v, *, is_causal=False, mask=None, bias=None,
+                        window=None):
+    """Plain einsum attention with fp32 softmax — numerical oracle for tests.
+    Grouped key/value heads are repeated to q's; ``window`` as in
+    `dot_product_attention`."""
     dtype = q.dtype
     depth = q.shape[-1]
+    if k.shape[2] != q.shape[2]:
+        group = q.shape[2] // k.shape[2]
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     q = q.astype(jnp.float32) / jnp.sqrt(depth)
     logits = jnp.einsum("bqnd,bknd->bnqk", q, k.astype(jnp.float32))
     sq, sk = logits.shape[-2], logits.shape[-1]
@@ -268,6 +307,9 @@ def reference_attention(q, k, v, *, is_causal=False, mask=None, bias=None):
     if is_causal:
         causal = jnp.tril(jnp.ones((sq, sk), dtype=bool))
         logits = jnp.where(causal, logits, -jnp.inf)
+    if window is not None:
+        near = jnp.arange(sq)[:, None] - jnp.arange(sk)[None, :] < window
+        logits = jnp.where(near, logits, -jnp.inf)
     if mask is not None:
         logits = jnp.where(mask, logits, -jnp.inf)
     weights = jax.nn.softmax(logits, axis=-1)

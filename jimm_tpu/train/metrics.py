@@ -232,16 +232,31 @@ def moe_decoder_fwd_flops(d) -> float:
     router, the shared experts and the routed experts at the expected
     ``top_k * held / num_experts`` applications a token; the untied head."""
     a, e = d.mla, d.moe
-    qk = a.qk_nope_dim + a.qk_rope_dim
-    mla = 2 * (d.width * d.num_heads * qk
-               + d.width * (a.kv_lora_rank + a.qk_rope_dim)
-               + a.kv_lora_rank * d.num_heads * (a.qk_nope_dim + a.v_head_dim)
-               + d.num_heads * a.v_head_dim * d.width) \
-        + d.seq_len * d.num_heads * (qk + a.v_head_dim)
+    if d.gqa is not None:
+        # grouped-query attention: q, gate and output at num_heads x head_dim,
+        # k and v at kv_heads x head_dim; a windowed layer sees at most
+        # ``window`` keys a query
+        g = d.gqa
+        inner = d.num_heads * g.head_dim
+        proj = 2 * d.width * ((3 if g.gate else 2) * inner
+                              + 2 * g.kv_heads * g.head_dim)
+        seen = [d.seq_len / 2 if full or not g.window else
+                min(g.window, d.seq_len) * (1 - min(g.window, d.seq_len)
+                                            / (2 * d.seq_len))
+                for full in d.full_layers]
+        attn = proj + sum(seen) / d.depth * 4 * inner
+    else:
+        qk = a.qk_nope_dim + a.qk_rope_dim
+        attn = 2 * (d.width * d.num_heads * qk
+                    + d.width * (a.kv_lora_rank + a.qk_rope_dim)
+                    + a.kv_lora_rank * d.num_heads
+                    * (a.qk_nope_dim + a.v_head_dim)
+                    + d.num_heads * a.v_head_dim * d.width) \
+            + d.seq_len * d.num_heads * (qk + a.v_head_dim)
     swiglu = 2 * 3 * d.width * e.expert_dim
     sparse = 2 * d.width * e.num_experts + swiglu * (
         e.shared_experts + e.top_k * e.held_experts / e.num_experts)
-    per_token = (d.depth * mla + d.dense_layers * 2 * 3 * d.width * d.mlp_dim
+    per_token = (d.depth * attn + d.dense_layers * 2 * 3 * d.width * d.mlp_dim
                  + (d.depth - d.dense_layers) * sparse
                  + 2 * d.width * d.vocab_size)
     return float(per_token * d.seq_len)

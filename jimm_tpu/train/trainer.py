@@ -258,6 +258,7 @@ def _routing_metrics(model, counts: jax.Array) -> dict[str, jax.Array]:
 LM_STEPS: dict[str, tuple[Callable, Callable]] = {
     "ouro": (lm_loss_fn, _exit_metrics),
     "kanana": (moe_lm_loss_fn, _routing_metrics),
+    "trinity": (moe_lm_loss_fn, _routing_metrics),
 }
 
 

