@@ -4,8 +4,8 @@ The reference locks attention to ``flax.nnx.MultiHeadAttention``'s einsum path
 (ref `common/transformer.py:67-87`). Here attention is a *function* over
 ``(B, S, N, D)`` q/k/v so the kernel is a config choice:
 
-- ``"xla"``  — ``jax.nn.dot_product_attention`` (XLA fuses; fine for short
-  vision/text sequences and for CPU tests).
+- ``"xla"``  — ``jax.nn.dot_product_attention`` (XLA fuses; what wins for
+  short or small calls, whose scores stay on the chip, and for CPU tests).
 - ``"flash"`` — Pallas TPU flash attention (fwd + custom-vjp bwd), used for
   training and long sequences. See `jimm_tpu/ops/flash_attention.py`.
   Key-padding masks route to the masked variant automatically.
@@ -35,7 +35,9 @@ The reference locks attention to ``flax.nnx.MultiHeadAttention``'s einsum path
 - ``"auto"`` — when the ambient mesh carries a live ``seq`` axis and the
   shapes divide, route to the sequence-parallel planner (ring vs ulysses
   by comm cost — `jimm_tpu/parallel/seqpar.py`); otherwise flash on TPU
-  when shapes qualify, else XLA. Key-padding masks route to
+  where `_flash_eligible` says the kernels win (every call from 512 tokens
+  up; under 512 from 128 tokens and 20 Mi scores up at a head width on the
+  tiles, set from chip readings), else XLA. Key-padding masks route to
   ``flash_masked`` (instead of silently densifying) and batch-free biases
   to ``flash_bias``.
 """
@@ -55,18 +57,104 @@ def _default_backend() -> str:
     return jax.default_backend()
 
 
-def _flash_eligible(q: jax.Array, k: jax.Array) -> bool:
-    # the threshold predates the single-tile regime of the flash kernels
-    # (PERF.md §7, candidate (2), has the readings that question it): when
-    # it was set, XLA's fused attention won below seq 512 (grid-step
-    # overhead dominated the tiled Pallas kernel at small tiles); flash wins
-    # from 512 up and scales to long context where XLA's materialized S^2
-    # probabilities drown in HBM traffic. Head dims are NOT gated here anymore: off-tile D (e.g. 80,
-    # 96) is lane-padded to the next supported tile inside the flash
-    # wrapper. Measured on v5e: padding D=80 -> 128 costs ~1.25x the
-    # D=128 kernel's matmul FLOPs but still beats XLA's dense path past
-    # the same seq-512 crossover, so eligibility stays a pure seq test.
-    return q.shape[1] >= 512 and k.shape[1] >= 512
+#: from this many tokens (the shorter of S_q, S_k) every plain, masked or
+#: bias call takes the flash family, as it has since the tiled kernels
+_FLASH_MIN_SEQ = 512
+#: under `_FLASH_MIN_SEQ`: one whole 128-lane tile of tokens, and this many
+#: scores (B * N * S_q * S_k; 80 MiB of them in float32) in the call
+_SHORT_MIN_SEQ = 128
+_SHORT_MIN_SCORES = 20 << 20
+
+
+def _mesh_partitions() -> bool:
+    """Whether the ambient mesh would have XLA partition this call: an axis
+    of more than one device that no enclosing ``shard_map`` has mapped.
+    Mosaic kernels cannot be partitioned automatically (the step of a
+    ``data`` / ``model`` mesh with a kernel call in it does not compile)."""
+    from jimm_tpu.parallel.sharding import manual_axis_names
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return False
+    manual = manual_axis_names(mesh)
+    return any(size > 1 and name not in manual
+               for name, size in dict(mesh.shape).items())
+
+
+def _flash_eligible(q: jax.Array, k: jax.Array, *,
+                    has_bias: bool = False) -> bool:
+    """THE dispatch rule of ``impl="auto"`` on a TPU, a test of what the call
+    shows: lengths, batch, heads, head width, bias, and the ambient mesh.
+
+    From 512 tokens up every call takes the flash family (unchanged: the
+    tiled kernels stream where XLA's S^2 probabilities drown in HBM traffic;
+    an off-tile head width is lane-padded inside the wrapper). Under 512 the
+    single-tile pair takes a call when all of these hold, each set from the
+    readings below:
+
+    - ``min(S_q, S_k) >= 128``: a shorter sequence is padded to one 128-lane
+      tile and does that tile's work; nothing under 128 won a column, and a
+      1-row MAP probe against 256 keys loses threefold.
+    - ``B * N * S_q * S_k >= 20 Mi`` scores: XLA keeps a score tensor of
+      tens of MiB on the chip between its fusions and is then 2-3 times
+      faster than any kernel (a serving batch of 8); past about 96 MiB of
+      float32 scores it spills and loses.
+    - the head width is 64, 128 or 256 (72, 80 and 96 pay the pad to 128
+      lanes: forward slower, training a tie), q and k have the same heads
+      (a grouped call runs the tiled kernels: forward 1.5 times XLA's at 256
+      and 384), there is no bias (the bias variant stays tiled; not
+      measured), and no mesh axis would partition the call.
+
+    Causality, a window, a key-padding mask and the dtype do not enter: at
+    `(128, 256, 12, 64)` causal, windowed, masked and float32 calls read
+    like the plain bf16 one or better (XLA's masked forward at 196 doubles).
+
+    Readings (v5e, PR 35, `docs/performance.md` has them all): one call as
+    `Attention` makes it, ``(B, S, N * D)`` in and out, bf16, host clock over
+    30 back-to-back calls, ms, XLA | kernels; forward / forward + backward /
+    the same under ``--remat dots``' policy (XLA's forward runs twice, the
+    kernels' ``o`` and ``lse`` are kept by name). ``*``: eight chained calls
+    in one program, where the host's 0.2 ms a dispatch would hide the call.
+
+        (128, 256, 12, 64)  SigLIP-B/16-256  1.45|1.16  5.71|2.34  6.87|2.36
+          with a key-padding mask            1.45|1.18  5.70|2.36  6.87|2.38
+        (128, 197, 12, 64)  ViT-B/16-224     1.26|1.58  4.86|3.22  5.37|3.77
+        (128, 196, 12, 64)  SigLIP-B/16-224  1.25|1.57  4.85|3.22  5.36|3.79
+        (64, 257, 16, 64)   CLIP-L/14        1.45|1.44  5.91|3.00  6.54|3.31
+        (128, 128, 12, 64)                   0.47|0.36  1.32|1.10  1.61|1.22
+        (128, 129, 12, 64)                   0.88|1.44  3.23|2.99  3.65|3.10
+        (128, 112, 12, 64)                   0.31|0.37  1.13|1.08  1.27|1.17
+        (128, 64, 12, 64)   SigLIP text      0.37|0.36  1.03|1.07  1.29|1.12
+        (128, 50, 12, 64)   ViT-B/32         0.44|0.46  1.00|1.24  1.32|1.37
+        (128, 77, 8, 64) causal, CLIP text   0.19|0.36  0.55|0.92  0.54|1.04
+        (32, 256, 16, 72)   So400m/14-224    0.54|0.69  1.64|1.41  1.92|1.85
+        (32, 257, 16, 80)   ViT-H/14         0.79|1.35  2.64|2.70  3.14|3.32
+        (128, 256, 12 over 4, 64) causal     1.37|2.13  5.46|4.90  6.18|5.92
+        (128, 1 | 256, 12, 64)  MAP probe    0.22|0.46  0.52|1.74  0.81|1.74
+        (8, 256, 12, 64) *                   0.026|0.075
+        (32, 197, 12, 64) *                  0.14|0.30  0.84|0.63  1.07|0.64
+        (32, 256, 12, 64) *                  0.32|0.29  0.98|0.61  1.41|0.63
+
+    A shape sits on the side whose worst column loses least (so a forward
+    that loses twofold outweighs a training step that wins by half, as at
+    `(32, 197, 12, 64)`: the serve engine's forward shares this dispatch);
+    a shape within 15 % in every column stays on XLA, where it was (S = 64).
+    **What the rule costs:** lengths of 129-223 at a training batch run the
+    kernels of their padded 256: training gains 30-50 % a layer from 144 up,
+    and a forward alone at batch 64-128 loses 21-25 % at 196 / 197; at
+    129-143 that measure would keep XLA (forward 63 % slower, training 8-15 %
+    faster at 129: the rule's worst point, where no preset is). The kernels
+    clean an edge tile's rows there (1.57 ms where the aligned 256 takes
+    1.16)."""
+    from jimm_tpu.ops.flash_attention import _HEAD_TILES
+    b, s_q, n, d = q.shape
+    s_k = k.shape[1]
+    shorter = min(s_q, s_k)
+    if shorter >= _FLASH_MIN_SEQ:
+        return True
+    return (shorter >= _SHORT_MIN_SEQ
+            and b * n * s_q * s_k >= _SHORT_MIN_SCORES
+            and d in _HEAD_TILES and k.shape[2] == n and not has_bias
+            and not _mesh_partitions())
 
 
 def _ambient_seq_axis() -> tuple[str, int] | None:
@@ -153,7 +241,8 @@ def dot_product_attention(
             return seq_parallel_attention(q, k, v, mask=mask,
                                           is_causal=is_causal,
                                           axis_name=sp[0], plan="auto")
-        if _default_backend() == "tpu" and _flash_eligible(q, k):
+        if _default_backend() == "tpu" and _flash_eligible(
+                q, k, has_bias=bias is not None):
             if bias is not None and mask is None and bias.ndim <= 3:
                 impl = "flash_bias"
             elif bias is not None:

@@ -212,6 +212,44 @@ def test_single_tile_matches_reference(rng, s, d, dtype):
     assert err <= grad_tol
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("s,causal", [(256, False), (197, False),
+                                      (196, False), (128, False),
+                                      (64, False), (77, True)])
+def test_short_lengths_match_reference_in_the_models_layout(rng, s, causal,
+                                                            dtype):
+    """Forward and gradients of the single-tile pair at the lengths under
+    512 (SigLIP-B/16-256's vision tower at its 12 heads of 64, the 224 px
+    towers, one whole lane tile, and the text towers' 64 and 77 causal, which
+    `auto` leaves on XLA but ``impl="flash"`` still takes), called as
+    `Attention` calls it: ``(B, S, N * D)`` in and out."""
+    n, d = 12, 64
+    x32 = tuple(jnp.asarray(rng.randn(2, s, n * d).astype(np.float32) * 0.5)
+                for _ in range(3))
+    args = tuple(x.astype(dtype) for x in x32)
+    fwd_tol, grad_tol = (2e-5, 5e-4) if dtype == "float32" else (2e-2, 6e-2)
+
+    def attend(fn, cast=lambda x: x):
+        def run(q, k, v):
+            q, k, v = (cast(x).reshape(2, s, n, d) for x in (q, k, v))
+            return fn(q, k, v, is_causal=causal).reshape(2, s, n * d)
+        return run
+
+    grads = jax.grad(lambda *a: jnp.sum(attend(flash_attention)(*a).astype(
+        jnp.float32)), argnums=(0, 1, 2))
+    assert _calls(grads, *args) == (2, 0)
+    out = attend(flash_attention)(*args)
+    assert out.dtype == args[0].dtype
+    np.testing.assert_allclose(out.astype(np.float32),
+                               attend(reference_attention)(*x32),
+                               atol=fwd_tol)
+    to32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+    assert _grad_err(
+        lambda *a: jnp.sum(to32(attend(flash_attention)(*a)) ** 2),
+        lambda *a: jnp.sum(attend(reference_attention, to32)(*a) ** 2),
+        args) <= grad_tol
+
+
 def _masked(rng, b, s):
     m = rng.rand(b, s) > 0.3
     m[:, 0] = True
@@ -875,8 +913,9 @@ def test_tiled_kernels_keep_v_at_its_own_tile(monkeypatch):
 
 
 def test_auto_reaches_flash_with_unequal_widths(rng, monkeypatch):
-    """``impl="auto"`` on a TPU sends S >= 512 to the flash kernels whatever
-    the value width; off the TPU XLA's op gets v padded and cut back."""
+    """``impl="auto"`` on a TPU sends a call `_flash_eligible` admits (here
+    512 tokens: every call from there up) to the flash kernels whatever the
+    value width; off the TPU XLA's op gets v padded and cut back."""
     from jimm_tpu.ops import attention
     q, k, v = _qkv_split(rng, 1, 512, 2, 24, 16)
     want = reference_attention(q, k, v, is_causal=True)
@@ -889,6 +928,132 @@ def test_auto_reaches_flash_with_unequal_widths(rng, monkeypatch):
                         lambda q, k, v, **kw: seen.append(v.shape) or want)
     attention.dot_product_attention(q, k, v, is_causal=True)
     assert seen == [(1, 512, 2, 16)]
+
+
+def _auto_path(monkeypatch, q_shape, k_shape, dtype=jnp.bfloat16, **kw):
+    """Where ``impl="auto"`` sends a call on a (faked) TPU backend:
+    ``"flash"``, ``"flash_masked"``, ``"flash_bias"`` or ``"xla"``. Traced
+    on shapes alone: nothing runs."""
+    from jimm_tpu.ops import attention
+    seen = []
+
+    def spy(name):
+        def call(q, k, v, *rest, **kwargs):
+            seen.append(name)
+            return jnp.zeros((*q.shape[:3], v.shape[-1]), q.dtype)
+        return call
+
+    monkeypatch.setattr(attention, "_default_backend", lambda: "tpu")
+    monkeypatch.setattr(fa, "flash_attention", spy("flash"))
+    monkeypatch.setattr(fa, "flash_attention_masked", spy("flash_masked"))
+    monkeypatch.setattr(fa, "flash_attention_bias", spy("flash_bias"))
+    monkeypatch.setattr(jax.nn, "dot_product_attention", spy("xla"))
+    q = jax.ShapeDtypeStruct(q_shape, dtype)
+    k = jax.ShapeDtypeStruct(k_shape, dtype)
+    if kw.pop("masked", False):
+        kw["mask"] = jnp.ones((q_shape[0], 1, 1, k_shape[1]), bool)
+    if kw.pop("biased", False):
+        kw["bias"] = jnp.zeros((q_shape[2], q_shape[1], k_shape[1]))
+    jax.eval_shape(lambda q, k, v: attention.dot_product_attention(
+        q, k, v, **kw), q, k, k)
+    assert len(seen) == 1, seen
+    return seen[0]
+
+
+#: (q's shape, k and v's shape or None for q's, call, path): every shape
+#: PR 35 read on the chip (`_flash_eligible` has the readings) on the side
+#: its reading chose, the lengths from 512 up where they were, and the calls
+#: the short rule keeps off the kernels whatever their size
+AUTO_TABLE = [
+    # SigLIP-B/16-256's vision tower at the cell's batch: plain, causal,
+    # windowed, with a key-padding mask, in float32
+    ((128, 256, 12, 64), None, {}, "flash"),
+    ((128, 256, 12, 64), None, {"is_causal": True}, "flash"),
+    ((128, 256, 12, 64), None, {"is_causal": True, "window": 128}, "flash"),
+    ((128, 256, 12, 64), None, {"masked": True}, "flash_masked"),
+    ((128, 256, 12, 64), None, {"dtype": jnp.float32}, "flash"),
+    # the other presets under 512 at a training batch
+    ((128, 197, 12, 64), None, {}, "flash"),         # ViT-B/16-224
+    ((128, 196, 12, 64), None, {}, "flash"),         # SigLIP-B/16-224
+    ((128, 196, 12, 64), None, {"masked": True}, "flash_masked"),
+    ((64, 257, 16, 64), None, {}, "flash"),          # CLIP-L/14
+    ((64, 256, 8, 128), None, {}, "flash"),          # a head width of 128
+    ((24, 400, 16, 64), None, {}, "flash"),
+    ((128, 128, 12, 64), None, {}, "flash"),         # one whole lane tile
+    ((128, 129, 12, 64), None, {}, "flash"),
+    ((32, 256, 12, 64), None, {}, "flash"),          # 24 Mi scores
+    # under one lane tile of tokens, whatever the batch
+    ((128, 64, 12, 64), None, {}, "xla"),            # SigLIP's text tower
+    ((128, 50, 12, 64), None, {}, "xla"),            # ViT-B/32
+    ((128, 77, 8, 64), None, {"is_causal": True}, "xla"),   # CLIP's text
+    ((128, 112, 12, 64), None, {}, "xla"),
+    ((4096, 64, 12, 64), None, {}, "xla"),
+    ((128, 1, 12, 64), (128, 256, 12, 64), {}, "xla"),      # the MAP probe
+    ((128, 1, 12, 64), (128, 256, 12, 64), {"masked": True}, "xla"),
+    ((4096, 1, 12, 64), (4096, 256, 12, 64), {}, "xla"),
+    # too few scores: XLA keeps them on the chip (serving batches)
+    ((8, 256, 12, 64), None, {}, "xla"),
+    ((1, 256, 12, 64), None, {}, "xla"),
+    ((32, 197, 12, 64), None, {}, "xla"),
+    ((64, 128, 12, 64), None, {}, "xla"),
+    ((8, 256, 12, 64), None, {"masked": True}, "xla"),
+    # a head width off the tiles pays its padding under 512
+    ((32, 256, 16, 72), None, {}, "xla"),            # So400m/14-224
+    ((32, 257, 16, 80), None, {}, "xla"),            # ViT-H/14
+    ((32, 256, 16, 96), None, {}, "xla"),
+    # grouped key/value heads run the tiled kernels: under 512 they lose
+    ((128, 256, 12, 64), (128, 256, 4, 64), {"is_causal": True}, "xla"),
+    ((64, 384, 12, 64), (64, 384, 4, 64),
+     {"is_causal": True, "window": 128}, "xla"),
+    # the bias variant stays tiled and was not read under 512
+    ((128, 256, 12, 64), None, {"biased": True}, "xla"),
+    # from 512 up: every call, as before
+    ((1, 512, 2, 64), None, {}, "flash"),
+    ((24, 577, 16, 64), None, {}, "flash"),          # ViT-L/16-384's cell
+    ((1, 577, 16, 80), None, {"masked": True}, "flash_masked"),
+    ((2, 729, 16, 72), None, {}, "flash"),           # So400m/14-384
+    ((1, 4096, 16, 128), None, {"is_causal": True}, "flash"),   # Ouro
+    ((1, 4096, 48, 128), (1, 4096, 8, 128),
+     {"is_causal": True, "window": 1024}, "flash"),
+    ((1, 512, 2, 64), None, {"biased": True}, "flash_bias"),
+    ((1, 512, 2, 64), (1, 511, 2, 64), {}, "xla"),
+]
+
+
+@pytest.mark.parametrize("q_shape,k_shape,kw,path", AUTO_TABLE)
+def test_auto_dispatch_table(monkeypatch, q_shape, k_shape, kw, path):
+    assert _auto_path(monkeypatch, q_shape, k_shape or q_shape,
+                      **kw) == path
+
+
+def test_auto_keeps_a_partitioned_call_under_512_off_the_kernels(
+        monkeypatch, eight_devices):
+    """Mosaic kernels cannot be partitioned automatically: under a mesh with
+    a live axis the short rule admits nothing (SigLIP-B's sharded step keeps
+    compiling), a mesh of one device per axis changes nothing, and inside a
+    ``shard_map`` over every axis the call is local and takes the kernels."""
+    from jax.sharding import PartitionSpec as P
+
+    from jimm_tpu.parallel.mesh import make_mesh
+    shape = (128, 256, 12, 64)
+    assert _auto_path(monkeypatch, shape, shape) == "flash"
+    with jax.set_mesh(make_mesh({"data": 2, "model": 2},
+                                devices=eight_devices[:4])):
+        assert _auto_path(monkeypatch, shape, shape) == "xla"
+        assert _auto_path(monkeypatch, (64, 577, 16, 64),
+                          (64, 577, 16, 64)) == "flash"  # as before
+        seen = []
+
+        def local(q):
+            seen.append(_auto_path(monkeypatch, q.shape, q.shape))
+            return q
+        jax.eval_shape(jax.shard_map(
+            local, in_specs=P("data", None, "model"),
+            out_specs=P("data", None, "model")),
+            jax.ShapeDtypeStruct((256, 256, 24, 64), jnp.bfloat16))
+        assert seen == ["flash"]
+    with jax.set_mesh(make_mesh({"data": 1}, devices=eight_devices[:1])):
+        assert _auto_path(monkeypatch, shape, shape) == "flash"
 
 
 # ---------------------------------------------------------------------------

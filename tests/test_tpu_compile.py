@@ -69,6 +69,16 @@ KERNEL_CASES = {
     # ViT-L/16-384 and CLIP-L/14-336: S=577, D=64 — the single-tile forward
     # and the fused backward
     "flash_s577_d64": _flash((32, 577, 16, 64)),
+    # SigLIP-B/16-256's vision tower as its cell calls it (batch 128, 12 heads:
+    # four heads a cell over three lane groups), the shortest call `auto`
+    # sends here (one whole lane tile of tokens), a 224 px tower's edge tile
+    # and the masked member at the cell's shape
+    "flash_s256_d64": _flash((128, 256, 12, 64)),
+    "flash_s128_d64": _flash((128, 128, 12, 64)),
+    "flash_s196_d64": _flash((128, 196, 12, 64)),
+    "flash_masked_s256_d64": (
+        fa.flash_attention_masked,
+        [((128, 256, 12, 64), jnp.bfloat16)] * 3 + [((128, 256), jnp.bool_)]),
     # So400m/14-384: S=729, head width 72 (lane-padded inside the wrapper)
     "flash_s729_d72": _flash((16, 729, 16, 72)),
     # the largest working set the single-tile rule admits (S_p 1152 at 128
@@ -146,7 +156,9 @@ KERNEL_CASES = {
 
 #: Mosaic calls in forward + backward where it is not the tiled three: the
 #: single-tile regime is one forward and ONE fused backward
-SINGLE_TILE_CALLS = {"flash_s577_d64": 2, "flash_s729_d72": 2,
+SINGLE_TILE_CALLS = {"flash_s256_d64": 2, "flash_s128_d64": 2,
+                     "flash_s196_d64": 2, "flash_masked_s256_d64": 2,
+                     "flash_s577_d64": 2, "flash_s729_d72": 2,
                      "flash_masked_s577_d64": 2, "flash_s1152_d128": 2,
                      "flash_s197_d64_whole_row": 2,
                      "flash_s1153_d64": 3, "flash_causal_s4096_d128": 3,
@@ -203,14 +215,21 @@ def test_grouped_products_compile_for_v5e(rows, width, expert_dim, experts,
 
 
 @pytest.mark.slow
-def test_siglip_b16_256_train_step_fits_one_v5e_chip(one_chip):
+def test_siglip_b16_256_train_step_fits_one_v5e_chip(one_chip, monkeypatch,
+                                                     compiled_kernels):
     """The whole contrastive train step of `chip_smoke.py`'s train phase
     (published widths, bf16, batch 128, remat=dots, donated state) compiles
-    for one chip and asks for less than its 16 GB."""
+    for one chip and asks for less than its 16 GB, with ``auto`` deciding as
+    it does on the chip: the vision tower's attention at `(128, 256, 12, 64)`
+    is the single-tile kernel pair (a scanned layer body: one forward and one
+    backward call in the text), the text tower's at 64 tokens stays on XLA. A
+    silent return of the vision tower to XLA fails here."""
     from jimm_tpu import SigLIP, preset
     from jimm_tpu.configs import parse_remat, with_runtime
+    from jimm_tpu.ops import attention
     from jimm_tpu.train import (OptimizerConfig, make_contrastive_train_step,
                                 make_optimizer)
+    monkeypatch.setattr(attention, "_default_backend", lambda: "tpu")
 
     cfg = with_runtime(preset("siglip-base-patch16-256"),
                        **parse_remat("dots"), attn_impl="auto",
@@ -232,8 +251,10 @@ def test_siglip_b16_256_train_step_fits_one_v5e_chip(one_chip):
                                   sharding=one_chip)
     text = jax.ShapeDtypeStruct((batch, 64), jnp.int32, sharding=one_chip)
     step = make_contrastive_train_step("siglip", donate=True)
-    mem = step.lower(model, optimizer, images, text).compile() \
-        .memory_analysis()
+    compiled = step.lower(model, optimizer, images, text).compile()
+    assert compiled.as_text().count(
+        "custom_call_target=\"tpu_custom_call\"") == 2
+    mem = compiled.memory_analysis()
     resident = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
                 + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert resident < HBM_BYTES, mem
