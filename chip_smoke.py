@@ -67,39 +67,6 @@ def say(**fields) -> None:
     print(json.dumps(fields), flush=True)
 
 
-class CompileWatch:
-    """Counts backend compile requests and persistent-cache hits/misses
-    through jax.monitoring, with the wall time each request started."""
-
-    def __init__(self) -> None:
-        import jax
-        self.requests: list[dict] = []
-        self.cache = {"hits": 0, "misses": 0}
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event: str, duration: float, **kw) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.requests.append({"fun": str(kw.get("fun_name")),
-                                  "seconds": duration,
-                                  "started": time.time() - duration})
-
-    def _event(self, event: str, **kw) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache["hits"] += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.cache["misses"] += 1
-
-    def started_after(self, wall_time: float) -> list[str]:
-        return [c["fun"] for c in self.requests
-                if c["started"] > wall_time]
-
-    def close(self) -> None:
-        import jax
-        jax.monitoring.unregister_event_duration_listener(self._duration)
-        jax.monitoring.unregister_event_listener(self._event)
-
-
 def train_argv(args, *extra: str) -> list[str]:
     argv = ["train", "--preset", PRESET, "--bf16",
             "--batch-size", "8" if args.rehearse else "128",
@@ -110,6 +77,14 @@ def train_argv(args, *extra: str) -> list[str]:
     return argv
 
 
+def late_compiles(rows: list[dict]) -> list[str]:
+    """Functions the backend was asked for after the first step's row: a
+    run's rows carry what each step compiled or loaded."""
+    return [fun for row in rows[1:]
+            for kind, fun, _, _ in row.get("compiles", ())
+            if kind == "compile"]
+
+
 def read_metrics(path: Path) -> list[dict]:
     return [json.loads(line) for line in path.read_text().splitlines()]
 
@@ -118,7 +93,7 @@ def read_metrics(path: Path) -> list[dict]:
 # Phase: the main path, one chip
 # ---------------------------------------------------------------------------
 
-def train_phase(args, watch: CompileWatch) -> None:
+def train_phase(args, watch) -> None:
     import jax
     import numpy as np
 
@@ -136,14 +111,14 @@ def train_phase(args, watch: CompileWatch) -> None:
     rows = read_metrics(metrics_path)
     losses = [r["loss"] for r in rows]
     step_ms = [r["step_time_s"] * 1e3 for r in rows]
-    late = watch.started_after(rows[0]["time"])
+    late = late_compiles(rows)
     stats = jax.local_devices()[0].memory_stats() or {}
     say(phase="train", steps=len(rows), loss=losses,
         first_step_s=step_ms[0] / 1e3,
         steady_step_ms_median=statistics.median(step_ms[1:]),
         steady_step_ms=step_ms[1:],
         reading="smoke reading around block_until_ready, not a benchmark",
-        compile_requests=len(watch.requests),
+        compile_requests=watch.requests,
         compile_requests_after_first_step=late,
         peak_bytes_in_use=stats.get("peak_bytes_in_use", "not reported"),
         compile_cache=dict(watch.cache))
@@ -269,7 +244,7 @@ def kernel_cases(args):
            BF16_TOL)
 
 
-def kernel_phase(args, watch: CompileWatch) -> None:
+def kernel_phase(args, watch) -> None:
     import jax
     import jax.numpy as jnp
 
@@ -367,7 +342,7 @@ def state_bytes_by_device(*modules) -> tuple[dict[str, int], int]:
     return held, whole
 
 
-def multichip_phase(args, watch: CompileWatch) -> None:
+def multichip_phase(args, watch) -> None:
     import jax
     import numpy as np
 
@@ -387,7 +362,7 @@ def multichip_phase(args, watch: CompileWatch) -> None:
     sharded, rows4 = run("four_chips", "--mesh", "data=2,model=2",
                          "--max-devices", "4", "--rules", "fsdp_tp",
                          "--loss", "siglip_ring")
-    late = watch.started_after(rows4[0]["time"])
+    late = late_compiles(rows4)
     held, whole = state_bytes_by_device(sharded.model, sharded.optimizer)
     shares = {dev: n / whole for dev, n in sorted(held.items())}
     with use_sharding(sharded.mesh, sharded.rules):
@@ -476,7 +451,11 @@ def main(argv: list[str] | None = None) -> int:
             f"{'built' if native_available() else 'not built'}; the "
             f"synthetic-pairs path preprocesses nothing"))
 
-    watch = CompileWatch()
+    # counts over the whole script, the kernels' compiles too. Each
+    # `cli.train` opens its own for its rows and the `jimm_train` registry:
+    # this one counts into a registry of its own, or both would count there
+    from jimm_tpu.obs import CompileWatch, MetricRegistry
+    watch = CompileWatch(MetricRegistry("chip_smoke")).listen()
     phases = ([("multichip", multichip_phase)] if args.multichip
               else [("train", train_phase), ("kernels", kernel_phase)])
     failed = []

@@ -479,26 +479,43 @@ def train(args: argparse.Namespace) -> Any:
     (``model``, ``optimizer``, ``step_fn``, ``mesh``, ``rules``, last
     ``batch``) for a caller that inspects where the state landed
     (``chip_smoke.py``)."""
-    _configure_backend(args)
-    _configure_journal(args)
-    if args.compilation_cache_dir:
-        # persistent XLA compile cache: restarted runs (preemption,
-        # resume, sweep retries) skip straight past the train-step compile
-        from jimm_tpu.aot.export import enable_persistent_cache
-        enable_persistent_cache(args.compilation_cache_dir)
-    import jax.numpy as jnp
-    import numpy as np
-    from flax import nnx
+    # goodput ledger, born with the call: set-up runs under the phases of
+    # its ``setup`` bucket, every loop region under a measure() bucket, so
+    # the end-of-run report decomposes the call's wall time into
+    # setup/compile/data_wait/step/checkpoint/host_sync/other.
+    # This tracing adds no frame under train() and no local to it, on
+    # purpose: on a TPU host every word of stack under the imports below
+    # cost seconds of set-up (docs/observability.md, "What the set-up
+    # timeline may not cost")
+    from jimm_tpu import obs
+    acct = obs.GoodputAccounter()
+    with acct.measure("backend_init"):
+        _configure_backend(args)
+        _configure_journal(args)
+        if args.compilation_cache_dir:
+            # persistent XLA compile cache: restarted runs (preemption,
+            # resume, sweep retries) skip straight past the train-step compile
+            from jimm_tpu.aot.export import enable_persistent_cache
+            enable_persistent_cache(args.compilation_cache_dir)
+    with acct.measure("imports"):
+        import jax.numpy as jnp
+        import numpy as np
+        from flax import nnx
 
-    from jimm_tpu import obs, preset
-    from jimm_tpu.data import (PrefetchIterator, blob_classification,
-                               contrastive_pairs, token_sequences)
-    from jimm_tpu.parallel import PRESET_RULES, use_sharding
-    from jimm_tpu.train import (CheckpointManager, MetricsLogger,
-                                OptimizerConfig, StepTimer,
-                                make_classifier_train_step,
-                                make_contrastive_train_step,
-                                make_lm_train_step, make_optimizer)
+        from jimm_tpu import preset
+        from jimm_tpu.data import (PrefetchIterator, blob_classification,
+                                   contrastive_pairs, token_sequences)
+        from jimm_tpu.parallel import PRESET_RULES, use_sharding
+        from jimm_tpu.train import (CheckpointManager, MetricsLogger,
+                                    OptimizerConfig, StepTimer,
+                                    make_classifier_train_step,
+                                    make_contrastive_train_step,
+                                    make_lm_train_step, make_optimizer)
+    # the run's one listener to jax.monitoring: every compile request,
+    # named. It listens inside the with-blocks that name it (the stretches
+    # of set-up that build programs, and the loop), so it is gone on every
+    # way out of them
+    acct.compiles = obs.CompileWatch()
 
     fam = _family(args.preset)
     for name, value in {**_OPTIMIZER_DEFAULTS,
@@ -532,11 +549,13 @@ def train(args: argparse.Namespace) -> Any:
                          "(an ouro preset, a kanana preset, a trinity "
                          "preset)")
 
-    mesh = _parse_mesh(args.mesh, max_devices=args.max_devices)
-    import jax
-    # built ONCE: the preset path applies the fields to cfg, the fine-tune
-    # path passes them to from_pretrained
-    rt = resolve_runtime(args, cfg, mesh, jax.default_backend())
+    with acct.measure("backend_init"):
+        # the first touch of the backend: the TPU runtime starts here
+        mesh = _parse_mesh(args.mesh, max_devices=args.max_devices)
+        import jax
+        # built ONCE: the preset path applies the fields to cfg, the
+        # fine-tune path passes them to from_pretrained
+        rt = resolve_runtime(args, cfg, mesh, jax.default_backend())
     if rt and not args.from_pretrained:
         cfg = _replace_towers(cfg, **rt)
     def _validate_pp(cfg_obj) -> None:
@@ -581,40 +600,43 @@ def train(args: argparse.Namespace) -> Any:
         PRESET_RULES["dp"] if mesh is not None else None)
     dtype = jnp.bfloat16 if args.bf16 else jnp.float32
 
-    if args.from_pretrained:
-        # fine-tune: architecture from the checkpoint, execution strategy
-        # from the SAME rt dict the preset path applies (built above)
-        try:
-            model = _model_cls(fam).from_pretrained(
-                args.from_pretrained, mesh=mesh,
-                rules=rules if rules is not None else "replicated",
-                dtype=dtype, runtime=rt or None, image_size=args.image_size)
-        except ValueError as e:
-            # a checkpoint depth incompatible with the stage/virtual layout
-            # raises during construction (interleaved placement is baked
-            # into storage) — give it the same fast, clean exit as the
-            # parse-time checks; any OTHER load error keeps its traceback
-            if (args.rules == "pp" and "divisible" in str(e)
-                    and "stage" in str(e)):
-                raise SystemExit(f"pipeline config: {e}")
-            raise
-        if fam == "vit":
-            _fit_head(model, n_classes, dtype=dtype, seed=args.seed,
-                      mesh=mesh, rules=rules)
-        cfg = model.config
-        _validate_pp(cfg)
-    else:
-        model = _model_cls(fam)(cfg, rngs=nnx.Rngs(args.seed), mesh=mesh,
-                                rules=rules, dtype=dtype, param_dtype=dtype)
-    # low-precision training surgery, BEFORE the optimizer is built: the
-    # optimizer tracks nnx.Param state, and the fp8 wrapper shares the
-    # Linear's kernel/bias Params (amax histories are plain Variables, so
-    # they never enter optimizer state)
-    precision = getattr(_main_tower(cfg), "precision", "bf16")
-    if precision != "bf16":
-        from jimm_tpu.quant.policy import apply_precision_policy
-        n_lowp = apply_precision_policy(model, precision)
-        print(f"precision policy {precision}: {n_lowp} modules rewritten")
+    with acct.measure("model_build"), acct.compiles:
+        if args.from_pretrained:
+            # fine-tune: architecture from the checkpoint, execution strategy
+            # from the SAME rt dict the preset path applies (built above)
+            try:
+                model = _model_cls(fam).from_pretrained(
+                    args.from_pretrained, mesh=mesh,
+                    rules=rules if rules is not None else "replicated",
+                    dtype=dtype, runtime=rt or None,
+                    image_size=args.image_size)
+            except ValueError as e:
+                # a checkpoint depth incompatible with the stage/virtual layout
+                # raises during construction (interleaved placement is baked
+                # into storage) — give it the same fast, clean exit as the
+                # parse-time checks; any OTHER load error keeps its traceback
+                if (args.rules == "pp" and "divisible" in str(e)
+                        and "stage" in str(e)):
+                    raise SystemExit(f"pipeline config: {e}")
+                raise
+            if fam == "vit":
+                _fit_head(model, n_classes, dtype=dtype, seed=args.seed,
+                          mesh=mesh, rules=rules)
+            cfg = model.config
+            _validate_pp(cfg)
+        else:
+            model = _model_cls(fam)(cfg, rngs=nnx.Rngs(args.seed), mesh=mesh,
+                                    rules=rules, dtype=dtype,
+                                    param_dtype=dtype)
+        # low-precision training surgery, BEFORE the optimizer is built: the
+        # optimizer tracks nnx.Param state, and the fp8 wrapper shares the
+        # Linear's kernel/bias Params (amax histories are plain Variables, so
+        # they never enter optimizer state)
+        precision = getattr(_main_tower(cfg), "precision", "bf16")
+        if precision != "bf16":
+            from jimm_tpu.quant.policy import apply_precision_policy
+            n_lowp = apply_precision_policy(model, precision)
+            print(f"precision policy {precision}: {n_lowp} modules rewritten")
     # --moment-dtype wins over the legacy --bf16-momentum sugar
     moment_dtype = ({"f32": "float32", "bf16": "bfloat16"}[args.moment_dtype]
                     if args.moment_dtype
@@ -622,7 +644,8 @@ def train(args: argparse.Namespace) -> Any:
     # under the mesh: the optimizer's scalar counters are then born with the
     # mesh in their type, as the step returns them — created outside it they
     # change type after step 0 and the whole step compiles a second time
-    with use_sharding(mesh, rules):
+    with acct.measure("optimizer_build"), acct.compiles, \
+            use_sharding(mesh, rules):
         optimizer = make_optimizer(model, OptimizerConfig(
             learning_rate=args.lr, weight_decay=args.weight_decay,
             warmup_steps=args.warmup_steps, total_steps=args.steps,
@@ -648,16 +671,19 @@ def train(args: argparse.Namespace) -> Any:
 
     # mesh= records the topology each save was sharded over and counts a
     # topology change when a restore crosses mesh shapes (elastic restarts)
-    ckpt = CheckpointManager(args.ckpt_dir, save_interval_steps=args.save_every,
-                             mesh=mesh) \
-        if args.ckpt_dir else None
+    ckpt = None
     start_step = 0
-    if ckpt is not None and args.resume:
-        try:
-            start_step = ckpt.restore(model, optimizer) + 1
-            print(f"resumed from step {start_step - 1}")
-        except FileNotFoundError:
-            pass
+    if args.ckpt_dir:
+        with acct.measure("checkpoint"), acct.compiles:
+            ckpt = CheckpointManager(args.ckpt_dir,
+                                     save_interval_steps=args.save_every,
+                                     mesh=mesh)
+            if args.resume:
+                try:
+                    start_step = ckpt.restore(model, optimizer) + 1
+                    print(f"resumed from step {start_step - 1}")
+                except FileNotFoundError:
+                    pass
 
     # deterministic resume: resumed step N sees the same batch it would have
     # in the uninterrupted run. File pipelines fast-forward the raw example
@@ -703,115 +729,115 @@ def train(args: argparse.Namespace) -> Any:
         grain_stream = CheckpointableGrainStream(grain_iter)
         return grain_stream.batches()
 
-    lm_counters = ()
-    if fam in LM_FAMILIES:
-        step_fn = make_lm_train_step(fam, donate=True)
-        d = cfg.decoder
-        data = token_sequences(args.batch_size, seq_len=d.seq_len,
-                               vocab_size=d.vocab_size, seed=args.seed)
-        lm_counters = [
-            (obs.get_registry(registry).counter(name), amount)
-            for registry, name, amount in _lm_counters(cfg, args.batch_size)]
-    elif fam == "vit":
-        step_fn = make_classifier_train_step(donate=True)
-        if args.data and args.loader == "grain":
-            data = _grain_data("classification")
-        elif args.data:
-            if _is_tar_data(args.data):
-                from jimm_tpu.data.webdataset import (
-                    wds_classification_batches as classification_batches)
+    with acct.measure("data_build"), acct.compiles:
+        lm_counters = ()
+        if fam in LM_FAMILIES:
+            step_fn = make_lm_train_step(fam, donate=True)
+            d = cfg.decoder
+            data = token_sequences(args.batch_size, seq_len=d.seq_len,
+                                   vocab_size=d.vocab_size, seed=args.seed)
+            lm_counters = [
+                (obs.get_registry(registry).counter(name), amount)
+                for registry, name, amount
+                in _lm_counters(cfg, args.batch_size)]
+        elif fam == "vit":
+            step_fn = make_classifier_train_step(donate=True)
+            if args.data and args.loader == "grain":
+                data = _grain_data("classification")
+            elif args.data:
+                if _is_tar_data(args.data):
+                    from jimm_tpu.data.webdataset import (
+                        wds_classification_batches as classification_batches)
+                else:
+                    from jimm_tpu.data.records import classification_batches
+                data = classification_batches(
+                    args.data, args.batch_size,
+                    image_size=cfg.vision.image_size, **data_kw)
             else:
-                from jimm_tpu.data.records import classification_batches
-            data = classification_batches(
-                args.data, args.batch_size,
-                image_size=cfg.vision.image_size, **data_kw)
+                # temporal towers train on synthetic (B, T, H, W, C) clips;
+                # the file loaders stay image-only for now
+                data = blob_classification(args.batch_size,
+                                           image_size=cfg.vision.image_size,
+                                           num_classes=cfg.num_classes,
+                                           seed=args.seed,
+                                           num_frames=cfg.vision.num_frames)
         else:
-            # temporal towers train on synthetic (B, T, H, W, C) clips;
-            # the file loaders stay image-only for now
-            data = blob_classification(args.batch_size,
-                                       image_size=cfg.vision.image_size,
-                                       num_classes=cfg.num_classes,
-                                       seed=args.seed,
-                                       num_frames=cfg.vision.num_frames)
-    else:
-        # ring losses shard the batch over the "data" axis — on a mesh
-        # without one (e.g. model-only TP) the dense loss is the default
-        ring_ok = mesh is not None and ("data" in mesh.shape
-                                        or mesh.shape.get("seq", 1) > 1)
-        if fam == "clip":
-            loss_kind = args.loss or ("clip_ring" if ring_ok else "clip")
-        else:
-            loss_kind = args.loss or ("siglip_ring" if ring_ok
-                                      else "siglip")
-        # a seq axis joins the pair-dimension ring: the contrastive batch
-        # shards over ("data", "seq") combined, so sequence-parallel
-        # meshes spend every chip on the pairwise loss too
-        loss_axis = "data"
-        if (loss_kind.endswith("_ring") and mesh is not None
-                and mesh.shape.get("seq", 1) > 1):
-            loss_axis = tuple(a for a in ("data", "seq")
-                              if a in mesh.shape)
-        step_fn = make_contrastive_train_step(loss_kind, mesh=mesh,
-                                              axis_name=loss_axis,
-                                              donate=True)
-        if rules is not None and isinstance(loss_axis, tuple):
-            # batches land sharded over both pair axes (the loss's
-            # shard_map in_specs expect it)
-            rules = dataclasses.replace(rules, batch=loss_axis)
-        if args.naflex:
-            # variable-resolution SigLIP2 training (beyond the reference)
-            if fam != "siglip":
-                raise SystemExit("--naflex trains SigLIP2-style models; "
-                                 "use a siglip preset")
-            if args.rules == "pp":
-                raise SystemExit("--naflex needs attention masks, which the "
-                                 "pipelined path does not support yet")
-            if args.data and (args.loader == "grain"
-                              or _is_tar_data(args.data)):
-                raise SystemExit("--naflex reads tfrecord shards (records "
-                                 "loader) or synthetic data")
-            naflex_kw = dict(patch_size=cfg.vision.patch_size,
-                             max_num_patches=cfg.vision.num_patches,
-                             seq_len=cfg.text.context_length)
-            if args.data:
-                from jimm_tpu.data.records import naflex_image_text_batches
-                data = naflex_image_text_batches(
-                    args.data, args.batch_size, **naflex_kw, **data_kw)
+            # ring losses shard the batch over the "data" axis — on a mesh
+            # without one (e.g. model-only TP) the dense loss is the default
+            ring_ok = mesh is not None and ("data" in mesh.shape
+                                            or mesh.shape.get("seq", 1) > 1)
+            if fam == "clip":
+                loss_kind = args.loss or ("clip_ring" if ring_ok else "clip")
             else:
-                from jimm_tpu.data.synthetic import naflex_contrastive_pairs
-                data = naflex_contrastive_pairs(
-                    args.batch_size, **naflex_kw,
-                    vocab_size=cfg.text.vocab_size, seed=args.seed)
-        elif args.data and args.loader == "grain":
-            data = _grain_data("contrastive")
-        elif args.data:
-            if _is_tar_data(args.data):
-                from jimm_tpu.data.webdataset import (
-                    wds_image_text_batches as image_text_batches)
+                loss_kind = args.loss or ("siglip_ring" if ring_ok
+                                          else "siglip")
+            # a seq axis joins the pair-dimension ring: the contrastive batch
+            # shards over ("data", "seq") combined, so sequence-parallel
+            # meshes spend every chip on the pairwise loss too
+            loss_axis = "data"
+            if (loss_kind.endswith("_ring") and mesh is not None
+                    and mesh.shape.get("seq", 1) > 1):
+                loss_axis = tuple(a for a in ("data", "seq")
+                                  if a in mesh.shape)
+            step_fn = make_contrastive_train_step(loss_kind, mesh=mesh,
+                                                  axis_name=loss_axis,
+                                                  donate=True)
+            if rules is not None and isinstance(loss_axis, tuple):
+                # batches land sharded over both pair axes (the loss's
+                # shard_map in_specs expect it)
+                rules = dataclasses.replace(rules, batch=loss_axis)
+            if args.naflex:
+                # variable-resolution SigLIP2 training (beyond the reference)
+                if fam != "siglip":
+                    raise SystemExit("--naflex trains SigLIP2-style models; "
+                                     "use a siglip preset")
+                if args.rules == "pp":
+                    raise SystemExit("--naflex needs attention masks, which "
+                                     "the pipelined path does not support "
+                                     "yet")
+                if args.data and (args.loader == "grain"
+                                  or _is_tar_data(args.data)):
+                    raise SystemExit("--naflex reads tfrecord shards (records "
+                                     "loader) or synthetic data")
+                naflex_kw = dict(patch_size=cfg.vision.patch_size,
+                                 max_num_patches=cfg.vision.num_patches,
+                                 seq_len=cfg.text.context_length)
+                if args.data:
+                    from jimm_tpu.data.records import naflex_image_text_batches
+                    data = naflex_image_text_batches(
+                        args.data, args.batch_size, **naflex_kw, **data_kw)
+                else:
+                    from jimm_tpu.data.synthetic import (
+                        naflex_contrastive_pairs)
+                    data = naflex_contrastive_pairs(
+                        args.batch_size, **naflex_kw,
+                        vocab_size=cfg.text.vocab_size, seed=args.seed)
+            elif args.data and args.loader == "grain":
+                data = _grain_data("contrastive")
+            elif args.data:
+                if _is_tar_data(args.data):
+                    from jimm_tpu.data.webdataset import (
+                        wds_image_text_batches as image_text_batches)
+                else:
+                    from jimm_tpu.data.records import image_text_batches
+                data = image_text_batches(
+                    args.data, args.batch_size,
+                    image_size=cfg.vision.image_size,
+                    seq_len=cfg.text.context_length, **data_kw)
             else:
-                from jimm_tpu.data.records import image_text_batches
-            data = image_text_batches(
-                args.data, args.batch_size,
-                image_size=cfg.vision.image_size,
-                seq_len=cfg.text.context_length, **data_kw)
-        else:
-            data = contrastive_pairs(args.batch_size,
-                                     image_size=cfg.vision.image_size,
-                                     vocab_size=cfg.text.vocab_size,
-                                     seq_len=cfg.text.context_length,
-                                     seed=args.seed)
-    if not args.data:
-        for _ in range(start_step):
-            next(data)
+                data = contrastive_pairs(args.batch_size,
+                                         image_size=cfg.vision.image_size,
+                                         vocab_size=cfg.text.vocab_size,
+                                         seq_len=cfg.text.context_length,
+                                         seed=args.seed)
+        if not args.data:
+            for _ in range(start_step):
+                next(data)
 
     logger = MetricsLogger(path=args.metrics_file, print_every=args.log_every,
                            tensorboard_dir=args.tensorboard_dir,
                            registry=obs.get_registry("jimm_train"))
     timer = StepTimer()
-    # goodput ledger: every loop region below runs under a measure() bucket,
-    # so the end-of-run report decomposes wall time into
-    # compile/data_wait/step/checkpoint/host_sync/other
-    acct = obs.GoodputAccounter()
     profiler_ctx = None
     # continuous profiling ring: a bounded on-disk rotation of short
     # step-window captures, plus anomaly-triggered deep captures (installed
@@ -838,13 +864,14 @@ def train(args: argparse.Namespace) -> Any:
         # tree-map: a NaFlex batch nests the image triple inside
         return jax.tree.map(jnp.asarray, batch)
 
-    if mesh is not None:
-        # places in its own thread; the loop's "place" phase does not exist
-        data = PrefetchIterator(data, mesh=mesh, rules=rules)
-    if grain_stream is not None:
-        # advance consumed_state batch-by-batch on THIS (consumer) side of
-        # the prefetch queue, so checkpoints record the trained-on position
-        data = grain_stream.track(data)
+    with acct.measure("data_build"), acct.compiles:
+        if mesh is not None:
+            # places in its own thread; the loop's "place" phase does not exist
+            data = PrefetchIterator(data, mesh=mesh, rules=rules)
+        if grain_stream is not None:
+            # advance consumed_state batch-by-batch on THIS (consumer) side of
+            # the prefetch queue, so checkpoints record the trained-on position
+            data = grain_stream.track(data)
 
     # profile steps start+2..start+4 (past compile), falling back to the
     # whole run when it is shorter than that
@@ -890,11 +917,16 @@ def train(args: argparse.Namespace) -> Any:
             if f.fp is not None:
                 host_metrics["batch_fingerprint"] = f.fp
             # grouped by step, not by the clock: what the next step measured
-            # before this row (its next_batch .. dispatch) waits for its own
+            # before this row (its next_batch .. dispatch) waits for its own,
+            # and so does what it compiled or loaded: all of set-up in the
+            # first row, nothing in a steady step's
             logger.log(f.step, step_time_s=dt, **host_metrics,
-                       file_only={"phases": f.phases + acct.drain()})
+                       file_only={"phases": f.phases + acct.drain(),
+                                  **obs.compiles.row_keys(
+                                      f.compiles + acct.compiles.drain())})
 
     try:
+        acct.compiles.listen()
         with use_sharding(mesh, rules):
             for step in range(start_step, args.steps):
                 if prof_ring is not None:
@@ -924,7 +956,10 @@ def train(args: argparse.Namespace) -> Any:
                 with acct.measure("dispatch", bucket):
                     metrics = step_fn(model, optimizer, *batch)
                 dispatch_s = timer.stop()
-                own = acct.drain()
+                # this step's spans and compile events so far, before the
+                # step in flight takes what follows (a pair in the one local
+                # the spans had: the note at the top of train())
+                own = acct.drain(), acct.compiles.drain()
                 if flight is not None:
                     if not flight.metrics["loss"].is_ready():
                         # launched behind a running program: the device
@@ -936,7 +971,8 @@ def train(args: argparse.Namespace) -> Any:
                 # row before, then its own phases.
                 flight = types.SimpleNamespace(
                     step=step, metrics=metrics, fp=fp, bucket=bucket,
-                    dispatch_s=dispatch_s, phases=acct.drain() + own)
+                    dispatch_s=dispatch_s, phases=acct.drain() + own[0],
+                    compiles=own[1] + acct.compiles.drain())
                 extra = None
                 if ckpt is not None and grain_stream is not None:
                     import base64
@@ -953,6 +989,7 @@ def train(args: argparse.Namespace) -> Any:
                         saved_now = ckpt.save(step, model, optimizer,
                                               extra=extra)
                     flight.phases += acct.drain()
+                    flight.compiles += acct.compiles.drain()
                 if fault_plan is not None:
                     # drill events for this step (stall/corrupt/preempt/
                     # crash); a preempt's SIGTERM lands before the guard
@@ -968,6 +1005,7 @@ def train(args: argparse.Namespace) -> Any:
             if flight is not None:
                 finish_step_in_flight()
         finally:
+            acct.compiles.close()
             if guard is not None:
                 guard.uninstall()
             if profiler_ctx is not None:

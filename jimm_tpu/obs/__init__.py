@@ -9,6 +9,7 @@ Public surface::
     with obs.span("checkpoint_save"): ...        # host timing + TraceAnnotation
     acct = obs.GoodputAccounter()
     with acct.measure("data_wait"): batch = next(it)
+    watch = obs.CompileWatch()                   # every compile request, named
     obs.snapshot()                               # unified {prefix_name: value}
     obs.render_prometheus()                      # one text dump, all namespaces
 
@@ -17,6 +18,7 @@ Disable all optional instrumentation with ``JIMM_OBS=0`` (or
 registries keep counting (serve counters are product behavior).
 """
 
+from jimm_tpu.obs.compiles import CompileWatch
 from jimm_tpu.obs.exporters import (JsonlExporter, console_table,
                                     diff_snapshots, parse_prometheus_text,
                                     render_prometheus_text)
@@ -39,16 +41,16 @@ from jimm_tpu.obs.timeline import (export_timeline, validate_chrome_trace,
                                    write_timeline)
 
 __all__ = [
-    "BUCKETS", "BaselineStore", "CaptureManager", "Counter",
+    "BUCKETS", "CaptureManager", "CompileWatch", "Counter",
     "DuplicateMetricError", "EventJournal", "Gauge", "GoodputAccounter",
     "Histogram", "JsonlExporter", "MemoryMonitor", "MetricRegistry",
-    "SloEngine", "SloObjective", "chain", "check_rows", "configure_capture",
+    "SloEngine", "SloObjective", "chain", "configure_capture",
     "configure_journal", "console_table", "correlate", "current_cid",
     "diff_snapshots", "enabled", "export_timeline", "get_capture_manager",
-    "get_journal", "get_registry", "is_fallback", "maybe_trigger",
+    "get_journal", "get_registry", "maybe_trigger",
     "new_correlation_id", "new_trace_id", "parse_prometheus_text",
     "percentile", "publish", "read_events", "registries",
     "render_prometheus", "render_prometheus_text", "reset_capture",
-    "reset_journal", "row_key", "set_enabled", "snapshot", "span",
+    "reset_journal", "set_enabled", "snapshot", "span",
     "unpublish", "validate_chrome_trace", "write_timeline",
 ]

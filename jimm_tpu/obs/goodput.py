@@ -5,11 +5,15 @@ actual training steps, as opposed to compiling, waiting for data, writing
 checkpoints, or syncing scalars back to the host. The accounter is a small
 stopwatch ledger: wrap each region of the training loop in
 ``acct.measure("bucket")`` and ask for a :meth:`report` at the end — the
-residual (startup code, python glue) is attributed to ``other`` so the
-buckets always sum to exactly the wall time.
+residual (python glue between the measured regions) is attributed to
+``other`` so the buckets always sum to exactly the wall time. The wall time
+runs from the accounter's birth: ``cli.train`` creates it in its first
+statement, so a run's start-up is inside the wall time, under ``setup``.
 
 Buckets (the fixed vocabulary the docs and CI smoke assert on):
 
+- ``setup``      — what ``train()`` does before its loop: imports, the
+                   backend's start, building model, optimizer and data
 - ``compile``    — first-step tracing/compilation (and explicit AOT compiles)
 - ``data_wait``  — blocked on the input pipeline (``next(iterator)``)
 - ``step``       — dispatched training step incl. the device sync that
@@ -38,7 +42,20 @@ either, and a bucket's name is a phase of itself):
 - ``dispatch``    → ``step``: the call of the jitted step until it returns
 - ``device_wait`` → ``step``: ``block_until_ready`` on the loss
 
-(the last two land in ``compile`` on the first step: ``bucket=``). Every
+- ``imports``         → ``setup``: ``train()``'s own imports (jax, flax,
+  ``jimm_tpu.data`` / ``.parallel`` / ``.train``: optax, grain, orbax)
+- ``backend_init``    → ``setup``: platform and cluster configuration, the
+  compile cache, the mesh, the first ``jax.default_backend()`` (the TPU
+  runtime's start)
+- ``model_build``     → ``setup``: the constructor or ``from_pretrained``,
+  the head's fit and the precision policy
+- ``optimizer_build`` → ``setup``: ``make_optimizer``
+- ``data_build``      → ``setup``: the step function's choice and the input
+  iterator with its wrappers
+
+(``dispatch`` and ``device_wait`` land in ``compile`` on the first step:
+``bucket=``; building the ``CheckpointManager`` and a resume's ``restore``
+are a ``checkpoint``). Every
 measured region is also kept as ``[phase, start_unix_ns, dur_ns]`` until
 :meth:`GoodputAccounter.drain` hands it out, once: the loop writes them into
 its step's ``--metrics-file`` row, and because ``start`` is ``time.time_ns()``
@@ -55,13 +72,17 @@ from contextlib import contextmanager
 
 from jimm_tpu.obs.registry import MetricRegistry, enabled, get_registry
 
-__all__ = ["BUCKETS", "PHASES", "GoodputAccounter"]
+__all__ = ["BUCKETS", "PHASES", "SETUP_PHASES", "GoodputAccounter"]
 
-BUCKETS = ("compile", "data_wait", "step", "checkpoint", "host_sync",
-           "preemption_save", "lost_work", "replan", "heal")
+BUCKETS = ("setup", "compile", "data_wait", "step", "checkpoint",
+           "host_sync", "preemption_save", "lost_work", "replan", "heal")
+#: the phases of ``setup``, in the order ``cli.train`` runs through them
+SETUP_PHASES = ("imports", "backend_init", "model_build", "optimizer_build",
+                "data_build")
 #: phase -> the bucket it adds to, unless ``measure(..., bucket=)`` says so
 PHASES = {"next_batch": "data_wait", "place": "data_wait",
           "dispatch": "step", "device_wait": "step",
+          **{name: "setup" for name in SETUP_PHASES},
           **{name: name for name in BUCKETS}}
 #: spans kept until the next drain(); bounds an accounter nobody drains
 MAX_UNDRAINED_SPANS = 4096
@@ -82,6 +103,10 @@ class GoodputAccounter:
         self._spans: collections.deque[list] = collections.deque(
             maxlen=MAX_UNDRAINED_SPANS)
         self._t_start = time.monotonic()
+        #: the run's :class:`~jimm_tpu.obs.compiles.CompileWatch`, where its
+        #: owner opened one: ``cli.train`` hangs it here and drains both into
+        #: the same rows (one more local in ``train`` would cost set-up time)
+        self.compiles = None
         self.registry = registry if registry is not None \
             else get_registry("jimm_train")
         self._counters = {

@@ -80,7 +80,11 @@ def test_train_rows_carry_the_loops_phases(tmp_path, capsys, mesh,
     # step's checkpoint runs after its dispatch and so stands after it
     own = ["next_batch", "dispatch", "checkpoint", "device_wait"] if mesh \
         else ["next_batch", "place", "dispatch", "checkpoint", "device_wait"]
-    assert [p[0] for p in rows[0]["phases"]] == own
+    # the first row begins with the set-up spans: everything train() did
+    # before its loop, from its first statement (--ckpt-dir: the manager)
+    assert [p[0] for p in rows[0]["phases"]] == [
+        "backend_init", "imports", "backend_init", "model_build",
+        "optimizer_build", "checkpoint", "data_build", "data_build", *own]
     for r in rows[1:]:
         assert [p[0] for p in r["phases"]] == ["host_sync", *own]
 
@@ -127,6 +131,12 @@ def test_train_rows_carry_the_loops_phases(tmp_path, capsys, mesh,
         sum(total(r, "dispatch", "device_wait") for r in rows[1:]), **near)
     assert goodput["data_wait_s"] == pytest.approx(
         sum(total(r, "next_batch", "place") for r in rows), **near)
+    from jimm_tpu.obs.goodput import SETUP_PHASES
+    assert goodput["setup_s"] == pytest.approx(
+        total(rows[0], *SETUP_PHASES), **near)
+    # the wall runs from train()'s first statement to the goodput line
+    assert sum(goodput[f"{b}_s"] for b in ("setup", "compile", "step")) < (
+        goodput["wall_s"]) <= (t1 - t0) / 1e9
     # every checkpoint is in a row; the last step's host_sync ends after it
     assert goodput["checkpoint_s"] == pytest.approx(
         sum(total(r, "checkpoint") for r in rows), **near)

@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from jimm_tpu import obs
+from jimm_tpu.obs.compiles import row_keys
 from jimm_tpu.obs.registry import _hub
 
 
@@ -185,7 +186,10 @@ class TestGoodputPhases:
     @pytest.mark.parametrize("phase, bucket", [
         ("next_batch", "data_wait"), ("place", "data_wait"),
         ("dispatch", "step"), ("device_wait", "step"),
-        ("host_sync", "host_sync"), ("checkpoint", "checkpoint")])
+        ("host_sync", "host_sync"), ("checkpoint", "checkpoint"),
+        ("imports", "setup"), ("backend_init", "setup"),
+        ("model_build", "setup"), ("optimizer_build", "setup"),
+        ("data_build", "setup")])
     def test_a_phase_adds_to_its_bucket_and_to_nothing_else(self, phase,
                                                             bucket):
         from jimm_tpu.obs.goodput import PHASES
@@ -202,6 +206,38 @@ class TestGoodputPhases:
         assert snap[f"goodput_{bucket}_seconds_total"] >= 0.003
         assert not any(phase in k for k in snap if phase != bucket), (
             "a phase is not mirrored to the registry under its own name")
+
+    def test_setup_is_a_bucket_and_its_phases_are_the_ones_train_runs(self):
+        from jimm_tpu.obs.goodput import BUCKETS, PHASES, SETUP_PHASES
+        assert "setup" in BUCKETS and "setup" in obs.BUCKETS
+        assert SETUP_PHASES == ("imports", "backend_init", "model_build",
+                                "optimizer_build", "data_build")
+        assert {p for p, b in PHASES.items() if b == "setup"} == {
+            "setup", *SETUP_PHASES}
+        acct = obs.GoodputAccounter(obs.MetricRegistry("t_setup_report"))
+        with acct.measure("model_build"):
+            time.sleep(0.02)
+        report = acct.report()  # rounds its fields to 0.1 ms
+        assert report["setup_s"] >= 0.02
+        assert report["setup_frac"] == pytest.approx(
+            report["setup_s"] / report["wall_s"], abs=0.01)
+
+    def test_the_drain_at_step_0_hands_out_the_setup_spans_first(self):
+        """``cli.train``'s loop drains after step 0's dispatch: everything
+        measured since the accounter's birth, set-up first."""
+        from jimm_tpu.obs.goodput import SETUP_PHASES
+        acct = obs.GoodputAccounter(obs.MetricRegistry("t_setup_drain"))
+        for phase in (*SETUP_PHASES, "next_batch", "place"):
+            with acct.measure(phase):
+                pass
+        with acct.measure("dispatch", "compile"):
+            time.sleep(0.002)
+        names = [s[0] for s in acct.drain()]
+        assert names == [*SETUP_PHASES, "next_batch", "place", "dispatch"]
+        secs = acct.seconds()
+        assert secs["setup"] > 0 and secs["compile"] >= 0.002
+        # the wall runs from the accounter's birth: set-up is inside it
+        assert acct.wall_s() >= secs["setup"] + secs["compile"]
 
     def test_the_first_steps_phases_can_land_in_compile(self):
         acct = obs.GoodputAccounter(obs.MetricRegistry("t_phase_compile"))
@@ -280,6 +316,188 @@ class TestGoodputPhases:
         monkeypatch.undo()
         assert acct.drain() == []
         assert acct.seconds(wall=1.0)["step"] == 0.0
+
+
+def _monitoring_listeners():
+    from jax._src import monitoring
+    return (list(monitoring.get_event_duration_listeners())
+            + list(monitoring.get_event_listeners()))
+
+
+class TestCompileWatch:
+    """``obs/compiles.py``: the program's one listener to jax.monitoring."""
+
+    def test_every_request_is_named_and_lies_on_the_wall_clock(self):
+        import jax
+        import jax.numpy as jnp
+        reg = obs.MetricRegistry("t_compiles")
+        before = len(_monitoring_listeners())
+        watch = obs.CompileWatch(reg)
+        assert len(_monitoring_listeners()) == before, "not until asked to"
+        watch.listen()
+        try:
+            assert len(_monitoring_listeners()) == before + 2
+            t0 = time.time_ns()
+
+            @jax.jit
+            def a_function_of_this_test(x):
+                return jnp.tanh(x) * 2 + jnp.clip(x, 0, 1)
+
+            a_function_of_this_test(jnp.ones((3, 5))).block_until_ready()
+            t1 = time.time_ns()
+            row = row_keys(watch.drain())
+        finally:
+            watch.close()
+        assert len(_monitoring_listeners()) == before
+        mine = [e for e in row["compiles"]
+                if "a_function_of_this_test" in e[1]]
+        assert [e[0] for e in mine] == ["trace", "lower", "compile"]
+        for kind, fun, start, dur in row["compiles"]:
+            assert isinstance(start, int) and isinstance(dur, int)
+            assert t0 - 1_000_000 <= start and start + dur <= t1
+        # clip and tanh are jitted functions of their own: traced inside the
+        # outer trace, and not kept
+        by_clock = sorted(row["compiles"], key=lambda e: e[2])
+        for (_, _, a0, adur), (_, _, b0, _) in zip(by_clock, by_clock[1:]):
+            assert a0 + adur <= b0 + 1_000, "no event inside another"
+        assert json.loads(json.dumps(row)) == row
+        n = sum(e[0] == "compile" for e in row["compiles"])
+        assert watch.requests == n >= 1
+        snap = reg.snapshot()
+        assert snap["compile_requests_total"] == n
+        assert snap["compile_seconds_total"] == pytest.approx(
+            sum(e[3] for e in row["compiles"] if e[0] == "compile") / 1e9,
+            abs=1e-6)
+        # each event is handed out once; a closed watch hears nothing
+        assert row_keys(watch.drain()) == {}
+        jax.jit(lambda x: x - 3)(jnp.ones(7)).block_until_ready()
+        assert row_keys(watch.drain()) == {} and watch.requests == n
+
+    def test_a_trace_inside_another_stage_is_dropped_with_hand_made_events(
+            self, monkeypatch):
+        from jimm_tpu.obs import compiles
+        watch = obs.CompileWatch(obs.MetricRegistry("t_compiles_nested"))
+        watch.close()  # fed by hand below
+        now = [1_000_000_000_000]
+        monkeypatch.setattr(compiles.time, "time_ns", lambda: now[0])
+        trace, lower, backend = compiles.KINDS  # in the order of a request
+
+        def ends(event, at_s, dur_s, fun):
+            now[0] = int(1e12 + at_s * 1e9)
+            watch._duration(event, dur_s, fun_name=fun)
+
+        ends(trace, 0.5, 0.5, "earlier")           # [0.0, 0.5]
+        ends(trace, 1.2, 0.1, "inner_a")           # [1.1, 1.2]
+        ends(backend, 1.5, 0.2, "jit(constant)")   # [1.3, 1.5] inside outer
+        ends(trace, 1.7, 0.1, "inner_b")           # [1.6, 1.7]
+        ends(trace, 2.0, 1.0, "outer")             # [1.0, 2.0]
+        ends(trace, 2.3, 0.1, "helper")            # [2.2, 2.3] inside lower
+        ends(lower, 2.5, 0.5, "jit(outer)")        # [2.0, 2.5]
+        ends(backend, 3.5, 1.0, "jit(outer)")      # [2.5, 3.5]
+        ends("/jax/core/compile/something_else", 3.6, 0.1, "x")
+        for _ in range(3):
+            watch._event("/jax/compilation_cache/cache_hits")
+        watch._event("/jax/compilation_cache/cache_misses")
+        watch._event("/jax/compilation_cache/something_else")
+        row = row_keys(watch.drain())
+        assert [(e[0], e[1]) for e in row["compiles"]] == [
+            ("trace", "earlier"), ("compile", "jit(constant)"),
+            ("trace", "outer"), ("lower", "jit(outer)"),
+            ("compile", "jit(outer)")]
+        assert row["compiles"][2][2:] == [int(1e12 + 1e9), int(1e9)]
+        assert (row["cache_hits"], row["cache_misses"]) == (3, 1)
+        assert watch.requests == 2 and watch.cache == {"hits": 3,
+                                                       "misses": 1}
+        # counts alone make a row too; an empty one has no key at all
+        watch._event("/jax/compilation_cache/cache_hits")
+        assert row_keys(watch.drain()) == {"cache_hits": 1}
+        assert row_keys(watch.drain()) == {}
+
+    def test_a_watch_nobody_drains_stays_bounded(self):
+        from jimm_tpu.obs import compiles
+        watch = obs.CompileWatch(obs.MetricRegistry("t_compiles_bounded"))
+        watch.close()
+        for i in range(compiles.MAX_UNDRAINED_EVENTS + 10):
+            watch._duration("/jax/core/compile/backend_compile_duration",
+                            0.0, fun_name=f"jit(f{i})")
+        assert len(row_keys(watch.drain())["compiles"]) == (
+            compiles.MAX_UNDRAINED_EVENTS)
+        assert watch.requests == compiles.MAX_UNDRAINED_EVENTS + 10
+
+    def test_two_watches_alive_at_once_count_into_their_own_registries(self):
+        """``chip_smoke.py`` counts over several ``cli.train`` calls, each of
+        which opens the run's own: on one registry both would count."""
+        import jax
+        import jax.numpy as jnp
+        outer_reg = obs.MetricRegistry("t_compiles_outer")
+        run_reg = obs.MetricRegistry("t_compiles_run")
+        outer = obs.CompileWatch(outer_reg).listen()
+        run = obs.CompileWatch(run_reg).listen()
+        try:
+            jax.jit(lambda x: x * 7 - 2)(jnp.ones(13)).block_until_ready()
+        finally:
+            run.close()
+            outer.close()
+        n = run.requests
+        assert n >= 1 and outer.requests == n
+        assert run_reg.snapshot()["compile_requests_total"] == n
+        assert outer_reg.snapshot()["compile_requests_total"] == n
+
+    def test_a_watch_listens_inside_each_with_and_is_gone_on_every_way_out(
+            self):
+        """``cli.train`` enters its watch beside each phase of set-up that
+        builds programs, and once more for the loop."""
+        import jax
+        import jax.numpy as jnp
+        before = _monitoring_listeners()
+        watch = obs.CompileWatch(obs.MetricRegistry("t_compiles_with"))
+        with watch as entered:
+            assert entered is watch and watch.listening
+            assert watch.listen() is watch, "asking twice registers once"
+            assert len(_monitoring_listeners()) == len(before) + 2
+            jax.jit(lambda x: x / 3 + 8)(jnp.ones(17)).block_until_ready()
+        assert _monitoring_listeners() == before and not watch.listening
+        n = watch.requests
+        assert n >= 1
+        jax.jit(lambda x: x / 5 + 9)(jnp.ones(19)).block_until_ready()
+        assert watch.requests == n, "between two stretches it hears nothing"
+        with pytest.raises(ZeroDivisionError):
+            with watch:
+                jax.jit(lambda x: x / 7)(jnp.ones(23)).block_until_ready()
+                1 / 0
+        assert _monitoring_listeners() == before
+        assert watch.requests > n, "and counts on where it left off"
+        watch.close()  # closing a closed watch is nothing
+
+    def test_a_row_is_made_of_the_events_drained_for_it(self):
+        """``cli.train`` drains where it drains its accounter and adds the
+        lists up, like a row's phases."""
+        stage = ["compile", "jit(f)", 5, 7]
+        assert row_keys([]) == {}
+        assert row_keys([["hits", "", 1, 0]]) == {"cache_hits": 1}
+        assert row_keys([["misses", "", 1, 0], stage] + [stage]) == {
+            "cache_misses": 1, "compiles": [stage, stage]}
+
+    def test_disabled_registers_no_listener_and_makes_no_row_key(self):
+        import jax
+        import jax.numpy as jnp
+        obs.set_enabled(False)
+        reg = obs.MetricRegistry("t_compiles_off")
+        before = _monitoring_listeners()
+        with obs.CompileWatch(reg) as watch:
+            assert _monitoring_listeners() == before
+            jax.jit(lambda x: x * 5 + 1)(jnp.ones(11)).block_until_ready()
+        assert row_keys(watch.drain()) == {} and watch.requests == 0
+        assert not any(reg.snapshot().values())
+        assert _monitoring_listeners() == before
+
+
+def test_the_star_import_finds_every_name_it_lists():
+    """``__all__`` listed four names of a module deleted in PR 29."""
+    namespace = {}
+    exec("from jimm_tpu.obs import *", namespace)
+    assert set(obs.__all__) <= set(namespace)
+    assert {"GoodputAccounter", "CompileWatch", "span"} <= set(namespace)
 
 
 class TestExporters:

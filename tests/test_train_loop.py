@@ -220,6 +220,140 @@ def test_a_failing_step_still_leaves_the_rows_before_it(tmp_path,
 
 
 # ---------------------------------------------------------------------------
+# set-up: the first row begins with it, and the listener leaves with the call
+# ---------------------------------------------------------------------------
+
+def _monitoring_listeners():
+    from jax._src import monitoring
+    return (list(monitoring.get_event_duration_listeners())
+            + list(monitoring.get_event_listeners()))
+
+
+def test_row_0_begins_with_the_setup_spans_and_names_every_compile(tmp_path):
+    import time
+    metrics = tmp_path / "m.jsonl"
+    before = _monitoring_listeners()
+    t0 = time.time_ns()
+    assert main(TINY_VIT + ["--log-every", "0",
+                            "--metrics-file", str(metrics)]) == 0
+    t1 = time.time_ns()
+    assert _monitoring_listeners() == before, "the listener left with train()"
+    rows = read_rows(metrics)
+    names = [p[0] for p in rows[0]["phases"]]
+    assert names[:2] == ["backend_init", "imports"], (
+        "from train()'s first statement, in the order they ran")
+    builds = [n for n in names
+              if n in ("model_build", "optimizer_build", "data_build")]
+    assert builds == ["model_build", "optimizer_build", "data_build",
+                      "data_build"], "in order; the wrappers' span is last"
+    assert names.index("data_build") < names.index("next_batch")
+    spans = sorted((p for r in rows for p in r["phases"]),
+                   key=lambda p: p[1])
+    assert t0 <= spans[0][1] and spans[0][0] == "backend_init"
+    for (_, a0, adur), (_, b0, _) in zip(spans, spans[1:]):
+        assert a0 + adur <= b0, "never overlapping"
+    assert not any(p[0] in obs.goodput.SETUP_PHASES
+                   for r in rows[1:] for p in r["phases"])
+
+    # every compile request of the run is in row 0, inside the process's
+    # lifetime and inside one phase: the small programs in the two builds,
+    # the step's own three stages in step 0's dispatch
+    events = rows[0]["compiles"]
+    assert not any("compiles" in r or "cache_misses" in r for r in rows[1:])
+
+    def parent(event):
+        (found,) = [name for name, start, dur in spans
+                    if start <= event[2] < start + dur]
+        return found
+
+    for kind, fun, start, dur in events:
+        assert kind in ("trace", "lower", "compile")
+        assert t0 <= start and start + dur <= t1
+    parents = {parent(e) for e in events}
+    # (a process that has built this model before holds the builds' small
+    # programs already: only the step's own stages are sure to be there)
+    assert "dispatch" in parents
+    assert parents <= {"imports", "backend_init", "model_build",
+                       "optimizer_build", "data_build", "next_batch", "place",
+                       "dispatch"}
+    (dispatch0,) = [p for p in rows[0]["phases"] if p[0] == "dispatch"]
+    step = [(kind, fun) for kind, fun, start, _ in events
+            if dispatch0[1] <= start < dispatch0[1] + dispatch0[2]]
+    assert step == [("trace", "train_step"), ("lower", "jit(train_step)"),
+                    ("compile", "jit(train_step)")]
+    snap = obs.get_registry("jimm_train").snapshot()
+    assert snap["compile_requests_total"] >= sum(
+        e[0] == "compile" for e in events)
+
+
+def test_a_late_compile_is_in_the_row_of_the_step_that_asked_for_it(
+        tmp_path, monkeypatch):
+    """Rows are grouped by step and so are their compile events: with one
+    step in flight, row k is written after step k+1's dispatch, and what that
+    dispatch compiled waits for row k+1. Here step 2's batch is half the
+    size, and the step compiles a second time."""
+    from jimm_tpu import data as data_lib
+    original = data_lib.blob_classification
+
+    def a_smaller_third_batch(*a, **kw):
+        for i, batch in enumerate(original(*a, **kw)):
+            yield tuple(x[:4] for x in batch) if i == 2 else batch
+
+    monkeypatch.setattr(data_lib, "blob_classification",
+                        a_smaller_third_batch)
+    metrics = tmp_path / "m.jsonl"
+    assert main(TINY_VIT + ["--log-every", "0",
+                            "--metrics-file", str(metrics)]) == 0
+    rows = read_rows(metrics)
+    assert [i for i, r in enumerate(rows) if "compiles" in r] == [0, 2]
+    (dispatch2,) = [p for p in rows[2]["phases"] if p[0] == "dispatch"]
+    late = [(kind, fun) for kind, fun, start, _ in rows[2]["compiles"]
+            if dispatch2[1] <= start < dispatch2[1] + dispatch2[2]]
+    assert late == [("trace", "train_step"), ("lower", "jit(train_step)"),
+                    ("compile", "jit(train_step)")]
+
+
+def test_the_listener_is_gone_after_train_raises(tmp_path):
+    """A ``crash@1`` drill out of the loop, and a refusal before the model
+    is built: neither leaves a listener behind for the next call."""
+    before = _monitoring_listeners()
+    metrics = tmp_path / "m.jsonl"
+    with pytest.raises(RuntimeError, match="injected failure at step 1"):
+        main(TINY_VIT + ["--log-every", "0", "--metrics-file", str(metrics),
+                         "--inject-faults", "crash@1"])
+    assert _monitoring_listeners() == before
+    rows = read_rows(metrics)
+    assert [r["step"] for r in rows] == [0, 1]
+    assert rows[0]["phases"][1][0] == "imports" and "compiles" in rows[0]
+    with pytest.raises(SystemExit, match="--tiny conflicts"):
+        main(TINY_VIT + ["--from-pretrained", str(tmp_path)])
+    assert _monitoring_listeners() == before
+
+
+def test_with_obs_off_no_span_no_listener_no_row_key(tmp_path, monkeypatch):
+    import jax
+    heard = []
+    for register in ("register_event_duration_secs_listener",
+                     "register_event_listener"):
+        monkeypatch.setattr(jax.monitoring, register, heard.append)
+    obs.CompileWatch(obs.MetricRegistry("t_loop_watch")).listen()
+    assert len(heard) == 2, "what a watch registers with obs on"
+    del heard[:]
+    metrics = tmp_path / "m.jsonl"
+    prev = obs.enabled()
+    obs.set_enabled(False)
+    try:
+        assert main(TINY_VIT + ["--log-every", "0",
+                                "--metrics-file", str(metrics)]) == 0
+    finally:
+        obs.set_enabled(prev)
+    assert heard == [], "JIMM_OBS=0 registers no listener"
+    for r in read_rows(metrics):
+        assert r["phases"] == []
+        assert not {"compiles", "cache_hits", "cache_misses"} & set(r)
+
+
+# ---------------------------------------------------------------------------
 # the counter that says the mechanism engaged
 # ---------------------------------------------------------------------------
 
