@@ -52,8 +52,28 @@ walked by one straight-line loop so that one head's softmax runs beside
 another head's matmuls. A causal call's grid is not the ``(q, kv)``
 rectangle but the list of its live block pairs (`_live_pairs`, two
 scalar-prefetched int32 tables: row-major for the forward and dq,
-column-major for dk/dv), so no grid step is taken for a block above the
-diagonal.
+column-major for dk/dv and the fused backward), so no grid step is taken for
+a block above the diagonal.
+
+The tiled backward recomputes attention blockwise (from the saved logsumexp
+for softmax kinds; from scratch for sigmoid), in one of two arrangements of
+the same sums, picked by a test of shapes alone (`_DQ_RESIDENT_BUDGET`; no
+flag, no preset's name):
+
+- **fused** — while the fp32 dq of a cell's heads over their whole padded
+  S_q fits in VMEM (up to 65,536 tokens at D = 128, 32,768 at 256): ONE
+  kernel on dk/dv's column-major grid. A block pair's s, dp, p and ds are
+  computed once; dk and dv accumulate over the column's q blocks in scratch
+  as they always have, and ``ds k`` adds into the resident dq at the step's
+  q block, whose pairs arrive at ascending kv block, dq's own order. Every
+  visit writes the q block's sum so far to its output block; the last one
+  holds the whole sum. 8 MXU passes a pair at 256 | 128 lanes where the pair
+  of kernels makes 11, 5 matmuls where it makes 7.
+- **dq + dk/dv** — above that bound: the flash-attention-2 arrangement, two
+  kernels that each recompute s and dp.
+
+For the bias variant a further kernel's grid runs batch innermost to
+accumulate dbias across samples.
 
 ``flash_attention`` also takes **grouped key/value heads** (k and v with
 fewer heads than q, a divisor of its count) and a **causal window**. Grouped
@@ -62,15 +82,11 @@ count's lanes): a cell's ``hb`` query heads all read ONE k/v head (``hb``
 divides the group), whose blocks the index maps fetch at its own index, so k
 and v are never repeated in HBM; dk/dv's grid runs over the k/v heads with the
 group's cells as a last, innermost axis that accumulates into the one head's
-scratch, so dk and dv leave at the k/v heads' own count. Under a window the
+scratch, so dk and dv leave at the k/v heads' own count (fused, the dq of all
+the group's cells stays resident). Under a window the
 live-pair tables also drop the pairs wholly left of it and the position mask
 takes its second edge; a window that reaches over every key is dropped before
 dispatch (`live_window`), so that call is the plain causal one.
-
-The tiled backward recomputes attention blockwise (from the saved logsumexp
-for softmax kinds; from scratch for sigmoid) — dq kernel plus dk/dv kernel in
-the flash-attention-2 arrangement, and for the bias variant a third kernel
-whose grid runs batch innermost to accumulate dbias across samples.
 
 Numerical contract: softmax variants match
 `jimm_tpu.ops.attention.reference_attention` (fp32 softmax einsum) to
@@ -375,10 +391,19 @@ def _bwd_dq_kernel(*refs, sk_real: int, block_k: int, causal: bool,
 def _bwd_dkv_kernel(*refs, sq_real: int, block_q: int, causal: bool,
                     sm_scale: float, logit_bias: float, n_q: int,
                     spec: VariantSpec, window: int | None = None,
-                    cells: int = 1):
+                    cells: int = 1, fused: bool = False):
     """``cells`` > 1 (grouped key/value heads, a group wider than a cell): the
     grid's last axis walks the group's cells innermost, all adding into the
-    one k/v head's scratch, so dk and dv leave summed over the group."""
+    one k/v head's scratch, so dk and dv leave summed over the group.
+
+    ``fused``: the call is the whole tiled backward. Each block pair's
+    ``ds`` also adds ``ds k`` into a fp32 dq accumulator that holds ALL the q
+    blocks of the cell's heads (and of the group's other cells) in VMEM over
+    the sweep, at the step's q block. On this grid a q block's pairs arrive
+    at ascending kv block, `_bwd_dq_kernel`'s order, so the sums are its
+    sums. Every visit writes the q block's sum so far, scaled and cast, to
+    its dq block: the last visit holds the whole sum and is the last to be
+    written back."""
     qi, kj, refs = _block_ids(refs, causal, kv_major=True)
     softmax = spec.kind == "softmax"
     it = iter(refs)
@@ -390,8 +415,10 @@ def _bwd_dkv_kernel(*refs, sq_real: int, block_q: int, causal: bool,
     delta_ref = next(it) if softmax else None
     dk_ref = next(it)
     dv_ref = next(it)
+    dq_ref = next(it) if fused else None
     dk_scr = next(it)
     dv_scr = next(it)
+    dq_scr = next(it) if fused else None
     hkv, bk, d = k_ref.shape
     hb = q_ref.shape[0]
     cell = pl.program_id(2 if causal else 3) if cells > 1 else None
@@ -403,12 +430,21 @@ def _bwd_dkv_kernel(*refs, sq_real: int, block_q: int, causal: bool,
         dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
         dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
 
+    if fused:
+        # the q block's place in the resident accumulator
+        slot = qi if cell is None else cell * n_q + qi
+
+        @pl.when(kj == _first_kv(qi, block_q, bk, window))
+        def _init_dq():
+            dq_scr[slot] = jnp.zeros(dq_scr.shape[1:], jnp.float32)
+
     pos = _pos_mask(qi, kj, block_q, bk, causal, sq_real=sq_real,
                     window=window)
     for h in range(hb):
         q = q_ref[h]
         do = do_ref[h]
-        s = _scores(q, k_ref[h * hkv // hb], sm_scale,
+        k = k_ref[h * hkv // hb]
+        s = _scores(q, k, sm_scale,
                     mask_ref[h] if spec.has_mask else None,
                     bias_ref[h] if spec.has_bias else None, pos)
         p, ds = _ds_tile(spec, s, do, v_ref[h * hkv // hb],
@@ -421,9 +457,17 @@ def _bwd_dkv_kernel(*refs, sq_real: int, block_q: int, causal: bool,
         dv_scr[h * hkv // hb] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        ds = ds.astype(q.dtype)
         dk_scr[h * hkv // hb] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if fused:
+            dq_scr[slot, h] += jax.lax.dot_general(
+                ds, k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+    if fused:
+        dq_ref[...] = (dq_scr[slot] * sm_scale).astype(dq_ref.dtype)
 
     last = qi == _last_q(kj, block_q, bk, n_q, window)
 
@@ -838,14 +882,16 @@ def _tiled_specs(g: _TiledGrid, hb: int, block_q: int, block_k: int, d: int,
 
 
 def _tiled_call(kernel, g: _TiledGrid, in_specs, out_specs, out_shape,
-                scratch, vmem_limit: int, inputs, *, inner_cells: bool = False,
-                window: int | None = None, grouped: bool = False):
-    """The one pallas_call of the tiled regime's forward, dq and dk/dv.
-    ``inner_cells``: the grid's last axis is a group's cells (dk/dv), which
-    accumulate like the axis before it."""
+                scratch, vmem_limit: int, inputs, *, carried: int = 1,
+                window: int | None = None, grouped: bool = False,
+                fused: bool = False):
+    """The one pallas_call of the tiled regime's forward, dq and dk/dv (or
+    the ``fused`` backward). ``carried``: the grid's last axes that
+    accumulate into scratch: the pairs (or a block's row or column of the
+    rectangle), before them the rectangle's other axis where dq stays
+    resident over it, after them a group's cells (dk/dv)."""
     _count_call("tiled", steps=math.prod(g.grid), live_steps=g.live_steps,
-                window=window is not None, grouped=grouped)
-    carried = 2 if inner_cells else 1
+                window=window is not None, grouped=grouped, fused=fused)
     params = pltpu.CompilerParams(
         dimension_semantics=("parallel",) * (len(g.grid) - carried)
         + ("arbitrary",) * carried, vmem_limit_bytes=vmem_limit)
@@ -871,21 +917,33 @@ def _tiled_call(kernel, g: _TiledGrid, in_specs, out_specs, out_shape,
 #: default scope and held a cell of 512 x 512 blocks at one or two heads.
 _VMEM_BUDGET = 32 * 1024 * 1024
 
+#: what the fused tiled backward's dq accumulators may add up to for one grid
+#: cell, on top of `_VMEM_BUDGET`'s tiles: they are scratch, held once (no
+#: second buffer), so a fused call states at most 64 + 32 of the v5e's 128
+#: MiB. THE rule of the backward's arrangement, a test of shapes alone: a
+#: call whose padded S_q x D x 4 bytes a head fit under it at some head count
+#: is ONE kernel, any other (65,536 tokens up at D = 128, 32,768 at 256)
+#: keeps the dq + dk/dv pair.
+_DQ_RESIDENT_BUDGET = 32 * 1024 * 1024
+
 
 def _per_head_vmem_bytes(block_q: int, block_k: int, d: int, *,
                          kind: str = "softmax", has_mask: bool = False,
-                         has_bias: bool = False) -> int:
+                         has_bias: bool = False, dq_rows: int = 0) -> int:
     """Estimated resident VMEM per head in one grid cell — the model behind
     `_pick_hb`, exposed for `scripts/vmem_probe.py` to validate against
     Mosaic's compile-time accounting (one shared formula, no drift). Sized
     on the backward, which holds the most: the fp32 s, p, dp and ds tiles
     and the two MXU-operand copies of p and ds (20 bytes a score: 20 MB at
-    1024 x 1024). The per-variant terms are mirrored jax-free in
-    `tune/space.py` (sync-tested in tests/test_tune.py)."""
+    1024 x 1024), and in the fused backward the head's ``dq_rows`` resident
+    rows of fp32 dq (its whole padded S_q, times the cells of its group). The
+    per-variant terms are mirrored jax-free in `tune/space.py` (sync-tested in
+    tests/test_tune.py)."""
     n = (3 * block_k * d * 2            # k/v in + one of q/do
          + 2 * block_q * d * 2          # q tile + bf16 out tile
          + 2 * block_q * d * 4          # fp32 accumulators
-         + block_q * block_k * 20)      # s, p, dp, ds fp32 + 2 bf16 copies
+         + block_q * block_k * 20       # s, p, dp, ds fp32 + 2 bf16 copies
+         + dq_rows * d * 4)             # fused backward: resident fp32 dq
     if kind == "softmax":
         n += 2 * block_q * _LANES * 4   # m/l stats scratch (sigmoid: none)
     if has_mask:
@@ -896,15 +954,15 @@ def _per_head_vmem_bytes(block_q: int, block_k: int, d: int, *,
 
 
 def _spec_vmem_bytes(block_q: int, block_k: int, d: int,
-                     spec: VariantSpec) -> int:
+                     spec: VariantSpec, dq_rows: int = 0) -> int:
     return _per_head_vmem_bytes(block_q, block_k, d, kind=spec.kind,
                                 has_mask=spec.has_mask,
-                                has_bias=spec.has_bias)
+                                has_bias=spec.has_bias, dq_rows=dq_rows)
 
 
 def _pick_hb(bn: int, block_q: int, block_k: int, d: int,
              spec: VariantSpec = _SOFTMAX, n_heads: int | None = None,
-             group: int = 1) -> int:
+             group: int = 1, dq_seq: int = 0) -> int:
     """Heads per grid cell: the per-head (S, 64) matmuls are too small to
     hide the ~us grid-step sequencing cost, so each cell processes `hb`
     heads back to back (measured ~2x on ViT-shape attention on v5e), and a
@@ -919,25 +977,34 @@ def _pick_hb(bn: int, block_q: int, block_k: int, d: int,
     a head block never straddles two samples' rows (its bias index map
     divides by N/hb). Under grouped key/value heads (``group`` query heads to
     one) a cell's heads all read the same k/v head, so ``hb`` divides the
-    group: 6, 3, 2 or 1 of 48 heads over 8."""
+    group: 6, 3, 2 or 1 of 48 heads over 8.
+
+    ``dq_seq`` (the fused backward: the padded S_q): the cell also keeps the
+    fp32 dq of its heads' whole sequence, and of its group's other cells',
+    under `_DQ_RESIDENT_BUDGET`; 0 heads if no count fits it, and the
+    backward is then the dq + dk/dv pair at the forward's heads."""
     per_head = _spec_vmem_bytes(block_q, block_k, d, spec)
-    for hb in ((8, 4, 2) if group == 1 else range(min(group, 8), 1, -1)):
+    for hb in ((8, 4, 2, 1) if group == 1 else range(min(group, 8), 0, -1)):
         if bn % hb or (group > 1 and group % hb):
             continue
         if spec.has_bias and (n_heads or bn) % hb:
             continue
-        if hb * per_head <= _VMEM_BUDGET:
+        if hb > 1 and hb * per_head > _VMEM_BUDGET:
+            continue
+        if max(hb, group) * dq_seq * d * 4 <= _DQ_RESIDENT_BUDGET:
             return hb
-    return 1
+    return 0
 
 
 def _tiled_vmem_limit(hb: int, block_q: int, block_k: int, d: int,
-                      spec: VariantSpec) -> int:
+                      spec: VariantSpec, dq_rows: int = 0) -> int:
     """The scoped VMEM a tiled call states (forward, dq, dk/dv and dbias
-    alike): twice its model and never under the budget, as
-    `_single_tile_call` does. `_pick_hb` holds the model under the budget, so
-    at most 64 MiB."""
-    return max(2 * hb * _spec_vmem_bytes(block_q, block_k, d, spec),
+    alike): twice its model of the tiles and never under the budget, as
+    `_single_tile_call` does, and once the fused backward's ``dq_rows``
+    resident rows a head. `_pick_hb` holds the tiles and the rows under their
+    budgets, so at most 64 MiB, 96 fused."""
+    return max(hb * (_spec_vmem_bytes(block_q, block_k, d, spec)
+                     + _spec_vmem_bytes(block_q, block_k, d, spec, dq_rows)),
                _VMEM_BUDGET)
 
 
@@ -995,7 +1062,8 @@ def _single_tile_plan(n: int, sq: int, sk: int, d: int, itemsize: int,
 
 
 def _count_call(regime: str, steps: int = 0, live_steps: int = 0, *,
-                window: bool = False, grouped: bool = False) -> None:
+                window: bool = False, grouped: bool = False,
+                fused: bool = False) -> None:
     """One count per pallas_call built (trace time, like the tuner's
     ``jimm_tune_*``): ``jimm_flash_single_tile_total`` /
     ``jimm_flash_tiled_total``, and ``jimm_flash_direct_total`` for a call
@@ -1007,7 +1075,8 @@ def _count_call(regime: str, steps: int = 0, live_steps: int = 0, *,
     `_live_pairs` keeps where keys lie right of every query).
     ``jimm_flash_window_total`` counts the calls built with a causal window,
     ``jimm_flash_grouped_kv_total`` those with fewer key/value heads than
-    query heads."""
+    query heads, ``jimm_flash_fused_bwd_total`` the tiled backwards built as
+    one kernel (dq, dk and dv from one pass over the block pairs)."""
     from jimm_tpu.obs.registry import get_registry
     registry = get_registry("jimm_flash")
     registry.counter(f"{regime}_total").inc()
@@ -1018,6 +1087,8 @@ def _count_call(regime: str, steps: int = 0, live_steps: int = 0, *,
         registry.counter("window_total").inc()
     if grouped:
         registry.counter("grouped_kv_total").inc()
+    if fused:
+        registry.counter("fused_bwd_total").inc()
 
 
 def _single_tile_call(kernel, inputs, outputs, n: int, hb: int, sq_p: int,
@@ -1202,7 +1273,12 @@ def _flash_bwd(causal, spec, sm_scale, logit_bias, block_q, block_k, n, res,
     qp, dop = _pad_seq(q3, sq_p), _pad_seq(do, sq_p)
     kp, vp = _pad_seq(k3, sk_p), _pad_seq(v3, sk_p)
     n_heads = bias.shape[0] if spec.has_bias else bn
-    hb = _pick_hb(bn, block_q, block_k, d, spec, n_heads, group)
+    # one kernel where the heads' fp32 dq stays in VMEM over the sweep (the
+    # rule is `_DQ_RESIDENT_BUDGET`'s), else the dq + dk/dv pair
+    hb = _pick_hb(bn, block_q, block_k, d, spec, n_heads, group, dq_seq=sq_p)
+    fused = hb > 0
+    if not fused:
+        hb = _pick_hb(bn, block_q, block_k, d, spec, n_heads, group)
     n_hb = n_heads // hb
     # grouped key/value heads: a cell's heads read one k/v head, and
     # ``cells`` cells make up a group
@@ -1250,26 +1326,38 @@ def _flash_bwd(causal, spec, sm_scale, logit_bias, block_q, block_k, n, res,
             specs += [sp["stat"], sp["stat"]]
         return g, sp, inputs, specs
 
-    # ---- dq ---------------------------------------------------------------
-    g, sp, inputs, specs = operands(kv_major=False)
-    dq = _tiled_call(
-        partial(_bwd_dq_kernel, sk_real=sk, block_k=block_k, n_k=n_k,
-                **static),
-        g, specs, sp["q"], jax.ShapeDtypeStruct((bn, sq_p, d), q3.dtype),
-        [pltpu.VMEM((hb, block_q, d), jnp.float32)], vmem_limit,
-        inputs, **counted)[:, :sq]
+    # ---- dq (above the residency bound) -----------------------------------
+    if not fused:
+        g, sp, inputs, specs = operands(kv_major=False)
+        dq = _tiled_call(
+            partial(_bwd_dq_kernel, sk_real=sk, block_k=block_k, n_k=n_k,
+                    **static),
+            g, specs, sp["q"], jax.ShapeDtypeStruct((bn, sq_p, d), q3.dtype),
+            [pltpu.VMEM((hb, block_q, d), jnp.float32)], vmem_limit,
+            inputs, **counted)
 
-    # ---- dk / dv ----------------------------------------------------------
+    # ---- dk / dv, and under the bound dq with them ------------------------
     g, sp, inputs, specs = operands(kv_major=True)
-    dk, dv = _tiled_call(
+    out_specs = [sp["k"], sp["v"]]
+    out_shape = [jax.ShapeDtypeStruct((bn // group, sk_p, d), q3.dtype),
+                 jax.ShapeDtypeStruct((bn // group, sk_p, d_v), q3.dtype)]
+    scratch = [pltpu.VMEM((hkv, block_k, d), jnp.float32),
+               pltpu.VMEM((hkv, block_k, d_v), jnp.float32)]
+    carried, limit = (2 if cells > 1 else 1), vmem_limit
+    if fused:
+        out_specs.append(sp["q"])
+        out_shape.append(jax.ShapeDtypeStruct((bn, sq_p, d), q3.dtype))
+        scratch.append(pltpu.VMEM((cells * n_q, hb, block_q, d), jnp.float32))
+        # dq lives across every axis but the heads'
+        carried = len(g.grid) - 1
+        limit = _tiled_vmem_limit(hb, block_q, block_k, d, spec,
+                                  dq_rows=cells * sq_p)
+    dk, dv, *rest = _tiled_call(
         partial(_bwd_dkv_kernel, sq_real=sq, block_q=block_q, n_q=n_q,
-                cells=cells, **static),
-        g, specs, [sp["k"], sp["v"]],
-        [jax.ShapeDtypeStruct((bn // group, sk_p, d), q3.dtype),
-         jax.ShapeDtypeStruct((bn // group, sk_p, d_v), q3.dtype)],
-        [pltpu.VMEM((hkv, block_k, d), jnp.float32),
-         pltpu.VMEM((hkv, block_k, d_v), jnp.float32)], vmem_limit, inputs,
-        inner_cells=cells > 1, **counted)
+                cells=cells, fused=fused, **static),
+        g, specs, out_specs, out_shape, scratch, limit, inputs,
+        carried=carried, fused=fused, **counted)
+    dq = (rest[0] if fused else dq)[:, :sq]
 
     # ---- dbias ------------------------------------------------------------
     dbias = None

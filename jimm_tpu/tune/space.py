@@ -63,14 +63,19 @@ def _ceil_to(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
-def flash_vmem_bytes(block_q: int, block_k: int, d: int) -> int:
-    """jax-free mirror of ``_per_head_vmem_bytes`` (see module docstring)."""
+def flash_vmem_bytes(block_q: int, block_k: int, d: int,
+                     dq_rows: int = 0) -> int:
+    """jax-free mirror of ``_per_head_vmem_bytes`` (see module docstring).
+    ``dq_rows``: the fused tiled backward's resident fp32 dq rows a head (its
+    padded S_q); the block search prunes on the tiles alone, as `_pick_hb`
+    holds the two under budgets of their own."""
     return (
         3 * block_k * d * 2
         + 2 * block_q * d * 2
         + 2 * block_q * _LANES * 4
         + 2 * block_q * d * 4
-        + block_q * block_k * 20)  # s, p, dp, ds fp32 + two bf16 MXU copies
+        + block_q * block_k * 20   # s, p, dp, ds fp32 + two bf16 MXU copies
+        + dq_rows * d * 4)
 
 
 def _attn_space(shapes: Sequence[Sequence[int]], vmem_fn) -> list[dict]:

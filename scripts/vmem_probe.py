@@ -74,7 +74,9 @@ def probe(bn: int, seq: int, d: int, budget_deadline: float) -> None:
                                       "note": "budget exhausted"}),
                           flush=True)
                     return
-                fa._pick_hb = lambda *a, _hb=hb: _hb
+                # (a forced backward is the fused one, its dq resident on
+                # top of the tiles, whatever the residency bound says)
+                fa._pick_hb = lambda *a, _hb=hb, **kw: _hb
                 ok, err = compiles(which)
                 print(json.dumps({
                     "metric": "vmem_probe", "bn": bn, "seq": seq, "d": d,

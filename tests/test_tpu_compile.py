@@ -90,11 +90,13 @@ KERNEL_CASES = {
     # the first length over the rule: the tiled kernels, as at the parent
     "flash_s1153_d64": _flash((4, 1153, 16, 64)),
     # the looped decoder's training sequence: causal, 4096 tokens, 16 heads
-    # of 128: the tiled kernels (forward, dq, dk/dv)
+    # of 128: the tiled kernels (forward, and ONE backward with four heads'
+    # 8 MiB of fp32 dq resident)
     "flash_causal_s4096_d128": _flash((1, 4096, 16, 128), is_causal=True),
     # latent attention at the sparse decoder's training size: 8192 tokens,
     # 32 heads, q/k 192 wide (256 lanes), v 128: the tiled kernels with v, o,
-    # do and dv at their own tile
+    # do and dv at their own tile; the fused backward holds four heads' dq,
+    # 32 MiB (the residency bound itself), and states 94 MiB of the 128
     "flash_causal_s8192_qk192_v128": (
         functools.partial(fa.flash_attention, is_causal=True),
         [((2, 8192, 32, 192), jnp.bfloat16)] * 2
@@ -116,14 +118,15 @@ KERNEL_CASES = {
         [((2, 2048, 16, 256), jnp.bfloat16)] * 3
         + [((16, 2048, 2048), jnp.float32)]),
     # the longest causal length compiled: 256 blocks of 512 a side, 32,896
-    # live pairs, so 263 KB of scalar-prefetched tables in SMEM
+    # live pairs, so 263 KB of scalar-prefetched tables in SMEM; a head's dq
+    # is 64 MiB, over the residency bound, so the backward stays dq + dk/dv
     "flash_causal_s131072_d128": (
         functools.partial(fa.flash_attention, is_causal=True),
         [((1, 131072, 4, 128), jnp.bfloat16)] * 3),
     # grouped-query attention at the sparse decoder's training size: 8192
     # tokens, 48 heads of 128 over 8 key/value heads, three heads a cell; under
-    # a window of 4096 (108 of 136 live pairs; dk/dv over two cells a group)
-    # and full
+    # a window of 4096 (108 of 136 live pairs; the backward over two cells a
+    # group, both cells' dq resident: 24 MiB) and full
     "flash_gqa_window_s8192_48over8_d128": (
         functools.partial(fa.flash_attention, is_causal=True, window=4096),
         [((1, 8192, 48, 128), jnp.bfloat16)]
@@ -154,20 +157,22 @@ KERNEL_CASES = {
 }
 
 
-#: Mosaic calls in forward + backward where it is not the tiled three: the
-#: single-tile regime is one forward and ONE fused backward
+#: Mosaic calls in forward + backward: the single-tile regime is one forward
+#: and ONE fused backward, and so is the tiled regime while the heads' fp32
+#: dq fits in VMEM (`_DQ_RESIDENT_BUDGET`); above it dq and dk/dv are two
+#: calls, and the bias variant adds dbias
 SINGLE_TILE_CALLS = {"flash_s256_d64": 2, "flash_s128_d64": 2,
                      "flash_s196_d64": 2, "flash_masked_s256_d64": 2,
                      "flash_s577_d64": 2, "flash_s729_d72": 2,
                      "flash_masked_s577_d64": 2, "flash_s1152_d128": 2,
                      "flash_s197_d64_whole_row": 2,
-                     "flash_s1153_d64": 3, "flash_causal_s4096_d128": 3,
-                     "flash_causal_s8192_qk192_v128": 3,
-                     "flash_s2048_d64": 3, "flash_bias_s2048_d64": 4,
-                     "flash_bias_causal_s2048_d256": 4,
+                     "flash_s1153_d64": 2, "flash_causal_s4096_d128": 2,
+                     "flash_causal_s8192_qk192_v128": 2,
+                     "flash_s2048_d64": 2, "flash_bias_s2048_d64": 3,
+                     "flash_bias_causal_s2048_d256": 3,
                      "flash_causal_s131072_d128": 3,
-                     "flash_gqa_window_s8192_48over8_d128": 3,
-                     "flash_gqa_full_s8192_48over8_d128": 3,
+                     "flash_gqa_window_s8192_48over8_d128": 2,
+                     "flash_gqa_full_s8192_48over8_d128": 2,
                      "flash_window_s577_d64": 2}
 
 
@@ -181,6 +186,47 @@ def test_kernel_compiles_for_v5e(case, one_chip, compiled_kernels):
     if case in SINGLE_TILE_CALLS:
         assert text.count("custom_call_target=\"tpu_custom_call\"") \
             == SINGLE_TILE_CALLS[case]
+
+
+#: the three decoder cells' attention calls: case -> (rows of the kernels'
+#: batch, query heads to a k/v head, D padded, S_q) and the heads a cell the
+#: fused backward runs at there
+FUSED_BACKWARD_CALLS = {
+    "flash_causal_s8192_qk192_v128": ((64, 1, 256, 8192), 4),
+    "flash_causal_s4096_d128": ((16, 1, 128, 4096), 4),
+    "flash_gqa_window_s8192_48over8_d128": ((48, 6, 128, 8192), 3),
+    "flash_gqa_full_s8192_48over8_d128": ((48, 6, 128, 8192), 3),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FUSED_BACKWARD_CALLS))
+def test_fused_backward_compiles_under_the_limit_it_states(case, one_chip,
+                                                           compiled_kernels):
+    """The one backward call of a decoder cell's shape, at the heads a cell
+    and the ``vmem_limit_bytes`` the code picks: Mosaic is given that scope
+    and uses less (the resident dq once, the tiles' pipeline twice)."""
+    import re
+    (bn, group, d, sq), hb = FUSED_BACKWARD_CALLS[case]
+    assert fa._pick_hb(bn, 512, 512, d, group=group, dq_seq=sq) == hb
+    cells = group // hb if group > 1 else 1
+    stated = fa._tiled_vmem_limit(hb, 512, 512, d, fa._SOFTMAX,
+                                  dq_rows=cells * sq)
+    resident = hb * cells * sq * d * 4
+    assert resident <= fa._DQ_RESIDENT_BUDGET
+    fn, arg_shapes = KERNEL_CASES[case]
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in arg_shapes]
+    text = jax.jit(_fwd_bwd(fn)).lower(*args).compile().as_text()
+    scopes = [tuple(int(re.search(key + r'":\[\{"memory_space":"1",'
+                                  r'"offset":"\d+","size":"(\d+)"', ln)[1])
+                    for key in ("\"scoped_memory_configs",
+                                "used_scoped_memory_configs"))
+              for ln in text.splitlines()
+              if "custom_call_target=\"tpu_custom_call\"" in ln]
+    assert len(scopes) == 2             # the forward, the backward
+    given, used = scopes[1]
+    assert given == stated <= 96 * 1024 * 1024
+    assert resident < used <= given
 
 
 @pytest.mark.parametrize("rows,width,expert_dim,experts,tile_m", [

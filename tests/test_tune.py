@@ -240,6 +240,12 @@ class TestSpace:
                 for d in (64, 128, 256):
                     assert flash_vmem_bytes(bq, bk, d) == \
                         fa._per_head_vmem_bytes(bq, bk, d)
+                    # the fused backward's resident dq: S_q x D x 4 a head
+                    for rows in (1536, 8192):
+                        assert flash_vmem_bytes(bq, bk, d, rows) == \
+                            fa._per_head_vmem_bytes(bq, bk, d, dq_rows=rows)
+        assert flash_vmem_bytes(512, 512, 256, 8192) \
+            - flash_vmem_bytes(512, 512, 256) == 8 * 1024 * 1024
         # the backward's four fp32 score tiles and two MXU copies: 5 MB
         assert flash_vmem_bytes(512, 512, 128) \
             - flash_vmem_bytes(512, 0, 128) - 3 * 512 * 128 * 2 \
