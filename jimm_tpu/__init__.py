@@ -26,6 +26,7 @@ _LAZY = {
     "Ouro": "jimm_tpu.models",
     "Kanana": "jimm_tpu.models.kanana",
     "Trinity": "jimm_tpu.models.trinity",
+    "KimiLinear": "jimm_tpu.models.kimi_linear",
     "SigLIP": "jimm_tpu.models",
     "VisionTransformer": "jimm_tpu.models",
     "CLIPConfig": "jimm_tpu.configs",
@@ -34,6 +35,7 @@ _LAZY = {
     "OuroConfig": "jimm_tpu.configs",
     "KananaConfig": "jimm_tpu.configs",
     "TrinityConfig": "jimm_tpu.configs",
+    "KimiLinearConfig": "jimm_tpu.configs",
     "MoEDecoderConfig": "jimm_tpu.configs",
     "DecoderConfig": "jimm_tpu.configs",
     "VisionConfig": "jimm_tpu.configs",
@@ -47,7 +49,8 @@ _LAZY = {
 
 __all__ = [
     "CLIP", "SigLIP", "VisionTransformer", "Ouro", "Kanana", "Trinity",
-    "OuroConfig", "DecoderConfig", "KananaConfig", "TrinityConfig",
+    "KimiLinear", "OuroConfig", "DecoderConfig", "KananaConfig",
+    "TrinityConfig", "KimiLinearConfig",
     "MoEDecoderConfig",
     "CLIPConfig", "SigLIPConfig", "ViTConfig", "VisionConfig", "TextConfig",
     "TransformerConfig", "PRESETS", "preset",

@@ -102,7 +102,8 @@ def _parse_mesh(spec: str | None, max_devices: int | None = None):
 
 #: model family (the prefix of its presets' names) -> its class in `jimm_tpu`
 _FAMILIES = {"vit": "VisionTransformer", "clip": "CLIP", "siglip": "SigLIP",
-             "ouro": "Ouro", "kanana": "Kanana", "trinity": "Trinity"}
+             "ouro": "Ouro", "kanana": "Kanana", "trinity": "Trinity",
+             "kimi": "KimiLinear"}
 
 
 def _lm_counters(cfg: Any, batch_size: int) -> list[tuple[str, str, Any]]:
@@ -134,7 +135,8 @@ def _lm_counters(cfg: Any, batch_size: int) -> list[tuple[str, str, Any]]:
 #: by about its own spread, routers included)
 LM_FAMILIES = {"ouro": {"lr": 1e-4, "warmup_steps": 20},
                "kanana": {"lr": 1e-4, "warmup_steps": 20},
-               "trinity": {"lr": 1e-4, "warmup_steps": 20}}
+               "trinity": {"lr": 1e-4, "warmup_steps": 20},
+               "kimi": {"lr": 1e-4, "warmup_steps": 20}}
 
 
 def _family(preset_name: str) -> str:
@@ -305,9 +307,9 @@ def _restore_run(args: argparse.Namespace):
 
 def _tiny_override(cfg: Any) -> Any:
     """Shrink any preset to CPU-demo size, keeping its architecture class."""
-    from jimm_tpu.configs import (CLIPConfig, KananaConfig, MLAConfig,
-                                  OuroConfig, SigLIPConfig, TrinityConfig,
-                                  ViTConfig)
+    from jimm_tpu.configs import (CLIPConfig, KananaConfig, KDAConfig,
+                                  KimiLinearConfig, MLAConfig, OuroConfig,
+                                  SigLIPConfig, TrinityConfig, ViTConfig)
 
     # depth 4 (not 2) so tiny runs can still exercise pipeline stages x
     # virtual-chunk splits (depth % (stages * virtual) == 0 for 2x2)
@@ -349,6 +351,18 @@ def _tiny_override(cfg: Any) -> Any:
             gqa=dataclasses.replace(cfg.decoder.gqa, head_dim=32, kv_heads=2,
                                     window=8),
             moe=dataclasses.replace(cfg.decoder.moe, num_experts=8, top_k=2,
+                                    expert_dim=48, held_experts=4)))
+    if isinstance(cfg, KimiLinearConfig):
+        # published layers 1-5 as the preset holds them: (KDA, dense), two
+        # (KDA, sparse), (MLA without rotary, sparse), (KDA, sparse); chunks
+        # of 16 under 32 tokens, 4 of 16 experts held
+        return dataclasses.replace(cfg, decoder=dataclasses.replace(
+            cfg.decoder, vocab_size=512, seq_len=32, width=64, depth=5,
+            num_heads=4, mlp_dim=176,
+            mla=MLAConfig(kv_lora_rank=32, qk_nope_dim=16, qk_rope_dim=8,
+                          v_head_dim=16),
+            kda=KDAConfig(num_heads=4, head_dim=16, gate_rank=16, chunk=16),
+            moe=dataclasses.replace(cfg.decoder.moe, num_experts=16, top_k=2,
                                     expert_dim=48, held_experts=4)))
     raise TypeError(type(cfg))
 
@@ -547,7 +561,7 @@ def train(args: argparse.Namespace) -> Any:
     elif args.num_layers or args.seq_len:
         raise SystemExit("--num-layers and --seq-len shape a language model "
                          "(an ouro preset, a kanana preset, a trinity "
-                         "preset)")
+                         "preset, a kimi-linear preset)")
 
     with acct.measure("backend_init"):
         # the first touch of the backend: the TPU runtime starts here

@@ -202,6 +202,21 @@ class GQAConfig:
 
 
 @dataclass(frozen=True)
+class KDAConfig:
+    """Kimi Delta Attention (`nn/kda.py`): ``num_heads`` heads of ``head_dim``
+    (keys and values alike), a causal depthwise convolution of ``conv_taps``
+    taps on q, k and v, the forget gate and the output gate each through a
+    ``gate_rank``-wide bottleneck, the recurrence in chunks of ``chunk``
+    tokens (`ops/delta_rule.py`)."""
+
+    num_heads: int = 32
+    head_dim: int = 128
+    conv_taps: int = 4
+    gate_rank: int = 128
+    chunk: int = 64
+
+
+@dataclass(frozen=True)
 class TransformerConfig:
     """Shared encoder-stack hyperparameters (vision or text tower)."""
 
@@ -287,6 +302,9 @@ class TransformerConfig:
     #: fewer key/value heads, qk-norm, an output gate, windowed layers beside
     #: full ones); None = ``num_heads`` heads of ``width / num_heads`` each way
     gqa: GQAConfig | None = None
+    #: Kimi Delta Attention (`nn/kda.py`) in place of `Attention`: a
+    #: linear-attention mixer with a recurrent state, no position signal
+    kda: KDAConfig | None = None
 
     @property
     def head_dim(self) -> int:
@@ -473,10 +491,12 @@ class MoEDecoderConfig:
     mlp_dim: int = 6144
     act: Activation = "silu"
     ln_eps: float = 1e-6
-    rope_theta: float = 1e6
+    rope_theta: float | None = 1e6
     mla: MLAConfig | None = field(default_factory=MLAConfig)
     moe: MoEConfig = field(default_factory=lambda: MoEConfig(held_experts=16))
     gqa: GQAConfig | None = None
+    kda: KDAConfig | None = None
+    mixers: tuple[str, ...] = ()
     post_norm: bool = False
     post_norm_gain: float = 1.0
     first_layer: int = 0
@@ -497,21 +517,58 @@ class MoEDecoderConfig:
         return dataclasses.replace(
             self.gqa, first_layer=self.first_layer).full_layers(self.depth)
 
-    def encoder(self, *, sparse: bool) -> TransformerConfig:
-        """The dense stack's block, or the sparse stack's."""
+    @property
+    def held_mixers(self) -> tuple[str | None, ...]:
+        """The token mixer of each of the ``depth`` held layers (None: the
+        one kind the config has)."""
+        if not self.mixers:
+            return (None,) * self.depth
+        held = self.mixers[self.first_layer:self.first_layer + self.depth]
+        if len(held) < self.depth:
+            raise ValueError(f"layers {self.first_layer}.."
+                             f"{self.first_layer + self.depth} are not among "
+                             f"the {len(self.mixers)} of `mixers`")
+        return held
+
+    def runs(self) -> tuple[tuple[str, TransformerConfig], ...]:
+        """The held layers as runs of like layers (same token mixer, same
+        kind of FFN), in layer order: ``(name, the run's block)`` each. A
+        stack of one mixer is the two runs ``dense`` and ``sparse``; a mixed
+        one names its runs by their first layer (``run0``, ``run1``, ...)."""
+        kinds = [(mixer, i >= self.dense_layers)
+                 for i, mixer in enumerate(self.held_mixers)]
+        starts = [i for i, kind in enumerate(kinds)
+                  if i == 0 or kind != kinds[i - 1]]
+        out = []
+        for lo, hi in zip(starts, [*starts[1:], self.depth]):
+            mixer, sparse = kinds[lo]
+            name = f"run{lo}" if self.mixers else (
+                "sparse" if sparse else "dense")
+            out.append((name, self.encoder(sparse=sparse, first=lo,
+                                           depth=hi - lo, mixer=mixer)))
+        return tuple(out)
+
+    def encoder(self, *, sparse: bool, first: int | None = None,
+                depth: int | None = None, mixer: str | None = None
+                ) -> TransformerConfig:
+        """The block of a run of ``depth`` like layers from held layer
+        ``first`` on; by default the dense stack's, or the sparse stack's."""
+        if first is None:
+            first = self.dense_layers if sparse else 0
+            depth = (self.depth - self.dense_layers if sparse
+                     else self.dense_layers)
         gqa = self.gqa and dataclasses.replace(
-            self.gqa, first_layer=self.first_layer
-            + (self.dense_layers if sparse else 0))
+            self.gqa, first_layer=self.first_layer + first)
         return TransformerConfig(
-            width=self.width,
-            depth=(self.depth - self.dense_layers if sparse
-                   else self.dense_layers),
+            width=self.width, depth=depth,
             num_heads=self.num_heads, mlp_dim=self.mlp_dim, act=self.act,
             ln_eps=self.ln_eps, dropout=self.dropout, causal=True,
             attn_impl=self.attn_impl, remat=self.remat,
             remat_policy=self.remat_policy, scan_unroll=self.scan_unroll,
             precision=self.precision, norm="rms", rope_theta=self.rope_theta,
-            gated_mlp=True, use_bias=False, mla=self.mla,
+            gated_mlp=True, use_bias=False,
+            mla=None if mixer == "kda" else self.mla,
+            kda=self.kda if mixer == "kda" else None,
             moe=self.moe if sparse else None, gqa=gqa,
             post_norm=self.post_norm, post_norm_gain=self.post_norm_gain,
         )
@@ -609,6 +666,35 @@ class TrinityConfig:
     `KananaConfig`'s model; ``bias_update_rate`` as there."""
 
     decoder: MoEDecoderConfig = field(default_factory=_trinity_decoder)
+    bias_update_rate: float = 1e-3
+
+
+#: Kimi-Linear-48B-A3B's 27 published layers (``linear_attn_config``:
+#: ``full_attn_layers`` 4, 8, 12, 16, 20, 24, 27 counted from 1)
+_KIMI_LINEAR_MIXERS = tuple(
+    "mla" if i + 1 in (4, 8, 12, 16, 20, 24, 27) else "kda" for i in range(27))
+
+
+def _kimi_linear_decoder() -> MoEDecoderConfig:
+    return MoEDecoderConfig(
+        vocab_size=20480, seq_len=16384, width=2304, depth=5, dense_layers=1,
+        num_heads=32, mlp_dim=9216, ln_eps=1e-5, rope_theta=None,
+        mla=MLAConfig(), kda=KDAConfig(), mixers=_KIMI_LINEAR_MIXERS,
+        moe=MoEConfig(num_experts=256, top_k=8, expert_dim=1024,
+                      shared_experts=1, routed_scale=2.446, held_experts=16))
+
+
+@dataclass(frozen=True)
+class KimiLinearConfig:
+    """Kimi-Linear-48B-A3B-Instruct (moonshotai, ``model_type`` kimi_linear)
+    as one chip of a 16-way expert-parallel group holds its first five
+    layers: Kimi Delta Attention (`KDAConfig`) on three layers of four and
+    latent attention with NO position signal on the fourth, a dense SwiGLU in
+    the first layer and a 256-expert top-8 mixture with one shared expert in
+    the rest (16 of 256 routed experts a layer, an eighth of the vocabulary).
+    Trained like `KananaConfig`'s model; ``bias_update_rate`` as there."""
+
+    decoder: MoEDecoderConfig = field(default_factory=_kimi_linear_decoder)
     bias_update_rate: float = 1e-3
 
 
@@ -721,6 +807,12 @@ PRESETS: dict[str, Any] = {
     # preset is ONE chip's share of 32-way expert parallelism from the last
     # dense layer on (experts 0-7, an eighth of the vocabulary)
     "trinity-large": TrinityConfig(),
+    # Kimi-Linear-48B-A3B-Instruct: Kimi Delta Attention on three layers of
+    # four, position-free latent attention on the fourth, 256 experts top-8
+    # and one shared; the preset is published layers 1-5 as ONE chip of
+    # 16-way expert parallelism holds them (experts 0-15, an eighth of the
+    # vocabulary)
+    "kimi-linear-48b-a3b": KimiLinearConfig(),
 }
 
 

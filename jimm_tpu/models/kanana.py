@@ -8,8 +8,11 @@ latent attention in every layer, a dense SwiGLU in the first layer and a
     z = RMS_f(x) W_head                                head untied from E
 
 `nn/mla.py` and `nn/moe.py` hold the two mechanisms; the block is
-`nn/transformer.py::Block` under `MoEDecoderConfig.encoder()`, two stacks of
-it (dense, then sparse). The model is ONE chip's share of an expert-parallel
+`nn/transformer.py::Block`, one scanned stack of it a run of like layers
+(`MoEDecoderConfig.runs`: same token mixer, same kind of FFN; here ``dense``,
+then ``sparse``; a family whose layers differ in their mixer, as
+`models/kimi_linear.py`, has a run per kind and place). The model is ONE
+chip's share of an expert-parallel
 group: it holds ``held_experts`` of each sparse layer's experts and a slice of
 the vocabulary, and computes its own experts' part of each layer's result.
 Training goes through `train/trainer.py::make_lm_train_step`. Not built: the
@@ -48,11 +51,14 @@ class Kanana(nnx.Module):
             embedding_init=logical(nnx.initializers.normal(0.02),
                                    "vocab", "embed"),
             rngs=rngs)
-        self.dense = Transformer(d.encoder(sparse=False), rngs, dtype=dtype,
-                                 param_dtype=param_dtype)
-        self.sparse = Transformer(d.encoder(sparse=True), rngs, dtype=dtype,
-                                  param_dtype=param_dtype)
-        self.norm = _norm(d.encoder(sparse=False), rngs, dtype=dtype,
+        # a run of like layers is one scanned stack, an attribute under the
+        # run's name, walked in layer order
+        runs = d.runs()
+        self.run_names = tuple(name for name, _ in runs)
+        for name, block in runs:
+            setattr(self, name, Transformer(block, rngs, dtype=dtype,
+                                            param_dtype=param_dtype))
+        self.norm = _norm(runs[0][1], rngs, dtype=dtype,
                           param_dtype=param_dtype)
         self.head = nnx.Linear(
             d.width, d.vocab_size, use_bias=False, dtype=dtype,
@@ -70,7 +76,32 @@ class Kanana(nnx.Module):
         with jax.named_scope("embed"):
             x = logical_constraint(self.embed(tokens), "batch", "seq", None)
         with jax.named_scope("decoder_stack"):
-            return self.sparse(self.dense(x))
+            return self.decode(x)
+
+    def sparse_runs(self) -> list[Transformer]:
+        """The runs that hold expert layers, in layer order."""
+        return [run for run in (getattr(self, name)
+                                for name in self.run_names)
+                if run.cfg.moe is not None]
+
+    def decode(self, x: jax.Array) -> tuple[jax.Array, jax.Array]:
+        """``x`` through every run in order: the last layer's output and the
+        sparse layers' routing choices, in layer order."""
+        chosen = []
+        for name in self.run_names:
+            run = getattr(self, name)
+            if run.cfg.moe is None:
+                x = run(x)
+            else:
+                x, picked = run(x)
+                chosen.append(picked)
+        return x, jnp.concatenate(chosen)
+
+    def router_bias(self) -> jax.Array:
+        """The sparse layers' selection biases ``(sparse layers,
+        num_experts)``, in layer order."""
+        return jnp.concatenate([run.blocks.mlp.router_bias[...]
+                                for run in self.sparse_runs()])
 
     def __call__(self, tokens: jax.Array) -> jax.Array:
         """Logits ``(B, S, vocab)``, whole: for sizes where that fits."""
@@ -80,7 +111,11 @@ class Kanana(nnx.Module):
         """Auxiliary-loss-free balancing: each sparse layer's selection bias
         moves by ``bias_update_rate`` toward the experts that drew fewer than
         the mean of this step's ``counts (sparse layers, num_experts)``."""
-        bias = self.sparse.blocks.mlp.router_bias
         load = counts.astype(jnp.float32)
-        bias[...] = bias[...] + self.config.bias_update_rate * jnp.sign(
+        step = self.config.bias_update_rate * jnp.sign(
             jnp.mean(load, axis=-1, keepdims=True) - load)
+        first = 0
+        for run in self.sparse_runs():
+            bias = run.blocks.mlp.router_bias
+            bias[...] = bias[...] + step[first:first + run.cfg.depth]
+            first += run.cfg.depth

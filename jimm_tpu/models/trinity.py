@@ -12,8 +12,9 @@ rest.
     z = RMS_f(x) W_head                                        head untied from E
 
 `nn/transformer.py::Attention` under `GQAConfig` and `nn/moe.py` hold the two
-mechanisms; the rest is `models/kanana.py::Kanana` (two stacks of the one
-`Block`, final norm, untied head, the routers' bias update), of which this is
+mechanisms; the rest is `models/kanana.py::Kanana` (a run of the one `Block`
+per kind of layer: dense, then sparse; final norm, untied head, the routers'
+bias update), of which this is
 the same one-chip share of an expert-parallel group. The gains of RMS' start
 at 1 / sqrt(60) (`post_norm_gain`: the depth-scaled sandwich with a constant
 of 1, the model's own not being public): with gains of 1 the averaged
@@ -45,4 +46,4 @@ class Trinity(Kanana):
             x = logical_constraint(x * math.sqrt(x.shape[-1]),
                                    "batch", "seq", None)
         with jax.named_scope("decoder_stack"):
-            return self.sparse(self.dense(x))
+            return self.decode(x)

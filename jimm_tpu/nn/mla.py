@@ -6,7 +6,8 @@ apart from it.
     [c | k_r]  = x W_kva          -> r + d_r      (ONE rotary key for all heads)
     c          = RMS_r(c)
     [k_n | v]  = c W_kvb          -> (B, S, N, d_n + d_v)
-    q_r, k_r   = rotary(q_r), rotary(k_r)         pairs (2i, 2i + 1)
+    q_r, k_r   = rotary(q_r), rotary(k_r)         pairs (2i, 2i + 1); with no
+                                                  rope tables: left as they are
     k          = [k_n | k_r broadcast over the heads]
     o          = softmax(causal(q k^T / sqrt(d_n + d_r))) v
     out        = o W_o            (N * d_v -> width)
@@ -74,9 +75,12 @@ class LatentAttention(nnx.Module):
             latent, k_r = jnp.split(self.kv_a(x), [self.rank], axis=-1)
             kv = self.kv_b(self.kv_norm(latent)).reshape(b, s, n, d_n + d_v)
             k_n, v = kv[..., :d_n], kv[..., d_n:]
-            q_r = apply_rope_pairs(q[..., d_n:], rope)
-            k_r = apply_rope_pairs(k_r[:, :, None, :], rope)
-            q = jnp.concatenate([q[..., :d_n], q_r], axis=-1)
+            if rope is None:  # no position signal: the dims stay, unturned
+                k_r = k_r[:, :, None, :]
+            else:
+                q_r = apply_rope_pairs(q[..., d_n:], rope)
+                k_r = apply_rope_pairs(k_r[:, :, None, :], rope)
+                q = jnp.concatenate([q[..., :d_n], q_r], axis=-1)
             k = jnp.concatenate(
                 [k_n, jnp.broadcast_to(k_r, (b, s, n, d_r))], axis=-1)
             o = dot_product_attention(q, k, v, is_causal=True, mask=mask,

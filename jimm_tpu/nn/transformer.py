@@ -261,6 +261,10 @@ class Block(nnx.Module):
             from jimm_tpu.nn.mla import LatentAttention
             self.attn = LatentAttention(cfg, rngs, dtype=dtype,
                                         param_dtype=param_dtype)
+        elif cfg.kda is not None:
+            from jimm_tpu.nn.kda import KimiDeltaAttention
+            self.attn = KimiDeltaAttention(cfg, rngs, dtype=dtype,
+                                           param_dtype=param_dtype)
         else:
             self.attn = Attention(cfg.width, cfg.num_heads, rngs,
                                   is_causal=cfg.causal, impl=cfg.attn_impl,
@@ -366,7 +370,9 @@ class Transformer(nnx.Module):
                     "moe_chosen")
             return None
         parts = remat_policy_parts(policy)
-        names = ["flash_o", "flash_lse"]
+        # ... and the delta-rule scan's output and per-slab states
+        # (`ops/delta_rule.py`), so neither kernel nor scan runs twice
+        names = ["flash_o", "flash_lse", "kda_o", "kda_states"]
         if "ln" in parts:
             names.append("ln_out")
         if "act" in parts:

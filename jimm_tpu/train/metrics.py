@@ -253,6 +253,18 @@ def moe_decoder_fwd_flops(d) -> float:
                     * (a.qk_nope_dim + a.v_head_dim)
                     + d.num_heads * a.v_head_dim * d.width) \
             + d.seq_len * d.num_heads * (qk + a.v_head_dim)
+    mixers = d.held_mixers
+    if "kda" in mixers:
+        # Kimi Delta Attention in place of ``attn`` on its layers: the
+        # projections (q, k, v, output, two low-rank gates, the write
+        # strength) and the recurrence's three head_dim x head_dim products
+        # a token and head
+        k = d.kda
+        inner = k.num_heads * k.head_dim
+        kda = 2 * (4 * d.width * inner + 2 * k.gate_rank * (d.width + inner)
+                   + d.width * k.num_heads) + 6 * inner * k.head_dim
+        attn = (mixers.count("kda") * kda
+                + (d.depth - mixers.count("kda")) * attn) / d.depth
     swiglu = 2 * 3 * d.width * e.expert_dim
     sparse = 2 * d.width * e.num_experts + swiglu * (
         e.shared_experts + e.top_k * e.held_experts / e.num_experts)

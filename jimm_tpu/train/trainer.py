@@ -245,7 +245,7 @@ def _routing_metrics(model, counts: jax.Array) -> dict[str, jax.Array]:
     moe = model.config.decoder.moe
     held = counts[:, moe.first_expert:moe.first_expert + moe.held_experts] \
         .astype(jnp.float32)
-    bias = model.sparse.blocks.mlp.router_bias[...]
+    bias = model.router_bias()
     return {"moe_held_rows": jnp.sum(held),
             "moe_load_max_over_mean": jnp.max(
                 jnp.max(held, axis=-1)
@@ -259,6 +259,7 @@ LM_STEPS: dict[str, tuple[Callable, Callable]] = {
     "ouro": (lm_loss_fn, _exit_metrics),
     "kanana": (moe_lm_loss_fn, _routing_metrics),
     "trinity": (moe_lm_loss_fn, _routing_metrics),
+    "kimi": (moe_lm_loss_fn, _routing_metrics),
 }
 
 

@@ -75,28 +75,59 @@ def clip_vocab_dir(tmp_path_factory):
     return d
 
 
-#: ONE test of `tests/benchmark/` (files only a `benchmark` PR may edit) ends
-#: with three lines that hold PR 32's cell to be the manifest's LAST cell and
-#: its nine metrics the LAST nine of `per_layer`:
-#:     assert manifest["workloads"][-1]["name"] == CELL
-#:     assert manifest["workloads"][-1]["chips"] == 1
-#:     assert [m["name"] for m in manifest["per_layer"]][-9:] == mine
-#: PR 34 appended a cell and its metrics, as the contract tells a
-#: `model_config` PR to, so those three lapse until a `benchmark` PR drops
-#: them (PERF.md section 7 (ix)). Everything else that test asserts (the
-#: driver's argv, its rehearsal argv, the window's steps, the cell's chips and
-#: the nine names in order) runs, position-free, in
-#: `tests/benchmark/test_gqa_moe_lm.py::
-#: test_the_older_sparse_cell_keeps_its_driver_and_its_entries`. Strict: the
-#: day the three lines go, this marker fails the run until it goes too.
-_LAPSED = ("tests/benchmark/test_moe_lm.py::"
-           "test_driver_takes_depth_and_length_from_the_files")
+#: Tests of `tests/benchmark/` (files only a `benchmark` PR may edit) that pin
+#: the END of a list of BENCHMARK.json, which any later append ends: node id ->
+#: (the source text of the ONE pinning line, where to read on). The first
+#: ends with three lines that hold PR 32's cell to be the manifest's LAST cell
+#: and its nine metrics the LAST nine of `per_layer`; PR 34 appended a cell
+#: and its metrics, as the contract tells a `model_config` PR to, so the first
+#: of the three fails. Everything else that test asserts (the driver's argv,
+#: its rehearsal argv, the window's steps, the cell's chips and the nine names
+#: in order) runs, position-free, in `tests/benchmark/test_gqa_moe_lm.py::
+#: test_the_older_sparse_cell_keeps_its_driver_and_its_entries`.
+#: PR 36's fourteen `setup_*` entries were the last of `per_layer`, and one
+#: line of the second test holds them there; PR 38 appended a cell's metrics
+#: behind them. Everything else it asserts (the names in order, one reader
+#: each, no `workloads`, units, layers) runs, position-free, in
+#: `tests/benchmark/test_hybrid_lm.py::
+#: test_the_setup_entries_keep_their_readers_and_their_order`.
+#: A test listed here may fail AT ITS PINNING LINE and nowhere else: an
+#: assertion that fails on any other line fails the run as ever, and so does
+#: the test passing (the day a `benchmark` PR drops the pinning lines, PERF.md
+#: section 7 (ix), its entry here goes too).
+_LAPSED = {
+    "tests/benchmark/test_moe_lm.py::"
+    "test_driver_takes_depth_and_length_from_the_files": (
+        'assert manifest["workloads"][-1]["name"] == CELL',
+        "pins BENCHMARK.json to PR 32's end of list (PERF.md section 7 (ix))"),
+    "tests/benchmark/test_setup_timeline.py::"
+    "test_the_module_has_one_reader_per_manifest_entry": (
+        'assert manifest["per_layer"][-len(mine):] == mine, '
+        '"appended at the end"',
+        "pins PR 36's entries to the end of per_layer (PERF.md section 7 "
+        "(ix))"),
+}
+
+
+def _lapse(item, line: str, reason: str) -> None:
+    """``item`` xfails if, and only if, it fails at ``line``."""
+    import traceback
+    run = item.runtest
+
+    def runtest():
+        try:
+            run()
+        except AssertionError as error:
+            failed_at = traceback.extract_tb(error.__traceback__)[-1].line
+            if failed_at != line:
+                raise
+            pytest.xfail(reason)
+        pytest.fail(f"passes: take it out of conftest._LAPSED ({reason})")
+
+    item.runtest = runtest
 
 
 def pytest_collection_modifyitems(config, items):
     for item in items:
-        if item.nodeid == _LAPSED:
-            item.add_marker(pytest.mark.xfail(
-                reason="its last three lines pin BENCHMARK.json to PR 32's "
-                       "end of list (PERF.md section 7 (ix))",
-                raises=AssertionError, strict=True))
+        if item.nodeid in _LAPSED:
+            _lapse(item, *_LAPSED[item.nodeid])

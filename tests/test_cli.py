@@ -207,7 +207,8 @@ def test_unflagged_runtime_is_shape_and_platform_only(name):
 #: new cell gets its row here
 LEDGER_SCAN_UNROLL = {"siglip_b16_256.train": 12, "vit_l16_384.train": 24,
                       "ouro_2_6b.train": 8, "kanana_2_30b_a3b.train": 6,
-                      "trinity_large.train": 5}
+                      "trinity_large.train": 5,
+                      "kimi_linear_48b_a3b.train": 5}
 #: the remat policy each cell's `--remat` argument resolves to
 LEDGER_REMAT_POLICY = {"trinity_large.train": "none"}
 
@@ -226,7 +227,8 @@ def test_cell_argv_resolves_as_the_ledger_says(cell):
             "--batch-size", str(traffic["batch_size"]), "--steps", "40",
             "--log-every", "1", "--metrics-file", "m.jsonl",
             *traffic["cli_args"]]
-    if workload["driver"] in ("train_lm", "train_moe_lm", "train_gqa_moe_lm"):
+    if workload["driver"] in ("train_lm", "train_moe_lm", "train_gqa_moe_lm",
+                              "train_hybrid_lm"):
         argv += ["--num-layers", str(config["num_layers"]),
                  "--seq-len", str(traffic["seq_len"])]
     assert _resolve(argv, "tpu") == {

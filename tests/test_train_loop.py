@@ -92,6 +92,10 @@ LOWERED_STEP = {
     # grouped-query attention with windowed layers beside full ones (PR 34)
     "trinity_large.train":
         "ca16e4b89235d31826e60fad26c34aeab43579c4c7656171764d520e6bb3bc75",
+    # Kimi Delta Attention beside position-free latent attention, four runs
+    # of like layers (PR 38)
+    "kimi_linear_48b_a3b.train":
+        "4f8ee20c1fcbaaa809f391d70ddb4ef69f49ba80b504b48d12159cac48abad2b",
 }
 
 
