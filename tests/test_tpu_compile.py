@@ -17,8 +17,8 @@ import jax.numpy as jnp
 import pytest
 from flax import nnx
 
-from jimm_tpu.ops import (flash_attention as fa, fp8_matmul as f8,
-                          int8_matmul as i8, layer_norm as ln)
+from jimm_tpu.ops import (delta_rule as dr, flash_attention as fa,
+                          fp8_matmul as f8, int8_matmul as i8, layer_norm as ln)
 
 HBM_BYTES = 16 * 1000 ** 3  # one v5e chip
 
@@ -47,8 +47,9 @@ def one_chip():
 def compiled_kernels(monkeypatch):
     """The kernels pick interpret mode from ``jax.default_backend()``, which
     is the CPU here: steer that in the test, not through a program option."""
-    for module in (fa, f8, i8, ln):
+    for module in (fa, f8, i8, ln, dr):
         monkeypatch.setattr(module, "_interpret", lambda: False)
+    monkeypatch.setattr(dr, "_default_backend", lambda: "tpu")
 
 
 def _fwd_bwd(fn):
@@ -154,6 +155,12 @@ KERNEL_CASES = {
                                                ((K, N), jnp.int8),
                                                ((N,), jnp.float32),
                                                ((N,), jnp.float32)]),
+    # the delta-rule scan at kimi_linear_48b_a3b.train's shape: 16,384
+    # tokens, 32 heads of 128, chunks of 64; q, k, v in bfloat16
+    "kda_scan_s16384_h32_d128": (
+        functools.partial(dr.chunk_kda, chunk=64),
+        [((1, 16384, 32, 128), jnp.bfloat16)] * 3
+        + [((1, 16384, 32, 128), jnp.float32), ((1, 16384, 32), jnp.float32)]),
 }
 
 
@@ -227,6 +234,90 @@ def test_fused_backward_compiles_under_the_limit_it_states(case, one_chip,
     given, used = scopes[1]
     assert given == stated <= 96 * 1024 * 1024
     assert resident < used <= given
+
+
+def _kernel_calls(text: str) -> list[tuple[str, int, int]]:
+    """``(op_name, scope Mosaic was given, scope it used)`` of each Pallas
+    call in a compiled program's text."""
+    import re
+    calls = []
+    for ln in text.splitlines():
+        if "custom_call_target=\"tpu_custom_call\"" not in ln:
+            continue
+        given, used = (int(re.search(key + r'":\[\{"memory_space":"1",'
+                                     r'"offset":"\d+","size":"(\d+)"', ln)[1])
+                       for key in ("\"scoped_memory_configs",
+                                   "used_scoped_memory_configs"))
+        calls.append((re.search(r'op_name="([^"]*)"', ln)[1], given, used))
+    return calls
+
+
+def test_kda_kernels_compile_under_the_limit_they_state(one_chip,
+                                                        compiled_kernels):
+    """The scan's forward and backward at the cell's shape: two Pallas calls,
+    named, each under the ``vmem_limit_bytes`` it states, and both under the
+    ``kda_scan`` scope the call is made in (the backward too: a custom vjp's
+    rule keeps its caller's scopes; the model's step is the next test)."""
+    fn, arg_shapes = KERNEL_CASES["kda_scan_s16384_h32_d128"]
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in arg_shapes]
+
+    def scoped(*a):
+        with jax.named_scope("kda"), jax.named_scope("kda_scan"):
+            return fn(*a)
+
+    calls = _kernel_calls(jax.jit(_fwd_bwd(scoped)).lower(*args).compile()
+                          .as_text())
+    assert [name.rsplit("/", 2)[1] for name, _, _ in calls] \
+        == ["kda_fwd", "kda_bwd"]
+    for name, given, used in calls:
+        assert "/kda_scan/" in name
+        assert used <= given == dr._VMEM_LIMIT
+
+
+def test_kda_kernels_carry_the_scopes_in_the_models_step(one_chip,
+                                                         compiled_kernels):
+    """A hybrid decoder's train step compiled for the v5e with its KDA layers
+    at heads of 128 in chunks of 64: every Pallas call of the scan, forward
+    and backward, has ``kda`` and ``kda_scan`` as plain components of its
+    ``op_name``, which is how `benchmarks/layer_metrics/hybrid_lm.py` finds
+    ``kda_scan_ms`` (a backward without them would leave the scan's time to
+    the stray ops around it)."""
+    import dataclasses
+    import re
+
+    from jimm_tpu import KimiLinear, preset
+    from jimm_tpu.cli import _tiny_override
+    from jimm_tpu.train import OptimizerConfig, make_optimizer
+    from jimm_tpu.train.trainer import make_lm_train_step
+    cfg = _tiny_override(preset("kimi-linear-48b-a3b"))
+    cfg = dataclasses.replace(cfg, decoder=dataclasses.replace(
+        cfg.decoder, kda=dataclasses.replace(cfg.decoder.kda, num_heads=2,
+                                             head_dim=128, chunk=64)))
+
+    def build():
+        model = KimiLinear(cfg, rngs=nnx.Rngs(0))
+        return model, make_optimizer(model, OptimizerConfig(total_steps=4))
+
+    model, optimizer = nnx.eval_shape(build)
+    for module in (model, optimizer):
+        nnx.update(module, jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip),
+            nnx.state(module)))
+    tokens = jax.ShapeDtypeStruct((2, cfg.decoder.seq_len + 1), jnp.int32,
+                                  sharding=one_chip)
+    text = make_lm_train_step("kimi").lower(model, optimizer, tokens) \
+        .compile().as_text()
+    kinds = {}
+    for name, _, _ in _kernel_calls(text):
+        kind = name.rsplit("/", 2)[1]
+        if kind.startswith("kda_"):
+            assert re.search(r"(^|/)kda/kda_scan/kda_(fwd|bwd)/", name), name
+            kinds[kind] = kinds.get(kind, 0) + 1
+    # one forward and one backward a run of KDA layers (three in the tiny
+    # stack; a scanned run's body is one call in the text)
+    assert kinds == {"kda_fwd": 3, "kda_bwd": 3}, kinds
 
 
 @pytest.mark.parametrize("rows,width,expert_dim,experts,tile_m", [
