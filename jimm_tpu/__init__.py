@@ -27,6 +27,7 @@ _LAZY = {
     "Kanana": "jimm_tpu.models.kanana",
     "Trinity": "jimm_tpu.models.trinity",
     "KimiLinear": "jimm_tpu.models.kimi_linear",
+    "Granite": "jimm_tpu.models.granite",
     "SigLIP": "jimm_tpu.models",
     "VisionTransformer": "jimm_tpu.models",
     "CLIPConfig": "jimm_tpu.configs",
@@ -36,6 +37,7 @@ _LAZY = {
     "KananaConfig": "jimm_tpu.configs",
     "TrinityConfig": "jimm_tpu.configs",
     "KimiLinearConfig": "jimm_tpu.configs",
+    "GraniteConfig": "jimm_tpu.configs",
     "MoEDecoderConfig": "jimm_tpu.configs",
     "DecoderConfig": "jimm_tpu.configs",
     "VisionConfig": "jimm_tpu.configs",
@@ -49,8 +51,8 @@ _LAZY = {
 
 __all__ = [
     "CLIP", "SigLIP", "VisionTransformer", "Ouro", "Kanana", "Trinity",
-    "KimiLinear", "OuroConfig", "DecoderConfig", "KananaConfig",
-    "TrinityConfig", "KimiLinearConfig",
+    "KimiLinear", "Granite", "OuroConfig", "DecoderConfig", "KananaConfig",
+    "TrinityConfig", "KimiLinearConfig", "GraniteConfig",
     "MoEDecoderConfig",
     "CLIPConfig", "SigLIPConfig", "ViTConfig", "VisionConfig", "TextConfig",
     "TransformerConfig", "PRESETS", "preset",

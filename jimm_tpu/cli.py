@@ -103,7 +103,7 @@ def _parse_mesh(spec: str | None, max_devices: int | None = None):
 #: model family (the prefix of its presets' names) -> its class in `jimm_tpu`
 _FAMILIES = {"vit": "VisionTransformer", "clip": "CLIP", "siglip": "SigLIP",
              "ouro": "Ouro", "kanana": "Kanana", "trinity": "Trinity",
-             "kimi": "KimiLinear"}
+             "kimi": "KimiLinear", "granite": "Granite"}
 
 
 def _lm_counters(cfg: Any, batch_size: int) -> list[tuple[str, str, Any]]:
@@ -117,7 +117,7 @@ def _lm_counters(cfg: Any, batch_size: int) -> list[tuple[str, str, Any]]:
         # cost is counted in
         counters.append(("jimm_loop", "block_applications_total",
                          d.loops * d.depth))
-    if hasattr(d, "moe"):
+    if getattr(d, "moe", None) is not None:
         counters += [
             ("jimm_moe", "assignments_total", batch_size * d.seq_len
              * d.moe.top_k * (d.depth - d.dense_layers)),
@@ -136,7 +136,8 @@ def _lm_counters(cfg: Any, batch_size: int) -> list[tuple[str, str, Any]]:
 LM_FAMILIES = {"ouro": {"lr": 1e-4, "warmup_steps": 20},
                "kanana": {"lr": 1e-4, "warmup_steps": 20},
                "trinity": {"lr": 1e-4, "warmup_steps": 20},
-               "kimi": {"lr": 1e-4, "warmup_steps": 20}}
+               "kimi": {"lr": 1e-4, "warmup_steps": 20},
+               "granite": {"lr": 1e-4, "warmup_steps": 20}}
 
 
 def _family(preset_name: str) -> str:
@@ -307,9 +308,10 @@ def _restore_run(args: argparse.Namespace):
 
 def _tiny_override(cfg: Any) -> Any:
     """Shrink any preset to CPU-demo size, keeping its architecture class."""
-    from jimm_tpu.configs import (CLIPConfig, KananaConfig, KDAConfig,
-                                  KimiLinearConfig, MLAConfig, OuroConfig,
-                                  SigLIPConfig, TrinityConfig, ViTConfig)
+    from jimm_tpu.configs import (CLIPConfig, GraniteConfig, KananaConfig,
+                                  KDAConfig, KimiLinearConfig, Mamba2Config,
+                                  MLAConfig, OuroConfig, SigLIPConfig,
+                                  TrinityConfig, ViTConfig)
 
     # depth 4 (not 2) so tiny runs can still exercise pipeline stages x
     # virtual-chunk splits (depth % (stages * virtual) == 0 for 2x2)
@@ -364,6 +366,16 @@ def _tiny_override(cfg: Any) -> Any:
             kda=KDAConfig(num_heads=4, head_dim=16, gate_rank=16, chunk=16),
             moe=dataclasses.replace(cfg.decoder.moe, num_experts=16, top_k=2,
                                     expert_dim=48, held_experts=4)))
+    if isinstance(cfg, GraniteConfig):
+        # published layers 0-9 as the preset holds them: Mamba-2 x 5, the
+        # attention layer, Mamba-2 x 4; 4 state-space heads of 16 with a
+        # state of 16, chunks of 16 under 32 tokens, 4 query heads over 2
+        return dataclasses.replace(cfg, decoder=dataclasses.replace(
+            cfg.decoder, vocab_size=512, seq_len=32, width=64, depth=10,
+            num_heads=4, mlp_dim=176,
+            gqa=dataclasses.replace(cfg.decoder.gqa, head_dim=16, kv_heads=2),
+            mamba=Mamba2Config(num_heads=4, head_dim=16, state=16,
+                               chunk=16)))
     raise TypeError(type(cfg))
 
 
@@ -398,6 +410,7 @@ def cmd_presets(args: argparse.Namespace) -> int:
         if hasattr(cfg, "decoder"):
             d = cfg.decoder
             kind = (f"x {d.loops} passes" if hasattr(d, "loops") else
+                    "dense" if d.moe is None else
                     f"experts={d.moe.held_experts}/{d.moe.num_experts} held")
             print(f"{name:32s} {params_m(name, cfg)} "
                   f"decoder(width={d.width} depth={d.depth} {kind} "
@@ -561,7 +574,7 @@ def train(args: argparse.Namespace) -> Any:
     elif args.num_layers or args.seq_len:
         raise SystemExit("--num-layers and --seq-len shape a language model "
                          "(an ouro preset, a kanana preset, a trinity "
-                         "preset, a kimi-linear preset)")
+                         "preset, a kimi-linear preset, a granite preset)")
 
     with acct.measure("backend_init"):
         # the first touch of the backend: the TPU runtime starts here

@@ -217,6 +217,22 @@ class KDAConfig:
 
 
 @dataclass(frozen=True)
+class Mamba2Config:
+    """A Mamba-2 state-space mixer (`nn/mamba2.py`): ``num_heads`` heads of
+    ``head_dim`` channels, a state of ``head_dim x state`` a head, ``B`` and
+    ``C`` ``state`` wide in each of ``groups`` groups of heads, a causal
+    depthwise convolution of ``conv_taps`` taps with a bias, the scan in
+    chunks of ``chunk`` tokens (`ops/ssd.py`)."""
+
+    num_heads: int = 64
+    head_dim: int = 64
+    state: int = 128
+    groups: int = 1
+    conv_taps: int = 4
+    chunk: int = 256
+
+
+@dataclass(frozen=True)
 class TransformerConfig:
     """Shared encoder-stack hyperparameters (vision or text tower)."""
 
@@ -305,6 +321,12 @@ class TransformerConfig:
     #: Kimi Delta Attention (`nn/kda.py`) in place of `Attention`: a
     #: linear-attention mixer with a recurrent state, no position signal
     kda: KDAConfig | None = None
+    #: a Mamba-2 state-space mixer (`nn/mamba2.py`) in place of `Attention`
+    mamba: Mamba2Config | None = None
+    #: what each sub-layer's output is multiplied by before the residual add
+    residual_scale: float = 1.0
+    #: the grouped-query attention's softmax scale; None = ``1/sqrt(head_dim)``
+    attn_scale: float | None = None
 
     @property
     def head_dim(self) -> int:
@@ -475,12 +497,16 @@ class DecoderConfig:
 class MoEDecoderConfig:
     """Causal decoder stack with latent attention (``mla``) or grouped-query
     attention (``gqa``) in every layer, a dense SwiGLU in the first
-    ``dense_layers`` and a sparse expert layer (`MoEConfig`) in the rest:
-    pre-norm RMS blocks (``post_norm``: a second norm on each sub-layer's
-    output, its scale starting at ``post_norm_gain``), rotary on the rotary
-    dims alone, no biases. ``depth`` counts both
-    kinds; ``first_layer`` is the index, in the whole published model, of the
-    first layer held here (`GQAConfig.full_layers` counts from it)."""
+    ``dense_layers`` and a sparse expert layer (`MoEConfig`) in the rest
+    (``moe`` None: a dense SwiGLU in every layer): pre-norm RMS blocks
+    (``post_norm``: a second norm on each sub-layer's output, its scale
+    starting at ``post_norm_gain``; ``residual_scale``: each sub-layer's output
+    multiplied before the add), rotary on the rotary dims alone, no biases.
+    ``mixers`` names each published layer's token mixer where they differ
+    (``kda``, ``mla``, ``mamba``; any other name is the attention above).
+    ``depth`` counts every held layer; ``first_layer`` is the index, in the
+    whole published model, of the first layer held here
+    (`GQAConfig.full_layers` counts from it)."""
 
     vocab_size: int = 16032
     seq_len: int = 8192
@@ -493,12 +519,16 @@ class MoEDecoderConfig:
     ln_eps: float = 1e-6
     rope_theta: float | None = 1e6
     mla: MLAConfig | None = field(default_factory=MLAConfig)
-    moe: MoEConfig = field(default_factory=lambda: MoEConfig(held_experts=16))
+    moe: MoEConfig | None = field(
+        default_factory=lambda: MoEConfig(held_experts=16))
     gqa: GQAConfig | None = None
     kda: KDAConfig | None = None
+    mamba: Mamba2Config | None = None
     mixers: tuple[str, ...] = ()
     post_norm: bool = False
     post_norm_gain: float = 1.0
+    residual_scale: float = 1.0
+    attn_scale: float | None = None
     first_layer: int = 0
     # runtime fields, as `DecoderConfig` has them
     dropout: float = 0.0
@@ -535,7 +565,7 @@ class MoEDecoderConfig:
         kind of FFN), in layer order: ``(name, the run's block)`` each. A
         stack of one mixer is the two runs ``dense`` and ``sparse``; a mixed
         one names its runs by their first layer (``run0``, ``run1``, ...)."""
-        kinds = [(mixer, i >= self.dense_layers)
+        kinds = [(mixer, self.moe is not None and i >= self.dense_layers)
                  for i, mixer in enumerate(self.held_mixers)]
         starts = [i for i, kind in enumerate(kinds)
                   if i == 0 or kind != kinds[i - 1]]
@@ -567,10 +597,13 @@ class MoEDecoderConfig:
             remat_policy=self.remat_policy, scan_unroll=self.scan_unroll,
             precision=self.precision, norm="rms", rope_theta=self.rope_theta,
             gated_mlp=True, use_bias=False,
-            mla=None if mixer == "kda" else self.mla,
+            mla=self.mla if mixer in (None, "mla") else None,
             kda=self.kda if mixer == "kda" else None,
-            moe=self.moe if sparse else None, gqa=gqa,
+            mamba=self.mamba if mixer == "mamba" else None,
+            moe=self.moe if sparse else None,
+            gqa=None if mixer == "mamba" else gqa,
             post_norm=self.post_norm, post_norm_gain=self.post_norm_gain,
+            residual_scale=self.residual_scale, attn_scale=self.attn_scale,
         )
 
 
@@ -698,6 +731,38 @@ class KimiLinearConfig:
     bias_update_rate: float = 1e-3
 
 
+#: granite-4.0-h-micro's 40 published layers (``layer_types``: attention on
+#: 5, 15, 25, 35 counted from 0, Mamba-2 on the rest)
+_GRANITE_MIXERS = tuple("attention" if i % 10 == 5 else "mamba"
+                        for i in range(40))
+
+
+def _granite_decoder() -> MoEDecoderConfig:
+    return MoEDecoderConfig(
+        vocab_size=100352, seq_len=16384, width=2048, depth=10, num_heads=32,
+        mlp_dim=8192, ln_eps=1e-5, rope_theta=None, mla=None, moe=None,
+        gqa=GQAConfig(head_dim=64, kv_heads=8, qk_norm=False, gate=False,
+                      window=None, full_every=1),
+        mamba=Mamba2Config(), mixers=_GRANITE_MIXERS, residual_scale=0.22,
+        attn_scale=0.015625)
+
+
+@dataclass(frozen=True)
+class GraniteConfig:
+    """granite-4.0-h-micro (ibm-granite, ``model_type`` granitemoehybrid)
+    as one stage of a four-stage pipeline holds its first ten layers: Mamba-2
+    (`Mamba2Config`) on nine layers of ten and position-free grouped-query
+    attention (32 heads over 8, softmax scale 1/64) on the tenth, a dense
+    SwiGLU in every layer, each sub-layer's output times 0.22 before the add.
+    The embedding is multiplied by ``embedding_multiplier`` and is also the
+    head: the logits are ``RMS_f(h) E^T / logits_scaling``. Trained on the
+    mean next-token cross-entropy."""
+
+    decoder: MoEDecoderConfig = field(default_factory=_granite_decoder)
+    embedding_multiplier: float = 12.0
+    logits_scaling: float = 8.0
+
+
 def _vit(size: str, patch: int, image: int, classes: int = 1000) -> ViTConfig:
     w, d, h, m = {
         "T": (192, 12, 3, 768),
@@ -813,6 +878,10 @@ PRESETS: dict[str, Any] = {
     # 16-way expert parallelism holds them (experts 0-15, an eighth of the
     # vocabulary)
     "kimi-linear-48b-a3b": KimiLinearConfig(),
+    # granite-4.0-h-micro: Mamba-2 on nine layers of ten, position-free
+    # grouped-query attention on the tenth, dense SwiGLU, tied head; the
+    # preset is published layers 0-9, one stage of a four-stage pipeline
+    "granite-4.0-h-micro": GraniteConfig(),
 }
 
 

@@ -11,7 +11,8 @@ latent attention in every layer, a dense SwiGLU in the first layer and a
 `nn/transformer.py::Block`, one scanned stack of it a run of like layers
 (`MoEDecoderConfig.runs`: same token mixer, same kind of FFN; here ``dense``,
 then ``sparse``; a family whose layers differ in their mixer, as
-`models/kimi_linear.py`, has a run per kind and place). The model is ONE
+`models/kimi_linear.py`, has a run per kind and place; a stack with no
+sparse layer, as `models/granite.py`, has dense runs alone). The model is ONE
 chip's share of an expert-parallel
 group: it holds ``held_experts`` of each sparse layer's experts and a slice of
 the vocabulary, and computes its own experts' part of each layer's result.
@@ -34,6 +35,10 @@ from jimm_tpu.parallel.sharding import (ShardingRules, TENSOR_PARALLEL,
 
 
 class Kanana(nnx.Module):
+    #: the head is the embedding's transpose and no module of its own
+    #: (`models/granite.py`)
+    tied_head = False
+
     def __init__(self, config: KananaConfig | None = None, *,
                  rngs: nnx.Rngs | None = None,
                  mesh: jax.sharding.Mesh | None = None,
@@ -42,7 +47,7 @@ class Kanana(nnx.Module):
         cfg = config or KananaConfig()
         self.config = cfg
         d = cfg.decoder
-        if not 0 < d.dense_layers < d.depth:
+        if d.moe is not None and not 0 < d.dense_layers < d.depth:
             raise ValueError(f"depth {d.depth} needs at least one dense and "
                              f"one sparse layer ({d.dense_layers} dense)")
         rngs = rngs if rngs is not None else nnx.Rngs(0)
@@ -60,12 +65,13 @@ class Kanana(nnx.Module):
                                             param_dtype=param_dtype))
         self.norm = _norm(runs[0][1], rngs, dtype=dtype,
                           param_dtype=param_dtype)
-        self.head = nnx.Linear(
-            d.width, d.vocab_size, use_bias=False, dtype=dtype,
-            param_dtype=param_dtype,
-            kernel_init=logical(nnx.initializers.normal(0.02),
-                                "embed", "vocab"),
-            rngs=rngs)
+        if not self.tied_head:
+            self.head = nnx.Linear(
+                d.width, d.vocab_size, use_bias=False, dtype=dtype,
+                param_dtype=param_dtype,
+                kernel_init=logical(nnx.initializers.normal(0.02),
+                                    "embed", "vocab"),
+                rngs=rngs)
         if mesh is not None:
             shard_model(self, mesh, rules)
 
@@ -84,9 +90,10 @@ class Kanana(nnx.Module):
                                 for name in self.run_names)
                 if run.cfg.moe is not None]
 
-    def decode(self, x: jax.Array) -> tuple[jax.Array, jax.Array]:
+    def decode(self, x: jax.Array) -> tuple[jax.Array, jax.Array | None]:
         """``x`` through every run in order: the last layer's output and the
-        sparse layers' routing choices, in layer order."""
+        sparse layers' routing choices, in layer order (None in a stack
+        without a sparse layer)."""
         chosen = []
         for name in self.run_names:
             run = getattr(self, name)
@@ -95,7 +102,7 @@ class Kanana(nnx.Module):
             else:
                 x, picked = run(x)
                 chosen.append(picked)
-        return x, jnp.concatenate(chosen)
+        return x, jnp.concatenate(chosen) if chosen else None
 
     def router_bias(self) -> jax.Array:
         """The sparse layers' selection biases ``(sparse layers,

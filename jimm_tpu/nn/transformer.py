@@ -23,6 +23,7 @@ Parity-preserved semantics (SURVEY Appendix A):
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Callable
 
@@ -114,12 +115,15 @@ class Attention(nnx.Module):
         a windowed layer: rotary on q and k, key j visible to query i iff
             0 <= i - j < window;  a full layer: no rotary, every j <= i
         out = (softmax(q k^T / sqrt(D)) v * sigmoid(x W_gate)) W_o   (gate)
+
+    ``scale`` (with ``gqa``) stands in for ``1 / sqrt(D)``: q is multiplied
+    by ``scale * sqrt(D)`` before the call.
     """
 
     def __init__(self, width: int, num_heads: int, rngs: nnx.Rngs, *,
                  is_causal: bool = False, impl: str = "auto",
                  fused_qkv: bool = False, use_bias: bool = True,
-                 gqa=None, ln_eps: float = 1e-6,
+                 gqa=None, ln_eps: float = 1e-6, scale: float | None = None,
                  dtype: Dtype = None, param_dtype=jnp.float32):
         if gqa is None and width % num_heads:
             raise ValueError(f"width {width} not divisible by heads {num_heads}")
@@ -134,6 +138,8 @@ class Attention(nnx.Module):
         self.fused_qkv = fused_qkv
         self.dtype = dtype
         self.gqa = gqa
+        self.q_scale = (None if scale is None
+                        else scale * math.sqrt(self.head_dim))
         lin = partial(_linear, use_bias=use_bias, dtype=dtype,
                       param_dtype=param_dtype)
         inner, kv_inner = (n * self.head_dim
@@ -175,6 +181,8 @@ class Attention(nnx.Module):
             v = self.v(x).reshape(b, s, self.kv_heads, self.head_dim)
             if g.qk_norm:
                 q, k = self.q_norm(q), self.k_norm(k)
+            if self.q_scale is not None:
+                q = q * jnp.asarray(self.q_scale, q.dtype)
 
             def attend(full: bool) -> jax.Array:
                 qr, kr = (q, k) if full or rope is None else (
@@ -265,12 +273,16 @@ class Block(nnx.Module):
             from jimm_tpu.nn.kda import KimiDeltaAttention
             self.attn = KimiDeltaAttention(cfg, rngs, dtype=dtype,
                                            param_dtype=param_dtype)
+        elif cfg.mamba is not None:
+            from jimm_tpu.nn.mamba2 import Mamba2
+            self.attn = Mamba2(cfg, rngs, dtype=dtype,
+                               param_dtype=param_dtype)
         else:
             self.attn = Attention(cfg.width, cfg.num_heads, rngs,
                                   is_causal=cfg.causal, impl=cfg.attn_impl,
                                   fused_qkv=cfg.fused_qkv,
                                   use_bias=cfg.use_bias, gqa=cfg.gqa,
-                                  ln_eps=cfg.ln_eps,
+                                  ln_eps=cfg.ln_eps, scale=cfg.attn_scale,
                                   dtype=dtype, param_dtype=param_dtype)
         self.layer_kinds = cfg.gqa is not None  # windowed or full, per call
         self.ln2 = norm()
@@ -285,6 +297,7 @@ class Block(nnx.Module):
                            dtype=dtype, param_dtype=param_dtype)
         self.dropout = nnx.Dropout(cfg.dropout, rngs=rngs)
         self.post_norm = cfg.post_norm
+        self.residual_scale = cfg.residual_scale
         if cfg.post_norm:
             self.ln1_post = norm(gain=cfg.post_norm_gain)
             self.ln2_post = norm(gain=cfg.post_norm_gain)
@@ -301,13 +314,19 @@ class Block(nnx.Module):
         # under every other policy
         a = self.attn(checkpoint_name(self.ln1(x), "ln_out"), mask=mask,
                       rope=rope, **({"full": full} if self.layer_kinds else {}))
-        x = x + self.dropout(self.ln1_post(a) if self.post_norm else a)
+        x = x + self._residual(self.ln1_post(a) if self.post_norm else a)
         m = self.mlp(checkpoint_name(self.ln2(x), "ln_out"))
         if self.sparse:
             m, chosen = m
-        x = x + self.dropout(self.ln2_post(m) if self.post_norm else m)
+        x = x + self._residual(self.ln2_post(m) if self.post_norm else m)
         x = logical_constraint(x, "batch", "seq", None)
         return (x, chosen) if self.sparse else x
+
+    def _residual(self, y: jax.Array) -> jax.Array:
+        """What a sub-layer adds to the residual stream."""
+        if self.residual_scale != 1.0:
+            y = y * jnp.asarray(self.residual_scale, y.dtype)
+        return self.dropout(y)
 
 
 class Transformer(nnx.Module):
@@ -370,9 +389,11 @@ class Transformer(nnx.Module):
                     "moe_chosen")
             return None
         parts = remat_policy_parts(policy)
-        # ... and the delta-rule scan's output and per-slab states
-        # (`ops/delta_rule.py`), so neither kernel nor scan runs twice
-        names = ["flash_o", "flash_lse", "kda_o", "kda_states"]
+        # ... and the delta-rule scan's and the state-space scan's outputs and
+        # kept states (`ops/delta_rule.py`, `ops/ssd.py`), so neither kernel
+        # nor scan runs twice
+        names = ["flash_o", "flash_lse", "kda_o", "kda_states", "ssm_y",
+                 "ssm_states"]
         if "ln" in parts:
             names.append("ln_out")
         if "act" in parts:

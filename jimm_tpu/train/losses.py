@@ -195,8 +195,8 @@ def ring_sigmoid_loss(img: jax.Array, txt: jax.Array, logit_scale: jax.Array,
 # ---------------------------------------------------------------------------
 
 def blocked_cross_entropy(hidden: jax.Array, head_kernel: jax.Array,
-                          targets: jax.Array, *, block: int = 1024
-                          ) -> jax.Array:
+                          targets: jax.Array, *, block: int = 1024,
+                          vocab_major: bool = False) -> jax.Array:
     """Per-position softmax cross-entropy of ``hidden @ head_kernel`` against
     ``targets``, float32, without ever holding the logits: positions go
     through in blocks of ``block``, and each block's ``(block, vocab)``
@@ -204,7 +204,9 @@ def blocked_cross_entropy(hidden: jax.Array, head_kernel: jax.Array,
     vocabulary one pass's float32 logits are 0.8 GB, and a looped model has
     one set per pass). ``hidden`` is ``(..., width)`` and ``targets``
     broadcasts against its leading axes. The matmul runs in ``hidden``'s
-    dtype and the softmax in float32."""
+    dtype and the softmax in float32. ``vocab_major``: ``head_kernel`` is
+    ``(vocab, width)``, a tied embedding, contracted on its last axis as it
+    lies (no transposed copy)."""
     lead, width = hidden.shape[:-1], hidden.shape[-1]
     h = hidden.reshape(-1, width)
     t = jnp.broadcast_to(targets, lead).reshape(-1)
@@ -218,7 +220,12 @@ def blocked_cross_entropy(hidden: jax.Array, head_kernel: jax.Array,
     @jax.checkpoint
     def one_block(args):
         h_blk, t_blk = args
-        logits = (h_blk @ kernel).astype(jnp.float32)
+        if vocab_major:
+            logits = jax.lax.dot_general(
+                h_blk, kernel, (((1,), (1,)), ((), ())),
+                preferred_element_type=h_blk.dtype).astype(jnp.float32)
+        else:
+            logits = (h_blk @ kernel).astype(jnp.float32)
         picked = jnp.take_along_axis(logits, t_blk[:, None], axis=-1)[:, 0]
         return jax.nn.logsumexp(logits, axis=-1) - picked
 

@@ -230,7 +230,8 @@ def moe_decoder_fwd_flops(d) -> float:
     projections and causal attention over half of S^2 (q k^T at the q/k
     width, p v at v's); the dense layers' SwiGLU; in a sparse layer the
     router, the shared experts and the routed experts at the expected
-    ``top_k * held / num_experts`` applications a token; the untied head."""
+    ``top_k * held / num_experts`` applications a token (without ``moe``, a
+    dense SwiGLU in every layer); the head."""
     a, e = d.mla, d.moe
     if d.gqa is not None:
         # grouped-query attention: q, gate and output at num_heads x head_dim,
@@ -265,10 +266,25 @@ def moe_decoder_fwd_flops(d) -> float:
                    + d.width * k.num_heads) + 6 * inner * k.head_dim
         attn = (mixers.count("kda") * kda
                 + (d.depth - mixers.count("kda")) * attn) / d.depth
+    if "mamba" in mixers:
+        # Mamba-2 in place of ``attn`` on its layers: its two projections,
+        # the convolution, and the recurrence's write and read, head_dim x
+        # state each a token and head
+        m = d.mamba
+        inner = m.num_heads * m.head_dim
+        conv = inner + 2 * m.groups * m.state
+        ssm = 2 * (d.width * (inner + conv + m.num_heads) + inner * d.width
+                   + m.conv_taps * conv) + 4 * inner * m.state
+        attn = (mixers.count("mamba") * ssm
+                + (d.depth - mixers.count("mamba")) * attn) / d.depth
+    dense_ffn = 2 * 3 * d.width * d.mlp_dim
+    if e is None:
+        return float((d.depth * (attn + dense_ffn)
+                      + 2 * d.width * d.vocab_size) * d.seq_len)
     swiglu = 2 * 3 * d.width * e.expert_dim
     sparse = 2 * d.width * e.num_experts + swiglu * (
         e.shared_experts + e.top_k * e.held_experts / e.num_experts)
-    per_token = (d.depth * attn + d.dense_layers * 2 * 3 * d.width * d.mlp_dim
+    per_token = (d.depth * attn + d.dense_layers * dense_ffn
                  + (d.depth - d.dense_layers) * sparse
                  + 2 * d.width * d.vocab_size)
     return float(per_token * d.seq_len)

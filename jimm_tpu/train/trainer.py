@@ -253,6 +253,27 @@ def _routing_metrics(model, counts: jax.Array) -> dict[str, jax.Array]:
             "router_bias_absmax": jnp.max(jnp.abs(bias))}
 
 
+def dense_lm_forward(model, tokens: jax.Array
+                     ) -> tuple[jax.Array, jax.Array]:
+    """A decoder without a sparse layer and with a tied head
+    (`models/granite.py`) on ``(B, S + 1)`` token ids: the mean next-token
+    cross-entropy of ``model.head_input(RMS_f(h)) E^T`` (the logits taken in
+    blocks) and, of the same pass, the final hidden state after its norm."""
+    inputs, targets = tokens[:, :-1], tokens[:, 1:]
+    hidden = model.hidden_states(inputs)
+    with jax.named_scope("lm_head"):
+        normed = model.norm(hidden)
+        ce = blocked_cross_entropy(model.head_input(normed),
+                                   model.embed.embedding[...], targets,
+                                   vocab_major=True)
+    return jnp.mean(ce), normed
+
+
+def dense_lm_loss_fn(model, tokens: jax.Array) -> tuple[jax.Array, None]:
+    """The dense decoder's loss (`dense_lm_forward`), no auxiliary."""
+    return dense_lm_forward(model, tokens)[0], None
+
+
 #: a language-model family's step: its loss ``(model, tokens) -> (loss, aux)``
 #: and what follows the optimizer's update, ``(model, aux) -> metrics``
 LM_STEPS: dict[str, tuple[Callable, Callable]] = {
@@ -260,6 +281,7 @@ LM_STEPS: dict[str, tuple[Callable, Callable]] = {
     "kanana": (moe_lm_loss_fn, _routing_metrics),
     "trinity": (moe_lm_loss_fn, _routing_metrics),
     "kimi": (moe_lm_loss_fn, _routing_metrics),
+    "granite": (dense_lm_loss_fn, lambda model, aux: {}),
 }
 
 
@@ -267,7 +289,8 @@ def make_lm_train_step(family: str = "ouro", *, donate: bool = False
                        ) -> Callable:
     """Next-token step of a language-model ``family`` (`LM_STEPS`): the
     looped decoder's metrics carry `_exit_metrics`, the sparse decoder's
-    `_routing_metrics`. ``donate`` as in ``make_contrastive_train_step``."""
+    `_routing_metrics`, the dense decoder's none but the loss. ``donate`` as
+    in ``make_contrastive_train_step``."""
     loss_fn, after_update = LM_STEPS[family]
 
     @partial(nnx.jit, donate_argnums=(0, 1) if donate else ())

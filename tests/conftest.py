@@ -90,7 +90,10 @@ def clip_vocab_dir(tmp_path_factory):
 #: behind them. Everything else it asserts (the names in order, one reader
 #: each, no `workloads`, units, layers) runs, position-free, in
 #: `tests/benchmark/test_hybrid_lm.py::
-#: test_the_setup_entries_keep_their_readers_and_their_order`.
+#: test_the_setup_entries_keep_their_readers_and_their_order`. That test in
+#: turn ends with a line that holds the hybrid cell's entries to be the LAST
+#: of `per_layer`, which the dense hybrid cell's appended entries end; its
+#: pinning line is its last, so everything else it asserts still runs.
 #: A test listed here may fail AT ITS PINNING LINE and nowhere else: an
 #: assertion that fails on any other line fails the run as ever, and so does
 #: the test passing (the day a `benchmark` PR drops the pinning lines, PERF.md
@@ -106,6 +109,11 @@ _LAPSED = {
         '"appended at the end"',
         "pins PR 36's entries to the end of per_layer (PERF.md section 7 "
         "(ix))"),
+    "tests/benchmark/test_hybrid_lm.py::"
+    "test_the_setup_entries_keep_their_readers_and_their_order": (
+        "assert names[first + len(mine):] == MINE",
+        "pins the hybrid cell's entries to the end of per_layer (PERF.md "
+        "section 7 (ix))"),
 }
 
 

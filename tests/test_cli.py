@@ -208,9 +208,11 @@ def test_unflagged_runtime_is_shape_and_platform_only(name):
 LEDGER_SCAN_UNROLL = {"siglip_b16_256.train": 12, "vit_l16_384.train": 24,
                       "ouro_2_6b.train": 8, "kanana_2_30b_a3b.train": 6,
                       "trinity_large.train": 5,
-                      "kimi_linear_48b_a3b.train": 5}
+                      "kimi_linear_48b_a3b.train": 5,
+                      "granite_4_0_h_micro.train": 10}
 #: the remat policy each cell's `--remat` argument resolves to
-LEDGER_REMAT_POLICY = {"trinity_large.train": "none"}
+LEDGER_REMAT_POLICY = {"trinity_large.train": "none",
+                       "granite_4_0_h_micro.train": "none"}
 
 
 @pytest.mark.parametrize("cell", CELLS)

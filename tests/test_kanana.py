@@ -294,7 +294,8 @@ def test_language_model_families_are_one_table():
     from jimm_tpu import cli
     from jimm_tpu.train.trainer import LM_STEPS
     assert set(cli.LM_FAMILIES) == set(LM_STEPS) == {"ouro", "kanana",
-                                                     "trinity", "kimi"}
+                                                     "trinity", "kimi",
+                                                     "granite"}
     assert set(cli.LM_FAMILIES) < set(cli._FAMILIES)
     assert cli._family("kanana-2-30b-a3b") == "kanana"
     assert cli._model_cls("kanana") is Kanana

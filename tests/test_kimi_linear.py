@@ -450,8 +450,8 @@ def test_no_other_preset_imports_the_familys_modules():
         "        'jimm_tpu.models.kimi_linear')\n"
         "assert not [m for m in mine if m in sys.modules], 'at import'\n"
         "for name, cfg in PRESETS.items():\n"
-        "    if name.startswith('kimi'):\n"
-        "        continue\n"
+        "    if name.startswith(('kimi', 'granite')):\n"
+        "        continue  # granite's Mamba-2 shares nn/kda.py's convolution\n"
         "    cfg = cli._tiny_override(cfg)\n"
         "    nnx.eval_shape(lambda: cli._model_cls(cli._family(name))(\n"
         "        cfg, rngs=nnx.Rngs(0)))\n"

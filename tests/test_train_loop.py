@@ -96,6 +96,10 @@ LOWERED_STEP = {
     # of like layers (PR 38)
     "kimi_linear_48b_a3b.train":
         "4f8ee20c1fcbaaa809f391d70ddb4ef69f49ba80b504b48d12159cac48abad2b",
+    # Mamba-2 beside position-free grouped-query attention, a dense stack of
+    # three runs
+    "granite_4_0_h_micro.train":
+        "617b0e482c94f74ab33cf6477442d43bf800ff45bbb2bf14d2b6ce09e47d6b84",
 }
 
 
