@@ -43,16 +43,54 @@ derivative by slabs, the state's cotangent backwards over the chunks, and
 phase 1's derivative by slabs. A remat policy that keeps ``ssm_states`` and the
 caller's copy of the output (`nn/transformer.py::Transformer._remat_policy`)
 runs no second scan in a layer's backward. float32 inside, every product at
-`_PRECISION`. This is XLA code: the oracle that a later kernel is held to.
+`_PRECISION`.
+
+Two paths compute it, and `chunk_ssd` chooses between them from what it can
+observe (`kernel_takes`): on a TPU, with a head count a step whose ``x``
+lanes are whole 128-lane tiles, ``N`` a whole number of tiles and a chunk of
+whole 128-row tiles, the Pallas kernels; everywhere else (the CPU, the tiny
+preset's heads of 16 with a state of 16 in chunks of 16, any other width) the
+XLA path above, which is also the tests' oracle. No flag picks one.
+
+**The kernels** (``ssd_fwd``, ``ssd_bwd``) read x in the model's ``(B, S,
+H * P)`` layout, B and C as ``(B, S, G * N)`` (the index map picks the group
+of the step's heads) and ``dt`` and ``a = dt A`` as ``(B, H, 1, S)`` rows, and
+write y (and dx) in x's layout: no pad, transpose or reshape of an x-sized
+array around them. The grid is ``(B, H / heads, chunks)``: a step holds
+`_heads_a_step` heads, each a ``(heads, R, .)`` batch of every product, and
+walks the chunks in order (the last axis ``"arbitrary"``), each head's
+``(P, N)`` float32 state in a VMEM scratch, zeroed at the first. A chunk is
+cut into tiles of `_ROWS` = 128 rows (`_parts`): inside a tile ``E`` is a
+product of the triangle of ones with ``a`` (``(U * a) V``: every term of one
+sign); a pair across tiles sums the row's head, the whole tiles between and
+the key's tail; ``into``, ``out_of`` and the chunk's total sum a tile's part
+and whole tiles. So the rule above holds: no exponent is a difference. ``C
+B^T`` of bfloat16 operands is one MXU pass (their products are exact in
+float32); every product with a float32 operand is at `_PRECISION`. Then ``y``
+from the tokens and from the state, and the next state. The forward writes
+the state each chunk is entered with (``ssm_states``, as the XLA path). The
+backward walks the chunks from the last (index map ``n - 1 - i``), ``dS`` in
+a VMEM scratch: it rebuilds a chunk's intra-chunk quantities from its inputs
+and its kept state (no forward is run again) and writes dx in x's dtype, the
+direct part of ``ddt`` and ``da`` as float32 rows, and each head block's
+share of dB and dC in float32 (the head axis of the grid is parallel), which
+are summed outside with ``ddt += da A`` and ``dA = sum da dt``. Both kernels
+run where `chunk_ssd` is called, so their custom calls carry the caller's
+scopes (``ssm/ssm_scan`` in `nn/mamba2.py`), forward and backward.
 """
 
 from __future__ import annotations
 
 import math
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from jimm_tpu.ops.delta_rule import _dot, _flip, _interpret, _iota
 
 #: tokens of a sub-chunk: inside one the segment sums are taken term by term
 #: (``_SUB^3`` a sub-chunk)
@@ -239,6 +277,9 @@ def chunk_ssd(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
     registry = get_registry("jimm_ssm")
     registry.counter("calls_total").inc()
     registry.counter("chunks_total").inc(n)
+    if kernel_takes(x.shape, B.shape, chunk, _default_backend()):
+        registry.counter("kernel_total").inc()
+        return _kernel_ssd(x, dt, A, B, C, chunk)
     slab = min(n, max(1, _PAIR_BYTES // (b * h * chunk * chunk * 4)))
     while n % slab:
         slab -= 1
@@ -254,3 +295,345 @@ def chunk_ssd(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
              A.astype(jnp.float32))
     y = jnp.moveaxis(y.reshape(n, b, h, chunk, -1), (0, 2), (1, 3))
     return y.reshape(b, n * chunk, h, -1)[:, :s]
+
+
+# -- the Pallas kernels -------------------------------------------------------
+
+#: rows of a tile: a chunk is ``chunk / _ROWS`` tiles, and a pair of tokens in
+#: two tiles meets through the parts of its exponent (`_parts`)
+_ROWS = 128
+#: the most heads a grid step holds, each product a batch of them. One
+#: layer's forward + backward at (1, 16384, 64, 64), N = 128, on the v5e:
+#: 24.2 ms at two heads, 21.5 at four, 19.9 at eight, 19.6 at sixteen, whose
+#: kernels take Mosaic twice as long to compile (PERF.md, section 6)
+_HEADS = 8
+#: what a call may take of VMEM (the backward uses 19.4 MiB of it at eight
+#: heads, the forward 11.0)
+_VMEM_LIMIT = 64 << 20
+
+
+def _default_backend() -> str:
+    return jax.default_backend()
+
+
+def _heads_a_step(heads_per_group: int, head_dim: int) -> int | None:
+    """The most heads up to `_HEADS` that divide a group's heads and fill
+    whole 128-lane tiles of ``x``; None where no count does."""
+    fits = [n for n in range(1, min(heads_per_group, _HEADS) + 1)
+            if heads_per_group % n == 0 and n * head_dim % 128 == 0]
+    return max(fits) if fits else None
+
+
+def kernel_takes(x_shape, b_shape, chunk: int, backend: str) -> bool:
+    """Whether `chunk_ssd` runs on the Pallas kernels: on a TPU, with a head
+    count a step whose ``x`` lanes are whole 128-lane tiles, a state ``N`` of
+    whole tiles and a chunk of whole 128-row tiles. Everything else (the CPU,
+    the tiny preset's heads of 16 with a state of 16 in chunks of 16, any
+    other width) takes the XLA path."""
+    _, _, h, p = x_shape
+    g, n = b_shape[2:]
+    return (backend == "tpu" and h % g == 0
+            and _heads_a_step(h // g, p) is not None
+            and n % 128 == 0 and chunk % _ROWS == 0)
+
+
+def _cb(c: jax.Array, b: jax.Array) -> jax.Array:
+    """``C B^T`` of a row tile and a key tile. Of two bfloat16 operands it is
+    one MXU pass: every product exact in float32, summed in float32, what six
+    passes would give; of float32 ones, six."""
+    if c.dtype == b.dtype == jnp.bfloat16:
+        return jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+    return _dot(c.astype(jnp.float32), b.astype(jnp.float32), "nt")
+
+
+def _split_heads(block: jax.Array, heads: int) -> jax.Array:
+    """A ``(L, heads * P)`` block as ``(heads, L, P)`` float32."""
+    block = block.astype(jnp.float32)
+    p = block.shape[-1] // heads
+    return jnp.stack([block[:, i * p:(i + 1) * p] for i in range(heads)])
+
+
+def _join_heads(x: jax.Array) -> jax.Array:
+    return jnp.concatenate([x[i] for i in range(x.shape[0])], axis=-1)
+
+
+def _parts(a_ref, dt_ref) -> dict:
+    """What a chunk's exponents are built from, tile by tile, for the rows
+    ``a, dt (1, heads, 1, L)`` (read a tile at a time): ``head`` (``(heads,
+    R, 1)``: the sum of ``a`` over the tile's tokens up to the row's),
+    ``tail`` (after the row's),
+    ``whole`` (``(heads, 1, 1)``), ``inside`` (``(heads, R, R)``: ``E`` on
+    the tile's diagonal block, a product of the triangle of ones with ``a``;
+    ``-inf`` above the diagonal), ``into``, ``out_of`` and ``total`` of the
+    chunk, each a sum of same-signed parts, and ``dt`` as rows and columns."""
+    _, heads, _, length = a_ref.shape
+    r = _ROWS
+    row, col = _iota((r, r), 0), _iota((r, r), 1)
+    upto, after = col <= row, col > row
+    # V_sj = [s > j]: rows s, lanes j
+    later = jnp.broadcast_to(jnp.where(row > col, 1.0, 0.0), (heads, r, r))
+    p = {k: [] for k in ("head", "tail", "whole", "inside", "dt_row",
+                         "dt_col")}
+    for t in range(length // r):
+        a = jnp.broadcast_to(a_ref[0, :, :, t * r:(t + 1) * r], (heads, r, r))
+        p["head"].append(jnp.sum(jnp.where(upto, a, 0.0), -1, keepdims=True))
+        p["tail"].append(jnp.sum(jnp.where(after, a, 0.0), -1, keepdims=True))
+        p["whole"].append(jnp.sum(a[:, :1], -1, keepdims=True))
+        p["inside"].append(jnp.where(
+            upto, _dot(jnp.where(upto, a, 0.0), later), -jnp.inf))
+        p["dt_row"].append(dt_ref[0, :, :, t * r:(t + 1) * r])
+        p["dt_col"].append(_flip(p["dt_row"][-1]))
+    n = len(p["whole"])
+    p["tail_row"] = [_flip(x) for x in p["tail"]]
+    p["into"] = [p["head"][t] + sum(p["whole"][:t], 0.0) for t in range(n)]
+    p["out_of"] = [p["tail"][t] + sum(p["whole"][t + 1:], 0.0)
+                   for t in range(n)]
+    p["total"] = sum(p["whole"][1:], p["whole"][0])
+    p["upto"], p["after"], p["later"] = upto, after, later
+    return p
+
+
+def _exponent(p: dict, rt: int, ct: int) -> jax.Array:
+    """``E`` on the block of row tile ``rt`` and key tile ``ct <= rt``: the
+    tile's own where they are one, else the row's head, the whole tiles
+    between and the key's tail."""
+    if rt == ct:
+        return p["inside"][rt]
+    return p["head"][rt] + (sum(p["whole"][ct + 1:rt], 0.0)
+                            + p["tail_row"][ct])
+
+
+def _fwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, kept_ref, state, *,
+                heads: int):
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state[...] = jnp.zeros_like(state)
+
+    r = _ROWS
+    x = _split_heads(x_ref[0], heads)
+    p = _parts(a_ref, dt_ref)
+    B, C = b_ref[0], c_ref[0]
+    s0 = state[...]
+    kept_ref[0, :, 0] = s0
+    new, ys = jnp.exp(p["total"]) * s0, []
+    for rt in range(len(p["whole"])):
+        rows = slice(rt * r, (rt + 1) * r)
+        c = jnp.broadcast_to(C[rows].astype(jnp.float32),
+                             (heads, r, C.shape[-1]))
+        y = jnp.exp(p["into"][rt]) * _dot(c, s0, "nt")
+        for ct in range(rt + 1):
+            keys = slice(ct * r, (ct + 1) * r)
+            w = jnp.exp(_exponent(p, rt, ct)) * _cb(C[rows], B[keys]) \
+                * p["dt_row"][ct]
+            y = y + _dot(w, x[:, keys])
+        ys.append(y)
+        written = jnp.exp(p["out_of"][rt]) * p["dt_col"][rt] \
+            * B[rows].astype(jnp.float32)
+        new = new + _dot(x[:, rows], written, "tn")
+    state[...] = new
+    y_ref[0] = _join_heads(jnp.concatenate(ys, axis=-2)).astype(y_ref.dtype)
+
+
+def _bwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, kept_ref, dy_ref,
+                dx_ref, ddt_ref, da_ref, db_ref, dc_ref, d_state, *,
+                heads: int):
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        d_state[...] = jnp.zeros_like(d_state)
+
+    r = _ROWS
+    x, dy = _split_heads(x_ref[0], heads), _split_heads(dy_ref[0], heads)
+    p = _parts(a_ref, dt_ref)
+    B, C = b_ref[0], c_ref[0]
+    Bf, Cf = B.astype(jnp.float32), C.astype(jnp.float32)
+    s0, ds1 = kept_ref[0, :, 0], d_state[...]
+    n = len(p["whole"])
+    zero_col = jnp.zeros((heads, r, 1), jnp.float32)
+    zero_row = jnp.zeros((heads, 1, r), jnp.float32)
+    dx = [jnp.zeros((heads, r, x.shape[-1]), jnp.float32)] * n
+    ddt, d_inside = [zero_row] * n, [zero_row] * n
+    d_head, d_tail = [zero_col] * n, [zero_col] * n
+    d_whole = [jnp.zeros((heads, 1, 1), jnp.float32)] * n
+    db = [jnp.zeros((r, B.shape[-1]), jnp.float32)] * n
+    dc = list(db)
+    decay = jnp.exp(p["total"])
+    ds0 = decay * ds1
+    d_total = jnp.sum(jnp.sum(ds1 * s0, -1, keepdims=True), -2,
+                      keepdims=True) * decay
+    for rt in range(n):
+        rows = slice(rt * r, (rt + 1) * r)
+        c = jnp.broadcast_to(Cf[rows], (heads, r, C.shape[-1]))
+        dy_r = dy[:, rows]
+        # y += exp(into) (C S^T)
+        dyi = jnp.exp(p["into"][rt]) * dy_r
+        d_into = jnp.sum(dyi * _dot(c, s0, "nt"), -1, keepdims=True)
+        d_head[rt] = d_head[rt] + d_into
+        for t in range(rt):
+            d_whole[t] = d_whole[t] + jnp.sum(d_into, -2, keepdims=True)
+        dc[rt] = dc[rt] + jnp.sum(_dot(dyi, s0), 0)
+        ds0 = ds0 + _dot(dyi, c, "tn")
+        # y += (exp(E) * C B^T * dt) x
+        for ct in range(rt + 1):
+            keys = slice(ct * r, (ct + 1) * r)
+            e = jnp.exp(_exponent(p, rt, ct))
+            wn = e * _cb(C[rows], B[keys])
+            w = wn * p["dt_row"][ct]
+            dw = _dot(dy_r, x[:, keys], "nt")
+            dx[ct] = dx[ct] + _dot(w, dy_r, "tn")
+            ddt[ct] = ddt[ct] + jnp.sum(dw * wn, -2, keepdims=True)
+            dE = dw * w
+            dcb = jnp.sum(dw * e * p["dt_row"][ct], 0)
+            dc[rt] = dc[rt] + _dot(dcb, Bf[keys])
+            db[ct] = db[ct] + _dot(dcb, Cf[rows], "tn")
+            if rt == ct:
+                # E = (U * a) V: a_s gets sum_r U_rs (dE V^T)_rs
+                d_inside[ct] = d_inside[ct] + jnp.sum(jnp.where(
+                    p["upto"], _dot(dE, p["later"], "nt"), 0.0), -2,
+                    keepdims=True)
+            else:
+                d_head[rt] = d_head[rt] + jnp.sum(dE, -1, keepdims=True)
+                d_tail[ct] = d_tail[ct] + _flip(jnp.sum(dE, -2,
+                                                        keepdims=True))
+                between = jnp.sum(jnp.sum(dE, -1, keepdims=True), -2,
+                                  keepdims=True)
+                for t in range(ct + 1, rt):
+                    d_whole[t] = d_whole[t] + between
+    # S' = exp(total) S + x^T (exp(out_of) dt B)
+    for ct in range(n):
+        keys = slice(ct * r, (ct + 1) * r)
+        out = jnp.exp(p["out_of"][ct])
+        f = out * p["dt_col"][ct]
+        b = jnp.broadcast_to(Bf[keys], (heads, r, B.shape[-1]))
+        dbd = _dot(x[:, keys], ds1)
+        dx[ct] = dx[ct] + _dot(f * b, ds1, "nt")
+        db[ct] = db[ct] + jnp.sum(f * dbd, 0)
+        df = jnp.sum(dbd * b, -1, keepdims=True)
+        d_tail[ct] = d_tail[ct] + df * f
+        for t in range(ct + 1, n):
+            d_whole[t] = d_whole[t] + jnp.sum(df * f, -2, keepdims=True)
+        ddt[ct] = ddt[ct] + _flip(df * out)
+    da = []
+    for t in range(n):
+        da.append(d_inside[t] + d_whole[t] + d_total
+                  + jnp.sum(jnp.where(p["upto"], d_head[t], 0.0), -2,
+                            keepdims=True)
+                  + jnp.sum(jnp.where(p["after"], d_tail[t], 0.0), -2,
+                            keepdims=True))
+    d_state[...] = ds0
+    dx_ref[0] = _join_heads(jnp.concatenate(dx, axis=-2)).astype(dx_ref.dtype)
+    ddt_ref[0] = jnp.concatenate(ddt, axis=-1)
+    da_ref[0] = jnp.concatenate(da, axis=-1)
+    db_ref[0] = jnp.concatenate(db, axis=0)
+    dc_ref[0] = jnp.concatenate(dc, axis=0)
+
+
+def _pallas(kernel, name: str, args, in_kinds, out, out_kinds, chunk: int,
+            groups: int):
+    """One kernel over the grid ``(B, H / heads, chunks)``; a ``kind`` is
+    ``"x"`` (a ``(B, S, H * P)`` array: a chunk's rows, the lanes of a head
+    block), ``"row"`` (``(B, H, 1, S)`` rows of ``dt`` or ``a``), ``"bc"``
+    (``B`` or ``C``, ``(B, S, G * N)``: the group of the step's heads),
+    ``"part"`` (``(B, S, H / heads * N)``: a head block's share of ``dB`` or
+    ``dC``) or ``"kept"`` (the states, ``(B, H, chunks, P, N)``). The
+    backward (``ssd_bwd``) walks the chunks from the last."""
+    b, h, _, s = args[1].shape
+    p = args[0].shape[2] // h
+    n = args[3].shape[2] // groups
+    heads = _heads_a_step(h // groups, p)
+    chunks = s // chunk
+    seg = (lambda i: chunks - 1 - i) if name == "ssd_bwd" else (lambda i: i)
+    per_group = h // groups // heads
+    specs = {
+        "x": pl.BlockSpec((1, chunk, heads * p),
+                          lambda i, j, t: (i, seg(t), j)),
+        "row": pl.BlockSpec((1, heads, 1, chunk),
+                            lambda i, j, t: (i, j, 0, seg(t))),
+        "bc": pl.BlockSpec((1, chunk, n),
+                           lambda i, j, t: (i, seg(t), j // per_group)),
+        "part": pl.BlockSpec((1, chunk, n), lambda i, j, t: (i, seg(t), j)),
+        "kept": pl.BlockSpec((1, heads, 1, p, n),
+                             lambda i, j, t: (i, j, seg(t), 0, 0))}
+    return pl.pallas_call(
+        partial(kernel, heads=heads),
+        grid=(b, h // heads, chunks),
+        in_specs=[specs[k] for k in in_kinds],
+        out_specs=[specs[k] for k in out_kinds],
+        out_shape=out,
+        scratch_shapes=[pltpu.VMEM((heads, p, n), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=_interpret(), name=name)(*args)
+
+
+def _fwd_call(x, dt, a, B, C, chunk: int, groups: int):
+    """``y (B, S, H * P)`` float32 and the state each chunk is entered with,
+    for ``x (B, S, H * P)``, ``dt, a (B, H, 1, S)``, ``B, C (B, S, G * N)``,
+    ``S`` a multiple of ``chunk``."""
+    b, h, _, s = dt.shape
+    p, n = x.shape[2] // h, B.shape[2] // groups
+    return _pallas(
+        _fwd_kernel, "ssd_fwd", (x, dt, a, B, C),
+        ["x", "row", "row", "bc", "bc"],
+        [jax.ShapeDtypeStruct(x.shape, jnp.float32),
+         jax.ShapeDtypeStruct((b, h, s // chunk, p, n), jnp.float32)],
+        ["x", "kept"], chunk, groups)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _kernel_scan(x, dt, a, B, C, chunk: int, groups: int) -> jax.Array:
+    return _fwd_call(x, dt, a, B, C, chunk, groups)[0]
+
+
+def _kernel_scan_fwd(x, dt, a, B, C, chunk, groups):
+    y, kept = _fwd_call(x, dt, a, B, C, chunk, groups)
+    return y, (x, dt, a, B, C, checkpoint_name(kept, "ssm_states"))
+
+
+def _kernel_scan_bwd(chunk, groups, residuals, dy):
+    """``dx`` in x's dtype, ``ddt`` (its direct terms) and ``da`` float32;
+    ``dB``, ``dC`` as the head blocks' float32 shares, summed here and cast
+    to their inputs' dtypes."""
+    x, dt, a, B, C, _ = residuals
+    b, h, _, s = dt.shape
+    blocks = h // _heads_a_step(h // groups, x.shape[2] // h)
+    n = B.shape[2] // groups
+    part = jax.ShapeDtypeStruct((b, s, blocks * n), jnp.float32)
+    dx, ddt, da, db, dc = _pallas(
+        _bwd_kernel, "ssd_bwd", (*residuals, dy),
+        ["x", "row", "row", "bc", "bc", "kept", "x"],
+        [jax.ShapeDtypeStruct(x.shape, x.dtype),
+         jax.ShapeDtypeStruct(dt.shape, jnp.float32),
+         jax.ShapeDtypeStruct(a.shape, jnp.float32), part, part],
+        ["x", "row", "row", "part", "part"], chunk, groups)
+
+    def summed(d, like):
+        return jnp.sum(d.reshape(b, s, groups, blocks // groups, n), 3) \
+            .reshape(like.shape).astype(like.dtype)
+
+    return dx, ddt, da, summed(db, B), summed(dc, C)
+
+
+_kernel_scan.defvjp(_kernel_scan_fwd, _kernel_scan_bwd)
+
+
+def _kernel_ssd(x, dt, A, B, C, chunk: int) -> jax.Array:
+    """`chunk_ssd` on the kernels: ``x``, ``B``, ``C`` in the model's layout
+    (merged trailing axes), ``dt`` and ``a = dt A`` as rows, padded only
+    where the length is no multiple of the chunk."""
+    b, s, h, p = x.shape
+    pad = -s % chunk
+
+    def flat(t):
+        if pad:
+            t = jnp.pad(t, [(0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 2))
+        return t.reshape(b, s + pad, -1)
+
+    def rows(t):
+        return jnp.moveaxis(flat(t), 2, 1)[:, :, None, :]
+
+    dt = dt.astype(jnp.float32)
+    y = _kernel_scan(flat(x), rows(dt), rows(dt * A.astype(jnp.float32)),
+                     flat(B), flat(C), chunk, B.shape[2])
+    return y.reshape(b, s + pad, h, p)[:, :s]

@@ -26,12 +26,12 @@ import jimm_tpu
 assert os.path.abspath(jimm_tpu.__file__).startswith(root), jimm_tpu.__file__
 from jimm_tpu import cli, preset
 from jimm_tpu.configs import with_runtime
-from jimm_tpu.ops import attention, delta_rule, flash_attention as fa
+from jimm_tpu.ops import attention, delta_rule, flash_attention as fa, ssd
 from jimm_tpu.train import (OptimizerConfig, make_classifier_train_step, make_contrastive_train_step,
                             make_optimizer)
 from jimm_tpu.train.trainer import make_lm_train_step
-fa._interpret = delta_rule._interpret = lambda: False
-attention._default_backend = delta_rule._default_backend = lambda: "tpu"
+fa._interpret = delta_rule._interpret = ssd._interpret = lambda: False
+attention._default_backend = delta_rule._default_backend = ssd._default_backend = lambda: "tpu"
 topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
 one = SingleDeviceSharding(topo.devices[0])
 manifest = json.load(open(os.path.join(root, "BENCHMARK.json")))

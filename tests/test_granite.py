@@ -2,10 +2,11 @@
 dense runs of `models/kanana.py`) at a small size on the CPU, against the plain
 float32 reference `benchmarks/reference/granite.py` on seeded random weights:
 the chunked selective scan against the token-by-token recurrence (forward and
-every gradient), its segment sums, the mixer, the whole model's loss and
-gradients, one test a multiplier, the dense-only stack, the scopes and
-counters, the benchmark's cut, what the family may not cost the others, and
-its way through `jimm-tpu train`."""
+every gradient), its segment sums, its Pallas kernels against its XLA path
+(interpret mode) and the rule that picks them, the mixer, the whole model's
+loss and gradients, one test a multiplier, the dense-only stack, the scopes
+and counters, the benchmark's cut, what the family may not cost the others,
+and its way through `jimm-tpu train`."""
 
 import dataclasses
 import json
@@ -127,6 +128,104 @@ def test_padding_tokens_leave_the_state_alone():
     want = ref.ssm_scan(*inputs)
     np.testing.assert_allclose(chunk_ssd(*inputs, chunk=64)[:, -1],
                                want[:, -1], rtol=1e-5, atol=1e-5)
+
+
+# -- the Pallas kernels (interpret mode here) ----------------------------------
+
+def _on_path(monkeypatch, path: str) -> None:
+    """What `chunk_ssd` decides from the backend: the kernels where the
+    backend is a TPU (interpret mode follows the real backend)."""
+    monkeypatch.setattr(ssd, "_default_backend",
+                        lambda: "tpu" if path == "kernel" else "cpu")
+
+
+def _kernel_inputs(case: str, s: int, h=4, p=64, n=128):
+    """Heads of 64 and a state of 128, the widths the kernels take. At
+    ``the_start`` ``dt`` is about softplus(1) and A = -(1 .. H), as the
+    model starts (no state outlives a chunk), x, B, C in bfloat16 as the
+    model hands them over; at ``memory`` each head's step is log-spaced over
+    `parity_granite.DT_RANGE` at A = -1, so the state handed from chunk to
+    chunk carries the output, all in float32."""
+    keys = jax.random.split(jax.random.key(3), 5)
+    x = jax.nn.silu(jax.random.normal(keys[0], (1, s, h, p)))
+    if case == "the_start":
+        bias, A = 1.0, -jnp.arange(1, h + 1, dtype=jnp.float32)
+    else:
+        bias = jnp.log(jnp.expm1(jnp.geomspace(*parity_granite.DT_RANGE, h)))
+        A = -jnp.ones((h,))
+    dt = jax.nn.softplus(jax.random.normal(keys[1], (1, s, h)) + bias)
+    B = jax.nn.silu(jax.random.normal(keys[2], (1, s, 1, n)))
+    C = jax.nn.silu(jax.random.normal(keys[3], (1, s, 1, n)))
+    if case == "the_start":
+        x, B, C = (t.astype(jnp.bfloat16) for t in (x, B, C))
+    return (x, dt, A, B, C), jax.random.normal(keys[4], (1, s, h, p))
+
+
+@pytest.mark.parametrize("case,tokens", [
+    ("the_start", 512), ("memory", 1024), ("memory", 384)],
+    ids=["the_start", "memory", "memory-ragged_length"])
+def test_the_kernels_are_the_xla_path(case, tokens, monkeypatch):
+    """y and the gradients of all five inputs on the kernels against the
+    XLA path, chunks of 256 as two tiles of 128: at the model's start, with
+    a state that lives over many chunks, and at a length that is no multiple
+    of the chunk (padded with tokens of ``dt = 0``). A gradient in bfloat16
+    is held to its rounding, one in float32 to float32's."""
+    inputs, w = _kernel_inputs(case, tokens)
+    got = {}
+    for path in ("xla", "kernel"):
+        _on_path(monkeypatch, path)
+        y, vjp = jax.vjp(lambda *a: chunk_ssd(*a, chunk=256), *inputs)
+        got[path] = (y, *vjp(w))
+    for name, a, b in zip(("y", "x", "dt", "A", "B", "C"), got["kernel"],
+                          got["xla"]):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        limit = 5e-4 if a.dtype == jnp.bfloat16 else 2e-6
+        assert _rel(a.astype(jnp.float32), b.astype(jnp.float32)) < limit, \
+            name
+
+
+@pytest.mark.parametrize("x_shape,b_shape,chunk,backend,takes", [
+    ((1, 16384, 64, 64), (1, 16384, 1, 128), 256, "tpu", True),  # the cell
+    ((2, 300, 8, 128), (2, 300, 2, 256), 128, "tpu", True),
+    ((1, 16384, 64, 64), (1, 16384, 1, 128), 256, "cpu", False),
+    ((2, 32, 4, 16), (2, 32, 1, 16), 16, "tpu", False),    # the tiny preset
+    ((1, 4096, 64, 64), (1, 4096, 1, 64), 256, "tpu", False),
+    ((1, 4096, 64, 64), (1, 4096, 1, 128), 64, "tpu", False),
+    ((1, 4096, 8, 64), (1, 4096, 8, 128), 256, "tpu", False),  # a head a group
+    ((1, 4096, 8, 72), (1, 4096, 1, 128), 256, "tpu", False),
+])
+def test_the_kernels_take_the_tpu_shapes_and_xla_the_rest(
+        x_shape, b_shape, chunk, backend, takes):
+    assert ssd.kernel_takes(x_shape, b_shape, chunk, backend) is takes
+
+
+def test_the_tiny_preset_keeps_the_xla_path():
+    m = _tiny().decoder.mamba
+    x_shape = (2, 32, m.num_heads, m.head_dim)
+    b_shape = (2, 32, m.groups, m.state)
+    assert not ssd.kernel_takes(x_shape, b_shape, m.chunk, "tpu")
+    assert ssd._default_backend() == "cpu"
+
+
+def test_the_kernel_counter_counts_the_scans_built_on_the_kernels(
+        monkeypatch):
+    """``jimm_ssm_kernel_total`` beside ``calls_total`` / ``chunks_total``
+    (the registry is the process's: reset it first)."""
+    from jimm_tpu import obs
+    obs.get_registry("jimm_ssm").reset()
+    _on_path(monkeypatch, "kernel")
+    wide, _ = _kernel_inputs("memory", 256)
+    small, _ = _scan_inputs(1.0, s=32)
+
+    def counts():
+        snap = obs.snapshot()
+        return [snap.get(f"jimm_ssm_{k}_total", 0)
+                for k in ("calls", "chunks", "kernel")]
+
+    chunk_ssd(*wide, chunk=128)
+    assert counts() == [1, 2, 1]
+    chunk_ssd(*small, chunk=16)
+    assert counts() == [2, 4, 1]
 
 
 # -- the mixer, the model, the multipliers -------------------------------------
@@ -361,6 +460,8 @@ def test_the_remat_policies_keep_the_scans_output_and_states():
     assert '"ssm_y"' in source and '"ssm_states"' in source
     assert 'checkpoint_name(entered, "ssm_states")' in inspect.getsource(
         ssd._ssd_fwd)
+    assert 'checkpoint_name(kept, "ssm_states")' in inspect.getsource(
+        ssd._kernel_scan_fwd)
 
 
 # -- through the CLI ------------------------------------------------------------------

@@ -18,7 +18,8 @@ import pytest
 from flax import nnx
 
 from jimm_tpu.ops import (delta_rule as dr, flash_attention as fa,
-                          fp8_matmul as f8, int8_matmul as i8, layer_norm as ln)
+                          fp8_matmul as f8, int8_matmul as i8,
+                          layer_norm as ln, ssd)
 
 HBM_BYTES = 16 * 1000 ** 3  # one v5e chip
 
@@ -47,9 +48,10 @@ def one_chip():
 def compiled_kernels(monkeypatch):
     """The kernels pick interpret mode from ``jax.default_backend()``, which
     is the CPU here: steer that in the test, not through a program option."""
-    for module in (fa, f8, i8, ln, dr):
+    for module in (fa, f8, i8, ln, dr, ssd):
         monkeypatch.setattr(module, "_interpret", lambda: False)
-    monkeypatch.setattr(dr, "_default_backend", lambda: "tpu")
+    for module in (dr, ssd):
+        monkeypatch.setattr(module, "_default_backend", lambda: "tpu")
 
 
 def _fwd_bwd(fn):
@@ -161,6 +163,13 @@ KERNEL_CASES = {
         functools.partial(dr.chunk_kda, chunk=64),
         [((1, 16384, 32, 128), jnp.bfloat16)] * 3
         + [((1, 16384, 32, 128), jnp.float32), ((1, 16384, 32), jnp.float32)]),
+    # the Mamba-2 scan at granite_4_0_h_micro.train's shape: 16,384 tokens, 64
+    # heads of 64, B and C 128 wide in one group, chunks of 256; x, B, C in
+    # bfloat16, dt and A in float32
+    "ssd_scan_s16384_h64_p64_n128": (
+        functools.partial(ssd.chunk_ssd, chunk=256),
+        [((1, 16384, 64, 64), jnp.bfloat16), ((1, 16384, 64), jnp.float32),
+         ((64,), jnp.float32)] + [((1, 16384, 1, 128), jnp.bfloat16)] * 2),
 }
 
 
@@ -318,6 +327,73 @@ def test_kda_kernels_carry_the_scopes_in_the_models_step(one_chip,
     # one forward and one backward a run of KDA layers (three in the tiny
     # stack; a scanned run's body is one call in the text)
     assert kinds == {"kda_fwd": 3, "kda_bwd": 3}, kinds
+
+
+def test_ssd_kernels_compile_under_the_limit_they_state(one_chip,
+                                                        compiled_kernels):
+    """The Mamba-2 scan's forward and backward at the cell's shape: two
+    Pallas calls, named, each under the ``vmem_limit_bytes`` it states, and
+    both under the ``ssm_scan`` scope the call is made in."""
+    fn, arg_shapes = KERNEL_CASES["ssd_scan_s16384_h64_p64_n128"]
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in arg_shapes]
+
+    def scoped(*a):
+        with jax.named_scope("ssm"), jax.named_scope("ssm_scan"):
+            return fn(*a)
+
+    calls = _kernel_calls(jax.jit(_fwd_bwd(scoped)).lower(*args).compile()
+                          .as_text())
+    assert [name.rsplit("/", 2)[1] for name, _, _ in calls] \
+        == ["ssd_fwd", "ssd_bwd"]
+    for name, given, used in calls:
+        assert "/ssm_scan/" in name
+        assert used <= given == ssd._VMEM_LIMIT
+
+
+def test_ssd_kernels_carry_the_scopes_in_the_models_step(one_chip,
+                                                         compiled_kernels):
+    """A granite train step compiled for the v5e with its Mamba-2 layers at
+    heads of 64 and a state of 128 in chunks of 128: every Pallas call of
+    the scan, forward and backward, has ``ssm`` and ``ssm_scan`` as plain
+    components of its ``op_name``, which is how
+    `benchmarks/layer_metrics/granite.py` finds ``ssm_scan_ms``."""
+    import dataclasses
+    import re
+
+    from jimm_tpu import Granite, preset
+    from jimm_tpu.cli import _tiny_override
+    from jimm_tpu.train import OptimizerConfig, make_optimizer
+    from jimm_tpu.train.trainer import make_lm_train_step
+    cfg = _tiny_override(preset("granite-4.0-h-micro"))
+    cfg = dataclasses.replace(cfg, decoder=dataclasses.replace(
+        cfg.decoder, mamba=dataclasses.replace(
+            cfg.decoder.mamba, num_heads=2, head_dim=64, state=128,
+            chunk=128)))
+
+    def build():
+        model = Granite(cfg, rngs=nnx.Rngs(0))
+        return model, make_optimizer(model, OptimizerConfig(total_steps=4))
+
+    model, optimizer = nnx.eval_shape(build)
+    for module in (model, optimizer):
+        nnx.update(module, jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip),
+            nnx.state(module)))
+    tokens = jax.ShapeDtypeStruct((2, cfg.decoder.seq_len + 1), jnp.int32,
+                                  sharding=one_chip)
+    text = make_lm_train_step("granite").lower(model, optimizer, tokens) \
+        .compile().as_text()
+    kinds = {}
+    for name, _, _ in _kernel_calls(text):
+        kind = name.rsplit("/", 2)[1]
+        if kind.startswith("ssd_"):
+            assert re.search(r"(^|/)ssm/ssm_scan/ssd_(fwd|bwd)/", name), name
+            kinds[kind] = kinds.get(kind, 0) + 1
+    # one forward and one backward a run of Mamba-2 layers (two in the tiny
+    # stack; a scanned run's body is one call in the text)
+    assert kinds == {"ssd_fwd": 2, "ssd_bwd": 2}, kinds
 
 
 @pytest.mark.parametrize("rows,width,expert_dim,experts,tile_m", [
