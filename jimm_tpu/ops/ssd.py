@@ -64,9 +64,19 @@ cut into tiles of `_ROWS` = 128 rows (`_parts`): inside a tile ``E`` is a
 product of the triangle of ones with ``a`` (``(U * a) V``: every term of one
 sign); a pair across tiles sums the row's head, the whole tiles between and
 the key's tail; ``into``, ``out_of`` and the chunk's total sum a tile's part
-and whole tiles. So the rule above holds: no exponent is a difference. ``C
-B^T`` of bfloat16 operands is one MXU pass (their products are exact in
-float32); every product with a float32 operand is at `_PRECISION`. Then ``y``
+and whole tiles. So the rule above holds: no exponent is a difference. The
+exact operands stay in the inputs' dtype inside (x, a row tile of C, B, and
+the triangle of ones, built in x's dtype), and a product takes as many MXU
+passes as its operands' dtypes need (`_mx`): ``C B^T`` of bfloat16 operands
+is one pass (every product exact in float32); a product with one bfloat16
+operand and one float32 is one pass of the float32 operand's three bfloat16
+pieces side by side along the contraction against the exact operand three
+times (the float32 operand cut once where it enters several: the state in
+every row tile), the terms `_PRECISION`'s six passes sum less the three that
+multiply the exact operand's zero low pieces, so the same numbers but for
+the order of a float32 sum; a product of two float32 operands (``dyi S``,
+``w^T dy``, ``(f B) dS'^T`` in the backward, and every product of float32
+inputs) is at `_PRECISION`. Then ``y``
 from the tokens and from the state, and the next state. The forward writes
 the state each chunk is entered with (``ssm_states``, as the XLA path). The
 backward walks the chunks from the last (index map ``n - 1 - i``), ``dS`` in
@@ -337,19 +347,64 @@ def kernel_takes(x_shape, b_shape, chunk: int, backend: str) -> bool:
             and n % 128 == 0 and chunk % _ROWS == 0)
 
 
-def _cb(c: jax.Array, b: jax.Array) -> jax.Array:
-    """``C B^T`` of a row tile and a key tile. Of two bfloat16 operands it is
-    one MXU pass: every product exact in float32, summed in float32, what six
-    passes would give; of float32 ones, six."""
-    if c.dtype == b.dtype == jnp.bfloat16:
-        return jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-    return _dot(c.astype(jnp.float32), b.astype(jnp.float32), "nt")
+def _exact(t) -> bool:
+    return not isinstance(t, tuple) and t.dtype == jnp.bfloat16
+
+
+def _pieces(a: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """A float32 ``a`` as three bfloat16 pieces whose sum is ``a`` exactly:
+    its top 8 significant bits, the top 8 of what is left, and the rest (8
+    at most), each cut off by a mask of the bits (a cast to bfloat16 and
+    back may be dropped by XLA as excess precision; a mask may not)."""
+    def top(t):
+        bits = jax.lax.bitcast_convert_type(t, jnp.uint32)
+        return jax.lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000),
+                                            jnp.float32)
+
+    hi = top(a)
+    mid = top(a - hi)
+    return tuple(t.astype(jnp.bfloat16) for t in (hi, mid, a - hi - mid))
+
+
+def _split_for(a: jax.Array, *partners: jax.Array):
+    """``a``'s `_pieces`, cut once for every product it enters, where each
+    operand it meets there is bfloat16; else ``a``."""
+    return _pieces(a) if all(map(_exact, partners)) else a
+
+
+def _stacked(t, axis: int) -> jax.Array:
+    """An operand of a split product along its contraction axis: a float32
+    one's `_pieces` side by side, an exact one three times."""
+    parts = t if isinstance(t, tuple) else [t] * 3 if _exact(t) \
+        else _pieces(t)
+    return jnp.concatenate(parts, axis)
+
+
+def _mx(a, b, form: str = "nn") -> jax.Array:
+    """`_dot`'s product in the fewest MXU passes that keep its terms. Two
+    bfloat16 operands: one pass, every product exact in float32. One
+    bfloat16 operand and one float32 (or that one's `_pieces`): one pass of
+    the float32 operand's three bfloat16 pieces side by side along the
+    contraction against the exact operand three times, what `_PRECISION`'s
+    six passes sum less the three that multiply the exact operand's zero low
+    pieces; counted in ``jimm_ssm_split_products_total`` as the kernel body
+    is traced. Two float32 operands: `_dot`, six passes."""
+    if not (_exact(a) or _exact(b)):
+        return _dot(a, b, form)
+    n = (b if _exact(b) else a).ndim
+    at_a, at_b = {"nn": (n - 1, n - 2), "nt": (n - 1, n - 1),
+                  "tn": (n - 2, n - 2)}[form]
+    if not (_exact(a) and _exact(b)):
+        from jimm_tpu.obs.registry import get_registry
+        get_registry("jimm_ssm").counter("split_products_total").inc()
+        a, b = _stacked(a, at_a), _stacked(b, at_b)
+    batch = tuple(range(n - 2))
+    return jax.lax.dot_general(a, b, (((at_a,), (at_b,)), (batch, batch)),
+                               preferred_element_type=jnp.float32)
 
 
 def _split_heads(block: jax.Array, heads: int) -> jax.Array:
-    """A ``(L, heads * P)`` block as ``(heads, L, P)`` float32."""
-    block = block.astype(jnp.float32)
+    """A ``(L, heads * P)`` block as ``(heads, L, P)``, in its dtype."""
     p = block.shape[-1] // heads
     return jnp.stack([block[:, i * p:(i + 1) * p] for i in range(heads)])
 
@@ -358,7 +413,7 @@ def _join_heads(x: jax.Array) -> jax.Array:
     return jnp.concatenate([x[i] for i in range(x.shape[0])], axis=-1)
 
 
-def _parts(a_ref, dt_ref) -> dict:
+def _parts(a_ref, dt_ref, exact) -> dict:
     """What a chunk's exponents are built from, tile by tile, for the rows
     ``a, dt (1, heads, 1, L)`` (read a tile at a time): ``head`` (``(heads,
     R, 1)``: the sum of ``a`` over the tile's tokens up to the row's),
@@ -366,13 +421,15 @@ def _parts(a_ref, dt_ref) -> dict:
     ``whole`` (``(heads, 1, 1)``), ``inside`` (``(heads, R, R)``: ``E`` on
     the tile's diagonal block, a product of the triangle of ones with ``a``;
     ``-inf`` above the diagonal), ``into``, ``out_of`` and ``total`` of the
-    chunk, each a sum of same-signed parts, and ``dt`` as rows and columns."""
+    chunk, each a sum of same-signed parts, and ``dt`` as rows and columns.
+    The triangle of ones is in the dtype ``exact``, the inputs'."""
     _, heads, _, length = a_ref.shape
     r = _ROWS
     row, col = _iota((r, r), 0), _iota((r, r), 1)
     upto, after = col <= row, col > row
     # V_sj = [s > j]: rows s, lanes j
-    later = jnp.broadcast_to(jnp.where(row > col, 1.0, 0.0), (heads, r, r))
+    later = jnp.broadcast_to(jnp.where(row > col, 1.0, 0.0).astype(exact),
+                             (heads, r, r))
     p = {k: [] for k in ("head", "tail", "whole", "inside", "dt_row",
                          "dt_col")}
     for t in range(length // r):
@@ -381,7 +438,7 @@ def _parts(a_ref, dt_ref) -> dict:
         p["tail"].append(jnp.sum(jnp.where(after, a, 0.0), -1, keepdims=True))
         p["whole"].append(jnp.sum(a[:, :1], -1, keepdims=True))
         p["inside"].append(jnp.where(
-            upto, _dot(jnp.where(upto, a, 0.0), later), -jnp.inf))
+            upto, _mx(jnp.where(upto, a, 0.0), later), -jnp.inf))
         p["dt_row"].append(dt_ref[0, :, :, t * r:(t + 1) * r])
         p["dt_col"].append(_flip(p["dt_row"][-1]))
     n = len(p["whole"])
@@ -412,25 +469,25 @@ def _fwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, kept_ref, state, *,
 
     r = _ROWS
     x = _split_heads(x_ref[0], heads)
-    p = _parts(a_ref, dt_ref)
+    p = _parts(a_ref, dt_ref, x.dtype)
     B, C = b_ref[0], c_ref[0]
     s0 = state[...]
     kept_ref[0, :, 0] = s0
+    s0_cut = _split_for(s0, C)
     new, ys = jnp.exp(p["total"]) * s0, []
     for rt in range(len(p["whole"])):
         rows = slice(rt * r, (rt + 1) * r)
-        c = jnp.broadcast_to(C[rows].astype(jnp.float32),
-                             (heads, r, C.shape[-1]))
-        y = jnp.exp(p["into"][rt]) * _dot(c, s0, "nt")
+        c = jnp.broadcast_to(C[rows], (heads, r, C.shape[-1]))
+        y = jnp.exp(p["into"][rt]) * _mx(c, s0_cut, "nt")
         for ct in range(rt + 1):
             keys = slice(ct * r, (ct + 1) * r)
-            w = jnp.exp(_exponent(p, rt, ct)) * _cb(C[rows], B[keys]) \
+            w = jnp.exp(_exponent(p, rt, ct)) * _mx(C[rows], B[keys], "nt") \
                 * p["dt_row"][ct]
-            y = y + _dot(w, x[:, keys])
+            y = y + _mx(w, x[:, keys])
         ys.append(y)
         written = jnp.exp(p["out_of"][rt]) * p["dt_col"][rt] \
             * B[rows].astype(jnp.float32)
-        new = new + _dot(x[:, rows], written, "tn")
+        new = new + _mx(x[:, rows], written, "tn")
     state[...] = new
     y_ref[0] = _join_heads(jnp.concatenate(ys, axis=-2)).astype(y_ref.dtype)
 
@@ -444,10 +501,10 @@ def _bwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, kept_ref, dy_ref,
 
     r = _ROWS
     x, dy = _split_heads(x_ref[0], heads), _split_heads(dy_ref[0], heads)
-    p = _parts(a_ref, dt_ref)
+    p = _parts(a_ref, dt_ref, x.dtype)
     B, C = b_ref[0], c_ref[0]
-    Bf, Cf = B.astype(jnp.float32), C.astype(jnp.float32)
     s0, ds1 = kept_ref[0, :, 0], d_state[...]
+    s0_cut, ds1_cut = _split_for(s0, C), _split_for(ds1, x)
     n = len(p["whole"])
     zero_col = jnp.zeros((heads, r, 1), jnp.float32)
     zero_row = jnp.zeros((heads, 1, r), jnp.float32)
@@ -463,33 +520,33 @@ def _bwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, kept_ref, dy_ref,
                       keepdims=True) * decay
     for rt in range(n):
         rows = slice(rt * r, (rt + 1) * r)
-        c = jnp.broadcast_to(Cf[rows], (heads, r, C.shape[-1]))
+        c = jnp.broadcast_to(C[rows], (heads, r, C.shape[-1]))
         dy_r = dy[:, rows]
         # y += exp(into) (C S^T)
         dyi = jnp.exp(p["into"][rt]) * dy_r
-        d_into = jnp.sum(dyi * _dot(c, s0, "nt"), -1, keepdims=True)
+        d_into = jnp.sum(dyi * _mx(c, s0_cut, "nt"), -1, keepdims=True)
         d_head[rt] = d_head[rt] + d_into
         for t in range(rt):
             d_whole[t] = d_whole[t] + jnp.sum(d_into, -2, keepdims=True)
         dc[rt] = dc[rt] + jnp.sum(_dot(dyi, s0), 0)
-        ds0 = ds0 + _dot(dyi, c, "tn")
+        ds0 = ds0 + _mx(dyi, c, "tn")
         # y += (exp(E) * C B^T * dt) x
         for ct in range(rt + 1):
             keys = slice(ct * r, (ct + 1) * r)
             e = jnp.exp(_exponent(p, rt, ct))
-            wn = e * _cb(C[rows], B[keys])
+            wn = e * _mx(C[rows], B[keys], "nt")
             w = wn * p["dt_row"][ct]
-            dw = _dot(dy_r, x[:, keys], "nt")
+            dw = _mx(dy_r, x[:, keys], "nt")
             dx[ct] = dx[ct] + _dot(w, dy_r, "tn")
             ddt[ct] = ddt[ct] + jnp.sum(dw * wn, -2, keepdims=True)
             dE = dw * w
-            dcb = jnp.sum(dw * e * p["dt_row"][ct], 0)
-            dc[rt] = dc[rt] + _dot(dcb, Bf[keys])
-            db[ct] = db[ct] + _dot(dcb, Cf[rows], "tn")
+            dcb = _split_for(jnp.sum(dw * e * p["dt_row"][ct], 0), B, C)
+            dc[rt] = dc[rt] + _mx(dcb, B[keys])
+            db[ct] = db[ct] + _mx(dcb, C[rows], "tn")
             if rt == ct:
                 # E = (U * a) V: a_s gets sum_r U_rs (dE V^T)_rs
                 d_inside[ct] = d_inside[ct] + jnp.sum(jnp.where(
-                    p["upto"], _dot(dE, p["later"], "nt"), 0.0), -2,
+                    p["upto"], _mx(dE, p["later"], "nt"), 0.0), -2,
                     keepdims=True)
             else:
                 d_head[rt] = d_head[rt] + jnp.sum(dE, -1, keepdims=True)
@@ -504,8 +561,9 @@ def _bwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, kept_ref, dy_ref,
         keys = slice(ct * r, (ct + 1) * r)
         out = jnp.exp(p["out_of"][ct])
         f = out * p["dt_col"][ct]
-        b = jnp.broadcast_to(Bf[keys], (heads, r, B.shape[-1]))
-        dbd = _dot(x[:, keys], ds1)
+        b = jnp.broadcast_to(B[keys].astype(jnp.float32),
+                             (heads, r, B.shape[-1]))
+        dbd = _mx(x[:, keys], ds1_cut)
         dx[ct] = dx[ct] + _dot(f * b, ds1, "nt")
         db[ct] = db[ct] + jnp.sum(f * dbd, 0)
         df = jnp.sum(dbd * b, -1, keepdims=True)

@@ -145,7 +145,8 @@ def _kernel_inputs(case: str, s: int, h=4, p=64, n=128):
     model starts (no state outlives a chunk), x, B, C in bfloat16 as the
     model hands them over; at ``memory`` each head's step is log-spaced over
     `parity_granite.DT_RANGE` at A = -1, so the state handed from chunk to
-    chunk carries the output, all in float32."""
+    chunk carries the output, all in float32 (``memory_bfloat16``: x, B, C in
+    bfloat16, so the state enters the kernels' three-pass products)."""
     keys = jax.random.split(jax.random.key(3), 5)
     x = jax.nn.silu(jax.random.normal(keys[0], (1, s, h, p)))
     if case == "the_start":
@@ -156,20 +157,22 @@ def _kernel_inputs(case: str, s: int, h=4, p=64, n=128):
     dt = jax.nn.softplus(jax.random.normal(keys[1], (1, s, h)) + bias)
     B = jax.nn.silu(jax.random.normal(keys[2], (1, s, 1, n)))
     C = jax.nn.silu(jax.random.normal(keys[3], (1, s, 1, n)))
-    if case == "the_start":
+    if case != "memory":
         x, B, C = (t.astype(jnp.bfloat16) for t in (x, B, C))
     return (x, dt, A, B, C), jax.random.normal(keys[4], (1, s, h, p))
 
 
 @pytest.mark.parametrize("case,tokens", [
-    ("the_start", 512), ("memory", 1024), ("memory", 384)],
-    ids=["the_start", "memory", "memory-ragged_length"])
+    ("the_start", 512), ("memory", 1024), ("memory", 384),
+    ("memory_bfloat16", 1024)],
+    ids=["the_start", "memory", "memory-ragged_length", "memory-bfloat16"])
 def test_the_kernels_are_the_xla_path(case, tokens, monkeypatch):
     """y and the gradients of all five inputs on the kernels against the
     XLA path, chunks of 256 as two tiles of 128: at the model's start, with
-    a state that lives over many chunks, and at a length that is no multiple
-    of the chunk (padded with tokens of ``dt = 0``). A gradient in bfloat16
-    is held to its rounding, one in float32 to float32's."""
+    a state that lives over many chunks (in float32 and in bfloat16), and at
+    a length that is no multiple of the chunk (padded with tokens of ``dt =
+    0``). A gradient in bfloat16 is held to its rounding, one in float32 to
+    float32's."""
     inputs, w = _kernel_inputs(case, tokens)
     got = {}
     for path in ("xla", "kernel"):
@@ -226,6 +229,82 @@ def test_the_kernel_counter_counts_the_scans_built_on_the_kernels(
     assert counts() == [1, 2, 1]
     chunk_ssd(*small, chunk=16)
     assert counts() == [2, 4, 1]
+
+
+_FORMS = {"nn": ((4, 128, 96), (4, 96, 64)), "nt": ((4, 128, 96), (4, 64, 96)),
+          "tn": ((4, 96, 128), (4, 96, 64))}
+
+
+@pytest.mark.parametrize("values", ["random", "bfloat16_exact"])
+@pytest.mark.parametrize("exact_side", ["left", "right"])
+@pytest.mark.parametrize("form", sorted(_FORMS))
+def test_a_split_product_is_the_highest_product(form, exact_side, values):
+    """`ssd._mx` of a float32 operand and a bfloat16 one (its three pieces
+    side by side against the exact operand three times) against the
+    ``HIGHEST`` product, batched over heads: to float32's rounding of a sum
+    for random values, and exactly for a float32 operand that is itself
+    bfloat16-exact, on a grid where every sum is exact in float32 whatever
+    its order (so a dropped or rounded piece shows, and the order of the
+    summation does not)."""
+    keys = jax.random.split(jax.random.key(11), 2)
+
+    def draw(key, shape, exact):
+        v = jax.random.normal(key, shape)
+        if values == "bfloat16_exact":
+            v = jnp.round(v * 32) / 64
+        return v.astype(jnp.bfloat16) if exact else v
+
+    shape_a, shape_b = _FORMS[form]
+    a = draw(keys[0], shape_a, exact_side == "left")
+    b = draw(keys[1], shape_b, exact_side == "right")
+    got = jax.jit(lambda a, b: ssd._mx(a, b, form))(a, b)
+    want = ssd._dot(a.astype(jnp.float32), b.astype(jnp.float32), form)
+    assert got.dtype == jnp.float32 and got.shape == want.shape
+    if values == "random":
+        assert _rel(got, want) < 1e-6
+    else:
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_the_pieces_sum_to_the_operand_exactly():
+    """`ssd._pieces` of float32 values over many binades: three bfloat16
+    pieces, each at most a third of the bits, whose float32 sum is the value
+    bit for bit."""
+    v = jax.random.normal(jax.random.key(5), (4096,)) \
+        * jnp.exp2(jnp.linspace(-60.0, 60.0, 4096))
+    pieces = ssd._pieces(v)
+    assert all(t.dtype == jnp.bfloat16 for t in pieces)
+    hi, mid, lo = (t.astype(jnp.float32) for t in pieces)
+    np.testing.assert_array_equal(np.asarray(hi + mid + lo), np.asarray(v))
+
+
+@pytest.mark.parametrize("path,dtype,want", [
+    ("kernel", jnp.bfloat16, (9, 28)), ("kernel", jnp.float32, (0, 0)),
+    ("xla", jnp.bfloat16, (0, 0))], ids=["kernel-bfloat16", "kernel-float32",
+                                        "xla-bfloat16"])
+def test_the_split_counter_counts_the_products_on_three_passes(
+        path, dtype, want, monkeypatch):
+    """``jimm_ssm_split_products_total``: the kernels' products built on the
+    three-piece form, counted as a body is traced. In chunks of two tiles,
+    with x, B, C in bfloat16, every product of the forward but ``C B^T``
+    (9) and then the backward's, all but its three of two float32 operands
+    (19 more); none with float32 inputs or on the XLA path."""
+    from jimm_tpu import obs
+    obs.get_registry("jimm_ssm").reset()
+    _on_path(monkeypatch, path)
+    (x, dt, A, B, C), w = _kernel_inputs("memory", 256)
+    x, B, C = (t.astype(dtype) for t in (x, B, C))
+
+    def split():
+        return obs.snapshot().get("jimm_ssm_split_products_total", 0)
+
+    def scan(*a):
+        return chunk_ssd(*a, chunk=256)
+
+    jax.jit(scan).lower(x, dt, A, B, C)
+    forward = split()
+    jax.jit(lambda *a: jax.vjp(scan, *a)[1](w)).lower(x, dt, A, B, C)
+    assert (forward, split()) == (want[0], want[0] + want[1])
 
 
 # -- the mixer, the model, the multipliers -------------------------------------
