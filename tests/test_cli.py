@@ -16,13 +16,7 @@ from jimm_tpu.configs import PRESETS
 from jimm_tpu.weights.safetensors_io import save_file
 
 
-def test_presets_lists_all(capsys):
-    assert main(["presets"]) == 0
-    out = capsys.readouterr().out
-    for name in ("vit-base-patch16-224", "clip-vit-large-patch14",
-                 "siglip-so400m-patch14-384", "siglip2-large-patch16-512"):
-        assert name in out
-
+# (``presets`` lists every preset: `tests/test_families.py`)
 
 def test_train_tiny_vit(tmp_path, capsys):
     metrics = tmp_path / "metrics.jsonl"

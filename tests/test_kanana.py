@@ -2,10 +2,10 @@
 `nn/moe.py`) at a small size on the CPU: its shapes, the share an expert layer
 holds, routing without a dropped assignment, the routers' bias update, and the
 family's way through `jimm-tpu train`. Agreement with the plain reference is
-`tests/benchmark/test_moe_lm.py`'s."""
+`tests/benchmark/test_moe_lm.py`'s; its entries in the CLI's tables and its
+`presets` line are `tests/test_families.py`'s."""
 
 import dataclasses
-import json
 
 import jax
 import jax.numpy as jnp
@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 from flax import nnx
 
+from family_util import train_rows
 from jimm_tpu import Kanana, KananaConfig, preset
 from jimm_tpu.cli import _tiny_override, main
 from jimm_tpu.configs import MLAConfig, MoEConfig, TransformerConfig
@@ -234,24 +235,21 @@ def test_loss_falls_on_a_batch_seen_again():
     assert np.isfinite(losses).all() and losses[-1] < losses[0] - 1.0
 
 
+# -- through the CLI ------------------------------------------------------------------
+# (its entries in the CLI's tables and its `presets` line:
+# `tests/test_families.py`)
+
 def test_train_cli_runs_the_family_through_the_same_loop(tmp_path, capsys):
     from jimm_tpu import obs
     before = obs.snapshot()
-    metrics = tmp_path / "metrics.jsonl"
-    assert main(["train", "--preset", "kanana-2-30b-a3b", "--tiny", "--steps",
-                 "30", "--batch-size", "2", "--remat", "dots",
-                 "--log-every", "0", "--metrics-file", str(metrics)]) == 0
-    rows = [json.loads(line) for line in metrics.read_text().splitlines()]
-    assert len(rows) == 30
+    rows = train_rows(tmp_path, capsys, [
+        "--preset", "kanana-2-30b-a3b", "--tiny", "--steps", "30",
+        "--batch-size", "2", "--remat", "dots", "--log-every", "0"])
     for row in rows:
-        assert np.isfinite(row["loss"])
-        assert {name for name, _, _ in row["phases"]} >= {
-            "next_batch", "place", "dispatch", "device_wait"}
         assert 0 <= row["moe_held_rows"] <= 2 * 64 * 2
         # uniform random ids: nothing to learn but ln(vocabulary)
         assert abs(row["loss"] - np.log(512)) < 0.15
     assert rows[-1]["router_bias_absmax"] > rows[0]["router_bias_absmax"]
-    assert "goodput: " in capsys.readouterr().out
     after = obs.snapshot()
 
     def grew(name):
@@ -273,7 +271,6 @@ def test_num_layers_and_seq_len_shape_the_preset(tmp_path):
     assert (d.depth, d.dense_layers, d.seq_len) == (4, 1, 16)
     assert result.batch[0].shape == (1, 17)
     assert result.model.sparse.blocks.mlp.router.shape == (3, 64, 16)
-    assert (args_lr := cli.LM_FAMILIES["kanana"]["lr"]) == 1e-4 and args_lr
 
 
 @pytest.mark.parametrize("argv,message", [
@@ -288,26 +285,6 @@ def test_train_cli_refuses_what_the_family_does_not_have(argv, message):
         main(["train", "--tiny", "--steps", "1", *argv])
 
 
-def test_language_model_families_are_one_table():
-    """A second decoder is an entry, not a fourth ``if``: the step, the
-    counters and the optimizer defaults of each are looked up by family."""
-    from jimm_tpu import cli
-    from jimm_tpu.train.trainer import LM_STEPS
-    assert set(cli.LM_FAMILIES) == set(LM_STEPS) == {"ouro", "kanana",
-                                                     "trinity", "kimi",
-                                                     "granite"}
-    assert set(cli.LM_FAMILIES) < set(cli._FAMILIES)
-    assert cli._family("kanana-2-30b-a3b") == "kanana"
-    assert cli._model_cls("kanana") is Kanana
-    names = {fam: [name for _, name, _ in cli._lm_counters(
-        _tiny_override(preset(p)), 2)]
-        for fam, p in (("ouro", "ouro-2.6b"), ("kanana", "kanana-2-30b-a3b"),
-                       ("trinity", "trinity-large"))}
-    assert names["ouro"] == ["tokens_total", "block_applications_total"]
-    assert names["kanana"] == names["trinity"] == [
-        "tokens_total", "assignments_total", "held_assignments_total"]
-
-
 def test_model_flops_of_the_benchmarks_cut():
     """The dense layer and five sparse ones, two sequences of 8192 tokens:
     53.7 TFLOP a step (ISSUE 32), causal attention at half of S^2 and the
@@ -317,10 +294,3 @@ def test_model_flops_of_the_benchmarks_cut():
     cut = dataclasses.replace(cfg, decoder=dataclasses.replace(cfg.decoder,
                                                                depth=6))
     assert train_step_flops(cut, 2) == pytest.approx(53.7e12, rel=2e-3)
-
-
-def test_presets_lists_the_share(capsys):
-    assert main(["presets"]) == 0
-    line = [ln for ln in capsys.readouterr().out.splitlines()
-            if ln.startswith("kanana-2-30b-a3b")][0]
-    assert "experts=16/128 held" in line and "vocab=16032" in line

@@ -1,9 +1,9 @@
 """The looped decoder language model (`models/ouro.py`) at a small size on
 the CPU: width 64, 4 heads of 16, MLP 176, vocabulary 512, n = 2 layers,
-R = 4 passes, S = 32, seeded random weights, float32."""
+R = 4 passes, S = 32, seeded random weights, float32. Its entries in the
+CLI's tables and its `presets` line are `tests/test_families.py`'s."""
 
 import dataclasses
-import json
 import warnings
 
 import jax
@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 from flax import nnx
 
+from family_util import train_rows
 from jimm_tpu import DecoderConfig, Ouro, OuroConfig, preset
 from jimm_tpu.cli import _tiny_override, main
 from jimm_tpu.nn.transformer import Transformer, apply_rope, rope_tables
@@ -241,23 +242,20 @@ def test_causal_flash_at_head_width_128_in_the_tiled_regime():
     assert tiled.value > before[0] and single.value == before[1]
 
 
+# -- through the CLI ------------------------------------------------------------------
+# (its entries in the CLI's tables and its `presets` line:
+# `tests/test_families.py`)
+
 def test_train_cli_runs_the_family_through_the_same_loop(tmp_path, capsys):
     from jimm_tpu import obs
     before = obs.snapshot()
-    metrics = tmp_path / "metrics.jsonl"
-    assert main(["train", "--preset", "ouro-2.6b", "--tiny", "--steps", "3",
-                 "--batch-size", "2", "--remat", "dots",
-                 "--metrics-file", str(metrics)]) == 0
-    rows = [json.loads(line) for line in metrics.read_text().splitlines()]
-    assert len(rows) == 3
+    rows = train_rows(tmp_path, capsys, [
+        "--preset", "ouro-2.6b", "--tiny", "--steps", "3", "--batch-size",
+        "2", "--remat", "dots"])
     for row in rows:
-        assert np.isfinite(row["loss"])
-        assert {name for name, _, _ in row["phases"]} >= {
-            "next_batch", "place", "dispatch", "device_wait"}
         p = [row[f"exit_p{r}"] for r in range(1, 5)]
         assert abs(sum(p) - 1.0) < 1e-3
         assert all(4.0 < row[f"loss_exit{r}"] < 8.0 for r in range(1, 5))
-    assert "goodput: " in capsys.readouterr().out
     after = obs.snapshot()
     assert after["jimm_lm_tokens_total"] \
         - before.get("jimm_lm_tokens_total", 0) == 3 * 2 * 32

@@ -2,10 +2,10 @@
 attention with qk-norm and a gate, windowed layers with rotary beside full
 layers without any position signal, the two stacks, the train step and the
 CLI. The model against its plain reference is
-``tests/benchmark/test_gqa_moe_lm.py``."""
+``tests/benchmark/test_gqa_moe_lm.py``; its entries in the CLI's tables and
+its ``presets`` line are ``tests/test_families.py``'s."""
 
 import dataclasses
-import json
 
 import jax
 import jax.numpy as jnp
@@ -13,8 +13,9 @@ import numpy as np
 import pytest
 from flax import nnx
 
+from family_util import train_rows
 from jimm_tpu import Trinity, preset
-from jimm_tpu.cli import _tiny_override, main
+from jimm_tpu.cli import _tiny_override
 from jimm_tpu.configs import GQAConfig, TransformerConfig, TrinityConfig
 from jimm_tpu.nn import moe
 from jimm_tpu.nn.transformer import (Attention, Block, Transformer,
@@ -283,14 +284,14 @@ def test_train_step_moves_the_bias_and_reports_the_routing():
     assert float(metrics["loss"]) < first
 
 
+# -- through the CLI ------------------------------------------------------------------
+# (its entries in the CLI's tables and its `presets` line:
+# `tests/test_families.py`)
+
 def test_train_cli_runs_the_family_through_the_same_loop(tmp_path, capsys):
-    rows = tmp_path / "m.jsonl"
-    assert main(["train", "--preset", "trinity-large", "--tiny", "--steps",
-                 "3", "--batch-size", "2", "--metrics-file", str(rows)]) == 0
-    out = capsys.readouterr().out
-    assert "goodput:" in out
-    logged = [json.loads(line) for line in rows.read_text().splitlines()]
-    assert len(logged) == 3
+    logged = train_rows(tmp_path, capsys, [
+        "--preset", "trinity-large", "--tiny", "--steps", "3",
+        "--batch-size", "2"])
     for row in logged:
         assert {"loss", "moe_held_rows", "moe_load_max_over_mean",
                 "router_bias_absmax", "phases"} <= set(row)
@@ -302,9 +303,6 @@ def test_num_layers_and_seq_len_shape_the_preset():
     args = cli.build_parser().parse_args(
         ["train", "--preset", "trinity-large", "--num-layers", "5",
          "--seq-len", "8192", "--bf16", "--remat", "full"])
-    assert cli._family(args.preset) == "trinity"
-    assert cli._model_cls("trinity") is Trinity
-    assert cli.LM_FAMILIES["trinity"] == {"lr": 1e-4, "warmup_steps": 20}
     cfg = cli._replace_towers(preset(args.preset), depth=5, seq_len=8192)
     built = nnx.eval_shape(lambda: Trinity(cfg, rngs=nnx.Rngs(0)))
     n = sum(int(np.prod(v.shape)) for v in jax.tree.leaves(
@@ -322,13 +320,6 @@ def test_model_flops_of_the_benchmarks_cut():
     cut = dataclasses.replace(cfg, decoder=dataclasses.replace(cfg.decoder,
                                                                depth=5))
     assert train_step_flops(cut, 1) == pytest.approx(41.12e12, rel=2e-3)
-
-
-def test_presets_lists_the_share(capsys):
-    assert main(["presets"]) == 0
-    line = [ln for ln in capsys.readouterr().out.splitlines()
-            if ln.startswith("trinity-large")][0]
-    assert "experts=8/256 held" in line and "vocab=25024" in line
 
 
 def test_full_remat_keeps_a_sparse_layers_routing_choices():
